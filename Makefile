@@ -5,11 +5,12 @@ PYTEST_ENV = XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cp
 
 .PHONY: test test-fast lint check check-update chaos soak scope meter \
         fleet spec zero route wire scale quant dryrun bench bench-cpu \
+        chip-smoke \
         store trace life clean
 
 # graftlint: AST-only jit-hygiene gate (no jax import, milliseconds).
-# Exit 1 on any non-baselined finding; the tier-1 suite and
-# benchmarks/on_grant.sh enforce the same gate.
+# Exit 1 on any non-baselined finding; the tier-1 suite enforces the
+# same gate.
 lint:
 	python -m pytorch_multiprocessing_distributed_tpu.analysis.lint
 
@@ -19,7 +20,7 @@ lint:
 # FLOPs, bytes accessed, argument/output/temp HBM per canonical
 # program), all in ONE pass (traces/compiles on the 8-device CPU
 # mesh; never executes). Exit 1 on any drift; enforced in tier-1
-# (tests/test_graftcheck.py) and on_grant.sh step 0.
+# (tests/test_graftcheck.py).
 check:
 	$(PYTEST_ENV) python -m pytorch_multiprocessing_distributed_tpu.analysis.check
 
@@ -179,13 +180,17 @@ dryrun:
 	python -c "import jax; jax.config.update('jax_platforms','cpu'); \
 	import __graft_entry__ as g; g.dryrun_multichip(8); print('dryrun OK')"
 
-# one-JSON-line benchmark (probes the TPU, falls back to CPU liveness)
+# one-JSON-line benchmark; fails (non-zero) without a TPU
 bench:
 	python bench.py
 
-# bench without touching the TPU plugin at all
+# rehearse the bench's control flow on the CPU: no device metric
 bench-cpu:
 	python bench.py --platform cpu
+
+# the standing chip check (one TPU; `--chips 4` = the DP phase only)
+chip-smoke:
+	python chip_smoke.py
 
 # the C++ TCP rendezvous store (ctypes-loaded on demand at runtime)
 store:

@@ -26,9 +26,11 @@ def timeit(fn, args, min_window=0.5):
 
 
 def enable_compile_cache() -> None:
-    """Persistent XLA executable cache (~/.cache/pmdt_xla): on a short
-    chip grant, the first script pays each compile once and every later
-    harness invocation reuses it. PMDT_XLA_CACHE=off disables."""
+    """Persistent XLA executable cache, where ``utils.compile_cache``
+    places it (``JAX_COMPILATION_CACHE_DIR``, else the checkout's
+    ``.jax_cache``): the first script pays each TPU compile once and
+    every later harness invocation reuses it. The platform itself is
+    jax's own business (``JAX_PLATFORMS``)."""
     import sys
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -37,29 +39,3 @@ def enable_compile_cache() -> None:
         enable_compilation_cache)
 
     enable_compilation_cache()
-
-
-def apply_platform_env() -> None:
-    """Force ``JAX_PLATFORMS`` through ``jax.config`` before the first
-    device query.
-
-    In a fresh interpreter JAX honors the env var natively and this is
-    a no-op. It exists because some PJRT plugin environments initialize
-    their platform regardless of ``JAX_PLATFORMS`` once the backend
-    comes up (bench.py's ``init_devices`` documents the same behavior),
-    and a sick accelerator then hangs the whole script at the first
-    ``jax.devices()``. Setting the config before any backend init is
-    the reliable selector either way.
-
-    (``decode_bench.py`` deliberately does not call this: it never
-    imports jax — decode is pure PIL/numpy — so no backend can
-    initialize.)
-    """
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        import jax
-
-        jax.config.update("jax_platforms", plat)
-    # every jax-using benchmark script also gets the persistent compile
-    # cache — on a short chip grant the scripts share compiled programs
-    enable_compile_cache()

@@ -82,7 +82,7 @@ def bench_ring(mesh, size_bytes: int, iters: int = 20) -> dict:
 
 
 def main():
-    _common.apply_platform_env()
+    _common.enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--sizes-mb", nargs="+", type=float, default=[1, 16, 64])
     p.add_argument("--iters", type=int, default=20)

@@ -185,7 +185,7 @@ def run_torch(args, loaders, init_export):
 
 
 def main():
-    _common.apply_platform_env()
+    _common.enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--epochs", default=5, type=int)
     p.add_argument("--batch_size", default=64, type=int)

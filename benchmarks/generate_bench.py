@@ -23,7 +23,7 @@ from benchmarks._common import timeit  # noqa: E402
 
 
 def main():
-    _common.apply_platform_env()
+    _common.enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--model", default="gpt_small")
     p.add_argument("--batch", default=8, type=int)

@@ -130,7 +130,7 @@ def run_torch(args, sd, tokens):
 
 
 def main():
-    _common.apply_platform_env()
+    _common.enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--epochs", default=3, type=int)
     p.add_argument("--batch_size", default=8, type=int)

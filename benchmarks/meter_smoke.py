@@ -161,7 +161,7 @@ def run(out_dir: str) -> dict:
 
 
 def main(argv=None):
-    _common.apply_platform_env()
+    _common.enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--out_dir", default="/tmp/pmdt_meter_smoke",
                    help="artifact directory (hbm_breakdown.png)")

@@ -94,7 +94,7 @@ def main():
     p.add_argument("--top", type=int, default=30)
     p.add_argument("--trace_dir", default="")
     args = p.parse_args()
-    _common.apply_platform_env()
+    _common.enable_compile_cache()
 
     import tempfile
 

@@ -29,7 +29,7 @@ from pytorch_multiprocessing_distributed_tpu.parallel.ring_attention import (
 
 
 def main():
-    _common.apply_platform_env()
+    _common.enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--dtype", default="bfloat16",
                    choices=["float32", "bfloat16"])

@@ -27,7 +27,8 @@ serves), then asserts:
 
 Exit code 0 and one ``graftroute smoke OK`` line = the fleet serving
 stack is wired. Run: ``python benchmarks/route_smoke.py``
-(CPU-runnable; tiny model, seconds).
+(one process, in-process replicas — no child ever needs a device;
+tiny model, seconds).
 """
 
 import argparse

@@ -32,8 +32,9 @@ Asserted end to end:
 
 Exit code 0 and one ``graftscale smoke OK`` line = the elastic fleet
 is deployable. Run: ``python benchmarks/scale_smoke.py``
-(CPU-runnable; tiny model, a few minutes — each subprocess pays the
-jax import).
+(A CPU-mesh rehearsal — ``ProcessReplicaSpawner`` refuses to start
+children from a parent that holds a TPU; tiny model, a few minutes —
+each subprocess pays the jax import).
 """
 
 import argparse

@@ -138,7 +138,7 @@ def run(out_dir: str) -> dict:
 
 
 def main(argv=None):
-    _common.apply_platform_env()
+    _common.enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--out_dir", default="/tmp/pmdt_scope_smoke",
                    help="artifact directory (trace/jsonl/prom)")

@@ -135,8 +135,9 @@ Three sweeps over the continuous-batching :class:`ServingEngine`:
     asserted away — int8 KV is not token-exact by construction).
 
 ``offered=inf`` is the closed-loop limit: every request submitted
-up front, measuring peak engine throughput. CPU-runnable (shapes clamp
-down off-TPU, same convention as ``generate_bench.py``), TPU-ready.
+up front, measuring peak engine throughput. Needs a TPU; ``--platform
+cpu`` is the explicit rehearsal mode (gpt_tiny, float32, clamped shapes
+— counts and correctness, never a speed).
 ``--json_out`` records every point (plus the compiled window set per
 engine) for the round's evidence JSON.
 
@@ -1680,7 +1681,6 @@ def run_autoscale_sweep(model, params, args, rng):
 
 
 def main():
-    _common.apply_platform_env()
     p = argparse.ArgumentParser()
     p.add_argument("--model", default="gpt_small")
     p.add_argument("--requests", default=32, type=int)
@@ -1729,15 +1729,23 @@ def main():
                    help="record every sweep point as JSON")
     p.add_argument("--dtype", default="bfloat16",
                    choices=["float32", "bfloat16"])
+    p.add_argument("--platform", default="tpu", choices=["tpu", "cpu"],
+                   help="cpu = rehearsal mode: gpt_tiny, float32 and "
+                        "capped sizes on the host platform — counts and "
+                        "correctness, never a speed. The default fails "
+                        "without a TPU")
     args = p.parse_args()
 
     from pytorch_multiprocessing_distributed_tpu import models
     from pytorch_multiprocessing_distributed_tpu.serving import (
         init_params)
 
-    platform = jax.devices()[0].platform
+    import bench
+
+    platform = bench.require_platform(args.platform)[0].platform
+    _common.enable_compile_cache()
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
-    if platform != "tpu":
+    if platform == "cpu":
         args.model = "gpt_tiny"
         args.requests = min(args.requests, 8)
         args.prompt_max = min(args.prompt_max, 24)

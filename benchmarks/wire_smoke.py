@@ -30,7 +30,8 @@ router in THIS process:
 
 Exit code 0 and one ``graftwire smoke OK`` line = the wire transport
 stack is deployable. Run: ``python benchmarks/wire_smoke.py``
-(CPU-runnable; tiny model, ~2 min — subprocesses pay the jax import).
+(A CPU-mesh rehearsal — it refuses to start children from a parent
+that holds a TPU; tiny model, ~2 min — subprocesses pay the jax import).
 """
 
 import argparse
@@ -106,6 +107,10 @@ def serve_replica(args) -> int:
 # -------------------------------------------------------------- parent
 
 def _spawn(tmpdir, rid, role, journal=None):
+    from pytorch_multiprocessing_distributed_tpu.serving.autoscale import (
+        refuse_children_beside_a_tpu)
+
+    refuse_children_beside_a_tpu(f"graftwire smoke replica {rid!r}")
     addr_file = os.path.join(tmpdir, f"addr_{rid}")
     cmd = [sys.executable, os.path.abspath(__file__),
            "--serve_replica", "--rid", rid, "--role", role,

@@ -181,13 +181,14 @@ def main(args):
         hbm.arm()
     # Backend selection must happen before device queries.
     from pytorch_multiprocessing_distributed_tpu.utils.hostenv import (
-        force_cpu_devices_from_env)
+        announce_done, announce_run, force_cpu_devices_from_env)
 
     force_cpu_devices_from_env()
     from pytorch_multiprocessing_distributed_tpu.utils.compile_cache import (
-        enable_compilation_cache)
+        CompileLog, enable_compilation_cache)
 
-    enable_compilation_cache()
+    cache_dir = enable_compilation_cache()
+    compile_log = CompileLog()
 
     import jax
     import jax.numpy as jnp
@@ -258,6 +259,8 @@ def main(args):
         )
 
     dist.init_process()
+    if dist.is_primary():
+        announce_run(cache_dir)
 
     mesh = make_mesh(args.world_size, args.model_parallel)
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
@@ -511,6 +514,7 @@ def main(args):
 
     if dist.is_primary():
         graftscope.export_from_args(args)
+        announce_done(compile_log)
     if stats_server is not None:
         if health is not None:
             health.to_dead("run complete")

@@ -44,14 +44,9 @@ import re
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
-try:  # the ClosedJaxpr/Jaxpr types moved around across 0.4.x
-    _JAXPR_TYPES = (jax_core.Jaxpr, jax_core.ClosedJaxpr)
-except AttributeError:  # pragma: no cover - much older jax
-    from jax._src import core as jax_core  # type: ignore
-
-    _JAXPR_TYPES = (jax_core.Jaxpr, jax_core.ClosedJaxpr)
+_JAXPR_TYPES = (jax_core.Jaxpr, jax_core.ClosedJaxpr)
 
 
 # collective primitives whose presence/size IS the communication budget

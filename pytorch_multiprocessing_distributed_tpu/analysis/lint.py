@@ -2,8 +2,7 @@
 
 Runs the AST rule engine (:mod:`.rules`) over the package (or explicit
 paths), applies per-line suppressions and the committed baseline, and
-exits non-zero on any live finding — the tier-1 gate and
-``benchmarks/on_grant.sh`` both call this.
+exits non-zero on any live finding — the tier-1 gate calls this.
 
 Deliberately jax-free: the gate costs milliseconds of ``ast.parse``,
 never a backend bring-up, so it runs first in every pipeline.

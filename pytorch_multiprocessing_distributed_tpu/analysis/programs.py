@@ -9,7 +9,7 @@ Each hook returns specs of the shape::
         "fn": callable,            # the program (jitted or plain)
         "args": tuple,             # abstract (ShapeDtypeStruct) inputs
         "kwargs": dict,            # jit-static kwargs (closed over)
-        "mesh": Mesh | None,       # entered (compat.set_mesh) around
+        "mesh": Mesh | None,       # entered (jax.set_mesh) around
                                    # trace/lower/compile
         "lower_fn": jit fn | None, # enables the donation audit
         "compile": bool,           # enables the HLO collective audit
@@ -51,7 +51,6 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 
-from ..utils.compat import set_mesh
 from . import ir
 
 # rule table (GC1xx — program-level, disjoint from graftlint's GL1xx)
@@ -155,7 +154,7 @@ def collect(names: Optional[Sequence[str]] = None) -> List[ProgramSpec]:
 
 
 def _mesh_ctx(mesh):
-    return set_mesh(mesh) if mesh is not None else contextlib.nullcontext()
+    return jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext()
 
 
 def audit_program(spec: ProgramSpec

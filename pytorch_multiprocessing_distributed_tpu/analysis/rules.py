@@ -89,8 +89,8 @@ RULES: Dict[str, str] = {
              "GL103's)",
     "GL113": "profiler misuse: jax.profiler.start_trace with no "
              "reachable stop_trace (an unstopped trace buffers "
-             "forever and the .xplane.pb never flushes — the grant "
-             "window ends with NO artifact), or profiler trace "
+             "forever and the .xplane.pb never flushes — the run "
+             "ends with NO artifact), or profiler trace "
              "control (utils.profiler.trace / jax.profiler.start_"
              "trace) inside jit-traced code (runs once at trace "
              "time; the profiled region is a lie)",
@@ -205,7 +205,7 @@ _JIT_DOTTED = {
 }
 # wrappers that TRACE their function argument(s)
 _TRACE_DOTTED = _JIT_DOTTED | {
-    "jax.shard_map", "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
     "jax.pmap", "jax.vmap", "jax.grad", "jax.value_and_grad",
     "jax.checkpoint", "jax.remat",
     "jax.lax.scan", "jax.lax.cond", "jax.lax.while_loop",
@@ -437,8 +437,7 @@ def _collect_file(path: str, src: str, modkey: Tuple[str, ...]) -> _File:
 def _is_trace_wrapper(dotted: Optional[str]) -> bool:
     if not dotted:
         return False
-    return (dotted in _TRACE_DOTTED
-            or dotted.endswith(".compat.shard_map"))
+    return dotted in _TRACE_DOTTED
 
 
 def _is_jit(dotted: Optional[str]) -> bool:
@@ -1190,7 +1189,7 @@ def _check_unpaired_trace(file: _File, out: List[Finding]):
     or a sibling wrapper method all count): the bug class this catches
     is the stop being FORGOTTEN entirely, which leaves the trace
     buffering until process exit and never flushes an .xplane.pb —
-    a whole grant window's profiling silently lost. Starts inside
+    a whole run's profiling silently lost. Starts inside
     jit-traced scope are the trace-time-misuse half's (skipped here
     so one line never double-reports)."""
     stop_seen = False

@@ -36,8 +36,6 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
-from ..utils.compat import get_abstract_mesh
-
 
 class MoEMlp(nn.Module):
     """Switch-style top-1 MoE feed-forward block.
@@ -173,10 +171,8 @@ class MoEMlp(nn.Module):
     def _constrain(self, t):
         if self.expert_axis is None or self.is_initializing():
             return t
-        mesh = get_abstract_mesh()
-        if mesh is None or self.expert_axis not in getattr(
-            mesh, "axis_names", ()
-        ):
+        mesh = jax.sharding.get_abstract_mesh()
+        if self.expert_axis not in mesh.axis_names:
             # no mesh context (e.g. plain CPU apply in tests): the
             # constraint is a layout hint, not semantics — skip it
             return t

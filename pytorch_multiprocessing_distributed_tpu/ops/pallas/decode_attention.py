@@ -10,11 +10,13 @@ unnormalized accumulator — the same recurrence as
 :mod:`.flash_attention`, degenerate q-block of 1), so HBM traffic is
 the single K/V read the step fundamentally owes.
 
-Per-slot positions ride in SMEM: block ``kb`` is folded only when
-``kb * block_k <= position`` — a slot at position p pays for
-``ceil((p+1)/block_k)`` blocks, not ``S/block_k``, which is what makes
-the engine's length-bucketed window *and* this kernel compose (the
-bucket bounds the grid, the position gate bounds the work inside it).
+Per-slot positions ride in SMEM (the whole ``[B]`` vector, scalar-
+prefetched — the TPU lowering has no one-element SMEM block): block
+``kb`` is folded only when ``kb * block_k <= position`` — a slot at
+position p pays for ``ceil((p+1)/block_k)`` blocks, not ``S/block_k``,
+which is what makes the engine's length-bucketed window *and* this
+kernel compose (the bucket bounds the grid, the position gate bounds
+the work inside it).
 
 Matmuls stay in the input dtype (bf16 hits the MXU's native rate),
 accumulation is f32, outputs are f32 (the engine casts back to model
@@ -23,8 +25,10 @@ dtype after the residual add, matching the XLA path's dtypes exactly).
 **graftquant**: every kernel (and every XLA reference) also takes the
 KV operand as a :class:`...kv_quant.QuantizedKV` pair — int8 data plus
 a per-(token, head) f32 scale streamed beside it (dense: a ``[B*H,
-S]`` row per block; paged: the ``[ps]`` sidecar of the SAME page the
-scalar-prefetched table steers in). The dequant is ONE multiply in the
+1, S]`` row per block; paged: the ``[ps]`` sidecar of the SAME page the
+scalar-prefetched table steers in, viewed ``[P, H, 1, ps]`` — the
+size-1 axis is what makes a one-row scale block legal on the TPU). The
+dequant is ONE multiply in the
 VMEM stream, applied before the existing MXU dot — so the decode step's
 dominant HBM bytes term (the K/V read) halves while the matmul dtype
 and f32 accumulation stay exactly as above. The XLA fallbacks dequant
@@ -59,6 +63,17 @@ __all__ = ["decode_attention", "paged_decode_attention",
            "xla_paged_verify_decode_attention"]
 
 
+def _paged_scale_spec(page_size, heads):
+    """Block of one (page, head) row of the ``[P, H, 1, ps]`` scale
+    side-car view, steered by the same scalar-prefetched table as its
+    page. The size-1 axis keeps the block's trailing two dims equal to
+    the array's — a ``(1, ps)`` block of ``[H, ps]`` is not a legal TPU
+    block."""
+    return pl.BlockSpec(
+        (1, 1, 1, page_size),
+        lambda i, kb, pos, tab: (tab[i // heads, kb], i % heads, 0, 0))
+
+
 def _kernel_dequant(blk, scale_row, dtype):
     """graftquant's ONE in-kernel dequant expression: int8 lanes times
     the per-(token, head) f32 scale, cast to the MXU compute dtype —
@@ -69,15 +84,18 @@ def _kernel_dequant(blk, scale_row, dtype):
 
 
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale, block_k,
-                   quant):
+                   heads, quant):
     """One (slot*head, k-block) grid cell; k is the innermost axis so
     the softmax state lives in VMEM scratch across the K/V stream.
-    ``quant`` (static) inserts two scale refs after v_ref and dequants
-    each K/V block in the VMEM stream before the dot."""
+    ``pos_ref`` is the whole scalar-prefetched ``[B]`` positions vector
+    in SMEM (a per-row SMEM block of one element is not a legal TPU
+    block). ``quant`` (static) inserts two scale refs after v_ref and
+    dequants each K/V block in the VMEM stream before the dot."""
     if quant:
         ks_ref, vs_ref, o_ref, acc, m_scr, l_scr = rest
     else:
         o_ref, acc, m_scr, l_scr = rest
+    i = pl.program_id(0)
     kb = pl.program_id(1)
     n_k = pl.num_programs(1)
 
@@ -87,7 +105,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale, block_k,
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    pos = pos_ref[0]
+    pos = pos_ref[i // heads]
 
     # whole block beyond the slot's position -> nothing to fold (this,
     # not the grid, is what makes cost track each slot's true length)
@@ -97,8 +115,8 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale, block_k,
         kblk = k_ref[0]       # [bk, d]
         vblk = v_ref[0]
         if quant:
-            kblk = _kernel_dequant(kblk, ks_ref[0], q.dtype)
-            vblk = _kernel_dequant(vblk, vs_ref[0], q.dtype)
+            kblk = _kernel_dequant(kblk, ks_ref[0, 0], q.dtype)
+            vblk = _kernel_dequant(vblk, vs_ref[0, 0], q.dtype)
         s = jnp.dot(q, kblk.T,
                     preferred_element_type=jnp.float32) * scale  # [1, bk]
         col = kb * block_k + jax.lax.broadcasted_iota(
@@ -142,49 +160,41 @@ def _pallas_decode(q, k, v, positions, scale, block_k, interpret,
     def merge(x):  # [B, S, H, Dh] -> [B*H, S, Dh]
         return jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1], d)
 
-    def merge_scale(x):  # [B, S, H] -> [B*H, S]
-        return jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1])
+    def merge_scale(x):  # [B, S, H] -> [B*H, 1, S]
+        return jnp.moveaxis(x, 2, 1).reshape(b * h, 1, x.shape[1])
 
     q3 = merge(q)                      # [B*H, 1, Dh]
     k3, v3 = merge(k), merge(v)
-    # one position scalar per (slot, head) row program
-    pos_bh = jnp.repeat(positions.astype(jnp.int32), h)
 
     in_specs = [
-        pl.BlockSpec((1,), lambda i, kb: (i,),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, 1, d), lambda i, kb: (i, 0, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda i, kb: (i, kb, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda i, kb: (i, kb, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, 1, d), lambda i, kb, pos: (i, 0, 0)),
+        pl.BlockSpec((1, block_k, d), lambda i, kb, pos: (i, kb, 0)),
+        pl.BlockSpec((1, block_k, d), lambda i, kb, pos: (i, kb, 0)),
     ]
-    operands = [pos_bh, q3, k3, v3]
+    operands = [q3, k3, v3]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, block_k), lambda i, kb: (i, kb),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k), lambda i, kb: (i, kb),
-                         memory_space=pltpu.VMEM),
-        ]
+        in_specs += [pl.BlockSpec((1, 1, block_k),
+                                  lambda i, kb, pos: (i, 0, kb))] * 2
         operands += [merge_scale(k_scale), merge_scale(v_scale)]
 
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, block_k=block_k,
-                          quant=quant),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # positions
         grid=(b * h, n_k),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, d), lambda i, kb: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b * h, 1, d), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, d), lambda i, kb, pos: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, d), jnp.float32),   # output accumulator
             pltpu.VMEM((1, 1), jnp.float32),   # running max
             pltpu.VMEM((1, 1), jnp.float32),   # running denominator
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, block_k=block_k,
+                          heads=h, quant=quant),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * h, 1, d), jnp.float32),
         interpret=interpret,
-    )(*operands)
+    )(positions.astype(jnp.int32), *operands)
     return jnp.moveaxis(out.reshape(b, h, 1, d), 1, 2)  # [B, 1, H, Dh]
 
 
@@ -224,8 +234,8 @@ def _paged_decode_kernel(pos_ref, tab_ref, q_ref, k_ref, v_ref, *rest,
         kblk = k_ref[0, 0]       # [ps, d]
         vblk = v_ref[0, 0]
         if quant:
-            kblk = _kernel_dequant(kblk, ks_ref[0, 0], q.dtype)
-            vblk = _kernel_dequant(vblk, vs_ref[0, 0], q.dtype)
+            kblk = _kernel_dequant(kblk, ks_ref[0, 0, 0], q.dtype)
+            vblk = _kernel_dequant(vblk, vs_ref[0, 0, 0], q.dtype)
         s = jnp.dot(q, kblk.T,
                     preferred_element_type=jnp.float32) * scale
         col = kb * page_size + jax.lax.broadcasted_iota(
@@ -271,15 +281,8 @@ def _pallas_paged_decode(q, k_pages, v_pages, page_table, positions,
     ]
     operands = [q3, k_pages, v_pages]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1, ps),
-                         lambda i, kb, pos, tab:
-                         (tab[i // h, kb], i % h, 0)),
-            pl.BlockSpec((1, 1, ps),
-                         lambda i, kb, pos, tab:
-                         (tab[i // h, kb], i % h, 0)),
-        ]
-        operands += [k_scale, v_scale]
+        in_specs += [_paged_scale_spec(ps, h)] * 2
+        operands += [k_scale[:, :, None], v_scale[:, :, None]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # positions, page table
         grid=(b * h, n_win),
@@ -494,9 +497,10 @@ def decode_attention(
 
 
 def _verify_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale, block_k,
-                   k1, quant):
+                   heads, k1, quant):
     """One (slot*head, k-block) grid cell; the softmax state is [K1]
-    rows of the same online recurrence as :func:`_decode_kernel`.
+    rows of the same online recurrence as :func:`_decode_kernel`
+    (``pos_ref``: the scalar-prefetched ``[B]`` positions, as there).
     ``quant`` (static): dequant each K/V block in-stream — the verify
     pass reads the SAME quantized pages one decode step reads, so
     spec-decode bandwidth halves with it."""
@@ -504,6 +508,7 @@ def _verify_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale, block_k,
         ks_ref, vs_ref, o_ref, acc, m_scr, l_scr = rest
     else:
         o_ref, acc, m_scr, l_scr = rest
+    i = pl.program_id(0)
     kb = pl.program_id(1)
     n_k = pl.num_programs(1)
 
@@ -513,7 +518,7 @@ def _verify_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale, block_k,
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    pos = pos_ref[0]
+    pos = pos_ref[i // heads]
 
     # the block matters to SOME query row iff its first column is
     # within the last row's reach (pos + k1 - 1); per-row masking
@@ -524,8 +529,8 @@ def _verify_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale, block_k,
         kblk = k_ref[0]       # [bk, d]
         vblk = v_ref[0]
         if quant:
-            kblk = _kernel_dequant(kblk, ks_ref[0], q.dtype)
-            vblk = _kernel_dequant(vblk, vs_ref[0], q.dtype)
+            kblk = _kernel_dequant(kblk, ks_ref[0, 0], q.dtype)
+            vblk = _kernel_dequant(vblk, vs_ref[0, 0], q.dtype)
         s = jnp.dot(q, kblk.T,
                     preferred_element_type=jnp.float32) * scale  # [K1, bk]
         col = kb * block_k + jax.lax.broadcasted_iota(
@@ -568,48 +573,41 @@ def _pallas_verify(q, k, v, positions, scale, block_k, interpret,
     def merge(x):  # [B, S, H, Dh] -> [B*H, S, Dh]
         return jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1], d)
 
-    def merge_scale(x):  # [B, S, H] -> [B*H, S]
-        return jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1])
+    def merge_scale(x):  # [B, S, H] -> [B*H, 1, S]
+        return jnp.moveaxis(x, 2, 1).reshape(b * h, 1, x.shape[1])
 
     q3 = merge(q)                      # [B*H, K1, Dh]
     k3, v3 = merge(k), merge(v)
-    pos_bh = jnp.repeat(positions.astype(jnp.int32), h)
 
     in_specs = [
-        pl.BlockSpec((1,), lambda i, kb: (i,),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, k1, d), lambda i, kb: (i, 0, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda i, kb: (i, kb, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda i, kb: (i, kb, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, k1, d), lambda i, kb, pos: (i, 0, 0)),
+        pl.BlockSpec((1, block_k, d), lambda i, kb, pos: (i, kb, 0)),
+        pl.BlockSpec((1, block_k, d), lambda i, kb, pos: (i, kb, 0)),
     ]
-    operands = [pos_bh, q3, k3, v3]
+    operands = [q3, k3, v3]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, block_k), lambda i, kb: (i, kb),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k), lambda i, kb: (i, kb),
-                         memory_space=pltpu.VMEM),
-        ]
+        in_specs += [pl.BlockSpec((1, 1, block_k),
+                                  lambda i, kb, pos: (i, 0, kb))] * 2
         operands += [merge_scale(k_scale), merge_scale(v_scale)]
 
-    out = pl.pallas_call(
-        functools.partial(_verify_kernel, scale=scale, block_k=block_k,
-                          k1=k1, quant=quant),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # positions
         grid=(b * h, n_k),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, k1, d), lambda i, kb: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b * h, k1, d), jnp.float32),
+        out_specs=pl.BlockSpec((1, k1, d), lambda i, kb, pos: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((k1, d), jnp.float32),   # output accumulator
             pltpu.VMEM((k1, 1), jnp.float32),   # running max
             pltpu.VMEM((k1, 1), jnp.float32),   # running denominator
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_verify_kernel, scale=scale, block_k=block_k,
+                          heads=h, k1=k1, quant=quant),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * h, k1, d), jnp.float32),
         interpret=interpret,
-    )(*operands)
+    )(positions.astype(jnp.int32), *operands)
     return jnp.moveaxis(out.reshape(b, h, k1, d), 1, 2)  # [B, K1, H, Dh]
 
 
@@ -641,8 +639,8 @@ def _paged_verify_kernel(pos_ref, tab_ref, q_ref, k_ref, v_ref, *rest,
         kblk = k_ref[0, 0]       # [ps, d]
         vblk = v_ref[0, 0]
         if quant:
-            kblk = _kernel_dequant(kblk, ks_ref[0, 0], q.dtype)
-            vblk = _kernel_dequant(vblk, vs_ref[0, 0], q.dtype)
+            kblk = _kernel_dequant(kblk, ks_ref[0, 0, 0], q.dtype)
+            vblk = _kernel_dequant(vblk, vs_ref[0, 0, 0], q.dtype)
         s = jnp.dot(q, kblk.T,
                     preferred_element_type=jnp.float32) * scale
         col = kb * page_size + jax.lax.broadcasted_iota(
@@ -687,15 +685,8 @@ def _pallas_paged_verify(q, k_pages, v_pages, page_table, positions,
     ]
     operands = [q3, k_pages, v_pages]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1, ps),
-                         lambda i, kb, pos, tab:
-                         (tab[i // h, kb], i % h, 0)),
-            pl.BlockSpec((1, 1, ps),
-                         lambda i, kb, pos, tab:
-                         (tab[i // h, kb], i % h, 0)),
-        ]
-        operands += [k_scale, v_scale]
+        in_specs += [_paged_scale_spec(ps, h)] * 2
+        operands += [k_scale[:, :, None], v_scale[:, :, None]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # positions, page table
         grid=(b * h, n_win),

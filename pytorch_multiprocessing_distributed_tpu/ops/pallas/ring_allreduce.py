@@ -39,24 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...utils.compat import axis_size
-
-
-def _compiler_params(collective_id: int):
-    """Mosaic compiler params across the TPUCompilerParams ->
-    CompilerParams rename, passing only the fields this jax knows
-    (``has_side_effects`` predates some 0.4.x builds; without it the
-    test-visible semantics are unchanged — the output is consumed, so
-    the RDMA ops are not DCE'd)."""
-    import dataclasses
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    fields = {f.name for f in dataclasses.fields(cls)}
-    kw = {"collective_id": collective_id}
-    if "has_side_effects" in fields:
-        kw["has_side_effects"] = True
-    return cls(**kw)
-
 _LANE = 128
 
 
@@ -66,7 +48,7 @@ def _ring_kernel(x_ref, o_ref, comm, send_sem, recv_sem, ack_sem, *,
     execution makes the barrier/credit protocol unnecessary (and remote
     ``semaphore_signal`` is not implemented there)."""
     my = jax.lax.axis_index(axis_name)
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     right = jax.lax.rem(my + 1, n)
     left = jax.lax.rem(my + n - 1, n)
     chunk = x_ref.shape[0] // n  # rows per chunk (pre-padded by caller)
@@ -166,7 +148,7 @@ def ring_all_reduce(
         from . import default_interpret
 
         interpret = default_interpret()
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
 
@@ -196,7 +178,8 @@ def ring_all_reduce(
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.REGULAR((2,)),
         ],
-        compiler_params=_compiler_params(collective_id),
+        compiler_params=pltpu.CompilerParams(
+            collective_id=collective_id, has_side_effects=True),
         interpret=interpret,
     )(x2)
     return out.reshape(-1)[:size].reshape(orig_shape).astype(orig_dtype)

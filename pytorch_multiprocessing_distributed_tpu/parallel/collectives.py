@@ -26,11 +26,11 @@ from typing import Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..runtime import fleet as graftfleet
 from ..runtime import scope as graftscope
-from ..utils.compat import shard_map
 from .mesh import DATA_AXIS
 
 AxisName = Union[str, Sequence[str]]

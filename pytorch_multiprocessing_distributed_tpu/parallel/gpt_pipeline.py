@@ -40,9 +40,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..utils.compat import shard_map
 from .mesh import DATA_AXIS
 
 # NB: ..train imports stay function-local — parallel/__init__ re-exports

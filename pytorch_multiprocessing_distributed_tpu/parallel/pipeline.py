@@ -38,24 +38,23 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from ..utils.compat import pcast, typeof
 
 
 def _vary(x, axis_name):
     """Mark ``x`` device-varying over ``axis_name`` if it isn't already
     (check_vma bookkeeping for values entering the per-shard schedule)."""
-    if axis_name in getattr(typeof(x), "vma", frozenset()):
+    if axis_name in jax.typeof(x).vma:
         return x
-    return pcast(x, axis_name, to="varying")
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def _match_vma(x, vma_of):
     """Widen ``x``'s device-varying axes to ``vma_of``'s (cotangents
     must carry the exact vma of the output they seed)."""
-    want = getattr(typeof(vma_of), "vma", frozenset())
-    have = getattr(typeof(x), "vma", frozenset())
+    want = jax.typeof(vma_of).vma
+    have = jax.typeof(x).vma
     for ax in want - have:
-        x = pcast(x, ax, to="varying")
+        x = jax.lax.pcast(x, ax, to="varying")
     return x
 
 

@@ -319,7 +319,7 @@ def comm_probe(plan: ZeroPlan, mesh: Mesh,
     standalone grad-comm wall — the denominator of the overlap
     fraction. Takes a list of ``[padded]`` arrays (one per bucket,
     replicated) and returns the gathered buckets."""
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     def body(flats):
         shards = []

@@ -173,10 +173,30 @@ class EngineReplicaSpawner:
         pass
 
 
+def refuse_children_beside_a_tpu(what: str) -> None:
+    """A chip belongs to one process: a parent that has initialized
+    jax on a TPU holds it, and a replica child that needs it would
+    fail or hang. Until replicas share a host's chips from ONE process
+    (ROADMAP B2) the process fleet is a CPU-mesh rehearsal — so this
+    refuses, loudly, instead of hanging. A parent that never imported
+    jax holds no chip and may spawn."""
+    import sys
+
+    jax = sys.modules.get("jax")
+    if jax is not None and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{what}: this process holds the TPU, so a replica child "
+            "could never reach it (one process per chip). The process "
+            "fleet is a CPU-mesh rehearsal: run it with "
+            "JAX_PLATFORMS=cpu (PMDT_FORCE_CPU_DEVICES=N for a mesh)")
+
+
 class ProcessReplicaSpawner:
     """Subprocess spawn seam: each replica is a ``--listen``
     replica-server child, dialed through
     :class:`~.remote.RemoteReplica` once it publishes its address.
+    A CPU-mesh rehearsal for now: :meth:`spawn` refuses when this
+    process holds a TPU (:func:`refuse_children_beside_a_tpu`).
 
     Args:
       argv_for: ``argv_for(rid, role, model_tag, addr_file) ->
@@ -214,6 +234,7 @@ class ProcessReplicaSpawner:
               model_tag: Optional[str] = None) -> ServingReplica:
         from .remote import RemoteReplica
 
+        refuse_children_beside_a_tpu(f"spawning replica {rid!r}")
         addr_file = os.path.join(self.workdir, f"addr_{rid}")
         try:
             os.remove(addr_file)  # a retry must not read last

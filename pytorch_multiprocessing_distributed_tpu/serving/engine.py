@@ -1540,6 +1540,18 @@ class ServingEngine:
         return self._horizon_max
 
     @property
+    def decode_attn(self) -> str:
+        """The decode attention implementation in use — ``"pallas"`` or
+        ``"xla"``, ``"auto"`` already resolved from the platform."""
+        return self._attn_impl
+
+    @property
+    def donate_cache(self) -> bool:
+        """Whether the jitted programs donate the KV pool (every
+        backend but the CPU's, which lacks donation)."""
+        return self._donate_cache
+
+    @property
     def decode_buckets(self) -> Tuple[int, ...]:
         """The configured window ladder (ends at ``s_max``)."""
         return self._buckets

@@ -24,11 +24,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from flax.traverse_util import flatten_dict
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.losses import cross_entropy_per_sample
-from ..utils.compat import shard_map
 from ..parallel.mesh import DATA_AXIS
 from .optim import Transform, apply_updates
 from .state import TrainState

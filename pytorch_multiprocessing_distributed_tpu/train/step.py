@@ -27,11 +27,11 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.losses import cross_entropy_loss, cross_entropy_per_sample
 from ..runtime import hbm
-from ..utils.compat import shard_map
 from ..utils.metrics import topk_accuracy
 from ..parallel.mesh import DATA_AXIS, MODEL_AXIS
 from .optim import Transform, apply_updates

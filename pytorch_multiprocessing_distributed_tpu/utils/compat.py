@@ -1,126 +1,33 @@
-"""jax version-skew shims.
+"""XLA's per-program analyses as plain dicts.
 
-The framework is written against the current jax surface; deployment
-containers lag. The one skew that matters today: ``jax.shard_map``
-moved to the top level (with ``check_vma``) after 0.4.x, where it
-lives at ``jax.experimental.shard_map.shard_map`` (with the same
-semantics under the name ``check_rep``). Every package call site
-imports :func:`shard_map` from here; the test suite (which calls
-``jax.shard_map`` directly, matching current-jax idiom) gets the alias
-installed by the root conftest via :func:`install_shard_map_alias`.
-
-Keyword mapping: ``check_vma`` (new name) -> ``check_rep`` (old name).
-Positional use is ``shard_map(f, mesh=..., in_specs=..., out_specs=...)``
-— both jax generations accept the keyword form this module enforces.
+The package is written against the one installed jax (0.9.0) and calls
+its surface directly (``jax.shard_map``, ``jax.lax.pcast``,
+``jax.set_mesh`` ...). What stays here is the shape the benchmark, the
+graftmeter budgets and the auditor share for a compiled program's cost
+and memory models.
 """
 
 from __future__ import annotations
 
-import jax
-
-HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
-
-# vma (varying-manual-axes) type tracking: the shard_map generation
-# whose check_vma machinery (jax.typeof().vma, jax.lax.pcast) can PROVE
-# replication invariants through collective AD. 0.4.x check_rep cannot
-# — the pipelined GPT trainer requires this and skips cleanly without.
-HAS_VMA = hasattr(jax.lax, "pcast")
-
-if HAS_NATIVE_SHARD_MAP:
-    _impl = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:
-    from jax.experimental.shard_map import shard_map as _impl  # type: ignore
-
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-    """``jax.shard_map`` on every supported jax.
-
-    ``check_vma=None`` defers to the backend's default (True on both
-    generations); an explicit bool is forwarded under whichever keyword
-    this jax spells it.
-    """
-    if check_vma is not None:
-        kw[_CHECK_KW] = check_vma
-    return _impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                 **kw)
-
-
-def install_shard_map_alias():
-    """Make ``jax.shard_map`` resolve on an old jax (no-op on a new
-    one). Additive only — never shadows a real ``jax.shard_map``."""
-    if not hasattr(jax, "shard_map"):
-        jax.shard_map = shard_map
-    return jax.shard_map
-
-
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` where it exists; the classic
-    ``psum(1, axis)`` identity elsewhere (a static Python int under
-    shard_map/pmap tracing — exactly what the new API returns)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def set_mesh(mesh):
-    """``jax.set_mesh(mesh)`` as a context manager on every jax: new
-    builds have it natively; on 0.4.x the ``Mesh`` object itself IS the
-    context manager that scopes named-axis resolution for jit."""
-    fn = getattr(jax, "set_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    return mesh
-
-
-def typeof(x):
-    """``jax.typeof`` (aval with vma tracking on new jax) or the plain
-    abstract value on 0.4.x — callers read optional attrs like ``vma``
-    with ``getattr(..., frozenset())`` so both work."""
-    fn = getattr(jax, "typeof", None)
-    if fn is not None:
-        return fn(x)
-    return jax.core.get_aval(x)
-
-
-def pcast(x, axis_name, *, to="varying"):
-    """``jax.lax.pcast`` on a jax with vma tracking; identity on 0.4.x
-    (check_rep-era shard_map has no varying-manual-axes type state to
-    cast between — replication bookkeeping is implicit there)."""
-    fn = getattr(jax.lax, "pcast", None)
-    if fn is not None:
-        return fn(x, axis_name, to=to)
-    return x
+_MEMORY_FIELDS = {
+    "argument_bytes": "argument_size_in_bytes",
+    "output_bytes": "output_size_in_bytes",
+    "temp_bytes": "temp_size_in_bytes",
+    "alias_bytes": "alias_size_in_bytes",
+    "generated_code_bytes": "generated_code_size_in_bytes",
+}
 
 
 def cost_analysis_dict(compiled):
-    """``compiled.cost_analysis()`` normalized to ONE plain dict across
-    jax generations: 0.4.x returns a per-device list of dicts (take
-    the first — SPMD programs are identical per device), newer jaxes
-    return the dict directly. None when the backend/executable exposes
-    no cost model (never raises — callers treat cost as optional)."""
-    try:
-        analyses = compiled.cost_analysis()
-    except Exception:  # noqa: BLE001  # graftlint: disable=GL111 cost model is optional; None IS the record
-        return None
-    if isinstance(analyses, (list, tuple)):
-        analyses = analyses[0] if analyses else None
-    if not analyses:
-        return None
-    try:
-        return dict(analyses)
-    except Exception:  # noqa: BLE001  # graftlint: disable=GL111 diagnostic-only surface
-        return None
+    """``compiled.cost_analysis()`` as a plain dict, or None when the
+    backend has no cost model for the executable (callers treat cost
+    as optional; an error from the call itself is a bug to see)."""
+    analyses = compiled.cost_analysis()
+    return dict(analyses) if analyses else None
 
 
 def memory_analysis_dict(compiled):
-    """``compiled.memory_analysis()`` normalized to ONE plain dict of
-    ints across jax generations: 0.4.x returns a per-device list (or a
-    bare ``CompiledMemoryStats``) of attribute objects, newer jaxes a
-    dict-like — either way the result is::
+    """``compiled.memory_analysis()`` as ONE plain dict of ints::
 
         {"argument_bytes", "output_bytes", "temp_bytes",
          "alias_bytes", "generated_code_bytes", "peak_bytes"}
@@ -129,51 +36,16 @@ def memory_analysis_dict(compiled):
     arguments + outputs + temporaries + generated code, minus the
     aliased (donated) bytes the outputs share with the arguments —
     the number a capacity plan charges per resident program. None when
-    the backend exposes no memory model (never raises — callers treat
-    memory as optional, like :func:`cost_analysis_dict`)."""
-    try:
-        stats = compiled.memory_analysis()
-    except Exception:  # noqa: BLE001  # graftlint: disable=GL111 memory model is optional; None IS the record
+    the backend exposes no memory model, or only part of one (a partial
+    memory model is not a budget)."""
+    stats = compiled.memory_analysis()
+    values = {key: getattr(stats, attr, None)
+              for key, attr in _MEMORY_FIELDS.items()}
+    if any(v is None for v in values.values()):
         return None
-    if isinstance(stats, (list, tuple)):
-        stats = stats[0] if stats else None
-    if stats is None:
-        return None
-    fields = {
-        "argument_bytes": "argument_size_in_bytes",
-        "output_bytes": "output_size_in_bytes",
-        "temp_bytes": "temp_size_in_bytes",
-        "alias_bytes": "alias_size_in_bytes",
-        "generated_code_bytes": "generated_code_size_in_bytes",
-    }
-    out = {}
-    for key, attr in fields.items():
-        v = getattr(stats, attr, None)
-        if v is None and isinstance(stats, dict):
-            v = stats.get(attr)
-        if v is None:
-            return None  # a partial memory model is not a budget
-        out[key] = int(v)
+    out = {key: int(v) for key, v in values.items()}
     out["peak_bytes"] = (out["argument_bytes"] + out["output_bytes"]
                          + out["temp_bytes"]
                          + out["generated_code_bytes"]
                          - out["alias_bytes"])
     return out
-
-
-def get_abstract_mesh():
-    """The mesh of the active :func:`set_mesh`/``with mesh:`` context,
-    or None when there is none (callers use it to decide whether a
-    ``with_sharding_constraint`` axis name can resolve). New jax:
-    ``jax.sharding.get_abstract_mesh``; 0.4.x: the thread-resources
-    physical mesh that backs the ``with mesh:`` context."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    try:
-        from jax._src import mesh as _mesh_lib  # 0.4.x private module
-
-        pm = _mesh_lib.thread_resources.env.physical_mesh
-        return None if pm.empty else pm
-    except Exception:  # noqa: BLE001  # graftlint: disable=GL111 a hint, not semantics; None = no mesh context
-        return None

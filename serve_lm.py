@@ -59,7 +59,7 @@ import sys
 from pytorch_multiprocessing_distributed_tpu.runtime import (
     fleet, heal, scope as graftscope)
 from pytorch_multiprocessing_distributed_tpu.utils.compile_cache import (
-    enable_compilation_cache)
+    CompileLog, enable_compilation_cache)
 
 parser = argparse.ArgumentParser(
     description="TPU-native continuous-batching LM serving")
@@ -398,10 +398,11 @@ def main():
         # hbm_* capacity gauges beside the serving meters
         hbm.arm()
     from pytorch_multiprocessing_distributed_tpu.utils.hostenv import (
-        force_cpu_devices_from_env)
+        announce_done, announce_run, force_cpu_devices_from_env)
 
     force_cpu_devices_from_env()
-    enable_compilation_cache()
+    cache_dir = enable_compilation_cache()
+    compile_log = CompileLog()
 
     import jax
     import jax.numpy as jnp
@@ -459,8 +460,10 @@ def main():
         else:
             draft_params = init_params(draft_model, args.seed + 1)
 
+    run_info = {}
+
     def build_engine(journal, params_override=None):
-        return ServingEngine(
+        engine = ServingEngine(
             model, params if params_override is None else params_override,
             max_slots=args.max_slots,
             s_max=args.s_max or None,
@@ -487,6 +490,14 @@ def main():
             draft_model=draft_model,
             draft_params=draft_params,
             journal=journal)
+        if not run_info:
+            # what was chosen from the platform, printed once at
+            # start-up and carried into the metrics snapshot
+            run_info.update(announce_run(
+                cache_dir, prefill_attn=model.attn_impl,
+                decode_attn=engine.decode_attn,
+                donate_cache=engine.donate_cache))
+        return engine
 
     # ---- graftwire: host this engine as one replica server ----------
     if args.listen:
@@ -556,6 +567,7 @@ def main():
 
         snap = engine.metrics.snapshot()
         snap.update(graftwire.wire_meter())
+        snap.update(run_info)
         print("metrics: " + json.dumps(snap, sort_keys=True),
               flush=True)
         if args.metrics_out:
@@ -995,6 +1007,7 @@ def main():
                 import wire as graftwire
 
             snap.update(graftwire.wire_meter())
+        snap.update(run_info)
         print("metrics: " + json.dumps(snap, sort_keys=True),
               flush=True)
         if args.metrics_out:
@@ -1041,6 +1054,8 @@ def main():
     # graftfleet: goodput fraction on the final record too ({} when
     # --stats_port never armed the ledger)
     snap.update(fleet.goodput_gauges())
+    snap.update(run_info)
+    snap.update(announce_done(compile_log))
     print("metrics: " + json.dumps(snap, sort_keys=True), flush=True)
     if args.metrics_out:
         with open(args.metrics_out, "w") as f:

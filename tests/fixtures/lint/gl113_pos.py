@@ -1,5 +1,5 @@
 """GL113 positive: an unstopped profiler trace (buffers forever, the
-.xplane.pb never flushes — a grant window's profiling silently lost),
+.xplane.pb never flushes — a run's profiling silently lost),
 and profiler trace control from inside jit-traced code (runs once at
 trace time, so the "profiled" region covers tracing, not execution)."""
 import jax

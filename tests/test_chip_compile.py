@@ -1,0 +1,215 @@
+"""The chip's compiler, kept as tests.
+
+Every Pallas kernel on the main path is compiled here with
+``interpret=False`` for a DESCRIBED ``v5e:2x2`` topology (no chip
+attached) at ``gpt_small`` widths — H=12, Dh=64, bf16, 8 slots, window
+1024. Interpret mode, which every other CPU test selects, accepts block
+shapes and relayouts that Mosaic refuses; these cases raise exactly what
+the chip would raise, at no chip time. Nothing runs, so they say nothing
+about results — the interpret-mode suites pin those.
+
+Code that asks ``jax.default_backend()`` still sees the CPU here, so each
+case calls the kernel with ``interpret=False`` itself.
+"""
+
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from pytorch_multiprocessing_distributed_tpu.ops.kv_quant import QuantizedKV
+from pytorch_multiprocessing_distributed_tpu.ops.pallas import (
+    flash_attention,
+    fused_sgd_apply,
+    ring_all_reduce,
+)
+
+# the module, not the same-named function ops.pallas re-exports
+da = importlib.import_module(
+    "pytorch_multiprocessing_distributed_tpu.ops.pallas.decode_attention")
+
+B, S, H, D = 8, 1024, 12, 64      # gpt_small serving: 8 slots, window 1024
+K1 = 5                             # --draft_k 4 verify block
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The v5e 2x2 host described once per module, compile cache off
+    around the cases (a described-device executable is written to the
+    persistent cache but cannot be read back without a chip — the next
+    compile would warn and recompile)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, args, sharding):
+    """Lower ``fn`` on shape-only ``args`` placed by ``sharding`` and
+    compile for the described chip; returns the compiled text."""
+    def place(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    text = jax.jit(fn).lower(*jax.tree.map(place, args)).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _kv(shape, kv_dtype):
+    """A K or V operand: bf16 array, or the int8 + f32-scale pair whose
+    scale drops the trailing head_dim axis (ops/kv_quant.py)."""
+    if kv_dtype == "int8":
+        return QuantizedKV(_sds(shape, jnp.int8),
+                           _sds(shape[:-1], jnp.float32))
+    return _sds(shape, BF16)
+
+
+def _decode_case(layout, kv_dtype, k1, page):
+    """(fn, args) of one decode/verify kernel call at serving shapes."""
+    q = _sds((B, k1, H, D), BF16)
+    pos = _sds((B,), jnp.int32)
+    if layout == "dense":
+        kv = _kv((B, S, H, D), kv_dtype)
+        kern = da.decode_attention if k1 == 1 else da.verify_decode_attention
+        return (lambda q, k, v, p: kern(q, k, v, p, impl="pallas",
+                                        interpret=False),
+                (q, kv, kv, pos))
+    n_win = S // page
+    pages = _kv((B * n_win + 1, H, page, D), kv_dtype)
+    tab = _sds((B, n_win), jnp.int32)
+    kern = (da.paged_decode_attention if k1 == 1
+            else da.paged_verify_decode_attention)
+    return (lambda q, k, v, t, p: kern(q, k, v, t, p, impl="pallas",
+                                       interpret=False),
+            (q, pages, pages, tab, pos))
+
+
+def _flash_case(batch, seq, grad):
+    x = _sds((batch, seq, H, D), BF16)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    if not grad:
+        return fwd, (x, x, x)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2)), (x, x, x)
+
+
+def _sgd_case():
+    # one leaf of each kind gpt_small/resnet carry: a matmul weight, a
+    # bias, a conv kernel
+    leaves = {"w": _sds((768, 3072), jnp.float32),
+              "b": _sds((3072,), jnp.float32),
+              "conv": _sds((3, 3, 64, 64), jnp.float32)}
+    return (lambda p, g, m: fused_sgd_apply(p, g, m, 0.1, interpret=False),
+            (leaves, leaves, leaves))
+
+
+_DECODE = [
+    pytest.param(lambda lay=lay, kv=kv, k1=k1, pg=pg:
+                 _decode_case(lay, kv, k1, pg),
+                 id=f"{lay}-{kv}-{'verify' if k1 > 1 else 'decode'}"
+                    + (f"-page{pg}" if pg else ""))
+    for lay, pages in (("dense", (None,)), ("paged", (16, 128)))
+    for kv in ("bf16", "int8")
+    for k1 in (1, K1)
+    for pg in pages
+]
+
+_CASES = _DECODE + [
+    pytest.param(lambda: _flash_case(B, S, False), id="flash-fwd-s1024"),
+    pytest.param(lambda: _flash_case(B, S, True), id="flash-fwdbwd-s1024"),
+    pytest.param(lambda: _flash_case(1, 4096, True),
+                 id="flash-fwdbwd-s4096"),
+    # ragged prefill lengths the engine's bucketless admission produces
+    pytest.param(lambda: _flash_case(1, 24, False), id="flash-fwd-s24"),
+    pytest.param(lambda: _flash_case(1, 37, False), id="flash-fwd-s37"),
+    pytest.param(lambda: _flash_case(1, 600, False), id="flash-fwd-s600"),
+    pytest.param(_sgd_case, id="fused-sgd"),
+]
+
+
+@pytest.mark.parametrize("make", _CASES)
+def test_kernel_compiles_for_v5e(topo, make):
+    fn, args = make()
+    _compile(fn, args, SingleDeviceSharding(topo.devices[0]))
+
+
+def test_ring_all_reduce_compiles_on_four_chips(topo):
+    """The RDMA ring needs the 4-device topology: one shard_map over all
+    four described chips."""
+    mesh = Mesh(np.array(topo.devices).reshape(-1), ("x",))
+    assert mesh.size == 4
+
+    def fn(x):
+        return jax.shard_map(
+            lambda s: ring_all_reduce(s, "x", interpret=False),
+            mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+            check_vma=False)(x)
+
+    x = _sds((4 * 8, 1024), jnp.float32)
+    _compile(fn, (x,), NamedSharding(mesh, P("x")))
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("chips", [1, 4])
+def test_gpt_small_train_step_compiles_with_its_kernel(topo, monkeypatch,
+                                                       chips):
+    """The whole ``make_lm_train_step`` program of ``chip_smoke.py``'s
+    train phase — gpt_small, bf16, 8x1024 a chip — for one described
+    chip and for the four-chip DP mesh: it fits the chip's memory, the
+    flash kernel compiles INSIDE it, and across chips the compiler put
+    the all-reduces in. ~40 s each, so outside tier-1."""
+    from pytorch_multiprocessing_distributed_tpu import models
+    from pytorch_multiprocessing_distributed_tpu.ops import pallas
+    from pytorch_multiprocessing_distributed_tpu.train.lm import (
+        create_lm_train_state, make_lm_train_step)
+    from pytorch_multiprocessing_distributed_tpu.train.optim import sgd
+
+    # the kernels ask the (CPU) backend and would pick interpret mode
+    monkeypatch.setattr(pallas, "default_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
+    model = models.get_model("gpt_small", dtype=BF16)
+    opt = sgd(learning_rate=0.01)
+    state = jax.eval_shape(
+        lambda: create_lm_train_state(
+            model, jax.random.PRNGKey(0), jnp.zeros((2, S), jnp.int32), opt))
+    replicated, split = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated),
+        state)
+    tokens = jax.ShapeDtypeStruct((B * chips, S), jnp.int32, sharding=split)
+    compiled = make_lm_train_step(model, opt, mesh).lower(
+        state, tokens).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("all-reduce" in text) == (chips > 1)
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 16e9

@@ -27,21 +27,10 @@ from pytorch_multiprocessing_distributed_tpu.train.lm import (
 )
 from pytorch_multiprocessing_distributed_tpu.train.optim import sgd
 from pytorch_multiprocessing_distributed_tpu.train.state import TrainState
-from pytorch_multiprocessing_distributed_tpu.utils.compat import HAS_VMA
 
 # tier-1 window: heaviest suite — runs with the full (slow) tier, not the 870s '-m not slow' gate
 # (pipelined-GPT trajectory parity: per-stage compiles)
-pytestmark = [
-    pytest.mark.slow,
-    # the pipelined trainer's out_specs replication can only be PROVEN
-    # by vma-tracking shard_map (jax.lax.pcast); 0.4.x check_rep
-    # rejects the schedule — and check_rep=False would silently
-    # mis-scale pipeline gradients, so skipping is the honest mode
-    pytest.mark.skipif(
-        not HAS_VMA,
-        reason="pipelined GPT trainer needs vma-tracking shard_map "
-               "(jax.lax.pcast); this jax predates it"),
-]
+pytestmark = pytest.mark.slow
 
 
 def _tokens(batch=16, seq=32):

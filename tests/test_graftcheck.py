@@ -11,7 +11,7 @@ Three layers, mirroring test_graftlint:
   the committed ``analysis/fingerprints.json`` (the tier-1 twin of
   ``make check``).
 
-Skips cleanly when jax cannot import (the HAS_VMA-gate convention).
+Skips cleanly when jax cannot import.
 """
 
 import json
@@ -30,8 +30,7 @@ from pytorch_multiprocessing_distributed_tpu.analysis.programs import (  # noqa:
     ProgramSpec, RULES_GC, audit_program, collect)
 from pytorch_multiprocessing_distributed_tpu.parallel.mesh import (  # noqa: E402
     audit_mesh)
-from pytorch_multiprocessing_distributed_tpu.utils.compat import (  # noqa: E402
-    shard_map)
+from jax import shard_map  # noqa: E402
 
 P = jax.sharding.PartitionSpec
 

@@ -81,30 +81,21 @@ class _FakeCompiled:
         return self._stats
 
 
-def test_memory_analysis_dict_normalizes_attr_and_list_shapes():
+def test_memory_analysis_dict_reads_the_stats_attributes():
     want = {"argument_bytes": 100, "output_bytes": 40,
             "temp_bytes": 300, "alias_bytes": 30,
             "generated_code_bytes": 7,
             "peak_bytes": 100 + 40 + 300 + 7 - 30}
     assert memory_analysis_dict(_FakeCompiled(_FakeStats())) == want
-    # 0.4.x list-of-per-device shape: take the first (SPMD-identical)
-    assert memory_analysis_dict(
-        _FakeCompiled([_FakeStats(), _FakeStats()])) == want
 
 
 def test_memory_analysis_dict_unavailable_is_none_never_zero():
-    class Broken:
-        def memory_analysis(self):
-            raise NotImplementedError
-
     class Partial:
         def memory_analysis(self):
             return object()  # none of the expected attributes
 
-    assert memory_analysis_dict(Broken()) is None
     assert memory_analysis_dict(Partial()) is None
     assert memory_analysis_dict(_FakeCompiled(None)) is None
-    assert memory_analysis_dict(_FakeCompiled([])) is None
 
 
 def test_memory_analysis_dict_real_compiled_program():

@@ -12,7 +12,6 @@ from pytorch_multiprocessing_distributed_tpu.ops.moe import (
 )
 from pytorch_multiprocessing_distributed_tpu.parallel import make_mesh
 from pytorch_multiprocessing_distributed_tpu.parallel.mesh import MODEL_AXIS
-from pytorch_multiprocessing_distributed_tpu.utils.compat import set_mesh
 
 B, S, D, E, H = 2, 16, 8, 4, 32
 
@@ -293,7 +292,7 @@ def test_expert_parallel_sharding_and_parity():
     assert w1.sharding.spec[0] == MODEL_AXIS
     assert w1.addressable_shards[0].data.shape[0] == 1  # 1 expert/device
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y = jax.jit(
             lambda p, x: model.apply({"params": p}, x)
         )(sharded, x)

@@ -196,13 +196,15 @@ def _run(args):
         # while the run is hot — arm the ledger before any state lands
         hbm.arm()
     from pytorch_multiprocessing_distributed_tpu.utils.hostenv import (
+        announce_done, announce_run, device_memory,
         force_cpu_devices_from_env)
 
     force_cpu_devices_from_env()
     from pytorch_multiprocessing_distributed_tpu.utils.compile_cache import (
-        enable_compilation_cache)
+        CompileLog, enable_compilation_cache, program_facts)
 
-    enable_compilation_cache()
+    cache_dir = enable_compilation_cache()
+    compile_log = CompileLog()
 
     import jax
     import jax.numpy as jnp
@@ -350,6 +352,8 @@ def _run(args):
     # backend/devices touched only AFTER every pure-flag validation —
     # an invalid combo must not cost a (possibly slow) TPU bring-up
     dist.init_process()
+    if dist.is_primary():
+        announce_run(cache_dir, attn_impl=model.attn_impl)
     n_dev = len(jax.devices())
     deg = args.degree if args.parallel != 'dp' else 1
     if n_dev % max(1, deg):
@@ -637,7 +641,20 @@ def _run(args):
                         "train.data", time.perf_counter() - t_ready,
                         cat="train", epoch=epoch, batch=i)
                 if use_prefetch:
+                    first = armed and epoch == start_epoch and i == 0
+                    if first and hasattr(step, 'lower'):
+                        graftscope.emit("train.program", cat="compile",
+                                        **program_facts(step, state, batch))
                     state, metrics = step(state, batch)
+                    if first:
+                        # where the state and the batch really live
+                        graftscope.emit(
+                            "train.placement", cat="train",
+                            param_devices=min(
+                                len(leaf.sharding.device_set) for leaf
+                                in jax.tree.leaves(state.params)),
+                            batch_devices=len(batch.sharding.device_set),
+                            bytes_in_use=device_memory("bytes_in_use"))
                 elif args.parallel in ('tp', 'pp'):
                     with graftscope.span("train.h2d", cat="train",
                                          batch=i):
@@ -869,6 +886,7 @@ def _run(args):
         ck.close()
     if dist.is_primary():
         graftscope.export_from_args(args)
+        announce_done(compile_log)
     if stats_server is not None:
         if health is not None:
             health.to_dead("run complete")
