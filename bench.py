@@ -216,9 +216,8 @@ def build_workload(config: str, dtype_name: str, batch_size: int,
                    zero: bool = False, zero_overlap: bool = True):
     """Construct the EXACT program a config benches: the jitted train
     step, its initialized state, the resident device batch, and the
-    item count per step. The ONE place this lives — ``run_bench`` times
-    it and ``benchmarks/profile_step.py`` traces it, so the profiled
-    program can never drift from the benched one.
+    item count per step. The ONE place this lives: every timed
+    variant in this file builds its program here.
 
     Returns ``(step, state, batch_args, items_per_step, batch)`` with
     ``batch`` after the data-axis divisibility rounding.

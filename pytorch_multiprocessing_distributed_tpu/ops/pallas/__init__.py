@@ -20,6 +20,14 @@ kernels cover the spots where hand scheduling buys something XLA can't:
 
 All kernels run compiled on TPU and under ``interpret=True`` on CPU (the
 test path; auto-selected when the backend is not TPU).
+
+Every ``pl.pallas_call`` here passes a stable ``name=``. The chip's
+compiler names the kernel's HLO instruction after it, so a profiler
+trace shows ``flash_attention_fwd.7`` rather than the flax module or
+jax wrapper the call happens to sit in, and ``perf/trace_reduce.py``
+labels it ``mosaic:flash_attention_fwd``. The benchmark's kernel
+metrics match on these names: letters and underscores, no trailing
+digit, and a rename is a change to what the ledger compares.
 """
 
 from .decode_attention import (  # noqa: F401

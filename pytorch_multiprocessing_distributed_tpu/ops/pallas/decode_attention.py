@@ -194,6 +194,7 @@ def _pallas_decode(q, k, v, positions, scale, block_k, interpret,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, 1, d), jnp.float32),
         interpret=interpret,
+        name="decode_attention",
     )(positions.astype(jnp.int32), *operands)
     return jnp.moveaxis(out.reshape(b, h, 1, d), 1, 2)  # [B, 1, H, Dh]
 
@@ -301,6 +302,7 @@ def _pallas_paged_decode(q, k_pages, v_pages, page_table, positions,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, 1, d), jnp.float32),
         interpret=interpret,
+        name="paged_decode_attention",
     )(positions.astype(jnp.int32), page_table.astype(jnp.int32),
       *operands)
     return jnp.moveaxis(out.reshape(b, h, 1, d), 1, 2)  # [B, 1, H, Dh]
@@ -607,6 +609,7 @@ def _pallas_verify(q, k, v, positions, scale, block_k, interpret,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, k1, d), jnp.float32),
         interpret=interpret,
+        name="verify_decode_attention",
     )(positions.astype(jnp.int32), *operands)
     return jnp.moveaxis(out.reshape(b, h, k1, d), 1, 2)  # [B, K1, H, Dh]
 
@@ -705,6 +708,7 @@ def _pallas_paged_verify(q, k_pages, v_pages, page_table, positions,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, k1, d), jnp.float32),
         interpret=interpret,
+        name="paged_verify_decode_attention",
     )(positions.astype(jnp.int32), page_table.astype(jnp.int32),
       *operands)
     return jnp.moveaxis(out.reshape(b, h, k1, d), 1, 2)

@@ -158,6 +158,7 @@ def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denominator
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qp, kp, vp)
     return out[:, :q_len], lse[:, :q_len, 0]
 
@@ -304,6 +305,7 @@ def _flash_pair_grads(q3, k3, v3, do, lse, dterm, *, scale, causal,
         out_shape=jax.ShapeDtypeStruct((bh, sq_pad, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(qp, kp, vp, dop, lsep, dtp)
 
     # transposed nest: grid (bh, k-block, q-block)
@@ -327,6 +329,7 @@ def _flash_pair_grads(q3, k3, v3, do, lse, dterm, *, scale, causal,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(qp, kp, vp, dop, lsep, dtp)
     return dq[:, :q_len], dk[:, :kv_len], dv[:, :kv_len]
 

@@ -79,6 +79,7 @@ def _fused_leaf(p, g, buf, scalars, *, momentum, weight_decay, nesterov,
         ],
         input_output_aliases={1: 0, 3: 1},  # param->new_param, buf->new_buf
         interpret=interpret,
+        name="fused_sgd_update",
     )(scalars, p2, g2, b2)
 
     def unprep(x):

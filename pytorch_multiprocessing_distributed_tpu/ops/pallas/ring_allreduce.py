@@ -181,5 +181,6 @@ def ring_all_reduce(
         compiler_params=pltpu.CompilerParams(
             collective_id=collective_id, has_side_effects=True),
         interpret=interpret,
+        name="ring_all_reduce",
     )(x2)
     return out.reshape(-1)[:size].reshape(orig_shape).astype(orig_dtype)

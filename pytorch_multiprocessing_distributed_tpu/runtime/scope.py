@@ -18,6 +18,24 @@ every emit helper is a single global read + ``is None`` check —
 :func:`emit` returns immediately, :func:`span` hands back a shared
 no-op context manager. No allocation, no clock read, nothing.
 
+**What reaches the profiler.** A second module global, the
+*annotator* (:func:`set_annotator`: a callable ``name -> context
+manager``), puts :func:`span` on the profiler's clock.
+``utils/profiler.py`` sets it to ``jax.profiler.TraceAnnotation`` when
+it is imported, which the serving engine and the trainer modules do.
+From then on every ``span()`` — armed or not — is entered as an
+annotation named :data:`ANNOTATION_PREFIX` + the span's name
+(``perf:decode.readback``) for exactly its duration, so a profiler
+trace shows the program's spans beside the device operations and
+``perf/trace_reduce.py`` can lay each idle gap of the device at one of
+them. Attributes stay on the :class:`Event`; the annotation carries the
+name alone. Outside a profiler session a ``TraceAnnotation`` is a flag
+check: nothing is recorded, and that is what "off" means — no arming
+call is needed to trace. Instants (:func:`emit`) and retroactive spans
+(:func:`emit_span`) are NOT annotated: an annotation has to be open
+while the work runs. Without an annotator (this module imported
+alone) the disarmed ``span()`` still returns the shared no-op.
+
 Pieces:
 
 - :class:`Event` / :class:`Scope` — the bus. A ``Scope`` keeps the
@@ -68,11 +86,13 @@ import sys
 import threading
 import time
 from collections import deque
-from typing import Callable, Deque, Dict, List, Optional, Sequence
+from typing import (Callable, ContextManager, Deque, Dict, List, Optional,
+                    Sequence)
 
 __all__ = [
     "Event", "Scope", "arm", "disarm", "active_scope", "scoped",
     "set_identity", "get_identity",
+    "ANNOTATION_PREFIX", "set_annotator",
     "emit", "span", "emit_span", "flight_dump",
     "to_chrome_trace", "write_chrome_trace", "write_jsonl",
     "events_from_jsonl", "prometheus_text", "scope_events_fn",
@@ -235,6 +255,22 @@ def active_scope() -> Optional[Scope]:
     return _SCOPE
 
 
+# The prefix perf/trace_reduce.py collects host annotations by
+# (perf/spans.py::ANNOTATION_PREFIX; this module imports nothing of the
+# benchmark's, so it keeps its own copy and a test holds the two equal).
+ANNOTATION_PREFIX = "perf:"
+
+_ANNOTATOR: Optional[Callable[[str], ContextManager]] = None
+
+
+def set_annotator(
+        annotator: Optional[Callable[[str], ContextManager]]) -> None:
+    """Install (or with None remove) the callable that opens a
+    profiler annotation for a name; see the module docstring."""
+    global _ANNOTATOR
+    _ANNOTATOR = annotator
+
+
 class scoped:
     """``with scoped(Scope()) as s: ...`` — arm for the block, always
     disarm (test/bench hygiene, mirrors ``faults.armed``)."""
@@ -296,29 +332,40 @@ _NULL_SPAN = _NullSpan()
 
 
 class _LiveSpan:
-    __slots__ = ("scope", "name", "cat", "attrs", "t_start")
+    """A span that records into ``scope`` (when armed), sits inside
+    ``annotation`` (when an annotator is set), or both."""
 
-    def __init__(self, scope: Scope, name: str, cat: str, attrs: Dict):
+    __slots__ = ("scope", "name", "cat", "attrs", "t_start", "annotation")
+
+    def __init__(self, scope: Optional[Scope], name: str, cat: str,
+                 attrs: Dict, annotation=None):
         self.scope = scope
         self.name = name
         self.cat = cat
         self.attrs = attrs
         self.t_start = 0.0
+        self.annotation = annotation
 
     def __enter__(self) -> "_LiveSpan":
-        self.t_start = time.perf_counter()
+        if self.annotation is not None:
+            self.annotation.__enter__()
+        if self.scope is not None:
+            self.t_start = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        now = time.perf_counter()
-        if exc_type is not None:
-            # a span that died names its killer — the flight
-            # recorder's most valuable line
-            self.attrs.setdefault("error", exc_type.__name__)
-        self.scope.record(Event(
-            self.name, self.cat, "X", self.t_start,
-            now - self.t_start, threading.get_ident(), next(_SEQ),
-            self.attrs))
+        if self.scope is not None:
+            now = time.perf_counter()
+            if exc_type is not None:
+                # a span that died names its killer — the flight
+                # recorder's most valuable line
+                self.attrs.setdefault("error", exc_type.__name__)
+            self.scope.record(Event(
+                self.name, self.cat, "X", self.t_start,
+                now - self.t_start, threading.get_ident(), next(_SEQ),
+                self.attrs))
+        if self.annotation is not None:
+            self.annotation.__exit__(exc_type, exc, tb)
         return False
 
     def note(self, **attrs) -> None:
@@ -329,12 +376,18 @@ class _LiveSpan:
 
 def span(name: str, cat: str = "run", **attrs):
     """Context manager recording one complete span (begin at
-    ``__enter__``, duration at ``__exit__``). Disarmed: returns a
-    shared no-op — one global read, no allocation, no clock read."""
+    ``__enter__``, duration at ``__exit__``), inside a profiler
+    annotation ``perf:<name>`` when an annotator is set. Disarmed and
+    without an annotator: returns a shared no-op — one global read
+    each, no allocation, no clock read."""
     s = _SCOPE
-    if s is None:
-        return _NULL_SPAN
-    return _LiveSpan(s, name, cat, dict(attrs))
+    annotator = _ANNOTATOR
+    if annotator is None:
+        if s is None:
+            return _NULL_SPAN
+        return _LiveSpan(s, name, cat, attrs)
+    return _LiveSpan(s, name, cat, attrs,
+                     annotator(ANNOTATION_PREFIX + name))
 
 
 # ---------------------------------------------------------- flight recorder

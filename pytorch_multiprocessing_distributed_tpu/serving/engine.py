@@ -131,6 +131,7 @@ from ..runtime.faults import (DeadlineExceeded, FaultInjected,
 from ..utils.compile_cache import (jit_cache_keys, jit_cache_size,
                                    record_jit_key)
 from ..utils.metrics import ServingMetrics
+from ..utils import profiler  # noqa: F401 (sets graftscope's annotator)
 from .kv_pages import PagePool, PagePoolExhausted, PrefixCache
 from .kv_slots import SlotPool
 from .scheduler import (DONE, FAILED, RUNNING, FIFOScheduler,
@@ -1741,10 +1742,17 @@ class ServingEngine:
         fills every free slot with one prefill call each; chunked mode
         advances the single in-flight :class:`PrefillPlan` by EXACTLY
         one chunk (the bounded stall the mode exists for) and splices
-        on the final chunk."""
-        if self._prefill_chunk is None:
-            return self._admit_whole()
-        return self._admit_chunked()
+        on the final chunk. Requests past their deadline are failed
+        first, inside the same ``engine.admit`` span: the span is all
+        the scheduling a step does before it can dispatch."""
+        with graftscope.span("engine.admit", cat="serving") as admit_span:
+            self._expire_deadlines()
+            queued = self.scheduler.queue_depth
+            events = (self._admit_whole() if self._prefill_chunk is None
+                      else self._admit_chunked())
+            depth = self.scheduler.queue_depth
+            admit_span.note(admitted=queued - depth, queue_depth=depth)
+        return events
 
     # ---- paged admission (graftpage) ----------------------------------
     def _paged_prep_head(self):
@@ -2350,84 +2358,85 @@ class ServingEngine:
         retried injection); exhaustion fails fast with a named
         ``GraftFaultError`` — the dispatch domain covers every
         resident slot, so there is no single request to quarantine."""
-        pool = self.pool
-        window, h, k = self._pick_schedule()
-        key = self._next_key()
+        with graftscope.span("decode.dispatch", cat="serving",
+                             overlapped=overlapped) as dispatch_span:
+            pool = self.pool
+            window, h, k = self._pick_schedule()
+            key = self._next_key()
 
-        if self._paged:
-            # lazy page-table upload: device_table() re-uploads (under
-            # its own expected_transfer) only when the host mirror
-            # changed at an admission/release boundary — steady state
-            # re-uses the device copy, so the armed-sentinel
-            # 0-transfer pin holds
-            caches = (pool.k_pages, pool.v_pages, pool.device_table())
-        else:
-            caches = (pool.k_caches, pool.v_caches)
+            if self._paged:
+                # lazy page-table upload: device_table() re-uploads (under
+                # its own expected_transfer) only when the host mirror
+                # changed at an admission/release boundary — steady state
+                # re-uses the device copy, so the armed-sentinel
+                # 0-transfer pin holds
+                caches = (pool.k_pages, pool.v_pages, pool.device_table())
+            else:
+                caches = (pool.k_caches, pool.v_caches)
 
-        if k:
-            if self._drafter is not None:
-                # lazy draft-table upload (the PagePool dirty-upload
-                # discipline): a converged repetitive stream stops
-                # changing its index, so steady state re-uses the
-                # device copy — the host-side refresh is the visible
-                # spec.draft span on the timeline
-                with graftscope.span("spec.draft", cat="serving",
-                                     draft_k=k):
-                    table = self._drafter.device_table()
+            if k:
+                if self._drafter is not None:
+                    # lazy draft-table upload (the PagePool dirty-upload
+                    # discipline): a converged repetitive stream stops
+                    # changing its index, so steady state re-uses the
+                    # device copy — the host-side refresh is the visible
+                    # spec.draft span on the timeline
+                    with graftscope.span("spec.draft", cat="serving",
+                                         draft_k=k):
+                        table = self._drafter.device_table()
 
+                    def launch():
+                        maybe_fault(_SITE_DISPATCH)
+                        return self._donated(lambda: self._decode_spec(
+                            self.params, *caches, pool.positions,
+                            pool.last_tokens, pool.active, pool.budgets,
+                            pool.eos_ids, table, window=window, horizon=h,
+                            draft_k=k))
+                else:
+                    def launch():
+                        maybe_fault(_SITE_DISPATCH)
+                        return self._donated(lambda: self._decode_spec(
+                            self.params, self._draft_params, *caches,
+                            self._draft_k_caches, self._draft_v_caches,
+                            pool.positions, pool.last_tokens, pool.active,
+                            pool.budgets, pool.eos_ids, window=window,
+                            horizon=h, draft_k=k))
+
+                out = self._attempted_engine(launch, "decode dispatch")
+                if self._draft_model is not None:
+                    (tokens, k_out, v_out, pool.positions,
+                     pool.last_tokens, pool.active, pool.budgets,
+                     self._draft_k_caches, self._draft_v_caches) = out
+                else:
+                    (tokens, k_out, v_out, pool.positions,
+                     pool.last_tokens, pool.active, pool.budgets) = out
+                record_jit_key(self._decode_spec,
+                               ("decode_spec", window, h, k))
+            else:
                 def launch():
                     maybe_fault(_SITE_DISPATCH)
-                    return self._donated(lambda: self._decode_spec(
+                    return self._donated(lambda: self._decode(
                         self.params, *caches, pool.positions,
                         pool.last_tokens, pool.active, pool.budgets,
-                        pool.eos_ids, table, window=window, horizon=h,
-                        draft_k=k))
-            else:
-                def launch():
-                    maybe_fault(_SITE_DISPATCH)
-                    return self._donated(lambda: self._decode_spec(
-                        self.params, self._draft_params, *caches,
-                        self._draft_k_caches, self._draft_v_caches,
-                        pool.positions, pool.last_tokens, pool.active,
-                        pool.budgets, pool.eos_ids, window=window,
-                        horizon=h, draft_k=k))
+                        pool.eos_ids, key, window=window, horizon=h))
 
-            out = self._attempted_engine(launch, "decode dispatch")
-            if self._draft_model is not None:
-                (tokens, k_out, v_out, pool.positions,
-                 pool.last_tokens, pool.active, pool.budgets,
-                 self._draft_k_caches, self._draft_v_caches) = out
+                (tokens, k_out, v_out, pool.positions, pool.last_tokens,
+                 pool.active, pool.budgets) = self._attempted_engine(
+                    launch, "decode dispatch")
+                if record_jit_key(self._decode, ("decode", window, h)):
+                    # this dispatch just paid a compile anyway — the one
+                    # moment measuring the program's temp HBM is off the
+                    # steady-state path (no-op unless a ledger is armed)
+                    self._note_decode_program(window, h)
+            if self._paged:
+                pool.k_pages, pool.v_pages = k_out, v_out
             else:
-                (tokens, k_out, v_out, pool.positions,
-                 pool.last_tokens, pool.active, pool.budgets) = out
-            record_jit_key(self._decode_spec,
-                           ("decode_spec", window, h, k))
-        else:
-            def launch():
-                maybe_fault(_SITE_DISPATCH)
-                return self._donated(lambda: self._decode(
-                    self.params, *caches, pool.positions,
-                    pool.last_tokens, pool.active, pool.budgets,
-                    pool.eos_ids, key, window=window, horizon=h))
-
-            (tokens, k_out, v_out, pool.positions, pool.last_tokens,
-             pool.active, pool.budgets) = self._attempted_engine(
-                launch, "decode dispatch")
-            if record_jit_key(self._decode, ("decode", window, h)):
-                # this dispatch just paid a compile anyway — the one
-                # moment measuring the program's temp HBM is off the
-                # steady-state path (no-op unless a ledger is armed)
-                self._note_decode_program(window, h)
-        if self._paged:
-            pool.k_pages, pool.v_pages = k_out, v_out
-        else:
-            pool.k_caches, pool.v_caches = k_out, v_out
-        self._blocks.append(
-            _TokenBlock(tokens, h, window, dict(self._running), k=k))
-        self.metrics.record_dispatch(h, overlapped)
-        graftscope.emit("decode.dispatch", cat="serving", window=window,
-                        horizon=h, draft_k=k, overlapped=overlapped,
-                        occupancy=pool.occupancy)
+                pool.k_caches, pool.v_caches = k_out, v_out
+            self._blocks.append(
+                _TokenBlock(tokens, h, window, dict(self._running), k=k))
+            self.metrics.record_dispatch(h, overlapped)
+            dispatch_span.note(window=window, horizon=h, draft_k=k,
+                               occupancy=pool.occupancy)
 
     def _overlap_ok(self) -> bool:
         """Dispatch horizon h+1 before syncing horizon h's block?
@@ -2487,8 +2496,12 @@ class ServingEngine:
 
         with graftscope.span("decode.drain", cat="serving", h=block.h,
                              window=block.window) as drain_span:
-            tokens = self._attempted_engine(
-                attempt, "horizon token-block readback")
+            # the wait for the device, apart from the per-token loop
+            # below; opened on THIS thread (the watchdog may run the
+            # read itself on a helper)
+            with graftscope.span("decode.readback", cat="serving"):
+                tokens = self._attempted_engine(
+                    attempt, "horizon token-block readback")
             realized: Dict[int, int] = {}
             for h in range(block.rows):
                 for slot, request in block.slots.items():
@@ -2560,7 +2573,8 @@ class ServingEngine:
         tokens included; a quarantined request emits no event — read
         its ``state``/``error``)."""
         try:
-            return self._step_inner()
+            with graftscope.span("engine.step", cat="serving"):
+                return self._step_inner()
         except (KeyboardInterrupt, SystemExit):
             raise
         except BaseException as e:
@@ -2580,7 +2594,6 @@ class ServingEngine:
             raise
 
     def _step_inner(self) -> List[Tuple[Request, int, bool]]:
-        self._expire_deadlines()
         events = self._admit()
         pool = self.pool
         if self._running or self._blocks:

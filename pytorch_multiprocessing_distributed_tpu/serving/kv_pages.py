@@ -55,6 +55,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.kv_quant import KV_DTYPES, QuantizedKV
 from ..runtime import hbm, life
+from ..runtime import scope as graftscope
 
 
 class PagePoolExhausted(RuntimeError):
@@ -363,9 +364,10 @@ class PagePool:
         if self._table_dirty or self._table_dev is None:
             from ..analysis.sentinels import expected_transfer
 
-            with expected_transfer("page-table upload after admission/"
-                                   "release (host-mirrored page "
-                                   "alloc)"):
+            with graftscope.span("pages.table_upload", cat="serving"), \
+                    expected_transfer("page-table upload after "
+                                      "admission/release (host-mirrored "
+                                      "page alloc)"):
                 self._table_dev = self._replicated(
                     jnp.asarray(self._table))
             self._table_dirty = False

@@ -42,6 +42,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..parallel import dist
 from ..runtime import scope as graftscope
 from ..runtime.faults import GraftFaultError, maybe_fault, register_site
+from ..utils import profiler  # noqa: F401 (sets graftscope's annotator)
 from .state import TrainState
 
 # the torn/corrupt-artifact hazard the fault matrix sweeps: fires on

@@ -39,6 +39,7 @@ from ..parallel import dist
 from ..runtime import scope as graftscope
 from ..parallel.mesh import MODEL_AXIS
 from ..utils import AverageMeter, Logger
+from ..utils import profiler  # noqa: F401 (sets graftscope's annotator)
 from ..utils.plotting import draw_plot
 from .checkpoint import prune_checkpoints, save_checkpoint
 from .state import TrainState

@@ -6,21 +6,23 @@ measure nothing unless steps are synchronized. This module provides:
 
 - :func:`trace` — context manager around ``jax.profiler`` emitting a
   TensorBoard-loadable trace (XLA op-level timeline, HBM usage);
-- :class:`StepTimer` — step timing whose tick boundary is a REAL
-  device-to-host readback, with warmup discard — the same measurement
-  discipline as ``bench.py``;
+- :func:`sync` — the framework's one timing boundary: a REAL
+  device-to-host readback;
 - :func:`annotate` — named trace regions (``jax.profiler.TraceAnnotation``)
-  so host-side phases (data, H2D, step) are visible in the timeline.
+  so host-side phases are visible in the timeline. Importing this
+  module makes it graftscope's annotator: every ``scope.span()`` of the
+  program is then a ``perf:<name>`` region of any profiler trace
+  (``runtime/scope.py`` says what does and does not reach the trace).
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
-from typing import List, Optional
 
 import jax
 import numpy as np
+
+from ..runtime import scope as graftscope
 
 
 def sync(step_output) -> None:
@@ -61,40 +63,4 @@ def annotate(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
-class StepTimer:
-    """Measures per-step wall time honestly under async dispatch.
-
-    Call :meth:`tick` with the step's output (any pytree); it blocks on
-    the output before reading the clock. The first ``warmup`` ticks
-    (compilation, autotuning) are recorded separately.
-    """
-
-    def __init__(self, warmup: int = 2):
-        self.warmup = warmup
-        self.times: List[float] = []
-        self.warmup_times: List[float] = []
-        self._last: Optional[float] = None
-
-    def start(self) -> None:
-        self._last = time.perf_counter()
-
-    def tick(self, step_output) -> float:
-        sync(step_output)
-        now = time.perf_counter()
-        if self._last is None:
-            self._last = now
-            return 0.0
-        dt = now - self._last
-        self._last = now
-        if len(self.warmup_times) < self.warmup:
-            self.warmup_times.append(dt)
-        else:
-            self.times.append(dt)
-        return dt
-
-    @property
-    def mean(self) -> float:
-        return sum(self.times) / len(self.times) if self.times else 0.0
-
-    def images_per_sec(self, batch_size: int) -> float:
-        return batch_size / self.mean if self.mean else 0.0
+graftscope.set_annotator(annotate)

@@ -31,6 +31,8 @@ from pytorch_multiprocessing_distributed_tpu.ops.pallas import (
     ring_all_reduce,
 )
 
+from perf.trace_reduce import MOSAIC, parse_instruction
+
 # the module, not the same-named function ops.pallas re-exports
 da = importlib.import_module(
     "pytorch_multiprocessing_distributed_tpu.ops.pallas.decode_attention")
@@ -73,6 +75,26 @@ def _compile(fn, args, sharding):
     return text
 
 
+# the names ops/pallas gives its kernels; perf/layer_metrics match on them
+KERNEL_NAMES = {
+    "flash_attention_fwd", "flash_attention_bwd_dq",
+    "flash_attention_bwd_dkv", "decode_attention",
+    "paged_decode_attention", "verify_decode_attention",
+    "paged_verify_decode_attention", "fused_sgd_update", "ring_all_reduce",
+}
+
+
+def _mosaic_names(text):
+    """The labels ``perf/trace_reduce.py`` would give a compiled
+    program's Mosaic kernels in a profiler trace of the chip (an "XLA
+    Ops" event's name is the instruction's text), ``mosaic:`` taken
+    off."""
+    labels = (parse_instruction(line.strip().removeprefix("ROOT "))[0]
+              for line in text.splitlines() if "tpu_custom_call" in line)
+    return {label[len(MOSAIC):] for label in labels
+            if label.startswith(MOSAIC)}
+
+
 def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
@@ -110,7 +132,12 @@ def _flash_case(batch, seq, grad):
     x = _sds((batch, seq, H, D), BF16)
 
     def fwd(q, k, v):
-        return flash_attention(q, k, v, causal=True, interpret=False)
+        # called from inside a named scope, as the models' flax modules
+        # do: a transform (jvp, transpose) wraps the enclosing scope's
+        # name and leaves the kernel's own alone; with nothing around
+        # the call it would wrap the kernel's (jvp_flash_attention_fwd_)
+        with jax.named_scope("attn"):
+            return flash_attention(q, k, v, causal=True, interpret=False)
 
     if not grad:
         return fwd, (x, x, x)
@@ -158,7 +185,11 @@ _CASES = _DECODE + [
 @pytest.mark.parametrize("make", _CASES)
 def test_kernel_compiles_for_v5e(topo, make):
     fn, args = make()
-    _compile(fn, args, SingleDeviceSharding(topo.devices[0]))
+    text = _compile(fn, args, SingleDeviceSharding(topo.devices[0]))
+    # the chip's compiler names the instruction after the kernel, not
+    # after whatever wrapper the call sits in
+    names = _mosaic_names(text)
+    assert names and names <= KERNEL_NAMES, names
 
 
 def test_ring_all_reduce_compiles_on_four_chips(topo):
@@ -174,7 +205,8 @@ def test_ring_all_reduce_compiles_on_four_chips(topo):
             check_vma=False)(x)
 
     x = _sds((4 * 8, 1024), jnp.float32)
-    _compile(fn, (x,), NamedSharding(mesh, P("x")))
+    text = _compile(fn, (x,), NamedSharding(mesh, P("x")))
+    assert _mosaic_names(text) == {"ring_all_reduce"}
 
 
 @pytest.mark.slow
@@ -208,7 +240,9 @@ def test_gpt_small_train_step_compiles_with_its_kernel(topo, monkeypatch,
     compiled = make_lm_train_step(model, opt, mesh).lower(
         state, tokens).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
+    assert _mosaic_names(text) == {
+        "flash_attention_fwd", "flash_attention_bwd_dq",
+        "flash_attention_bwd_dkv"}
     assert ("all-reduce" in text) == (chips > 1)
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes

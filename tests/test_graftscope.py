@@ -24,9 +24,12 @@ What must stay true:
   the events leading into the failure.
 """
 
+import contextlib
 import importlib.util
 import json
 import os
+import subprocess
+import sys
 import threading
 import urllib.request
 
@@ -58,6 +61,51 @@ def _tiny(**kw):
                       attn_impl="xla", **kw)
 
 
+class FakeAnnotator:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs
+    ``(what, name, thread)`` at every enter and exit."""
+
+    def __init__(self):
+        self.log = []
+
+    @contextlib.contextmanager
+    def __call__(self, name):
+        self.log.append(("enter", name, threading.get_ident()))
+        try:
+            yield
+        finally:
+            self.log.append(("exit", name, threading.get_ident()))
+
+    def names(self):
+        return [name for what, name, _tid in self.log if what == "enter"]
+
+    def parents(self):
+        """``[(name, parent name or None)]`` in enter order; asserts
+        the log is properly bracketed on one thread."""
+        assert len({tid for _w, _n, tid in self.log}) <= 1
+        stack, out = [], []
+        for what, name, _tid in self.log:
+            if what == "enter":
+                out.append((name, stack[-1] if stack else None))
+                stack.append(name)
+            else:
+                assert stack and stack.pop() == name, (name, self.log)
+        assert not stack, stack
+        return out
+
+
+@contextlib.contextmanager
+def annotator(fn):
+    """Swap graftscope's annotator for the block, then put back what
+    the imports had set (``utils.profiler.annotate``)."""
+    was = graftscope._ANNOTATOR
+    graftscope.set_annotator(fn)
+    try:
+        yield fn
+    finally:
+        graftscope.set_annotator(was)
+
+
 # ------------------------------------------------------------ event bus
 
 class TestEventBus:
@@ -68,9 +116,13 @@ class TestEventBus:
         graftscope.disarm()
         assert graftscope.active_scope() is None
         graftscope.emit("never", cat="x", huge=list(range(3)))
-        s1 = graftscope.span("a")
-        s2 = graftscope.span("b", cat="y", k=1)
+        # the engine's import set the profiler annotator; the shared
+        # no-op is what span() returns WITHOUT one (scope.py alone)
+        with annotator(None):
+            s1 = graftscope.span("a")
+            s2 = graftscope.span("b", cat="y", k=1)
         assert s1 is s2  # the shared _NULL_SPAN singleton
+        assert s1 is graftscope._NULL_SPAN
         with s1 as live:
             live.note(tokens=5)  # no-op twin keeps caller code unconditional
         assert graftscope.flight_dump("nothing armed") is None
@@ -138,6 +190,183 @@ class TestEventBus:
             graftscope.emit("b")
         assert s.counts() == {"a": 3, "b": 1}
         assert len(s.events()) == 4  # keep=True: full log
+
+
+# ------------------------------------------------- profiler annotations
+
+PREFIX = graftscope.ANNOTATION_PREFIX
+
+
+class TestAnnotator:
+    def test_prefix_is_the_one_trace_reduce_collects(self):
+        from perf.spans import ANNOTATION_PREFIX
+
+        assert graftscope.ANNOTATION_PREFIX == ANNOTATION_PREFIX == "perf:"
+
+    def test_engine_import_sets_the_profiler_annotator(self):
+        import jax
+
+        from pytorch_multiprocessing_distributed_tpu.utils import profiler
+
+        assert graftscope._ANNOTATOR is profiler.annotate
+        assert isinstance(profiler.annotate("x"),
+                          jax.profiler.TraceAnnotation)
+        # the real thing, outside any profiler session: enter, note, exit
+        graftscope.disarm()
+        with graftscope.span("decode.readback", cat="serving") as live:
+            live.note(tokens=1)
+
+    def test_scope_alone_imports_without_jax(self):
+        code = (
+            "import sys\n"
+            "from pytorch_multiprocessing_distributed_tpu.runtime "
+            "import scope\n"
+            "assert 'jax' not in sys.modules, 'scope.py pulled jax in'\n"
+            "assert scope._ANNOTATOR is None\n"
+            "assert scope.span('a') is scope.span('b')\n")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        done = subprocess.run([sys.executable, "-c", code], cwd=root,
+                              capture_output=True, text=True, timeout=120)
+        assert done.returncode == 0, done.stderr
+
+    def test_disarmed_span_enters_and_leaves_the_annotation(self):
+        graftscope.disarm()
+        with annotator(FakeAnnotator()) as fake:
+            with graftscope.span("outer", cat="run", k=1) as outer:
+                outer.note(tokens=3)   # attrs go nowhere, and harmlessly
+                with graftscope.span("inner"):
+                    pass
+        me = threading.get_ident()
+        assert fake.log == [
+            ("enter", PREFIX + "outer", me), ("enter", PREFIX + "inner", me),
+            ("exit", PREFIX + "inner", me), ("exit", PREFIX + "outer", me)]
+
+    def test_armed_span_records_the_event_and_annotates(self):
+        with annotator(FakeAnnotator()) as fake, scoped() as s:
+            with graftscope.span("work", cat="run", k=1) as live:
+                live.note(tokens=3)
+        (event,) = s.events()
+        assert (event.name, event.ph) == ("work", "X")
+        assert event.attrs == {"k": 1, "tokens": 3}
+        assert fake.names() == [PREFIX + "work"]
+        assert [what for what, _n, _t in fake.log] == ["enter", "exit"]
+
+    def test_instants_and_retroactive_spans_are_not_annotated(self):
+        with annotator(FakeAnnotator()) as fake, scoped() as s:
+            graftscope.emit("request.submit", cat="request")
+            graftscope.emit_span("train.data", 0.01, cat="train")
+        assert [e.name for e in s.events()] == ["request.submit",
+                                                "train.data"]
+        assert fake.log == []
+
+    def test_a_dying_span_closes_its_annotation_and_names_its_killer(self):
+        with annotator(FakeAnnotator()) as fake, scoped() as s:
+            with pytest.raises(ZeroDivisionError):
+                with graftscope.span("doomed"):
+                    1 / 0
+        assert [what for what, _n, _t in fake.log] == ["enter", "exit"]
+        assert s.events()[0].attrs["error"] == "ZeroDivisionError"
+
+
+# every span one engine step can open, and what it must sit inside
+ENGINE_SPANS = {
+    "engine.step": None,
+    "engine.admit": "engine.step",
+    "serving.prefill": "engine.admit",
+    "serving.prefill_chunk": "engine.admit",
+    "serving.prefill_tok0": "engine.admit",
+    "serving.slot_insert": "engine.admit",
+    "serving.prefix_hit": "engine.admit",
+    "decode.dispatch": "engine.step",
+    "pages.table_upload": "decode.dispatch",
+    "decode.drain": "engine.step",
+    "decode.readback": "decode.drain",
+}
+
+
+class TestEngineSpans:
+    def _paged_engine(self):
+        model = models.get_model("gpt_tiny", attn_impl="xla")
+        params = init_params(model, 3)
+        rng = np.random.default_rng(3)
+        first = rng.integers(0, model.vocab_size, (19,)).tolist()
+        # shares its first two pages with `first`: a partial prefix hit
+        second = first[:16] + rng.integers(0, model.vocab_size,
+                                           (5,)).tolist()
+        engine = ServingEngine(model, params, max_slots=2, s_max=64,
+                               min_bucket=8, kv_layout="paged",
+                               page_size=8, prefix_cache=4)
+        return engine, first, second
+
+    def test_one_paged_run_shows_every_span_properly_nested(self):
+        """A miss, then a partial prefix hit two steps later, then the
+        first request finishes two steps before the second: every span
+        of an engine step is annotated, each inside its parent, and the
+        page table is uploaded on exactly the steps that follow an
+        admission or a release."""
+        engine, first, second = self._paged_engine()
+        with annotator(FakeAnnotator()) as fake, scoped() as s:
+            engine.submit(first, 6)
+            steps = 0
+            while engine.in_flight:
+                engine.step()
+                steps += 1
+                if steps == 2:
+                    engine.submit(second, 6)
+        assert steps == 7
+        parents = fake.parents()          # also: properly bracketed
+        assert ({name for name, _p in parents}
+                == {PREFIX + name for name in ENGINE_SPANS})
+        for name, parent in parents:
+            want = ENGINE_SPANS[name[len(PREFIX):]]
+            assert parent == (want and PREFIX + want), (name, parent)
+        # the same spans, with their attrs, on the armed scope
+        spans = [e for e in s.events() if e.ph == "X"]
+        assert ([PREFIX + e.name for e in spans]
+                == [name for what, name, _t in fake.log if what == "exit"])
+        assert all("req" in e.attrs for e in spans
+                   if e.name.startswith("serving."))
+        dispatch = next(e for e in spans if e.name == "decode.dispatch")
+        assert set(dispatch.attrs) == {"window", "horizon", "draft_k",
+                                       "overlapped", "occupancy"}
+        # one group of events per step; engine.step is recorded last
+        groups, group = [], []
+        for e in s.events():
+            group.append(e)
+            if e.name == "engine.step":
+                groups.append(group)
+                group = []
+        uploaded = [any(e.name == "pages.table_upload" for e in g)
+                    for g in groups]
+        admitted = [next(e for e in g if e.name == "engine.admit")
+                    .attrs["admitted"] for g in groups]
+        released = [any(e.name == "request.done" for e in g)
+                    for g in groups]
+        assert admitted == [1, 0, 1, 0, 0, 0, 0]
+        assert released == [False] * 4 + [True, False, True]
+        assert uploaded == [bool(admitted[i]) or (i > 0 and released[i - 1])
+                            for i in range(steps)]
+        assert uploaded == [True, False, True, False, False, True, False]
+
+    def test_annotated_steady_state_adds_no_compile_or_transfer(self):
+        """With the profiler's annotator set and no scope armed — the
+        state every run is in now — a warmed paged engine re-serving
+        its mix makes no fresh compile and no transfer beyond the
+        expected ones: an annotation is host-only."""
+        from pytorch_multiprocessing_distributed_tpu.utils import profiler
+
+        engine, first, second = self._paged_engine()
+        work = [(first, 5), (second, 5), (first[:7], 5)]
+        engine.serve(work)                # warm every bucket
+        compiles = engine.decode_step_compiles
+        graftscope.disarm()
+        with annotator(profiler.annotate):
+            with guard_transfers():
+                with recompile_budget(engine._decode, 0,
+                                      label="annotated steady state"):
+                    finished = engine.serve(work)
+        assert all(r.state == DONE for r in finished)
+        assert engine.decode_step_compiles == compiles
 
 
 # ------------------------------------------------------- exact meters

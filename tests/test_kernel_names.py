@@ -1,0 +1,163 @@
+"""Every Pallas kernel carries the name the profiler trace shows.
+
+``perf/trace_reduce.py`` labels a Mosaic kernel ``mosaic:<name>`` from
+its HLO instruction's name, and the chip's compiler takes that from the
+``name=`` of the ``pl.pallas_call`` (``tests/test_chip_compile.py``
+reads it out of the compiled text). The benchmark's kernel metrics
+(``perf/layer_metrics/flash_*_ms.train.json``,
+``paged_decode_attn_ms.serve.json``) match on these names, so the
+ledger can compare a kernel's time across PRs that rewrite what is
+around it. Here each of the nine call sites is traced (nothing runs)
+and the name is read out of the jaxpr.
+"""
+
+import importlib
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
+
+from pytorch_multiprocessing_distributed_tpu.ops.kv_quant import QuantizedKV
+from pytorch_multiprocessing_distributed_tpu.ops.pallas import (
+    flash_attention, fused_sgd_apply, ring_all_reduce)
+
+# the module, not the same-named function ops.pallas re-exports
+da = importlib.import_module(
+    "pytorch_multiprocessing_distributed_tpu.ops.pallas.decode_attention")
+
+B, S, H, D, K1, PAGE = 2, 64, 2, 32, 3, 16
+
+
+def _sds(shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _kv(shape, int8):
+    if int8:
+        return QuantizedKV(_sds(shape, jnp.int8), _sds(shape[:-1]))
+    return _sds(shape)
+
+
+def _flash(grad):
+    x = _sds((B, S, H, D))
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True).sum()
+
+    return (jax.grad(loss, argnums=(0, 1, 2)) if grad else loss), (x, x, x)
+
+
+def _decode(paged, k1, int8=False):
+    q, pos = _sds((B, k1, H, D)), _sds((B,), jnp.int32)
+    if not paged:
+        kv = _kv((B, S, H, D), int8)
+        kern = da.decode_attention if k1 == 1 else da.verify_decode_attention
+        return (lambda q, k, v, p: kern(q, k, v, p, impl="pallas",
+                                        interpret=True), (q, kv, kv, pos))
+    pages = _kv((B * (S // PAGE) + 1, H, PAGE, D), int8)
+    table = _sds((B, S // PAGE), jnp.int32)
+    kern = (da.paged_decode_attention if k1 == 1
+            else da.paged_verify_decode_attention)
+    return (lambda q, k, v, t, p: kern(q, k, v, t, p, impl="pallas",
+                                       interpret=True),
+            (q, pages, pages, table, pos))
+
+
+def _sgd():
+    leaves = {"w": _sds((24, 40)), "b": _sds((40,))}
+    return (lambda p, g, m: fused_sgd_apply(p, g, m, 0.1, interpret=True),
+            (leaves, leaves, leaves))
+
+
+def _ring():
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("x",))
+    fn = jax.shard_map(lambda v: ring_all_reduce(v, "x", interpret=True),
+                       mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+                       check_vma=False)
+    return fn, (_sds((2 * 8, 128)),)
+
+
+def _pallas_names(jaxpr):
+    """Names of the ``pallas_call`` equations in ``jaxpr`` and in every
+    jaxpr nested in it (custom_vjp, pjit, shard_map), in order."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+            continue
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    names.extend(_pallas_names(inner))
+    return names
+
+
+# (call site, how to reach it, the name that site passes)
+_SITES = [
+    ("flash_attention.py fwd", lambda: _flash(False),
+     "flash_attention_fwd"),
+    ("flash_attention.py bwd dq", lambda: _flash(True),
+     "flash_attention_bwd_dq"),
+    ("flash_attention.py bwd dkv", lambda: _flash(True),
+     "flash_attention_bwd_dkv"),
+    ("decode_attention.py dense", lambda: _decode(False, 1),
+     "decode_attention"),
+    ("decode_attention.py paged", lambda: _decode(True, 1),
+     "paged_decode_attention"),
+    ("decode_attention.py verify", lambda: _decode(False, K1),
+     "verify_decode_attention"),
+    ("decode_attention.py paged verify", lambda: _decode(True, K1),
+     "paged_verify_decode_attention"),
+    ("fused_update.py", _sgd, "fused_sgd_update"),
+    ("ring_allreduce.py", _ring, "ring_all_reduce"),
+    # the int8 variants go through the same call sites
+    ("decode_attention.py dense int8", lambda: _decode(False, 1, True),
+     "decode_attention"),
+    ("decode_attention.py paged int8", lambda: _decode(True, 1, True),
+     "paged_decode_attention"),
+    ("decode_attention.py verify int8", lambda: _decode(False, K1, True),
+     "verify_decode_attention"),
+    ("decode_attention.py paged verify int8",
+     lambda: _decode(True, K1, True), "paged_verify_decode_attention"),
+]
+KERNEL_NAMES = {name for _site, _make, name in _SITES}
+
+
+@pytest.mark.parametrize("make, want", [s[1:] for s in _SITES],
+                         ids=[s[0].replace(" ", "-") for s in _SITES])
+def test_call_site_passes_its_stable_name(make, want):
+    fn, args = make()
+    names = _pallas_names(jax.make_jaxpr(fn)(*args).jaxpr)
+    # the backward trace holds the forward kernel too; no kernel of
+    # any trace goes unnamed (the default is the body's function name)
+    assert want in names and set(names) <= KERNEL_NAMES, names
+    # trace_reduce.op_kind strips a trailing number off a label
+    assert re.fullmatch(r"[a-z_]*[a-z]", want)
+
+
+def test_kernel_metrics_match_the_names_the_kernels_carry():
+    """The three metric files of PR 25 select by regex over
+    ``mosaic:<name>``: each must pick out exactly its kernels."""
+    assert len(KERNEL_NAMES) == 9
+    labels = ["mosaic:" + name for name in KERNEL_NAMES]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    picked = {}
+    for metric in ("flash_fwd_ms.train", "flash_bwd_ms.train",
+                   "paged_decode_attn_ms.serve"):
+        with open(os.path.join(root, "perf", "layer_metrics",
+                               metric + ".json")) as fh:
+            rx = re.compile(json.load(fh)["args"]["match"])
+        picked[metric] = {label for label in labels if rx.search(label)}
+    assert picked == {
+        "flash_fwd_ms.train": {"mosaic:flash_attention_fwd"},
+        "flash_bwd_ms.train": {"mosaic:flash_attention_bwd_dq",
+                               "mosaic:flash_attention_bwd_dkv"},
+        "paged_decode_attn_ms.serve": {"mosaic:paged_decode_attention"},
+    }
