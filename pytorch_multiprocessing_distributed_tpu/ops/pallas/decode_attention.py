@@ -18,6 +18,23 @@ which is what makes the engine's length-bucketed window *and* this
 kernel compose (the bucket bounds the grid, the position gate bounds
 the work inside it).
 
+The PAGED decode kernel (``[P, H, ps, Dh]`` pools behind a page table)
+has the grid ``(slot, block of G pages)``: one step folds ALL heads of
+``G * ps`` columns (G = 8 pages of 16, 1 page of 128), a softmax state
+per head, the two contractions batched over heads. A page's
+``[H, ps, Dh]`` is contiguous, so it is one DMA; each pool is passed G
+times with plain ``BlockSpec``s — operand ``g`` of a step is page ``g``
+of its block — and the pipeline double-buffers them, the next step's
+pages (the next slot's first block at a slot's end) in flight while
+this one is folded. Which pool page a step's operand names is worked
+out once, in XLA, from positions and table (:func:`_live_page_ids`):
+only pages at or before the slot's position, and a block beyond it
+names its predecessor's pages again, which the pipeline does not copy
+twice — so a slot costs its live pages, not its window. (A hand-rolled
+``make_async_copy`` of a page does not lower for ``Dh`` 64: Mosaic pads
+the pool's minor dimension to 128 lanes and then refuses the 64-wide
+slice.)
+
 Matmuls stay in the input dtype (bf16 hits the MXU's native rate),
 accumulation is f32, outputs are f32 (the engine casts back to model
 dtype after the residual add, matching the XLA path's dtypes exactly).
@@ -25,9 +42,10 @@ dtype after the residual add, matching the XLA path's dtypes exactly).
 **graftquant**: every kernel (and every XLA reference) also takes the
 KV operand as a :class:`...kv_quant.QuantizedKV` pair — int8 data plus
 a per-(token, head) f32 scale streamed beside it (dense: a ``[B*H,
-1, S]`` row per block; paged: the ``[ps]`` sidecar of the SAME page the
-scalar-prefetched table steers in, viewed ``[P, H, 1, ps]`` — the
-size-1 axis is what makes a one-row scale block legal on the TPU). The
+1, S]`` row per block; paged decode: the ``[H, ps]`` sidecar of the
+SAME page, through the same page ids; paged verify: one ``[ps]`` row
+of it, viewed ``[P, H, 1, ps]`` — the size-1 axis is what makes a
+one-row scale block legal on the TPU). The
 dequant is ONE multiply in the
 VMEM stream, applied before the existing MXU dot — so the decode step's
 dominant HBM bytes term (the K/V read) halves while the matmul dtype
@@ -199,23 +217,84 @@ def _pallas_decode(q, k, v, positions, scale, block_k, interpret,
     return jnp.moveaxis(out.reshape(b, h, 1, d), 1, 2)  # [B, 1, H, Dh]
 
 
-def _paged_decode_kernel(pos_ref, tab_ref, q_ref, k_ref, v_ref, *rest,
-                         scale, page_size, heads, quant):
-    """One (slot*head, page) grid cell of the PAGED flash-decode: the
-    same online-softmax recurrence as :func:`_decode_kernel`, but the
-    K/V block for step ``kb`` is whatever PAGE the scalar-prefetched
-    table maps column-block ``kb`` to — the index map does the
-    indirection BEFORE the DMA, so the stream through VMEM is still
-    one pass over exactly the pages the slot owns (never a gathered
-    contiguous copy in HBM). ``quant`` (static) inserts the two scale
-    sidecars, steered by the SAME table indirection."""
+# the K and V page blocks one grid step holds in VMEM (each double-
+# buffered by the pipeline) may take this much by their nominal size;
+# the chip's tiled layout pads a 64-wide head to 128 lanes, so at
+# most twice this
+_PAGE_BLOCK_BYTES = 4 << 20
+
+
+def _pages_per_step(page_size, n_win, page_bytes):
+    """G, the pages one grid step folds as one block: the fewest whose
+    block has 128 columns (8 at ``page_size`` 16, 1 at 128), no more
+    than the window has, and no more than the VMEM budget holds (K and
+    V, two buffers each)."""
+    fit = _PAGE_BLOCK_BYTES // (4 * page_bytes)
+    return max(1, min(128 // page_size, n_win, fit))
+
+
+def _live_page_ids(page_table, positions, group, page_size):
+    """``[B, n_blocks * G]``: the pool page each (grid step, operand)
+    of the paged kernel names. Only live pages are ever named, so
+    nothing beyond a slot's position is copied: a block that starts
+    beyond the position names the pages of the slot's last live block
+    again, and a page beyond the position inside that block names the
+    page its operand held a step before (in a slot's first block: the
+    last live page) — the pipeline copies nothing when a block index
+    repeats. Computed once from ``positions`` in XLA, so an index map
+    is one SMEM read."""
+    b, n_win = page_table.shape
+    last = jnp.clip(positions, 0, n_win * page_size - 1) // page_size
+    last = last[:, None, None]                           # [B, 1, 1]
+    blk = jnp.minimum(jnp.arange(pl.cdiv(n_win, group))[None, :, None],
+                      last // group)
+    page = blk * group + jnp.arange(group)[None, None, :]
+    page = jnp.where(page <= last, page,
+                     jnp.where(blk > 0, page - group, last))
+    return jnp.take_along_axis(page_table, page.reshape(b, -1), axis=1)
+
+
+def _page_spec(block_shape, g, group):
+    """Block of page ``g`` of a grid step's ``group``: all heads of ONE
+    page (or of its scale sidecar), steered by the scalar-prefetched
+    :func:`_live_page_ids`."""
+    zeros = (0,) * (len(block_shape) - 1)
+    return pl.BlockSpec(
+        block_shape,
+        lambda i, kb, pos, ids: (ids[i, kb * group + g],) + zeros)
+
+
+def _paged_decode_kernel(pos_ref, ids_ref, q_ref, *rest, scale,
+                         page_size, group, quant):
+    """One (slot, block of ``group`` pages) grid cell of the PAGED
+    flash-decode, all heads at once: the same online-softmax
+    recurrence as :func:`_decode_kernel` with a softmax state per
+    head. Each of the block's pages arrives as its own operand —
+    whatever PAGE the table maps it to, the index map doing the
+    indirection BEFORE the DMA (:func:`_page_spec`) — and the pages
+    are folded side by side as ONE block of ``group * ps`` columns. A
+    block that starts beyond the slot's position folds nothing (and
+    copied nothing); the column mask keeps the pages beyond the
+    position inside the last live block out of the softmax.
+    ``quant`` (static): each page brings its ``[H, ps]`` scale
+    sidecar through the same indirection."""
+    k_refs, v_refs = rest[:group], rest[group:2 * group]
+    rest = rest[2 * group:]
+    ks_refs = vs_refs = (None,) * group
     if quant:
-        ks_ref, vs_ref, o_ref, acc, m_scr, l_scr = rest
-    else:
-        o_ref, acc, m_scr, l_scr = rest
+        ks_refs, vs_refs = rest[:group], rest[group:2 * group]
+        rest = rest[2 * group:]
+    o_ref, acc, m_scr, l_scr = rest
     i = pl.program_id(0)
     kb = pl.program_id(1)
-    n_k = pl.num_programs(1)
+    block_k = group * page_size
+
+    def block(refs, scale_refs):
+        """The step's pages side by side: ``[H, G * ps, Dh]``."""
+        pages = [ref[0] if s_ref is None
+                 else _kernel_dequant(ref[0], s_ref[0], q_ref.dtype)
+                 for ref, s_ref in zip(refs, scale_refs)]
+        return pages[0] if group == 1 else jnp.concatenate(pages, axis=1)
 
     @pl.when(kb == 0)
     def _():
@@ -223,24 +302,21 @@ def _paged_decode_kernel(pos_ref, tab_ref, q_ref, k_ref, v_ref, *rest,
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    pos = pos_ref[i // heads]
+    pos = pos_ref[i]
 
-    # page entirely beyond the slot's position -> skip (same per-slot
+    # block entirely beyond the slot's position -> skip (same per-slot
     # cost gate as the dense kernel's block gate; unallocated table
     # entries point at the scratch page, whose values this gate and
     # the column mask keep out of the softmax)
-    @pl.when(kb * page_size <= pos)
+    @pl.when(kb * block_k <= pos)
     def _():
-        q = q_ref[0]             # [1, d]
-        kblk = k_ref[0, 0]       # [ps, d]
-        vblk = v_ref[0, 0]
-        if quant:
-            kblk = _kernel_dequant(kblk, ks_ref[0, 0, 0], q.dtype)
-            vblk = _kernel_dequant(vblk, vs_ref[0, 0, 0], q.dtype)
-        s = jnp.dot(q, kblk.T,
-                    preferred_element_type=jnp.float32) * scale
-        col = kb * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
+        q = q_ref[0]                                     # [H, 1, Dh]
+        s = jax.lax.dot_general(
+            q, block(k_refs, ks_refs),
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale  # [H, 1, G*ps]
+        col = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 2)
         s = jnp.where(col <= pos, s, NEG_INF)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -248,11 +324,12 @@ def _paged_decode_kernel(pos_ref, tab_ref, q_ref, k_ref, v_ref, *rest,
         corr = jnp.exp(m_prev - m_new)
         m_scr[:] = m_new
         l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc[:] = acc[:] * corr + jnp.dot(
-            p.astype(vblk.dtype), vblk,
-            preferred_element_type=jnp.float32)
+        acc[:] = acc[:] * corr + jax.lax.dot_general(
+            p.astype(q.dtype), block(v_refs, vs_refs),
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)          # [H, 1, Dh]
 
-    @pl.when(kb == n_k - 1)
+    @pl.when(kb == pl.num_programs(1) - 1)
     def _():
         o_ref[0] = acc[:] / jnp.maximum(l_scr[:], 1e-30)
 
@@ -261,51 +338,55 @@ def _pallas_paged_decode(q, k_pages, v_pages, page_table, positions,
                          scale, interpret, k_scale=None, v_scale=None):
     """q [B, 1, H, Dh]; k/v pages [P, H, ps, Dh]; page_table
     [B, n_win] int32; positions [B] -> f32 [B, 1, H, Dh]. Grid is
-    (slot*head, page); the table rides in SMEM via scalar prefetch and
-    steers each page block's DMA. graftquant: ``k_scale``/``v_scale``
-    (``[P, H, ps]`` f32) ride the same indirection as their pages."""
+    (slot, block of G pages), G from :func:`_pages_per_step`: one step
+    folds all heads of ``G * ps`` columns. Positions and the page ids
+    ride in SMEM via scalar prefetch; each pool is passed G times,
+    once per page of a step's block, so a whole ``[H, ps, Dh]`` page
+    is one DMA and the pipeline keeps the next step's G pages in
+    flight (the next slot's first block at a slot's end) — never a
+    page beyond a slot's position (:func:`_live_page_ids`). graftquant:
+    ``k_scale``/``v_scale`` (``[P, H, ps]`` f32) ride the same
+    indirection as their pages."""
     b, _, h, d = q.shape
     ps = k_pages.shape[2]
     n_win = page_table.shape[1]
     quant = k_scale is not None
-    q3 = jnp.moveaxis(q, 2, 1).reshape(b * h, 1, d)  # [B*H, 1, Dh]
+    group = _pages_per_step(ps, n_win,
+                            h * ps * d * k_pages.dtype.itemsize)
+    q4 = jnp.moveaxis(q, 2, 1)                           # [B, H, 1, Dh]
 
-    in_specs = [
-        pl.BlockSpec((1, 1, d),
-                     lambda i, kb, pos, tab: (i, 0, 0)),
-        pl.BlockSpec((1, 1, ps, d),
-                     lambda i, kb, pos, tab:
-                     (tab[i // h, kb], i % h, 0, 0)),
-        pl.BlockSpec((1, 1, ps, d),
-                     lambda i, kb, pos, tab:
-                     (tab[i // h, kb], i % h, 0, 0)),
-    ]
-    operands = [q3, k_pages, v_pages]
+    def page_specs(block_shape):
+        return [_page_spec(block_shape, g, group) for g in range(group)]
+
+    q_spec = pl.BlockSpec((1, h, 1, d),
+                          lambda i, kb, pos, ids: (i, 0, 0, 0))
+    in_specs = [q_spec] + page_specs((1, h, ps, d)) * 2
+    operands = [q4] + [k_pages] * group + [v_pages] * group
     if quant:
-        in_specs += [_paged_scale_spec(ps, h)] * 2
-        operands += [k_scale[:, :, None], v_scale[:, :, None]]
+        in_specs += page_specs((1, h, ps)) * 2
+        operands += [k_scale] * group + [v_scale] * group
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # positions, page table
-        grid=(b * h, n_win),
+        num_scalar_prefetch=2,  # positions, page ids
+        grid=(b, pl.cdiv(n_win, group)),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, d),
-                               lambda i, kb, pos, tab: (i, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),   # output accumulator
-            pltpu.VMEM((1, 1), jnp.float32),   # running max
-            pltpu.VMEM((1, 1), jnp.float32),   # running denominator
+            pltpu.VMEM((h, 1, d), jnp.float32),   # output accumulator
+            pltpu.VMEM((h, 1, 1), jnp.float32),   # running max
+            pltpu.VMEM((h, 1, 1), jnp.float32),   # running denominator
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale,
-                          page_size=ps, heads=h, quant=quant),
+                          page_size=ps, group=group, quant=quant),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, 1, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), jnp.float32),
         interpret=interpret,
         name="paged_decode_attention",
-    )(positions.astype(jnp.int32), page_table.astype(jnp.int32),
+    )(positions.astype(jnp.int32),
+      _live_page_ids(page_table.astype(jnp.int32), positions, group, ps),
       *operands)
-    return jnp.moveaxis(out.reshape(b, h, 1, d), 1, 2)  # [B, 1, H, Dh]
+    return jnp.moveaxis(out, 1, 2)                       # [B, 1, H, Dh]
 
 
 def _gather_paged_window(pages, page_table, q_dtype,

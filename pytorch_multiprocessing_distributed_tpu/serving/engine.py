@@ -2371,6 +2371,12 @@ class ServingEngine:
                 # re-uses the device copy, so the armed-sentinel
                 # 0-transfer pin holds
                 caches = (pool.k_pages, pool.v_pages, pool.device_table())
+                # the share of the window's pages the decode kernel has
+                # to read (host mirror: no device read)
+                dispatch_span.note(
+                    kv_pages_live=pool.live_pages,
+                    kv_pages_window=pool.max_slots
+                    * -(-window // pool.page_size))
             else:
                 caches = (pool.k_caches, pool.v_caches)
 

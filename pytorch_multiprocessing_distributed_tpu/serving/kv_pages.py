@@ -426,6 +426,14 @@ class PagePool:
                                   self._active_host) if live),
             default=-1)
 
+    @property
+    def live_pages(self) -> int:
+        """Pages the active slots' positions reach — what one layer's
+        paged decode kernel has to read — off the host mirror."""
+        return sum(p // self.page_size + 1
+                   for p, live in zip(self._positions_host,
+                                      self._active_host) if live)
+
 
 class PrefixEntry:
     """One cached shared prefix: ``n_full`` full pages covering

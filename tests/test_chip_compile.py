@@ -108,19 +108,19 @@ def _kv(shape, kv_dtype):
     return _sds(shape, BF16)
 
 
-def _decode_case(layout, kv_dtype, k1, page):
+def _decode_case(layout, kv_dtype, k1, page, slots=B, heads=H):
     """(fn, args) of one decode/verify kernel call at serving shapes."""
-    q = _sds((B, k1, H, D), BF16)
-    pos = _sds((B,), jnp.int32)
+    q = _sds((slots, k1, heads, D), BF16)
+    pos = _sds((slots,), jnp.int32)
     if layout == "dense":
-        kv = _kv((B, S, H, D), kv_dtype)
+        kv = _kv((slots, S, heads, D), kv_dtype)
         kern = da.decode_attention if k1 == 1 else da.verify_decode_attention
         return (lambda q, k, v, p: kern(q, k, v, p, impl="pallas",
                                         interpret=False),
                 (q, kv, kv, pos))
     n_win = S // page
-    pages = _kv((B * n_win + 1, H, page, D), kv_dtype)
-    tab = _sds((B, n_win), jnp.int32)
+    pages = _kv((slots * n_win + 1, heads, page, D), kv_dtype)
+    tab = _sds((slots, n_win), jnp.int32)
     kern = (da.paged_decode_attention if k1 == 1
             else da.paged_verify_decode_attention)
     return (lambda q, k, v, t, p: kern(q, k, v, t, p, impl="pallas",
@@ -167,6 +167,13 @@ _DECODE = [
     for kv in ("bf16", "int8")
     for k1 in (1, K1)
     for pg in pages
+] + [
+    # the benchmark's serving cell (gpt2-medium.serve.closed): 32 slots,
+    # 16 heads, pages of 16, window 1024 -> 64 table entries a slot
+    pytest.param(lambda kv=kv: _decode_case("paged", kv, 1, 16,
+                                            slots=32, heads=16),
+                 id=f"paged-{kv}-decode-page16-gpt2-medium-32slots")
+    for kv in ("bf16", "int8")
 ]
 
 _CASES = _DECODE + [

@@ -328,7 +328,14 @@ class TestEngineSpans:
                    if e.name.startswith("serving."))
         dispatch = next(e for e in spans if e.name == "decode.dispatch")
         assert set(dispatch.attrs) == {"window", "horizon", "draft_k",
-                                       "overlapped", "occupancy"}
+                                       "overlapped", "occupancy",
+                                       "kv_pages_live", "kv_pages_window"}
+        # the first dispatch: one slot holds the 19-token prompt (its
+        # next write is column 19, the third page of 8), and the grid
+        # the old kernel walked was both slots' whole window
+        assert dispatch.attrs["kv_pages_live"] == 19 // 8 + 1
+        assert (dispatch.attrs["kv_pages_window"]
+                == 2 * -(-dispatch.attrs["window"] // 8))
         # one group of events per step; engine.step is recorded last
         groups, group = [], []
         for e in s.events():
