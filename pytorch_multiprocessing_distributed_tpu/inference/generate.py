@@ -434,6 +434,109 @@ def _block_verify_slots(p, x_t, k_cache, v_cache, positions, h, dtype,
     return (x_t + _ffn(p, x_t, dtype, eps, top_k), k_cache, v_cache)
 
 
+# ------------------------------------------------------------- the seam
+
+class GPTServing:
+    """What the serving engine asks of a MODEL FAMILY, answered for the
+    GPT family with the block functions above, untouched.
+
+    The engine (``serving.engine``), its pools (``serving.kv_pages``,
+    ``serving.kv_slots``) and the shared decode core
+    (:func:`_decode_horizon`) know a family only through this surface;
+    a model of another family carries its own as ``model.
+    serving_family`` (:func:`serving_family`). The two cache operands
+    the engine threads through every program are the family's two
+    ``cache_rows``: K and V here, the latent and the position key of a
+    latent-attention family.
+    """
+
+    name = "gpt"
+    refuses: dict = {}      # engine option -> why this family lacks it
+
+    def cache_rows(self, model):
+        """``((name, trailing shape, dtype), (...))``: what one token
+        of one layer keeps in each of the two caches."""
+        h = model.num_heads
+        row = (h, model.hidden_size // h)
+        return (("k", row, model.dtype), ("v", row, model.dtype))
+
+    def aux_shape(self, model):
+        """Shape of the integers a decode horizon returns behind its
+        token block (None: none)."""
+        return None
+
+    def logits(self, model, params, x, cs=_no_cs):
+        return _logits(params, x, getattr(model, "ln_eps", _LN_EPS), cs)
+
+    def prefill(self, model, params, prompt, cs=_no_cs, cs_cache=None):
+        """Whole-prompt prefill of ``prompt [1, S]`` -> ``(x, k_pref,
+        v_pref)``, caches ``[L, 1, S, *row]``."""
+        return _prefill(model, params, prompt, prompt.shape[1], cs=cs,
+                        cs_cache=cs_cache)
+
+    def chunk(self, model, params, k_pref, v_pref, tokens, start,
+              cs=_no_cs, cs_cache=None):
+        """One ``[1, chunk]`` slice of an incremental prefill at
+        ``[start, start + chunk)`` against the standalone caches."""
+        dtype = model.dtype
+        eps = getattr(model, "ln_eps", _LN_EPS)
+        moe_k = getattr(model, "moe_top_k", 1)
+        x = _embed_at(params, tokens, start, dtype)
+        new_k, new_v = [], []
+        for i in range(model.num_layers):
+            x, kc, vc = _block_chunk_prefill(
+                params[f"block_{i}"], x, k_pref[i], v_pref[i],
+                start, model.num_heads, dtype, eps, cs, moe_k)
+            new_k.append(kc)
+            new_v.append(vc)
+        return (x, cs_cache(jnp.stack(new_k)), cs_cache(jnp.stack(new_v)))
+
+    def decode_step(self, model, params, k_caches, v_caches, positions,
+                    last_tokens, *, cs=_no_cs, cs_cache, window=None,
+                    attn_impl="xla", block_k=256, kv_valid=None,
+                    uniform_positions=False, page_table=None,
+                    page_size=None, offsets=None):
+        """One pending token a slot through every block; returns ``(x_t
+        [N, 1, D], k_caches, v_caches, aux)``."""
+        dtype = model.dtype
+        eps = getattr(model, "ln_eps", _LN_EPS)
+        moe_k = getattr(model, "moe_top_k", 1)
+        ids = (positions if offsets is None
+               else jnp.maximum(positions - offsets, 0))
+        # cast-then-add, the model's own order — see _embed
+        pos_emb = params["pos_embed"][ids][:, None, :]
+        x_t = (params["embed"][last_tokens][:, None, :].astype(dtype)
+               + pos_emb.astype(dtype))
+        new_k, new_v = [], []
+        for i in range(model.num_layers):
+            x_t, kc, vc = _block_decode_slots(
+                params[f"block_{i}"], x_t, k_caches[i], v_caches[i],
+                positions, model.num_heads, dtype, eps, cs, moe_k,
+                window=window, attn_impl=attn_impl, block_k=block_k,
+                kv_valid=kv_valid, uniform_positions=uniform_positions,
+                page_table=page_table, page_size=page_size)
+            new_k.append(kc)
+            new_v.append(vc)
+        return (x_t, cs_cache(stack_kv(new_k)), cs_cache(stack_kv(new_v)),
+                None)
+
+
+GPT_SERVING = GPTServing()
+
+
+def serving_family(model):
+    """The family surface of ``model``: its own (``model.
+    serving_family``) or, for every flax GPT, :data:`GPT_SERVING`."""
+    return getattr(model, "serving_family", None) or GPT_SERVING
+
+
+def pref_cache_shapes(model, width: int):
+    """Shapes of the two standalone prefill caches ``[L, 1, width,
+    *row]`` a chunked prefill accumulates into."""
+    return tuple((model.num_layers, 1, int(width)) + tuple(row)
+                 for _, row, _ in serving_family(model).cache_rows(model))
+
+
 def _decode_horizon(model, params, k_caches, v_caches, positions,
                     last_tokens, active, remaining, eos_ids, keys, *,
                     cs=_no_cs, cs_cache=None, window=None,
@@ -522,11 +625,7 @@ def _decode_horizon(model, params, k_caches, v_caches, positions,
     updated ``(k_caches, v_caches, positions, last_tokens, active,
     remaining)`` (+ the draft caches in draft-model mode).
     """
-    dtype = model.dtype
-    eps = getattr(model, "ln_eps", _LN_EPS)
-    moe_k = getattr(model, "moe_top_k", 1)
-    h = model.num_heads
-    n_layers = model.num_layers
+    family = serving_family(model)
     if cs_cache is None:
         def cs_cache(c):
             return c
@@ -559,23 +658,13 @@ def _decode_horizon(model, params, k_caches, v_caches, positions,
     def step(carry, key):
         (k_caches, v_caches, positions, last_tokens, active,
          remaining) = carry
-        ids = (positions if offsets is None
-               else jnp.maximum(positions - offsets, 0))
-        # cast-then-add, the model's own order — see _embed
-        pos_emb = params["pos_embed"][ids][:, None, :]
-        x_t = (params["embed"][last_tokens][:, None, :].astype(dtype)
-               + pos_emb.astype(dtype))
-        new_k, new_v = [], []
-        for i in range(n_layers):
-            x_t, kc, vc = _block_decode_slots(
-                params[f"block_{i}"], x_t, k_caches[i], v_caches[i],
-                positions, h, dtype, eps, cs, moe_k, window=window,
-                attn_impl=attn_impl, block_k=block_k, kv_valid=kv_valid,
-                uniform_positions=uniform_positions,
-                page_table=page_table, page_size=page_size)
-            new_k.append(kc)
-            new_v.append(vc)
-        logits = _logits(params, x_t, eps, cs)[:, 0]
+        x_t, k_caches, v_caches, aux = family.decode_step(
+            model, params, k_caches, v_caches, positions, last_tokens,
+            cs=cs, cs_cache=cs_cache, window=window, attn_impl=attn_impl,
+            block_k=block_k, kv_valid=kv_valid,
+            uniform_positions=uniform_positions, page_table=page_table,
+            page_size=page_size, offsets=offsets)
+        logits = family.logits(model, params, x_t, cs)[:, 0]
         nxt = _sample(logits, temperature, top_k, top_p,
                       key).astype(jnp.int32)
         # the finishing token IS emitted (the step engine appends the
@@ -588,12 +677,20 @@ def _decode_horizon(model, params, k_caches, v_caches, positions,
         positions = jnp.where(active, positions + 1, positions)
         last_tokens = jnp.where(active, nxt, last_tokens)
         active = jnp.logical_and(active, jnp.logical_not(finished))
-        return (cs_cache(stack_kv(new_k)), cs_cache(stack_kv(new_v)),
-                positions, last_tokens, active, remaining), emitted
+        return (k_caches, v_caches, positions, last_tokens, active,
+                remaining), (emitted if aux is None else (emitted, aux))
 
     carry, tokens = jax.lax.scan(
         step, (k_caches, v_caches, positions, last_tokens, active,
                remaining), keys)
+    if isinstance(tokens, tuple):
+        # a family with per-step integers (expert counts): summed over
+        # the horizon and packed BEHIND the token block, so they come
+        # back in the block's own readback (serving.engine._drain_one)
+        tokens, aux = tokens
+        tokens = jnp.concatenate(
+            [tokens.reshape(-1),
+             jnp.sum(aux, axis=0).astype(jnp.int32).reshape(-1)])
     return tokens, carry
 
 
@@ -925,6 +1022,11 @@ def generate(
     """
     b, t = prompt.shape
     s_max = t + max_new_tokens
+    if serving_family(model) is not GPT_SERVING:
+        raise NotImplementedError(
+            f"generate() decodes over dense caches, which the "
+            f"{serving_family(model).name} family does not have: serve "
+            "it through ServingEngine(kv_layout='paged')")
     if max_new_tokens < 1:
         raise ValueError(
             f"max_new_tokens must be >= 1, got {max_new_tokens}"

@@ -29,6 +29,7 @@ from .densenet import DenseNet, DenseNet121, DenseNetBC100
 from .vit import ViT, ViT_B16, ViT_S16, ViT_Tiny
 from .convnext import ConvNeXt, ConvNeXt_T, ConvNeXt_S, ConvNeXt_B, ConvNeXt_L
 from .gpt import GPT, GPT_Small, GPT_Medium, GPT_Tiny
+from .xing4 import Xing4, Xing4_29B_A4B, Xing4_Tiny
 
 __all__ = [
     "BasicBlock",
@@ -46,5 +47,6 @@ __all__ = [
     "ViT", "ViT_B16", "ViT_S16", "ViT_Tiny",
     "ConvNeXt", "ConvNeXt_T", "ConvNeXt_S", "ConvNeXt_B", "ConvNeXt_L",
     "GPT", "GPT_Small", "GPT_Medium", "GPT_Tiny", "LM_MODELS",
+    "Xing4", "Xing4_29B_A4B", "Xing4_Tiny",
 ]
 

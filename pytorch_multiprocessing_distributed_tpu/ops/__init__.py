@@ -8,7 +8,8 @@ torch/cuDNN (see SURVEY.md §2.2): cross-replica batch norm replaces
 
 from .batch_norm import SyncBatchNorm
 from .losses import cross_entropy_loss
-from .moe import MoEMlp, shard_expert_params
+from .moe import (MoEMlp, dropless_experts, route_sigmoid_topk,
+                  shard_expert_params)
 
 __all__ = ["SyncBatchNorm", "cross_entropy_loss", "MoEMlp",
-           "shard_expert_params"]
+           "dropless_experts", "route_sigmoid_topk", "shard_expert_params"]

@@ -250,3 +250,69 @@ def audit_programs():
         }
 
     return [{"name": "moe_mlp_ep", "min_devices": 4, "build": build}]
+
+
+# ------------------------------------------------------------ dropless
+
+def route_sigmoid_topk(x32, router, e_bias, top_k: int,
+                       routed_scale: float = 1.0):
+    """Sigmoid-scored top-k routing with a selection bias (the
+    ``noaux_tc`` method with one group): ``s = sigmoid(x Wg)`` in
+    float32 at the highest matmul precision (the top-k of near-equal
+    scores must not turn on a bf16 pass of the MXU); the ``top_k`` of
+    ``s + e_bias`` are CHOSEN, the weights come from ``s`` alone,
+    normalised over the chosen and scaled. ``x32 [T, D]`` ->
+    ``(experts [T, k] int32, weights [T, k] f32)``."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x32.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(scores + e_bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = (picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+               * routed_scale)
+    return chosen.astype(jnp.int32), weights
+
+
+def dropless_experts(x, chosen, weights, w_gate, w_up, w_down):
+    """The ONE dropless routed-expert layer (prefill, chunk and decode
+    alike): every (token, choice) assignment is computed by the expert
+    it names — no capacity, nothing dropped, every shape static.
+
+    The ``T * k`` assignments are sorted by expert (stable, so a
+    token's choices keep their order), each expert's rows then form one
+    contiguous group and the three gated-SiLU matmuls are grouped
+    matmuls over those groups (``jax.lax.ragged_dot``: on the TPU a
+    native kernel that does ``2 * T * k * D * F`` operations a matrix
+    and reads only the experts that have rows), and the outputs are
+    weighted, put back in token order and summed over a token's
+    choices.
+
+    Args:
+      x: ``[T, D]`` tokens in the compute dtype.
+      chosen: ``[T, k]`` int32 expert ids (:func:`route_sigmoid_topk`).
+      weights: ``[T, k]`` float32 combine weights.
+      w_gate, w_up: ``[E, D, F]``; w_down: ``[E, F, D]``.
+
+    Returns ``(y [T, D] float32, counts [E] int32)`` — ``counts`` is
+    the number of assignments each expert received (they sum to
+    ``T * k``: the dropless invariant, and the load the serving
+    metrics report).
+    """
+    t, k = chosen.shape
+    n_experts = w_gate.shape[0]
+    flat = chosen.reshape(t * k)
+    order = jnp.argsort(flat, stable=True)               # [T*k]
+    counts = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
+    rows = jnp.take(x, order // k, axis=0)               # [T*k, D]
+    gate = jax.lax.ragged_dot(rows, w_gate, counts,
+                              preferred_element_type=jnp.float32)
+    up = jax.lax.ragged_dot(rows, w_up, counts,
+                            preferred_element_type=jnp.float32)
+    hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+    out = jax.lax.ragged_dot(hidden, w_down, counts,
+                             preferred_element_type=jnp.float32)
+    out = out * jnp.take(weights.reshape(t * k), order)[:, None]
+    # back to (token, choice) order: the inverse permutation is a
+    # scatter of whole rows, then a sum over each token's k choices
+    y = jnp.zeros_like(out).at[order].set(out)
+    return jnp.sum(y.reshape(t, k, -1), axis=1), counts

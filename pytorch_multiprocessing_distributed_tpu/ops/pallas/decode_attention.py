@@ -75,6 +75,7 @@ from ..kv_quant import QuantizedKV, dequantize_kv
 from .flash_attention import NEG_INF
 
 __all__ = ["decode_attention", "paged_decode_attention",
+           "mla_paged_decode_attention", "xla_mla_paged_decode_attention",
            "verify_decode_attention", "paged_verify_decode_attention",
            "xla_decode_attention", "xla_paged_decode_attention",
            "xla_verify_decode_attention",
@@ -482,6 +483,182 @@ def paged_decode_attention(
             f"impl must be 'pallas', 'xla' or 'auto', got {impl!r}")
     return xla_paged_decode_attention(q, k_pages, v_pages, page_table,
                                       positions, window)
+
+
+# ---------------------------------------------------- latent (MLA) pages
+
+# columns one grid step of the latent kernel folds: G = this // page_size
+# pages, each ONE DMA of one contiguous [ps, R + Rw] page (the kernel's
+# time is the DMAs' issue, ~0.1 us each, not their bytes: PERF.md)
+_MLA_BLOCK_COLUMNS = 512
+
+
+def _mla_page_spec(block_shape, layer, g, group):
+    """Block of page ``g`` of a grid step's ``group`` in layer ``layer``
+    of a ``[L, P, ps, .]`` pool: the layer is static, the page comes
+    from the scalar-prefetched :func:`_live_page_ids`."""
+    return pl.BlockSpec(
+        block_shape,
+        lambda i, kb, pos, ids: (layer, ids[i, kb * group + g], 0, 0))
+
+
+def _mla_paged_decode_kernel(pos_ref, ids_ref, q_ref, *rest, scale,
+                             page_size, group, rank):
+    """One (slot, block of ``group`` pages) cell of the ABSORBED latent
+    decode, all heads at once. The cache holds one row a token that
+    every head shares: the normed latent ``c`` (``rank`` values), then
+    the rotated ``k_rope``, zero-padded to whole lanes. The query is
+    laid out the same way (``q_lat | q_rope | 0``), so the scores
+    ``q_lat . c + q_rope . k_rope`` are ONE contraction over the row,
+    and the output is ``P c`` — still in the latent space; the caller
+    applies the per-head value up-projection. Same online-softmax
+    recurrence and the same live-page indirection as
+    :func:`_paged_decode_kernel`; a row is read once and used as key
+    and (its first ``rank`` lanes) as value."""
+    row_refs = rest[:group]
+    o_ref, acc, m_scr, l_scr = rest[group:]
+    i = pl.program_id(0)
+    kb = pl.program_id(1)
+    block_k = group * page_size
+
+    @pl.when(kb == 0)
+    def _():
+        acc[:] = jnp.zeros_like(acc)
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+
+    pos = pos_ref[i]
+
+    @pl.when(kb * block_k <= pos)
+    def _():
+        pages = [ref[0, 0] for ref in row_refs]          # [ps, R + Rw]
+        rows = pages[0] if group == 1 else jnp.concatenate(pages, axis=0)
+        s = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [H, G*ps]
+        col = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        s = jnp.where(col <= pos, s, NEG_INF)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        m_scr[:] = m_new
+        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc[:] = acc[:] * corr + jnp.dot(
+            p.astype(rows.dtype), rows[:, :rank],
+            preferred_element_type=jnp.float32)
+
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _():
+        o_ref[0] = acc[:] / jnp.maximum(l_scr[:], 1e-30)
+
+
+def _pallas_mla_paged_decode(q, pages, page_table, positions, layer,
+                             rank, scale, interpret):
+    b, h, width = q.shape
+    ps = pages.shape[2]
+    n_win = page_table.shape[1]
+    group = max(1, min(_MLA_BLOCK_COLUMNS // ps, n_win))
+
+    def slot_spec(last):
+        return pl.BlockSpec((1, h, last), lambda i, kb, pos, ids: (i, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # positions, page ids
+        grid=(b, pl.cdiv(n_win, group)),
+        in_specs=[slot_spec(width)] + [
+            _mla_page_spec((1, 1, ps, width), layer, g, group)
+            for g in range(group)],
+        out_specs=slot_spec(rank),
+        scratch_shapes=[
+            pltpu.VMEM((h, rank), jnp.float32),   # latent accumulator
+            pltpu.VMEM((h, 1), jnp.float32),      # running max
+            pltpu.VMEM((h, 1), jnp.float32),      # running denominator
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_mla_paged_decode_kernel, scale=scale,
+                          page_size=ps, group=group, rank=rank),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, rank), jnp.float32),
+        interpret=interpret,
+        name="mla_paged_decode_attention",
+    )(positions.astype(jnp.int32),
+      _live_page_ids(page_table.astype(jnp.int32), positions, group, ps),
+      q, *([pages] * group))
+
+
+def xla_mla_paged_decode_attention(q, pages, page_table, positions, *,
+                                   layer, rank, scale,
+                                   window: Optional[int] = None):
+    """The latent decode in plain XLA: ``take``-gather layer
+    ``layer``'s windowed pages into ``[B, W, R + Rw]`` rows and run the
+    absorbed math with a float32 softmax."""
+    b, n_win = page_table.shape
+    rows = jnp.take(pages[layer], page_table, axis=0)    # [B, n, ps, .]
+    rows = rows.reshape(b, n_win * rows.shape[2], rows.shape[3])
+    if window is not None and window < rows.shape[1]:
+        rows = jax.lax.slice_in_dim(rows, 0, window, axis=1)
+    s = jnp.einsum("bhr,bwr->bhw", q, rows,
+                   preferred_element_type=jnp.float32) * scale
+    mask = jnp.arange(rows.shape[1])[None, :] <= positions[:, None]
+    p = jax.nn.softmax(jnp.where(mask[:, None, :], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhw,bwr->bhr", p.astype(rows.dtype),
+                      rows[..., :rank],
+                      preferred_element_type=jnp.float32)
+
+
+def mla_paged_decode_attention(
+    q: jax.Array,
+    pages: jax.Array,
+    page_table: jax.Array,
+    positions: jax.Array,
+    *,
+    layer: int,
+    rank: int,
+    scale: float,
+    window: Optional[int] = None,
+    impl: str = "auto",
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Single-step ABSORBED latent attention through a page table.
+
+    Args:
+      q: ``[B, H, R + Rw]`` — per head, the no-position query carried
+        into the latent space (``q_nope @ W_uk``, ``R = rank`` values),
+        then the rotated position query, zero beyond the rotary width:
+        the layout of a cache row.
+      pages: ``[L, P, page_size, R + Rw]`` — ALL layers' pages, a row a
+        token: the normed latent, then the rotated shared position key,
+        zero-padded to whole lanes. The kernel's index map picks
+        ``layer``, so no layer is ever sliced out of (and copied from)
+        the pool, and a page is one contiguous DMA.
+      page_table: ``[B, n_win]`` int32, windowed (see
+        :func:`paged_decode_attention`).
+      positions: ``[B]`` — slot ``b`` attends columns ``[0, pos]``.
+      layer: static layer index; rank: ``R``.
+      scale: softmax scale (the YaRN ``m^2`` folded in).
+
+    Returns ``[B, H, R]`` f32: ``softmax(scores) @ c`` per head, to be
+    carried out of the latent space by ``W_uv`` in the caller.
+    """
+    if impl == "auto":
+        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+    if impl == "pallas":
+        if interpret is None:
+            from . import default_interpret
+
+            interpret = default_interpret()
+        return _pallas_mla_paged_decode(
+            q, pages, page_table, positions, int(layer), int(rank),
+            float(scale), bool(interpret))
+    if impl != "xla":
+        raise ValueError(
+            f"impl must be 'pallas', 'xla' or 'auto', got {impl!r}")
+    return xla_mla_paged_decode_attention(
+        q, pages, page_table, positions, layer=layer, rank=rank,
+        scale=scale, window=window)
 
 
 def xla_decode_attention(q, k, v, mask):

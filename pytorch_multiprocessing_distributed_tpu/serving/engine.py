@@ -114,8 +114,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..analysis.sentinels import expected_transfer
 from ..inference.generate import (
-    _LN_EPS, _block_chunk_prefill, _decode_horizon, _embed_at,
-    _logits, _make_cs, _prefill, _sample)
+    _decode_horizon, _make_cs, _prefill, _sample, pref_cache_shapes,
+    serving_family)
 from ..ops.kv_quant import (KV_DTYPES, QuantizedKV, dequantize_kv,
                             kv_slice_in_dim, quantize_kv,
                             quantize_kv_np)
@@ -520,6 +520,19 @@ class ServingEngine:
         if draft_buckets < 1:
             raise ValueError(
                 f"draft_buckets must be >= 1, got {draft_buckets}")
+        # what this model's family does not support yet is refused
+        # here, by the option's name: nothing falls back silently
+        family = serving_family(model)
+        for option, asked in (("kv_layout=dense", kv_layout == "dense"),
+                              ("kv_dtype=int8", kv_dtype == "int8"),
+                              ("draft_k", draft_k > 0),
+                              ("prefix_cache", prefix_cache > 0),
+                              ("mesh", mesh is not None)):
+            if asked and option in family.refuses:
+                raise NotImplementedError(
+                    f"{option} is not supported for the {family.name} "
+                    f"family yet: {family.refuses[option]}")
+        self._family = family
         self.model = model
         self.params = params
         self.mesh = mesh
@@ -1013,21 +1026,19 @@ class ServingEngine:
         ``tok0``). Causality makes right-pad columns invisible to the
         real prefix, so no masks are needed; compiles once per bucket
         size (the prompt's padded shape)."""
-        model = self.model
+        model, family = self.model, self._family
         cs = _make_cs(self.mesh)
-        eps = getattr(model, "ln_eps", _LN_EPS)
         temperature, top_k, top_p = self._sampling
 
         def cs_cache(c):
             return cs(c, None, None, None, "model", None)
 
         def prefill(params, prompt, length, key):
-            x, k_pref, v_pref = _prefill(
-                model, params, prompt, prompt.shape[1], cs=cs,
-                cs_cache=cs_cache)
+            x, k_pref, v_pref = family.prefill(
+                model, params, prompt, cs=cs, cs_cache=cs_cache)
             x_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1,
                                                   axis=1)
-            logits = _logits(params, x_last, eps, cs)[:, 0]
+            logits = family.logits(model, params, x_last, cs)[:, 0]
             tok0 = _sample(logits, temperature, top_k, top_p, key)
             return tok0[0].astype(jnp.int32), k_pref, v_pref
 
@@ -1040,41 +1051,28 @@ class ServingEngine:
         (``inference.generate._block_chunk_prefill``). ONE static shape
         per (chunk, cache-width) pair regardless of prompt length or
         chunk index — ``start`` is traced."""
-        model = self.model
+        model, family = self.model, self._family
         cs = _make_cs(self.mesh)
-        dtype = model.dtype
-        eps = getattr(model, "ln_eps", _LN_EPS)
-        moe_k = getattr(model, "moe_top_k", 1)
-        h = model.num_heads
-        n_layers = model.num_layers
 
         def cs_cache(c):
             return cs(c, None, None, None, "model", None)
 
         def chunk(params, k_pref, v_pref, tokens, start):
-            x = _embed_at(params, tokens, start, dtype)
-            new_k, new_v = [], []
-            for i in range(n_layers):
-                x, kc, vc = _block_chunk_prefill(
-                    params[f"block_{i}"], x, k_pref[i], v_pref[i],
-                    start, h, dtype, eps, cs, moe_k)
-                new_k.append(kc)
-                new_v.append(vc)
-            return (x, cs_cache(jnp.stack(new_k)),
-                    cs_cache(jnp.stack(new_v)))
+            return family.chunk(model, params, k_pref, v_pref, tokens,
+                                start, cs=cs, cs_cache=cs_cache)
 
         return chunk
 
     def _make_tok0(self):
         """First-token sampling off the final chunk's activations —
         ``generate``'s ``tok0`` math on a dynamic within-chunk index."""
+        model, family = self.model, self._family
         cs = _make_cs(self.mesh)
-        eps = getattr(self.model, "ln_eps", _LN_EPS)
         temperature, top_k, top_p = self._sampling
 
         def tok0_fn(params, x, idx, key):
             x_last = jax.lax.dynamic_slice_in_dim(x, idx, 1, axis=1)
-            logits = _logits(params, x_last, eps, cs)[:, 0]
+            logits = family.logits(model, params, x_last, cs)[:, 0]
             tok = _sample(logits, temperature, top_k, top_p, key)
             return tok[0].astype(jnp.int32)
 
@@ -1144,12 +1142,16 @@ class ServingEngine:
         the dense splice. Compiles once per prefill width (the
         ``write_ids`` length is width-derived), like the dense
         per-bucket splice."""
-        ps = k_pages.shape[3]
+        # a page is [H, ps, Dh] for a per-head row (heads before the
+        # column offset), [ps, R] for a row all heads share
+        per_head = len(k_pref.shape) == 5
+        ps = k_pages.shape[3 if per_head else 2]
         n = write_ids.shape[0]
         w = k_pref.shape[2]
         pad = n * ps - w
         if pad:  # width not a page multiple: pad-only columns
-            cfg = ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))
+            cfg = ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (
+                len(k_pref.shape) - 3)
             if isinstance(k_pref, QuantizedKV):
                 k_pref = QuantizedKV(jnp.pad(k_pref.data, cfg),
                                      jnp.pad(k_pref.scale, cfg[:-1]))
@@ -1160,6 +1162,8 @@ class ServingEngine:
                 v_pref = jnp.pad(v_pref, cfg)
 
         def to_pages(c):  # [L, 1, n*ps, H, Dh] -> [L, n, H, ps, Dh]
+            if not per_head:  # [L, 1, n*ps, R] -> [L, n, ps, R]
+                return c.reshape(c.shape[0], n, ps, c.shape[3])
             l, _, _, h, d = c.shape
             return jnp.moveaxis(c.reshape(l, n, ps, h, d), 2, 3)
 
@@ -2262,16 +2266,14 @@ class ServingEngine:
                 else:
                     plan = PrefillPlan(request, self._prefill_chunk,
                                        self.min_bucket, pool.s_max)
-                    model = self.model
-                    shape = (model.num_layers, 1, plan.width,
-                             model.num_heads,
-                             model.hidden_size // model.num_heads)
+                    k_shape, v_shape = pref_cache_shapes(
+                        self.model, plan.width)
                     self._pending = _PendingPrefill(
                         request, plan,
                         self._pref_sharded(
-                            jnp.zeros(shape, model.dtype)),
+                            jnp.zeros(k_shape, self.model.dtype)),
                         self._pref_sharded(
-                            jnp.zeros(shape, model.dtype)),
+                            jnp.zeros(v_shape, self.model.dtype)),
                         prep)
         pend = self._pending
         if pend is None:
@@ -2508,6 +2510,14 @@ class ServingEngine:
             with graftscope.span("decode.readback", cat="serving"):
                 tokens = self._attempted_engine(
                     attempt, "horizon token-block readback")
+            if tokens.ndim == 1:
+                # a family with per-horizon integers (expert counts)
+                # packs them behind the token block: ONE readback
+                n_tok = block.rows * pool.max_slots
+                self.metrics.record_moe(tokens[n_tok:].reshape(
+                    self._family.aux_shape(self.model)))
+                tokens = tokens[:n_tok].reshape(block.rows,
+                                                pool.max_slots)
             realized: Dict[int, int] = {}
             for h in range(block.rows):
                 for slot, request in block.slots.items():
@@ -2828,10 +2838,9 @@ class ServingEngine:
         plan = PrefillPlan(request, int(chunk), self.min_bucket,
                            pool.s_max)
         model = self.model
-        shape = (model.num_layers, 1, plan.width, model.num_heads,
-                 model.hidden_size // model.num_heads)
-        k_pref = self._pref_sharded(jnp.zeros(shape, model.dtype))
-        v_pref = self._pref_sharded(jnp.zeros(shape, model.dtype))
+        k_shape, v_shape = pref_cache_shapes(model, plan.width)
+        k_pref = self._pref_sharded(jnp.zeros(k_shape, model.dtype))
+        v_pref = self._pref_sharded(jnp.zeros(v_shape, model.dtype))
         x = None
         start = 0
         while not plan.done:

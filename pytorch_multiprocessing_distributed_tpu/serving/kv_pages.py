@@ -12,7 +12,13 @@ length distribution while ``max_slots`` (concurrency) grows past the
 dense worst case: the capacity multiplier graftmeter's
 ``per_slot_kv_bytes`` ledger exists to measure.
 
-Layout note: pages keep heads BEFORE the column offset
+The two pools are shaped by the model family's ``cache_rows``
+(``inference.generate.serving_family``): K and V rows of ``(heads,
+head_dim)`` for GPT; for a latent-attention family one row all heads
+share, ``[layers, num_pages, page_size, width]``, and a zero-width
+placeholder.
+
+Layout note: per-head pages keep heads BEFORE the column offset
 (``[..., heads, page_size, head_dim]``) so the Pallas paged decode
 kernel's per-(slot, head) block is ``[page_size, head_dim]`` — the
 TPU-tileable trailing pair (:mod:`...ops.pallas.decode_attention`).
@@ -53,6 +59,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..inference.generate import serving_family
 from ..ops.kv_quant import KV_DTYPES, QuantizedKV
 from ..runtime import hbm, life
 from ..runtime import scope as graftscope
@@ -128,11 +135,13 @@ class PagePool:
             raise ValueError(
                 f"num_pages must be >= 2 (scratch + 1), got "
                 f"{self.num_pages}")
-        h = model.num_heads
-        shape = (model.num_layers, self.num_pages, h, page_size,
-                 model.hidden_size // h)
-        self.k_pages = self._cache_sharded(self._empty_pages(shape))
-        self.v_pages = self._cache_sharded(self._empty_pages(shape))
+        # the family's two cache rows (K and V; the latent and the
+        # position key), each one pool of pages
+        self.k_pages, self.v_pages = (
+            self._cache_sharded(self._empty_pages(
+                self.page_shape(row, self.num_pages, page_size,
+                                model.num_layers)))
+            for _, row, _ in serving_family(model).cache_rows(model))
         # per-slot decode state — identical to SlotPool's (the decode
         # horizon's freeze gates do not care where the columns live)
         self.positions = self._replicated(
@@ -207,24 +216,37 @@ class PagePool:
             return a
         return jax.device_put(a, NamedSharding(self.mesh, P()))
 
+    @staticmethod
+    def page_shape(row, num_pages: int, page_size: int, layers: int):
+        """One pool's shape for a cache row: a per-head row ``(H, Dh)``
+        keeps heads BEFORE the column offset (``[L, P, H, ps, Dh]``,
+        the paged kernel's tileable trailing pair); a row all heads
+        share ``(R,)`` is ``[L, P, ps, R]``."""
+        row = tuple(row)
+        return ((layers, num_pages) + row[:-1] + (int(page_size),)
+                + row[-1:])
+
     # ---- capacity accounting (graftmeter) ------------------------------
     @staticmethod
     def page_kv_bytes(model, page_size: int,
                       kv_dtype: str = "model") -> int:
-        """K+V bytes of ONE page — the exact shape x dtype product
-        ``__init__`` allocates per page (``2 x layers x heads x
-        page_size x head_dim x itemsize``; graftquant int8 charges 1
-        byte per element PLUS one f32 scale per ``head_dim`` group),
-        the planner's paged-mode unit
+        """Bytes of ONE page over both cache rows — the exact shape x
+        dtype product ``__init__`` allocates per page (GPT: ``2 x
+        layers x heads x page_size x head_dim x itemsize``; graftquant
+        int8 charges 1 byte per element PLUS one f32 scale per
+        trailing-dimension group), the planner's paged-mode unit
         (:func:`...analysis.meter.plan_capacity`), byte-exact in BOTH
         modes."""
-        head_dim = model.hidden_size // model.num_heads
-        if kv_dtype == "int8":
-            group_bytes = head_dim * 1 + 4  # int8 lanes + f32 scale
-        else:
-            group_bytes = head_dim * jnp.dtype(model.dtype).itemsize
-        return (2 * model.num_layers * model.num_heads * int(page_size)
-                * group_bytes)
+        total = 0
+        for _, row, dtype in serving_family(model).cache_rows(model):
+            width = int(row[-1])
+            if kv_dtype == "int8":
+                group_bytes = width * 1 + 4  # int8 lanes + f32 scale
+            else:
+                group_bytes = width * jnp.dtype(dtype).itemsize
+            total += (model.num_layers * int(np.prod(row[:-1], dtype=int))
+                      * int(page_size) * group_bytes)
+        return total
 
     @staticmethod
     def pages_for(total_tokens: int, page_size: int) -> int:

@@ -173,6 +173,11 @@ class ServingMetrics:
         self.tokens_drafted = 0
         self.tokens_accepted = 0
         self.accept_len = PercentileMeter()
+        # routed-expert load of the decode steps (families with
+        # experts): assignments computed, and the busiest expert's
+        # load over the mean load, averaged over layers and blocks
+        self.moe_assignments = 0
+        self.moe_load = AverageMeter()
         self._elapsed = 0.0
         self._occupancy_max = 0
         self._queue_wait_max = 0.0
@@ -295,6 +300,18 @@ class ServingMetrics:
             self.tokens_accepted += int(a)
             self.accept_len.update(float(a))
 
+    def record_moe(self, counts) -> None:
+        """One drained block's per-layer expert assignment counts
+        (``[layers, experts]``; they came back in the token block's
+        own readback). Dropless: a layer's counts sum to tokens x
+        top-k."""
+        self.moe_assignments += int(counts.sum())
+        means = counts.mean(axis=1)
+        live = means > 0
+        if live.any():
+            self.moe_load.update(
+                float((counts.max(axis=1)[live] / means[live]).mean()))
+
     def record_page_hold(self) -> None:
         """One admission deferred because the page pool could not
         cover the FIFO head's demand — the head stays QUEUED (held,
@@ -356,6 +373,8 @@ class ServingMetrics:
             "spec_accepted_per_target_step": (
                 0.0 if self.accept_len.count == 0
                 else 1.0 + self.accept_len.avg),
+            "moe_assignments": self.moe_assignments,
+            "moe_load_max_over_mean": self.moe_load.avg,
         }
         # graftscope percentile telemetry: the tail IS the SLO
         for name, meter in (("ttft", self.ttft),

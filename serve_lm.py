@@ -64,7 +64,12 @@ from pytorch_multiprocessing_distributed_tpu.utils.compile_cache import (
 parser = argparse.ArgumentParser(
     description="TPU-native continuous-batching LM serving")
 parser.add_argument('--model', default='gpt_tiny', type=str,
-                    help='gpt_tiny | gpt_small | gpt_medium')
+                    help='gpt_tiny | gpt_small | gpt_medium | '
+                         'xing4_tiny | xing4_29b_a4b')
+parser.add_argument('--model_kwargs', default='', type=str,
+                    help='JSON object of keywords for the registry '
+                         'constructor, e.g. the depth one serving stage '
+                         'holds: \'{"num_layers": 5, "first_k_dense": 1}\'')
 parser.add_argument('--ckpt', default='', type=str,
                     help='msgpack model_<epoch>.pth file, or an orbax '
                          'run directory (train_lm.py --save_path)')
@@ -418,7 +423,8 @@ def main():
     platform = jax.devices()[0].platform
     model = models.get_model(
         args.model, dtype=dtype,
-        attn_impl="flash" if platform == "tpu" else "xla")
+        attn_impl="flash" if platform == "tpu" else "xla",
+        **(json.loads(args.model_kwargs) if args.model_kwargs else {}))
     if args.random_init:
         params = init_params(model, args.seed)
     else:

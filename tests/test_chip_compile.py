@@ -81,6 +81,7 @@ KERNEL_NAMES = {
     "flash_attention_bwd_dkv", "decode_attention",
     "paged_decode_attention", "verify_decode_attention",
     "paged_verify_decode_attention", "fused_sgd_update", "ring_all_reduce",
+    "mla_paged_decode_attention",
 }
 
 
@@ -176,7 +177,24 @@ _DECODE = [
     for kv in ("bf16", "int8")
 ]
 
+def _mla_case(slots=64, heads=32, rank=512, rope=128, page=16,
+              window=8192, layers=5):
+    """The latent decode kernel at the shapes of the cell
+    ``xing4-29b-a4b.serve.closed-4k1k``: 64 slots, 32 heads, rows of
+    512 latent values and the rotary key in 128 lanes, pages of 16,
+    window 8,192, layer 2 of a five-layer pool."""
+    n_win = window // page
+    pages = slots * n_win + 1
+    args = (_sds((slots, heads, rank + rope), BF16),
+            _sds((layers, pages, page, rank + rope), BF16),
+            _sds((slots, n_win), jnp.int32), _sds((slots,), jnp.int32))
+    return (lambda q, c, t, p: da.mla_paged_decode_attention(
+        q, c, t, p, layer=2, rank=rank, scale=0.1, impl="pallas",
+        interpret=False), args)
+
+
 _CASES = _DECODE + [
+    pytest.param(_mla_case, id="mla-paged-decode-xing4-64slots-w8192"),
     pytest.param(lambda: _flash_case(B, S, False), id="flash-fwd-s1024"),
     pytest.param(lambda: _flash_case(B, S, True), id="flash-fwdbwd-s1024"),
     pytest.param(lambda: _flash_case(1, 4096, True),
@@ -254,3 +272,74 @@ def test_gpt_small_train_step_compiles_with_its_kernel(topo, monkeypatch,
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 16e9
+
+
+def test_xing4_decode_program_compiles_at_one_layer(topo):
+    """The decode program of the cell ``xing4-29b-a4b.serve.closed-
+    4k1k`` (64 slots, window 8,192, pages of 16, bfloat16) at ONE
+    expert layer of the published widths, through ``ServingEngine``:
+    the latent kernel compiles inside it beside the grouped expert
+    matmuls, and the donated page pools are written in place (the
+    program holds no second copy of them: ``ROADMAP.md`` A2)."""
+    from perf.rehearse import as_chip
+    from pytorch_multiprocessing_distributed_tpu import models
+    from pytorch_multiprocessing_distributed_tpu.serving import ServingEngine
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    model = models.get_model("xing4_29b_a4b", dtype=BF16, num_layers=1,
+                             first_k_dense=0)
+    params = jax.eval_shape(lambda: model._init(jax.random.PRNGKey(0)))
+    with as_chip(chip):
+        engine = ServingEngine(model, params, max_slots=64, s_max=8192,
+                               kv_layout="paged", page_size=16,
+                               prefill_chunk=1024)
+        assert engine.decode_attn == "pallas"
+        pool = engine.pool
+
+        def sds(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+
+        args = (jax.tree.map(sds, params), sds(pool.k_pages),
+                sds(pool.v_pages), sds(pool.device_table()),
+                sds(pool.positions), sds(pool.last_tokens),
+                sds(pool.active), sds(pool.budgets), sds(pool.eos_ids),
+                jax.ShapeDtypeStruct((2,), jnp.uint32))
+        compiled = engine._decode.lower(*args, window=8192,
+                                        horizon=1).compile()
+    assert "mla_paged_decode_attention" in _mosaic_names(compiled.as_text())
+    mem = compiled.memory_analysis()
+    pools = pool.k_pages.nbytes + pool.v_pages.nbytes
+    assert pools == 32769 * 16 * (512 + 128) * 2 and not pool.v_pages.size
+    # temporaries far below one copy of the pools: written in place
+    assert mem.temp_size_in_bytes < pools // 4, mem
+    assert mem.alias_size_in_bytes >= pools
+
+
+@pytest.mark.parametrize("positions", [[0, 37, 95], [95, 95, 16]])
+@pytest.mark.parametrize("columns", [512, 32])
+def test_mla_kernel_in_interpret_mode_equals_the_xla_form(
+        monkeypatch, positions, columns):
+    """Values, on the CPU: the kernel under the Pallas interpreter
+    against the gather-and-softmax form, live pages only, in one block
+    of pages a slot and in three (the online softmax across blocks)."""
+    monkeypatch.setattr(da, "_MLA_BLOCK_COLUMNS", columns)
+    rng = np.random.default_rng(0)
+    slots, heads, rank, rope, page, n_win, layers = 3, 4, 32, 128, 8, 12, 2
+    pages = slots * n_win + 1
+
+    def rand(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    table = jnp.asarray(rng.permutation(np.arange(1, pages)).reshape(
+        slots, n_win), jnp.int32)
+    args = (rand(slots, heads, rank + rope),
+            rand(layers, pages, page, rank + rope), table,
+            jnp.asarray(positions, jnp.int32))
+    want = da.mla_paged_decode_attention(
+        *args, layer=1, rank=rank, scale=0.2, window=page * n_win,
+        impl="xla")
+    got = da.mla_paged_decode_attention(
+        *args, layer=1, rank=rank, scale=0.2, impl="pallas",
+        interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)

@@ -7,7 +7,7 @@ reads it out of the compiled text). The benchmark's kernel metrics
 (``perf/layer_metrics/flash_*_ms.train.json``,
 ``paged_decode_attn_ms.serve.json``) match on these names, so the
 ledger can compare a kernel's time across PRs that rewrite what is
-around it. Here each of the nine call sites is traced (nothing runs)
+around it. Here each of the ten call sites is traced (nothing runs)
 and the name is read out of the jaxpr.
 """
 
@@ -68,6 +68,16 @@ def _decode(paged, k1, int8=False):
             (q, pages, pages, table, pos))
 
 
+def _mla():
+    """The latent decode kernel over a ``[L, P, ps, R + Rw]`` pool."""
+    pages = B * (S // PAGE) + 1
+    args = (_sds((B, H, D + 128)), _sds((2, pages, PAGE, D + 128)),
+            _sds((B, S // PAGE), jnp.int32), _sds((B,), jnp.int32))
+    return (lambda q, c, t, p: da.mla_paged_decode_attention(
+        q, c, t, p, layer=1, rank=D, scale=0.1, impl="pallas",
+        interpret=True), args)
+
+
 def _sgd():
     leaves = {"w": _sds((24, 40)), "b": _sds((40,))}
     return (lambda p, g, m: fused_sgd_apply(p, g, m, 0.1, interpret=True),
@@ -115,6 +125,8 @@ _SITES = [
      "verify_decode_attention"),
     ("decode_attention.py paged verify", lambda: _decode(True, K1),
      "paged_verify_decode_attention"),
+    ("decode_attention.py latent paged", _mla,
+     "mla_paged_decode_attention"),
     ("fused_update.py", _sgd, "fused_sgd_update"),
     ("ring_allreduce.py", _ring, "ring_all_reduce"),
     # the int8 variants go through the same call sites
@@ -143,14 +155,17 @@ def test_call_site_passes_its_stable_name(make, want):
 
 
 def test_kernel_metrics_match_the_names_the_kernels_carry():
-    """The three metric files of PR 25 select by regex over
-    ``mosaic:<name>``: each must pick out exactly its kernels."""
-    assert len(KERNEL_NAMES) == 9
+    """The metric files that select by regex over ``mosaic:<name>``
+    (PR 25's three, PR 27's and PR 29's rooflines, PR 29's latent
+    kernel): each must pick out exactly its kernels."""
+    assert len(KERNEL_NAMES) == 10
     labels = ["mosaic:" + name for name in KERNEL_NAMES]
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     picked = {}
     for metric in ("flash_fwd_ms.train", "flash_bwd_ms.train",
-                   "paged_decode_attn_ms.serve"):
+                   "paged_decode_attn_ms.serve", "mla_decode_attn_ms.serve",
+                   "mla_decode_attn_roofline.serve",
+                   "paged_decode_attn_roofline.serve"):
         with open(os.path.join(root, "perf", "layer_metrics",
                                metric + ".json")) as fh:
             rx = re.compile(json.load(fh)["args"]["match"])
@@ -160,4 +175,9 @@ def test_kernel_metrics_match_the_names_the_kernels_carry():
         "flash_bwd_ms.train": {"mosaic:flash_attention_bwd_dq",
                                "mosaic:flash_attention_bwd_dkv"},
         "paged_decode_attn_ms.serve": {"mosaic:paged_decode_attention"},
+        "paged_decode_attn_roofline.serve": {
+            "mosaic:paged_decode_attention"},
+        "mla_decode_attn_ms.serve": {"mosaic:mla_paged_decode_attention"},
+        "mla_decode_attn_roofline.serve": {
+            "mosaic:mla_paged_decode_attention"},
     }
