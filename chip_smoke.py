@@ -364,6 +364,7 @@ def child_kernels(platform):
     gpt_small serving shapes, compiled on the chip (interpret mode in
     the CPU rehearsal), against the repo's own XLA reference of the
     same call, to a bf16 tolerance."""
+    import functools
     import importlib
 
     import jax
@@ -371,7 +372,7 @@ def child_kernels(platform):
     import numpy as np
 
     from pytorch_multiprocessing_distributed_tpu.ops.kv_quant import (
-        quantize_kv)
+        flatten_heads, quantize_kv)
     da = importlib.import_module(
         "pytorch_multiprocessing_distributed_tpu.ops.pallas"
         ".decode_attention")
@@ -391,6 +392,7 @@ def child_kernels(platform):
         dense, paged = ((da.decode_attention, da.paged_decode_attention)
                         if k1 == 1 else (da.verify_decode_attention,
                                          da.paged_verify_decode_attention))
+        paged = functools.partial(paged, layer=1)
         for page in (None, 16, 128 if platform == "tpu" else 32):
             for kv in ("bf16", "int8"):
                 if page is None:
@@ -400,13 +402,15 @@ def child_kernels(platform):
                                  for impl in ("pallas", "xla"))
                 else:
                     def pages(x):  # slot j's block n is page j*n_win+n+1
-                        x = x.reshape(b, s // page, page, h, d)
-                        x = jnp.moveaxis(x, 3, 2).reshape(-1, h, page, d)
-                        return jnp.concatenate(
-                            [jnp.zeros_like(x[:1]), x])
+                        # of layer 1 of a two-layer [L, P, ps, H * Dh]
+                        # pool; the int8 pair keeps [L, P, ps, H] scales
+                        x = x.reshape(-1, page, h, d)
+                        x = jnp.stack([jnp.zeros_like(x), x])
+                        x = jnp.concatenate(
+                            [jnp.zeros_like(x[:, :1]), x], axis=1)
+                        return flatten_heads(
+                            quantize_kv(x) if kv == "int8" else x)
                     kk, vv = pages(k), pages(v)
-                    if kv == "int8":
-                        kk, vv = quantize_kv(kk), quantize_kv(vv)
                     tab = 1 + jnp.arange(b * (s // page),
                                          dtype=jnp.int32).reshape(b, -1)
                     got, want = (paged(q, kk, vv, tab, pos, impl=impl)

@@ -25,8 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.kv_quant import (QuantizedKV, kv_slice_in_dim, quantize_kv,
-                            stack_kv)
+from ..ops.kv_quant import (QuantizedKV, flatten_heads, kv_slice_in_dim,
+                            quantize_kv, stack_kv)
 from ..ops.pallas.decode_attention import (decode_attention,
                                            paged_decode_attention,
                                            paged_verify_decode_attention,
@@ -198,11 +198,33 @@ def _block_decode(p, x_t, k_cache, v_cache, pos, h, dtype, eps,
     return (x_t + _ffn(p, x_t, dtype, eps, top_k), k_cache, v_cache)
 
 
+def _write_pages(pages, layer, page_ids, offs, rows):
+    """New K or V ``rows [..., H, Dh]`` into a ``[L, P, ps, H * Dh]``
+    pool at ``(layer, page_ids, offs)`` (both ``[...]``): a token's
+    heads side by side in one row of lanes. graftquant pools quantize
+    the fresh rows over ``Dh`` and write BOTH leaves (data ``[..., H *
+    Dh]``, scale ``[..., H]``) at the same place."""
+    if isinstance(pages, QuantizedKV):
+        rows = quantize_kv(rows)
+    return jax.tree.map(
+        lambda pool, new: pool.at[layer, page_ids, offs].set(new),
+        pages, flatten_heads(rows))
+
+
+def _window_table(page_table, window, page_size):
+    """The table's leading ``ceil(window / page_size)`` entries: the
+    pages a windowed paged attention may name."""
+    n_win = (-(-int(window) // page_size) if window is not None
+             else page_table.shape[1])
+    return jax.lax.slice_in_dim(page_table, 0,
+                                min(n_win, page_table.shape[1]), axis=1)
+
+
 def _block_decode_slots(p, x_t, k_cache, v_cache, positions, h, dtype,
                         eps, cs=_no_cs, top_k=1, window=None,
                         attn_impl="xla", block_k=256, interpret=None,
                         kv_valid=None, uniform_positions=False,
-                        page_table=None, page_size=None):
+                        page_table=None, page_size=None, layer=None):
     """Vector-position variant of :func:`_block_decode` — the shared
     decode body (:func:`_decode_horizon`). Each row (slot) writes its
     pending token's K/V at its OWN position, then attends over the
@@ -227,14 +249,17 @@ def _block_decode_slots(p, x_t, k_cache, v_cache, positions, h, dtype,
     scatter — on TPU the scatter is markedly slower, and this is the
     hottest loop in the framework.
 
-    **Paged mode** (``page_table`` + ``page_size``, graftpage):
-    ``k_cache``/``v_cache`` are one layer's PAGE storage
-    ``[num_pages, H, page_size, Dh]`` and each row's logical column
-    ``p`` lives at ``(page_table[row, p // page_size], p %
-    page_size)``. The write scatters through the table; attention
-    gathers through it (:func:`...ops.pallas.decode_attention.
-    paged_decode_attention` — take-based XLA reference, or the Pallas
-    kernel whose index map does the indirection before the DMA). A
+    **Paged mode** (``page_table`` + ``page_size`` + ``layer``,
+    graftpage): ``k_cache``/``v_cache`` are the WHOLE pools, ALL
+    layers' page storage ``[L, num_pages, page_size, H * Dh]`` (heads
+    side by side in the lanes), carried through the layers untouched
+    but for this layer's new rows, so the donated pool is written in
+    place: each row's logical column ``p`` lives at ``(layer,
+    page_table[row, p // page_size], p % page_size)``. The write
+    scatters through the table; attention gathers through it
+    (:func:`...ops.pallas.decode_attention.paged_decode_attention` —
+    take-based XLA reference, or the Pallas kernel whose index map
+    does the indirection, layer included, before the DMA). A
     released slot's table row points at the scratch page 0, so the
     frozen-row re-write invariant (masked rows re-hit "their own
     column" each step) lands in scratch instead of a page since
@@ -259,28 +284,13 @@ def _block_decode_slots(p, x_t, k_cache, v_cache, positions, h, dtype,
             page_table, (positions // ps)[:, None], axis=1)[:, 0]
         offs = positions % ps
         # per-row write through the table: row j's K/V lands at its
-        # own (page, offset) — pages [P, H, ps, Dh], k[:, 0] [N, H, Dh].
-        # graftquant pages quantize the fresh token's K/V over Dh and
-        # write BOTH leaves at the same (page, offset)
-        if isinstance(k_cache, QuantizedKV):
-            qk, qv = quantize_kv(k[:, 0]), quantize_kv(v[:, 0])
-            k_cache = QuantizedKV(
-                k_cache.data.at[page_ids, :, offs].set(qk.data),
-                k_cache.scale.at[page_ids, :, offs].set(qk.scale))
-            v_cache = QuantizedKV(
-                v_cache.data.at[page_ids, :, offs].set(qv.data),
-                v_cache.scale.at[page_ids, :, offs].set(qv.scale))
-        else:
-            k_cache = k_cache.at[page_ids, :, offs].set(k[:, 0])
-            v_cache = v_cache.at[page_ids, :, offs].set(v[:, 0])
-        n_win = (-(-int(window) // ps) if window is not None
-                 else page_table.shape[1])
-        ids = jax.lax.slice_in_dim(page_table, 0,
-                                   min(n_win, page_table.shape[1]),
-                                   axis=1)
+        # own (layer, page, offset)
+        k_cache = _write_pages(k_cache, layer, page_ids, offs, k[:, 0])
+        v_cache = _write_pages(v_cache, layer, page_ids, offs, v[:, 0])
         att = paged_decode_attention(
-            q, k_cache, v_cache, ids, positions, window=window,
-            impl=attn_impl, interpret=interpret)
+            q, k_cache, v_cache, _window_table(page_table, window, ps),
+            positions, layer=layer, window=window, impl=attn_impl,
+            interpret=interpret)
         att = att.reshape(n, 1, -1).astype(dtype)
         x_t = x_t + _dense(att, p["attn"]["wo"], dtype)
         return (x_t + _ffn(p, x_t, dtype, eps, top_k), k_cache, v_cache)
@@ -352,7 +362,7 @@ def draft_bucket(tokens, n_buckets: int):
 def _block_verify_slots(p, x_t, k_cache, v_cache, positions, h, dtype,
                         eps, cs=_no_cs, top_k=1, window=None,
                         attn_impl="xla", block_k=256, interpret=None,
-                        page_table=None, page_size=None):
+                        page_table=None, page_size=None, layer=None):
     """k-query VERIFY variant of :func:`_block_decode_slots`
     (graftspec): ``x_t`` is ``[N, K1, D]`` — each slot's pending token
     plus its ``K1 - 1`` draft proposals. Row ``i``'s K/V is written at
@@ -371,7 +381,8 @@ def _block_verify_slots(p, x_t, k_cache, v_cache, positions, h, dtype,
     ``position + remaining <= s_max - 1``); paged writes whose column
     falls beyond the slot's table land on the scratch page 0, so a
     draft write can never touch a page owned by another tenant or a
-    shared read-only prefix page."""
+    shared read-only prefix page. Paged mode carries the whole pools
+    and a static ``layer``, as :func:`_block_decode_slots` does."""
     n, k1, _ = x_t.shape
     hn = _ln(x_t, p["ln1"], eps).astype(dtype)
     q, k, v = jnp.split(_dense(hn, p["attn"]["wqkv"], dtype), 3, axis=-1)
@@ -387,25 +398,12 @@ def _block_verify_slots(p, x_t, k_cache, v_cache, positions, h, dtype,
             page_table, jnp.clip(blk, 0, n_tab - 1), axis=1)
         page_ids = jnp.where(blk < n_tab, page_ids, 0)
         offs = cols % ps
-        if isinstance(k_cache, QuantizedKV):
-            qk, qv = quantize_kv(k), quantize_kv(v)
-            k_cache = QuantizedKV(
-                k_cache.data.at[page_ids, :, offs].set(qk.data),
-                k_cache.scale.at[page_ids, :, offs].set(qk.scale))
-            v_cache = QuantizedKV(
-                v_cache.data.at[page_ids, :, offs].set(qv.data),
-                v_cache.scale.at[page_ids, :, offs].set(qv.scale))
-        else:
-            k_cache = k_cache.at[page_ids, :, offs].set(k)
-            v_cache = v_cache.at[page_ids, :, offs].set(v)
-        n_win = (-(-int(window) // ps) if window is not None
-                 else page_table.shape[1])
-        ids = jax.lax.slice_in_dim(page_table, 0,
-                                   min(n_win, page_table.shape[1]),
-                                   axis=1)
+        k_cache = _write_pages(k_cache, layer, page_ids, offs, k)
+        v_cache = _write_pages(v_cache, layer, page_ids, offs, v)
         att = paged_verify_decode_attention(
-            q, k_cache, v_cache, ids, positions, window=window,
-            impl=attn_impl, interpret=interpret)
+            q, k_cache, v_cache, _window_table(page_table, window, ps),
+            positions, layer=layer, window=window, impl=attn_impl,
+            interpret=interpret)
     else:
         rows = jnp.arange(n)[:, None]
         if isinstance(k_cache, QuantizedKV):
@@ -507,14 +505,25 @@ class GPTServing:
         pos_emb = params["pos_embed"][ids][:, None, :]
         x_t = (params["embed"][last_tokens][:, None, :].astype(dtype)
                + pos_emb.astype(dtype))
+        block = partial(
+            _block_decode_slots, positions=positions, h=model.num_heads,
+            dtype=dtype, eps=eps, cs=cs, top_k=moe_k, window=window,
+            attn_impl=attn_impl, block_k=block_k, kv_valid=kv_valid,
+            uniform_positions=uniform_positions, page_table=page_table,
+            page_size=page_size)
+        if page_table is not None:
+            # the page pools travel WHOLE through the layers: a layer
+            # writes its rows into them and reads its pages out of them
+            # in place, so the donated pools are never copied
+            for i in range(model.num_layers):
+                x_t, k_caches, v_caches = block(
+                    params[f"block_{i}"], x_t, k_caches, v_caches,
+                    layer=i)
+            return x_t, cs_cache(k_caches), cs_cache(v_caches), None
         new_k, new_v = [], []
         for i in range(model.num_layers):
-            x_t, kc, vc = _block_decode_slots(
-                params[f"block_{i}"], x_t, k_caches[i], v_caches[i],
-                positions, model.num_heads, dtype, eps, cs, moe_k,
-                window=window, attn_impl=attn_impl, block_k=block_k,
-                kv_valid=kv_valid, uniform_positions=uniform_positions,
-                page_table=page_table, page_size=page_size)
+            x_t, kc, vc = block(params[f"block_{i}"], x_t, k_caches[i],
+                                v_caches[i])
             new_k.append(kc)
             new_v.append(vc)
         return (x_t, cs_cache(stack_kv(new_k)), cs_cache(stack_kv(new_v)),
@@ -585,7 +594,7 @@ def _decode_horizon(model, params, k_caches, v_caches, positions,
       offsets: ``[N]`` int32 left-pad offsets for ragged ``generate``
         (position-embedding ids become ``max(positions - offsets, 0)``).
       page_table / page_size: paged-KV mode (graftpage): ``k_caches``/
-        ``v_caches`` are ``[L, num_pages, H, page_size, Dh]`` page
+        ``v_caches`` are ``[L, num_pages, page_size, H * Dh]`` page
         storage and ``page_table`` ``[N, pages_per_slot]`` int32 maps
         each slot's logical columns onto pages (read-only inside the
         scan — allocation is host-side, pre-jit). See
@@ -779,15 +788,24 @@ def _decode_horizon_spec(model, params, k_caches, v_caches, positions,
         ids = jnp.clip(cols, 0, pe.shape[0] - 1)
         x_t = (params["embed"][qtok].astype(dtype)
                + pe[ids].astype(dtype))
-        new_k, new_v = [], []
-        for i in range(n_layers):
-            x_t, kc, vc = _block_verify_slots(
-                params[f"block_{i}"], x_t, k_caches[i], v_caches[i],
-                positions, h, dtype, eps, cs, moe_k, window=window,
-                attn_impl=attn_impl, block_k=block_k,
-                page_table=page_table, page_size=page_size)
-            new_k.append(kc)
-            new_v.append(vc)
+        verify = partial(
+            _block_verify_slots, positions=positions, h=h, dtype=dtype,
+            eps=eps, cs=cs, top_k=moe_k, window=window,
+            attn_impl=attn_impl, block_k=block_k, page_table=page_table,
+            page_size=page_size)
+        if page_table is not None:  # whole pools, written in place
+            for i in range(n_layers):
+                x_t, k_caches, v_caches = verify(
+                    params[f"block_{i}"], x_t, k_caches, v_caches,
+                    layer=i)
+        else:
+            new_k, new_v = [], []
+            for i in range(n_layers):
+                x_t, kc, vc = verify(params[f"block_{i}"], x_t,
+                                     k_caches[i], v_caches[i])
+                new_k.append(kc)
+                new_v.append(vc)
+            k_caches, v_caches = stack_kv(new_k), stack_kv(new_v)
         logits = _logits(params, x_t, eps, cs)        # [N, k+1, V]
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
@@ -815,7 +833,7 @@ def _decode_horizon_spec(model, params, k_caches, v_caches, positions,
             active, jnp.logical_or(hit_eos, remaining <= 0))
         positions = positions + e
         active = jnp.logical_and(active, jnp.logical_not(finished))
-        out = (cs_cache(stack_kv(new_k)), cs_cache(stack_kv(new_v)),
+        out = (cs_cache(k_caches), cs_cache(v_caches),
                positions, last_tokens, active, remaining)
         if draft_model is not None:
             out = out + (dk, dv)

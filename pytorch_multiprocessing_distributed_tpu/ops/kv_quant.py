@@ -4,19 +4,22 @@ The serving stack's decode hot loop is bandwidth- and residency-bound:
 KV pages are the dominant bytes term of every flash-decode dispatch and
 the per-slot HBM term that bounds batch. Storing K/V **int8 with
 per-token-per-head f32 scales** halves both at a budgeted logit cost —
-the scale sidecar lives BESIDE the data with the trailing ``[...,
-head_dim]`` pair untouched, so the tileable layout the Pallas kernels
-stream is unchanged and the dequant is one multiply in the VMEM stream.
+the scale sidecar lives BESIDE the data with the data's own layout
+untouched, so what the Pallas kernels stream is unchanged and the
+scale is one multiply in the VMEM stream.
 
 The representation is :class:`QuantizedKV`, a registered pytree node
-``(data int8, scale f32)`` whose scale carries the data's shape MINUS
-the trailing head_dim axis (quantization groups over head_dim — one
-amax per (…, token, head) group):
+``(data int8, scale f32)`` with one scale per head_dim group
+(quantization groups over head_dim — one amax per (…, token, head)
+group), so the scale carries the data's shape MINUS the trailing
+head_dim axis:
 
 * dense slot caches: data ``[L, slots, s_max, H, Dh]`` int8,
   scale ``[L, slots, s_max, H]`` f32;
-* paged caches: data ``[L, pages, H, page_size, Dh]`` int8,
-  scale ``[L, pages, H, page_size]`` f32.
+* paged caches keep a token's heads side by side in the lanes: data
+  ``[L, pages, page_size, H * Dh]`` int8, scale ``[L, pages,
+  page_size, H]`` f32 — the same numbers, the last two data axes
+  merged (a fresh ``[..., H, Dh]`` pair is reshaped on its way in).
 
 Because it is a pytree, every existing jitted program signature,
 ``donate_argnums`` index, and ``out_shardings`` arity is UNCHANGED — a
@@ -57,6 +60,7 @@ __all__ = [
     "quantize_kv_np",
     "kv_slice_in_dim",
     "stack_kv",
+    "flatten_heads",
 ]
 
 # engine-facing names for the cache element layout; "model" keeps the
@@ -75,7 +79,9 @@ _INV_QMAX = np.float32(1.0 / _QMAX)
 class QuantizedKV:
     """Pytree pair ``(data int8, scale f32)`` for a quantized KV cache.
 
-    ``scale.shape == data.shape[:-1]`` — one scale per head_dim group.
+    ``scale.shape == data.shape[:-1]`` — one scale per head_dim group
+    (a paged pool merges data's ``(H, Dh)`` into ``H * Dh`` lanes:
+    ``scale.shape == data.shape[:-1] + (H,)``).
     Registered as a pytree node so jit/scan/donation/sharding treat it
     as two ordinary leaves; duck-typed just enough (``shape``/``dtype``
     delegate to ``data``, ``__getitem__`` indexes both leaves) that
@@ -182,3 +188,12 @@ def stack_kv(leaves):
         return QuantizedKV(jnp.stack([kv.data for kv in leaves]),
                            jnp.stack([kv.scale for kv in leaves]))
     return jnp.stack(leaves)
+
+
+def flatten_heads(kv):
+    """``[..., H, Dh]`` K/V rows (array or quantized pair) as the paged
+    pools keep them: a token's heads side by side, ``[..., H * Dh]``.
+    A pair's ``[..., H]`` scales are already that row's sidecar."""
+    if isinstance(kv, QuantizedKV):
+        return QuantizedKV(flatten_heads(kv.data), kv.scale)
+    return kv.reshape(kv.shape[:-2] + (-1,))

@@ -18,22 +18,29 @@ which is what makes the engine's length-bucketed window *and* this
 kernel compose (the bucket bounds the grid, the position gate bounds
 the work inside it).
 
-The PAGED decode kernel (``[P, H, ps, Dh]`` pools behind a page table)
-has the grid ``(slot, block of G pages)``: one step folds ALL heads of
-``G * ps`` columns (G = 8 pages of 16, 1 page of 128), a softmax state
-per head, the two contractions batched over heads. A page's
-``[H, ps, Dh]`` is contiguous, so it is one DMA; each pool is passed G
-times with plain ``BlockSpec``s — operand ``g`` of a step is page ``g``
-of its block — and the pipeline double-buffers them, the next step's
-pages (the next slot's first block at a slot's end) in flight while
-this one is folded. Which pool page a step's operand names is worked
-out once, in XLA, from positions and table (:func:`_live_page_ids`):
-only pages at or before the slot's position, and a block beyond it
-names its predecessor's pages again, which the pipeline does not copy
-twice — so a slot costs its live pages, not its window. (A hand-rolled
-``make_async_copy`` of a page does not lower for ``Dh`` 64: Mosaic pads
-the pool's minor dimension to 128 lanes and then refuses the 64-wide
-slice.)
+The PAGED kernels read the pool the engine holds, whole:
+``[L, P, ps, H * Dh]`` — a page is ``ps`` rows of every head's ``Dh``
+values side by side in the lanes (head ``h`` in lanes ``h * Dh .. (h +
+1) * Dh``), so it is lane-dense whatever ``Dh`` is, one contiguous DMA,
+and the layer is a STATIC index of the block's index map: no layer is
+ever sliced out of the pool, so the donated pool is written and read in
+place. The grid is ``(slot, block of G pages)``: one step folds ALL
+heads of ``G * ps`` columns (G = 8 pages of 16, 1 page of 128). Each
+pool is passed G times with plain ``BlockSpec``s — operand ``g`` of a
+step is page ``g`` of its block — and the pipeline double-buffers them,
+the next step's pages (the next slot's first block at a slot's end) in
+flight while this one is folded. Which pool page a step's operand names
+is worked out once, in XLA, from positions and table
+(:func:`_live_page_ids`): only pages at or before the slot's position,
+and a block beyond it names its predecessor's pages again, which the
+pipeline does not copy twice — so a slot costs its live pages, not its
+window. Heads are kept apart by the QUERY: the caller's ``[H, Dh]``
+query becomes block-diagonal ``[H, H * Dh]`` (row ``h`` holds ``q_h`` in
+head ``h``'s lanes, zeros elsewhere), so ``Q rows^T`` is every head's
+scores in one contraction (the extra products are exact zeros), ``P
+rows`` is ``[H, H * Dh]`` and head ``h``'s output is its diagonal block,
+taken outside the kernel. The k-query verify pass is the same kernel
+with ``K1 * H`` query rows and a row-staggered mask.
 
 Matmuls stay in the input dtype (bf16 hits the MXU's native rate),
 accumulation is f32, outputs are f32 (the engine casts back to model
@@ -42,16 +49,18 @@ dtype after the residual add, matching the XLA path's dtypes exactly).
 **graftquant**: every kernel (and every XLA reference) also takes the
 KV operand as a :class:`...kv_quant.QuantizedKV` pair — int8 data plus
 a per-(token, head) f32 scale streamed beside it (dense: a ``[B*H,
-1, S]`` row per block; paged decode: the ``[H, ps]`` sidecar of the
-SAME page, through the same page ids; paged verify: one ``[ps]`` row
-of it, viewed ``[P, H, 1, ps]`` — the size-1 axis is what makes a
-one-row scale block legal on the TPU). The
-dequant is ONE multiply in the
-VMEM stream, applied before the existing MXU dot — so the decode step's
+1, S]`` row per block; paged: the ``[ps, H]`` sidecar of the SAME page,
+``[L, P, ps, H]``, through the same page ids). The dense kernels
+dequant each block in the VMEM stream (ONE multiply, before the MXU
+dot); the paged kernels feed the int8 lanes to the MXU as they are
+(exact in the compute dtype) and apply the scale where it is one number
+a head and column: to the scores (K) and to the probabilities (V) —
+``sum_d q_d (k_d s) = s sum_d q_d k_d``. Either way the decode step's
 dominant HBM bytes term (the K/V read) halves while the matmul dtype
 and f32 accumulation stay exactly as above. The XLA fallbacks dequant
-with the identical expression before the reference einsum, so CPU
-tier-1 pins the exact math the TPU kernel runs.
+with :func:`...kv_quant.dequantize_kv` before the reference einsum —
+the same numbers up to f32 rounding — so CPU tier-1 pins the math the
+TPU kernel runs.
 
 ``impl="xla"`` is the reference fallback — the exact einsum/softmax
 math the engine shipped with (and ``inference.generate`` still uses),
@@ -80,17 +89,6 @@ __all__ = ["decode_attention", "paged_decode_attention",
            "xla_decode_attention", "xla_paged_decode_attention",
            "xla_verify_decode_attention",
            "xla_paged_verify_decode_attention"]
-
-
-def _paged_scale_spec(page_size, heads):
-    """Block of one (page, head) row of the ``[P, H, 1, ps]`` scale
-    side-car view, steered by the same scalar-prefetched table as its
-    page. The size-1 axis keeps the block's trailing two dims equal to
-    the array's — a ``(1, ps)`` block of ``[H, ps]`` is not a legal TPU
-    block."""
-    return pl.BlockSpec(
-        (1, 1, 1, page_size),
-        lambda i, kb, pos, tab: (tab[i // heads, kb], i % heads, 0, 0))
 
 
 def _kernel_dequant(blk, scale_row, dtype):
@@ -219,9 +217,8 @@ def _pallas_decode(q, k, v, positions, scale, block_k, interpret,
 
 
 # the K and V page blocks one grid step holds in VMEM (each double-
-# buffered by the pipeline) may take this much by their nominal size;
-# the chip's tiled layout pads a 64-wide head to 128 lanes, so at
-# most twice this
+# buffered by the pipeline) may take this much; a lane-dense page is
+# not padded, so this is what they take
 _PAGE_BLOCK_BYTES = 4 << 20
 
 
@@ -236,7 +233,7 @@ def _pages_per_step(page_size, n_win, page_bytes):
 
 def _live_page_ids(page_table, positions, group, page_size):
     """``[B, n_blocks * G]``: the pool page each (grid step, operand)
-    of the paged kernel names. Only live pages are ever named, so
+    of a paged kernel names. Only live pages are ever named, so
     nothing beyond a slot's position is copied: a block that starts
     beyond the position names the pages of the slot's last live block
     again, and a page beyond the position inside that block names the
@@ -255,33 +252,58 @@ def _live_page_ids(page_table, positions, group, page_size):
     return jnp.take_along_axis(page_table, page.reshape(b, -1), axis=1)
 
 
-def _page_spec(block_shape, g, group):
-    """Block of page ``g`` of a grid step's ``group``: all heads of ONE
-    page (or of its scale sidecar), steered by the scalar-prefetched
+def _page_spec(block_shape, layer, g, group):
+    """Block of page ``g`` of a grid step's ``group`` in layer ``layer``
+    of a ``[L, P, ps, .]`` pool (or of its scale sidecar): the layer is
+    static, the page comes from the scalar-prefetched
     :func:`_live_page_ids`."""
-    zeros = (0,) * (len(block_shape) - 1)
     return pl.BlockSpec(
         block_shape,
-        lambda i, kb, pos, ids: (ids[i, kb * group + g],) + zeros)
+        lambda i, kb, pos, ids: (layer, ids[i, kb * group + g], 0, 0))
 
 
-def _paged_decode_kernel(pos_ref, ids_ref, q_ref, *rest, scale,
-                         page_size, group, quant):
+def _block_diagonal(q):
+    """``[B, K1, H, Dh]`` -> ``[B, K1 * H, H * Dh]``: query row ``(i,
+    h)`` holds ``q[b, i, h]`` in head ``h``'s lanes and zeros in every
+    other head's, so one contraction over a page's ``H * Dh`` lanes is
+    every head's own scores."""
+    b, k1, h, d = q.shape
+    eye = jnp.eye(h, dtype=q.dtype)
+    return (q[:, :, :, None, :] * eye[None, None, :, :, None]).reshape(
+        b, k1 * h, h * d)
+
+
+def _diagonal_blocks(out, k1, heads):
+    """``[B, K1 * H, H * Dh]`` -> ``[B, K1, H, Dh]``: of row ``(i,
+    h)``, the lanes of head ``h`` (what the other heads' lanes hold is
+    ``p_h`` against another head's values: dropped, never summed)."""
+    b = out.shape[0]
+    out = out.reshape(b, k1, heads, heads, -1)
+    eye = jnp.eye(heads, dtype=bool)[None, None, :, :, None]
+    return jnp.sum(jnp.where(eye, out, 0.0), axis=3)
+
+
+def _paged_attention_kernel(pos_ref, ids_ref, q_ref, *rest, scale,
+                            page_size, group, heads, k1, quant):
     """One (slot, block of ``group`` pages) grid cell of the PAGED
-    flash-decode, all heads at once: the same online-softmax
-    recurrence as :func:`_decode_kernel` with a softmax state per
-    head. Each of the block's pages arrives as its own operand —
-    whatever PAGE the table maps it to, the index map doing the
-    indirection BEFORE the DMA (:func:`_page_spec`) — and the pages
-    are folded side by side as ONE block of ``group * ps`` columns. A
-    block that starts beyond the slot's position folds nothing (and
-    copied nothing); the column mask keeps the pages beyond the
-    position inside the last live block out of the softmax.
-    ``quant`` (static): each page brings its ``[H, ps]`` scale
-    sidecar through the same indirection."""
+    flash-decode, all heads and all ``k1`` query tokens at once: the
+    same online-softmax recurrence as :func:`_decode_kernel` with a
+    softmax state per query row. The query block is
+    :func:`_block_diagonal`, ``[K1 * H, H * Dh]``; each of the block's
+    pages arrives as its own ``[ps, H * Dh]`` operand — whatever PAGE
+    the table maps it to, the index map doing the indirection BEFORE
+    the DMA (:func:`_page_spec`) — and the pages are folded one under
+    the other as ONE block of ``group * ps`` columns. A block that
+    starts beyond the slot's reach folds nothing (and copied nothing);
+    the column mask keeps the pages beyond the position inside the
+    last live block out of the softmax. Query row ``(i, h)`` sits at
+    column ``pos + i`` (``k1`` 1: the single-query decode step).
+    ``quant`` (static): each page brings its ``[ps, H]`` scale sidecar
+    through the same indirection; the int8 lanes go to the MXU as they
+    are and the scale multiplies the scores (K) and the probabilities
+    (V), one number a head and column."""
     k_refs, v_refs = rest[:group], rest[group:2 * group]
     rest = rest[2 * group:]
-    ks_refs = vs_refs = (None,) * group
     if quant:
         ks_refs, vs_refs = rest[:group], rest[group:2 * group]
         rest = rest[2 * group:]
@@ -289,13 +311,27 @@ def _paged_decode_kernel(pos_ref, ids_ref, q_ref, *rest, scale,
     i = pl.program_id(0)
     kb = pl.program_id(1)
     block_k = group * page_size
+    rows = k1 * heads
 
-    def block(refs, scale_refs):
-        """The step's pages side by side: ``[H, G * ps, Dh]``."""
-        pages = [ref[0] if s_ref is None
-                 else _kernel_dequant(ref[0], s_ref[0], q_ref.dtype)
-                 for ref, s_ref in zip(refs, scale_refs)]
-        return pages[0] if group == 1 else jnp.concatenate(pages, axis=1)
+    def block(refs):
+        """The step's pages one under the other: ``[G * ps, .]``."""
+        pages = [ref[0, 0] for ref in refs]
+        return pages[0] if group == 1 else jnp.concatenate(pages, axis=0)
+
+    if quant:  # pick[(i, h), h'] = 1 where h' is row (i, h)'s head
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, heads), 0)
+        h = jax.lax.broadcasted_iota(jnp.int32, (rows, heads), 1)
+        pick = sum((r == h + j * heads).astype(jnp.float32)
+                   for j in range(k1))
+
+    def row_scales(refs):
+        """``[K1 * H, G * ps]``: row ``(i, h)`` holds head ``h``'s
+        scale of every column — the ``[G * ps, H]`` sidecar block
+        turned over by a 0/1 selection contraction (exact in f32)."""
+        return jax.lax.dot_general(
+            pick, block(refs), (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
 
     @pl.when(kb == 0)
     def _():
@@ -305,125 +341,141 @@ def _paged_decode_kernel(pos_ref, ids_ref, q_ref, *rest, scale,
 
     pos = pos_ref[i]
 
-    # block entirely beyond the slot's position -> skip (same per-slot
-    # cost gate as the dense kernel's block gate; unallocated table
-    # entries point at the scratch page, whose values this gate and
-    # the column mask keep out of the softmax)
-    @pl.when(kb * block_k <= pos)
+    # block entirely beyond the last query row's reach -> skip (same
+    # per-slot cost gate as the dense kernel's block gate; unallocated
+    # table entries point at the scratch page, whose values this gate
+    # and the column mask keep out of the softmax)
+    @pl.when(kb * block_k <= pos + k1 - 1)
     def _():
-        q = q_ref[0]                                     # [H, 1, Dh]
+        q = q_ref[0]                                 # [K1*H, H*Dh]
+        kblk, vblk = block(k_refs), block(v_refs)    # [G*ps, H*Dh]
+        if quant:
+            kblk, vblk = kblk.astype(q.dtype), vblk.astype(q.dtype)
         s = jax.lax.dot_general(
-            q, block(k_refs, ks_refs),
-            (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # [H, 1, G*ps]
+            q, kblk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [K1*H, G*ps]
+        if quant:
+            s = s * row_scales(ks_refs)
         col = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 2)
-        s = jnp.where(col <= pos, s, NEG_INF)
+            jnp.int32, s.shape, 1)
+        # row (i, h) reaches column pos + i; i = row // heads, as a sum
+        # of comparisons (no vector division)
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        reach = pos + sum((row >= j * heads).astype(jnp.int32)
+                          for j in range(1, k1))
+        s = jnp.where(col <= reach, s, NEG_INF)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
         m_scr[:] = m_new
         l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc[:] = acc[:] * corr + jax.lax.dot_general(
-            p.astype(q.dtype), block(v_refs, vs_refs),
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)          # [H, 1, Dh]
+        if quant:
+            p = p * row_scales(vs_refs)
+        acc[:] = acc[:] * corr + jnp.dot(
+            p.astype(vblk.dtype), vblk,
+            preferred_element_type=jnp.float32)      # [K1*H, H*Dh]
 
     @pl.when(kb == pl.num_programs(1) - 1)
     def _():
         o_ref[0] = acc[:] / jnp.maximum(l_scr[:], 1e-30)
 
 
-def _pallas_paged_decode(q, k_pages, v_pages, page_table, positions,
-                         scale, interpret, k_scale=None, v_scale=None):
-    """q [B, 1, H, Dh]; k/v pages [P, H, ps, Dh]; page_table
-    [B, n_win] int32; positions [B] -> f32 [B, 1, H, Dh]. Grid is
-    (slot, block of G pages), G from :func:`_pages_per_step`: one step
-    folds all heads of ``G * ps`` columns. Positions and the page ids
+def _pallas_paged_attention(q, k_pages, v_pages, page_table, positions,
+                            layer, scale, interpret, name):
+    """q [B, K1, H, Dh]; k/v pools [L, P, ps, H*Dh] (or the int8
+    pairs, scale [L, P, ps, H]); page_table [B, n_win] int32;
+    positions [B] -> f32 [B, K1, H, Dh]. Grid is (slot, block of G
+    pages), G from :func:`_pages_per_step`: one step folds all heads
+    and query rows of ``G * ps`` columns. Positions and the page ids
     ride in SMEM via scalar prefetch; each pool is passed G times,
-    once per page of a step's block, so a whole ``[H, ps, Dh]`` page
-    is one DMA and the pipeline keeps the next step's G pages in
-    flight (the next slot's first block at a slot's end) — never a
-    page beyond a slot's position (:func:`_live_page_ids`). graftquant:
-    ``k_scale``/``v_scale`` (``[P, H, ps]`` f32) ride the same
-    indirection as their pages."""
-    b, _, h, d = q.shape
-    ps = k_pages.shape[2]
+    once per page of a step's block, so a whole ``[ps, H*Dh]`` page is
+    one DMA out of layer ``layer`` of the pool in place, and the
+    pipeline keeps the next step's G pages in flight — never a page
+    beyond a slot's reach (:func:`_live_page_ids`)."""
+    b, k1, h, _ = q.shape
+    quant = isinstance(k_pages, QuantizedKV)
+    k_data, v_data = ((k_pages.data, v_pages.data) if quant
+                      else (k_pages, v_pages))
+    ps, width = k_data.shape[2], k_data.shape[3]
     n_win = page_table.shape[1]
-    quant = k_scale is not None
-    group = _pages_per_step(ps, n_win,
-                            h * ps * d * k_pages.dtype.itemsize)
-    q4 = jnp.moveaxis(q, 2, 1)                           # [B, H, 1, Dh]
+    rows = k1 * h
+    group = _pages_per_step(ps, n_win, ps * width * k_data.dtype.itemsize)
 
-    def page_specs(block_shape):
-        return [_page_spec(block_shape, g, group) for g in range(group)]
+    def page_specs(last):
+        return [_page_spec((1, 1, ps, last), layer, g, group)
+                for g in range(group)]
 
-    q_spec = pl.BlockSpec((1, h, 1, d),
-                          lambda i, kb, pos, ids: (i, 0, 0, 0))
-    in_specs = [q_spec] + page_specs((1, h, ps, d)) * 2
-    operands = [q4] + [k_pages] * group + [v_pages] * group
+    q_spec = pl.BlockSpec((1, rows, width),
+                          lambda i, kb, pos, ids: (i, 0, 0))
+    in_specs = [q_spec] + page_specs(width) * 2
+    operands = [_block_diagonal(q)] + [k_data] * group + [v_data] * group
     if quant:
-        in_specs += page_specs((1, h, ps)) * 2
-        operands += [k_scale] * group + [v_scale] * group
+        in_specs += page_specs(h) * 2
+        operands += [k_pages.scale] * group + [v_pages.scale] * group
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # positions, page ids
         grid=(b, pl.cdiv(n_win, group)),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((h, 1, d), jnp.float32),   # output accumulator
-            pltpu.VMEM((h, 1, 1), jnp.float32),   # running max
-            pltpu.VMEM((h, 1, 1), jnp.float32),   # running denominator
+            pltpu.VMEM((rows, width), jnp.float32),  # output accumulator
+            pltpu.VMEM((rows, 1), jnp.float32),      # running max
+            pltpu.VMEM((rows, 1), jnp.float32),      # running denominator
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=scale,
-                          page_size=ps, group=group, quant=quant),
+        functools.partial(_paged_attention_kernel, scale=scale,
+                          page_size=ps, group=group, heads=h, k1=k1,
+                          quant=quant),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, rows, width), jnp.float32),
         interpret=interpret,
-        name="paged_decode_attention",
+        name=name,
     )(positions.astype(jnp.int32),
-      _live_page_ids(page_table.astype(jnp.int32), positions, group, ps),
+      _live_page_ids(page_table.astype(jnp.int32), positions + (k1 - 1),
+                     group, ps),
       *operands)
-    return jnp.moveaxis(out, 1, 2)                       # [B, 1, H, Dh]
+    return _diagonal_blocks(out, k1, h)              # [B, K1, H, Dh]
 
 
-def _gather_paged_window(pages, page_table, q_dtype,
+def _gather_paged_window(pages, layer, page_table, heads, q_dtype,
                          window: Optional[int] = None):
-    """``take``-gather windowed pages into the contiguous
-    ``[B, W, H, Dh]`` view the dense references consume. graftquant
-    pages gather BOTH leaves through the same table, then dequant with
-    the kernel's exact expression — per-element identical to the
-    in-VMEM dequant, which is what keeps the XLA fallback the pin."""
+    """``take``-gather layer ``layer``'s windowed pages into the
+    contiguous ``[B, W, H, Dh]`` view the dense references consume.
+    graftquant pages gather BOTH leaves through the same table, then
+    dequant (:func:`...kv_quant.dequantize_kv`) — which is what keeps
+    the XLA fallback the pin."""
     b, n_win = page_table.shape
+
+    def gather(pool, last):  # [L, P, ps, H * last] -> [B, W, H, last]
+        g = jnp.take(pool[layer], page_table, axis=0)
+        return g.reshape((b, n_win * g.shape[2], heads) + last)
+
     if isinstance(pages, QuantizedKV):
-        h, ps, d = pages.shape[1], pages.shape[2], pages.shape[3]
-        gd = jnp.take(pages.data, page_table, axis=0)
-        gd = jnp.moveaxis(gd, 3, 2).reshape(b, n_win * ps, h, d)
-        gs = jnp.take(pages.scale, page_table, axis=0)
-        gs = jnp.moveaxis(gs, 3, 2).reshape(b, n_win * ps, h)
-        g = dequantize_kv(QuantizedKV(gd, gs), q_dtype)
+        g = dequantize_kv(QuantizedKV(gather(pages.data, (-1,)),
+                                      gather(pages.scale, ())), q_dtype)
     else:
-        h, ps, d = pages.shape[1], pages.shape[2], pages.shape[3]
-        g = jnp.take(pages, page_table, axis=0)  # [B, n_win, H, ps, Dh]
-        g = jnp.moveaxis(g, 3, 2).reshape(b, n_win * ps, h, d)
-    if window is not None and window < n_win * ps:
+        g = gather(pages, (-1,))
+    if window is not None and window < g.shape[1]:
         g = jax.lax.slice_in_dim(g, 0, window, axis=1)
     return g
 
 
 def xla_paged_decode_attention(q, k_pages, v_pages, page_table,
-                               positions, window: Optional[int] = None):
+                               positions, window: Optional[int] = None,
+                               *, layer: int):
     """Reference paged path: ``take``-gather the windowed pages into
     the contiguous ``[B, W, H, Dh]`` view and run the EXACT dense
     reference math (:func:`xla_decode_attention`) — bit-identical to
     the dense-slot engine on the same logical columns, which is the
     seam the paged==dense equivalence pin rests on. Quantized pages
-    dequant at the gather (the kernel's exact per-element math)."""
-    k_win = _gather_paged_window(k_pages, page_table, q.dtype, window)
-    v_win = _gather_paged_window(v_pages, page_table, q.dtype, window)
+    dequant at the gather."""
+    h = q.shape[2]
+    k_win = _gather_paged_window(k_pages, layer, page_table, h, q.dtype,
+                                 window)
+    v_win = _gather_paged_window(v_pages, layer, page_table, h, q.dtype,
+                                 window)
     mask = (jnp.arange(k_win.shape[1])[None, :] <= positions[:, None])
     return xla_decode_attention(q, k_win, v_win, mask)
 
@@ -435,6 +487,7 @@ def paged_decode_attention(
     page_table: jax.Array,
     positions: jax.Array,
     *,
+    layer: int,
     window: Optional[int] = None,
     impl: str = "auto",
     interpret: Optional[bool] = None,
@@ -443,11 +496,13 @@ def paged_decode_attention(
 
     Args:
       q: ``[B, 1, H, Dh]`` — one pending query token per slot.
-      k_pages, v_pages: ``[P, H, page_size, Dh]`` page storage (ONE
-        layer's pages — heads before the column offset so the Pallas
-        block's trailing dims are the tileable ``[page_size, Dh]``),
-        or a :class:`...kv_quant.QuantizedKV` pair (int8 data + the
-        ``[P, H, page_size]`` f32 scale sidecar, dequanted in-stream).
+      k_pages, v_pages: ``[L, P, page_size, H * Dh]`` — ALL layers'
+        pages, a row a token with the heads side by side in the lanes.
+        The kernel's index map picks ``layer``, so no layer is ever
+        sliced out of (and copied from) the pool, and a page is one
+        contiguous lane-dense DMA. Or a :class:`...kv_quant.
+        QuantizedKV` pair (int8 data + the ``[L, P, page_size, H]``
+        f32 scale sidecar).
       page_table: ``[B, n_win]`` int32 — slot ``b``'s logical column
         block ``kb`` lives in page ``page_table[b, kb]``. Callers pass
         the WINDOWED slice of the full table (``ceil(window /
@@ -456,6 +511,7 @@ def paged_decode_attention(
         the softmax.
       positions: ``[B]`` int — slot ``b`` attends columns
         ``[0, positions[b]]`` inclusive.
+      layer: static layer index into the pools.
       window: optional logical column bound (< ``n_win * page_size``
         trims the gathered tail on the XLA path; the Pallas path's
         column mask makes it a no-op there).
@@ -470,19 +526,15 @@ def paged_decode_attention(
             from . import default_interpret
 
             interpret = default_interpret()
-        scale = q.shape[-1] ** -0.5
-        if isinstance(k_pages, QuantizedKV):
-            return _pallas_paged_decode(
-                q, k_pages.data, v_pages.data, page_table, positions,
-                scale, bool(interpret), k_scale=k_pages.scale,
-                v_scale=v_pages.scale)
-        return _pallas_paged_decode(q, k_pages, v_pages, page_table,
-                                    positions, scale, bool(interpret))
+        return _pallas_paged_attention(
+            q, k_pages, v_pages, page_table, positions, int(layer),
+            q.shape[-1] ** -0.5, bool(interpret),
+            "paged_decode_attention")
     if impl != "xla":
         raise ValueError(
             f"impl must be 'pallas', 'xla' or 'auto', got {impl!r}")
     return xla_paged_decode_attention(q, k_pages, v_pages, page_table,
-                                      positions, window)
+                                      positions, window, layer=layer)
 
 
 # ---------------------------------------------------- latent (MLA) pages
@@ -491,15 +543,6 @@ def paged_decode_attention(
 # pages, each ONE DMA of one contiguous [ps, R + Rw] page (the kernel's
 # time is the DMAs' issue, ~0.1 us each, not their bytes: PERF.md)
 _MLA_BLOCK_COLUMNS = 512
-
-
-def _mla_page_spec(block_shape, layer, g, group):
-    """Block of page ``g`` of a grid step's ``group`` in layer ``layer``
-    of a ``[L, P, ps, .]`` pool: the layer is static, the page comes
-    from the scalar-prefetched :func:`_live_page_ids`."""
-    return pl.BlockSpec(
-        block_shape,
-        lambda i, kb, pos, ids: (layer, ids[i, kb * group + g], 0, 0))
 
 
 def _mla_paged_decode_kernel(pos_ref, ids_ref, q_ref, *rest, scale,
@@ -568,7 +611,7 @@ def _pallas_mla_paged_decode(q, pages, page_table, positions, layer,
         num_scalar_prefetch=2,  # positions, page ids
         grid=(b, pl.cdiv(n_win, group)),
         in_specs=[slot_spec(width)] + [
-            _mla_page_spec((1, 1, ps, width), layer, g, group)
+            _page_spec((1, 1, ps, width), layer, g, group)
             for g in range(group)],
         out_specs=slot_spec(rank),
         scratch_shapes=[
@@ -872,106 +915,6 @@ def _pallas_verify(q, k, v, positions, scale, block_k, interpret,
     return jnp.moveaxis(out.reshape(b, h, k1, d), 1, 2)  # [B, K1, H, Dh]
 
 
-def _paged_verify_kernel(pos_ref, tab_ref, q_ref, k_ref, v_ref, *rest,
-                         scale, page_size, heads, k1, quant):
-    """Paged k-query verify: :func:`_paged_decode_kernel`'s
-    scalar-prefetched page indirection with the [K1, d] query block
-    and the row-staggered column mask. ``quant`` (static): the scale
-    sidecars ride the same table indirection."""
-    if quant:
-        ks_ref, vs_ref, o_ref, acc, m_scr, l_scr = rest
-    else:
-        o_ref, acc, m_scr, l_scr = rest
-    i = pl.program_id(0)
-    kb = pl.program_id(1)
-    n_k = pl.num_programs(1)
-
-    @pl.when(kb == 0)
-    def _():
-        acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-
-    pos = pos_ref[i // heads]
-
-    @pl.when(kb * page_size <= pos + k1 - 1)
-    def _():
-        q = q_ref[0]             # [K1, d]
-        kblk = k_ref[0, 0]       # [ps, d]
-        vblk = v_ref[0, 0]
-        if quant:
-            kblk = _kernel_dequant(kblk, ks_ref[0, 0, 0], q.dtype)
-            vblk = _kernel_dequant(vblk, vs_ref[0, 0, 0], q.dtype)
-        s = jnp.dot(q, kblk.T,
-                    preferred_element_type=jnp.float32) * scale
-        col = kb * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (k1, page_size), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (k1, page_size), 0)
-        s = jnp.where(col <= pos + row, s, NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc[:] = acc[:] * corr + jnp.dot(
-            p.astype(vblk.dtype), vblk,
-            preferred_element_type=jnp.float32)
-
-    @pl.when(kb == n_k - 1)
-    def _():
-        o_ref[0] = acc[:] / jnp.maximum(l_scr[:], 1e-30)
-
-
-def _pallas_paged_verify(q, k_pages, v_pages, page_table, positions,
-                         scale, interpret, k_scale=None, v_scale=None):
-    """q [B, K1, H, Dh]; pages [P, H, ps, Dh]; page_table [B, n_win]
-    -> f32 [B, K1, H, Dh]. graftquant: ``k_scale``/``v_scale``
-    ([P, H, ps] f32) ride the same indirection as their pages."""
-    b, k1, h, d = q.shape
-    ps = k_pages.shape[2]
-    n_win = page_table.shape[1]
-    quant = k_scale is not None
-    q3 = jnp.moveaxis(q, 2, 1).reshape(b * h, k1, d)
-
-    in_specs = [
-        pl.BlockSpec((1, k1, d),
-                     lambda i, kb, pos, tab: (i, 0, 0)),
-        pl.BlockSpec((1, 1, ps, d),
-                     lambda i, kb, pos, tab:
-                     (tab[i // h, kb], i % h, 0, 0)),
-        pl.BlockSpec((1, 1, ps, d),
-                     lambda i, kb, pos, tab:
-                     (tab[i // h, kb], i % h, 0, 0)),
-    ]
-    operands = [q3, k_pages, v_pages]
-    if quant:
-        in_specs += [_paged_scale_spec(ps, h)] * 2
-        operands += [k_scale[:, :, None], v_scale[:, :, None]]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # positions, page table
-        grid=(b * h, n_win),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, k1, d),
-                               lambda i, kb, pos, tab: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((k1, d), jnp.float32),   # output accumulator
-            pltpu.VMEM((k1, 1), jnp.float32),   # running max
-            pltpu.VMEM((k1, 1), jnp.float32),   # running denominator
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_paged_verify_kernel, scale=scale,
-                          page_size=ps, heads=h, k1=k1, quant=quant),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, k1, d), jnp.float32),
-        interpret=interpret,
-        name="paged_verify_decode_attention",
-    )(positions.astype(jnp.int32), page_table.astype(jnp.int32),
-      *operands)
-    return jnp.moveaxis(out.reshape(b, h, k1, d), 1, 2)
-
-
 def xla_verify_decode_attention(q, k, v, positions):
     """Reference k-query verify math: xla_decode_attention's exact
     einsum/masked-softmax shape with the row-staggered mask — query
@@ -991,12 +934,16 @@ def xla_verify_decode_attention(q, k, v, positions):
 
 def xla_paged_verify_decode_attention(q, k_pages, v_pages, page_table,
                                       positions,
-                                      window: Optional[int] = None):
+                                      window: Optional[int] = None,
+                                      *, layer: int):
     """Paged reference verify: the same take-gather (+ graftquant
     dequant) as :func:`xla_paged_decode_attention`, then the dense
     reference."""
-    k_win = _gather_paged_window(k_pages, page_table, q.dtype, window)
-    v_win = _gather_paged_window(v_pages, page_table, q.dtype, window)
+    h = q.shape[2]
+    k_win = _gather_paged_window(k_pages, layer, page_table, h, q.dtype,
+                                 window)
+    v_win = _gather_paged_window(v_pages, layer, page_table, h, q.dtype,
+                                 window)
     return xla_verify_decode_attention(q, k_win, v_win, positions)
 
 
@@ -1056,13 +1003,16 @@ def paged_verify_decode_attention(
     page_table: jax.Array,
     positions: jax.Array,
     *,
+    layer: int,
     window: Optional[int] = None,
     impl: str = "auto",
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Paged twin of :func:`verify_decode_attention` (graftspec x
-    graftpage): the k-query verify reads KV through the same windowed
-    page-table slice the single-query paged step uses. Pages may be
+    graftpage): the k-query verify reads layer ``layer`` of the same
+    ``[L, P, page_size, H * Dh]`` pools through the same windowed
+    page-table slice the single-query paged step uses — the same
+    kernel body with ``K1 * H`` query rows. Pages may be
     :class:`...ops.kv_quant.QuantizedKV` (graftquant)."""
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
@@ -1071,17 +1021,12 @@ def paged_verify_decode_attention(
             from . import default_interpret
 
             interpret = default_interpret()
-        scale = q.shape[-1] ** -0.5
-        if isinstance(k_pages, QuantizedKV):
-            return _pallas_paged_verify(
-                q, k_pages.data, v_pages.data, page_table, positions,
-                scale, bool(interpret),
-                k_scale=k_pages.scale, v_scale=v_pages.scale)
-        return _pallas_paged_verify(q, k_pages, v_pages, page_table,
-                                    positions, scale, bool(interpret))
+        return _pallas_paged_attention(
+            q, k_pages, v_pages, page_table, positions, int(layer),
+            q.shape[-1] ** -0.5, bool(interpret),
+            "paged_verify_decode_attention")
     if impl != "xla":
         raise ValueError(
             f"impl must be 'pallas', 'xla' or 'auto', got {impl!r}")
-    return xla_paged_verify_decode_attention(q, k_pages, v_pages,
-                                             page_table, positions,
-                                             window)
+    return xla_paged_verify_decode_attention(
+        q, k_pages, v_pages, page_table, positions, window, layer=layer)

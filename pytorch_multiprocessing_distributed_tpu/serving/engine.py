@@ -132,7 +132,8 @@ from ..utils.compile_cache import (jit_cache_keys, jit_cache_size,
                                    record_jit_key)
 from ..utils.metrics import ServingMetrics
 from ..utils import profiler  # noqa: F401 (sets graftscope's annotator)
-from .kv_pages import PagePool, PagePoolExhausted, PrefixCache
+from .kv_pages import (PAGE_SPEC, PagePool, PagePoolExhausted,
+                       PrefixCache)
 from .kv_slots import SlotPool
 from .scheduler import (DONE, FAILED, RUNNING, FIFOScheduler,
                         PrefillPlan, QueueFull, Request,
@@ -631,23 +632,23 @@ class ServingEngine:
         # breaking the bucketed compile budget on a mesh
         if mesh is not None:
             # dense caches shard heads at axis 3 ([L, N, S, H, Dh]);
-            # pages at axis 2 ([L, P, H, ps, Dh]); the standalone
-            # prefill caches keep the dense layout in BOTH modes.
-            # graftquant caches are the (data, scale) pytree pair, so
-            # the cache out-sharding is the matching pair — the scale
-            # sidecar drops the trailing Dh axis, heads stay put
-            cache_data_sh = NamedSharding(
-                mesh,
-                P(None, None, "model", None, None) if self._paged
-                else P(None, None, None, "model", None))
-            if self._kv_quant:
-                cache_scale_sh = NamedSharding(
-                    mesh,
-                    P(None, None, "model", None) if self._paged
-                    else P(None, None, None, "model"))
-                cache_sh = QuantizedKV(cache_data_sh, cache_scale_sh)
+            # pages at their last axis ([L, P, ps, H * Dh]: contiguous
+            # head groups of the lanes); the standalone prefill caches
+            # keep the dense layout in BOTH modes. graftquant caches
+            # are the (data, scale) pytree pair, so the cache
+            # out-sharding is the matching pair — the dense scale
+            # sidecar drops the trailing Dh axis, heads stay put; a
+            # page's scales [L, P, ps, H] shard like its data
+            if self._paged:
+                cache_data_sh = cache_scale_sh = NamedSharding(
+                    mesh, PAGE_SPEC)
             else:
-                cache_sh = cache_data_sh
+                cache_data_sh = NamedSharding(
+                    mesh, P(None, None, None, "model", None))
+                cache_scale_sh = NamedSharding(
+                    mesh, P(None, None, None, "model"))
+            cache_sh = (QuantizedKV(cache_data_sh, cache_scale_sh)
+                        if self._kv_quant else cache_data_sh)
             pref_sh = NamedSharding(
                 mesh, P(None, None, None, "model", None))
             rep = NamedSharding(mesh, P())
@@ -809,20 +810,7 @@ class ServingEngine:
         paged = self._paged
         page_size = self.pool.page_size if paged else None
 
-        def cs_cache(c):
-            if isinstance(c, QuantizedKV):
-                # the scale sidecar drops the trailing Dh axis only,
-                # so its spec is the data's minus the last entry
-                if paged:
-                    return QuantizedKV(
-                        cs(c.data, None, None, "model", None, None),
-                        cs(c.scale, None, None, "model", None))
-                return QuantizedKV(
-                    cs(c.data, None, None, None, "model", None),
-                    cs(c.scale, None, None, None, "model"))
-            if paged:  # pages: [L, P, H, ps, Dh] — heads at axis 2
-                return cs(c, None, None, "model", None, None)
-            return cs(c, None, None, None, "model", None)
+        cs_cache = self._make_cs_cache(cs)
 
         def horizon_step(params, k_caches, v_caches, positions,
                          last_tokens, active, remaining, eos_ids, key,
@@ -858,6 +846,25 @@ class ServingEngine:
 
         return paged_horizon_step
 
+    def _make_cs_cache(self, cs):
+        """The sharding constraint that pins a decode program's cache
+        operands to the pool's placement: pages ``[L, P, ps, H * Dh]``
+        (and an int8 pool's ``[L, P, ps, H]`` scales) on their last
+        axis, dense slots ``[L, N, S, H, Dh]`` on the heads (the dense
+        scale sidecar drops the trailing Dh axis only)."""
+        paged = self._paged
+
+        def cs_cache(c):
+            if paged:
+                return jax.tree.map(lambda leaf: cs(leaf, *PAGE_SPEC), c)
+            if isinstance(c, QuantizedKV):
+                return QuantizedKV(
+                    cs(c.data, None, None, None, "model", None),
+                    cs(c.scale, None, None, None, "model"))
+            return cs(c, None, None, None, "model", None)
+
+        return cs_cache
+
     def _make_decode_spec(self):
         """The speculative twin of :func:`_make_decode_horizon`
         (graftspec): ``horizon`` draft-then-verify passes as ONE
@@ -875,18 +882,7 @@ class ServingEngine:
         page_size = self.pool.page_size if paged else None
         draft_model = self._draft_model
 
-        def cs_cache(c):
-            if isinstance(c, QuantizedKV):
-                if paged:
-                    return QuantizedKV(
-                        cs(c.data, None, None, "model", None, None),
-                        cs(c.scale, None, None, "model", None))
-                return QuantizedKV(
-                    cs(c.data, None, None, None, "model", None),
-                    cs(c.scale, None, None, None, "model"))
-            if paged:
-                return cs(c, None, None, "model", None, None)
-            return cs(c, None, None, None, "model", None)
+        cs_cache = self._make_cs_cache(cs)
 
         def run(params, k_caches, v_caches, positions, last_tokens,
                 active, remaining, eos_ids, *, window, horizon,
@@ -1133,57 +1129,33 @@ class ServingEngine:
                          active, budgets, eos_ids, k_pref, v_pref,
                          write_ids, slot, length, tok0, budget, eos):
         """Paged splice (graftpage): the standalone prefill cache
-        ``[L, 1, W, H, Dh]`` is re-tiled into page blocks and
-        scattered at ``write_ids`` — the column-ordered page targets
-        the HOST chose (fresh pages for the columns this request
-        computed; the SCRATCH page 0 for columns a shared prefix
-        already holds — their stale re-write is discarded — and for
-        pure-pad overshoot). The slot's decode state arms exactly as
+        ``[L, 1, W, *row]`` is cut into page blocks (a reshape: a page
+        is ``ps`` whole rows, ``[ps, prod(row)]``) and scattered into
+        the donated pools, in place, at ``write_ids`` — the
+        column-ordered page targets the HOST chose (fresh pages for
+        the columns this request computed; the SCRATCH page 0 for
+        columns a shared prefix already holds — their stale re-write
+        is discarded — and for pure-pad overshoot). The slot's decode state arms exactly as
         the dense splice. Compiles once per prefill width (the
         ``write_ids`` length is width-derived), like the dense
-        per-bucket splice."""
-        # a page is [H, ps, Dh] for a per-head row (heads before the
-        # column offset), [ps, R] for a row all heads share
-        per_head = len(k_pref.shape) == 5
-        ps = k_pages.shape[3 if per_head else 2]
+        per-bucket splice. An int8 pair's scales ``[L, 1, W, H]``
+        follow the same rule into ``[L, P, ps, H]``."""
+        ps = k_pages.shape[2]
         n = write_ids.shape[0]
-        w = k_pref.shape[2]
-        pad = n * ps - w
-        if pad:  # width not a page multiple: pad-only columns
-            cfg = ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (
-                len(k_pref.shape) - 3)
-            if isinstance(k_pref, QuantizedKV):
-                k_pref = QuantizedKV(jnp.pad(k_pref.data, cfg),
-                                     jnp.pad(k_pref.scale, cfg[:-1]))
-                v_pref = QuantizedKV(jnp.pad(v_pref.data, cfg),
-                                     jnp.pad(v_pref.scale, cfg[:-1]))
-            else:
-                k_pref = jnp.pad(k_pref, cfg)
-                v_pref = jnp.pad(v_pref, cfg)
+        pad = n * ps - k_pref.shape[2]
 
-        def to_pages(c):  # [L, 1, n*ps, H, Dh] -> [L, n, H, ps, Dh]
-            if not per_head:  # [L, 1, n*ps, R] -> [L, n, ps, R]
-                return c.reshape(c.shape[0], n, ps, c.shape[3])
-            l, _, _, h, d = c.shape
-            return jnp.moveaxis(c.reshape(l, n, ps, h, d), 2, 3)
+        def to_pages(c):  # [L, 1, W, *row] -> [L, n, ps, prod(row)]
+            if pad:  # width not a page multiple: pad-only columns
+                c = jnp.pad(c, ((0, 0), (0, 0), (0, pad))
+                            + ((0, 0),) * (c.ndim - 3))
+            return c.reshape(c.shape[0], n, ps,
+                             int(np.prod(c.shape[3:], dtype=int)))
 
-        def to_scale_pages(s):  # [L, 1, n*ps, H] -> [L, n, H, ps]
-            l = s.shape[0]
-            h = s.shape[3]
-            return jnp.moveaxis(s.reshape(l, n, ps, h), 2, 3)
+        def splice(pool, pref):
+            return pool.at[:, write_ids].set(to_pages(pref))
 
-        if isinstance(k_pages, QuantizedKV):
-            k_pages = QuantizedKV(
-                k_pages.data.at[:, write_ids].set(to_pages(k_pref.data)),
-                k_pages.scale.at[:, write_ids].set(
-                    to_scale_pages(k_pref.scale)))
-            v_pages = QuantizedKV(
-                v_pages.data.at[:, write_ids].set(to_pages(v_pref.data)),
-                v_pages.scale.at[:, write_ids].set(
-                    to_scale_pages(v_pref.scale)))
-        else:
-            k_pages = k_pages.at[:, write_ids].set(to_pages(k_pref))
-            v_pages = v_pages.at[:, write_ids].set(to_pages(v_pref))
+        k_pages = jax.tree.map(splice, k_pages, k_pref)
+        v_pages = jax.tree.map(splice, v_pages, v_pref)
         positions = positions.at[slot].set(length)
         last_tokens = last_tokens.at[slot].set(tok0)
         active = active.at[slot].set(True)
@@ -1214,21 +1186,13 @@ class ServingEngine:
         is the single page — everything else about a prefix hit is
         copy-free table wiring (cf. arXiv:2112.01075 on keeping
         redistribution gather-free)."""
-        def one(pages):
-            if isinstance(pages, QuantizedKV):
-                # COW-fork BOTH leaves: the forked page keeps its
-                # exact quantized values (no requant round-trip)
-                sblk = jax.lax.dynamic_slice_in_dim(pages.scale, src,
-                                                    1, axis=1)
-                return QuantizedKV(
-                    one(pages.data),
-                    jax.lax.dynamic_update_slice(
-                        pages.scale, sblk, (0, dst, 0, 0)))
-            blk = jax.lax.dynamic_slice_in_dim(pages, src, 1, axis=1)
-            return jax.lax.dynamic_update_slice(
-                pages, blk, (0, dst, 0, 0, 0))
+        def one(leaf):
+            # an int8 pair COW-forks BOTH leaves: the forked page keeps
+            # its exact quantized values (no requant round-trip)
+            blk = jax.lax.dynamic_slice_in_dim(leaf, src, 1, axis=1)
+            return jax.lax.dynamic_update_slice(leaf, blk, (0, dst, 0, 0))
 
-        return one(k_pages), one(v_pages)
+        return jax.tree.map(one, (k_pages, v_pages))
 
     def _gather_pages_fn(self, k_pages, v_pages, ids, *, width):
         """PARTIAL prefix hit: materialize the ``len(ids)`` shared
@@ -1241,19 +1205,22 @@ class ServingEngine:
         never forks), and the shared prefix pages themselves are not
         re-written at splice time, so no requant error accrues."""
         dtype = self.model.dtype
+        (_, row, _), _ = serving_family(self.model).cache_rows(self.model)
+
+        def rows(leaf, last):  # [L, P, ps, .] -> [L, 1, k * ps, *last]
+            g = jnp.take(leaf, ids, axis=1)
+            return g.reshape((g.shape[0], 1, -1) + tuple(last))
 
         def one(pages):
             if isinstance(pages, QuantizedKV):
-                gd = jnp.take(pages.data, ids, axis=1)
-                gs = jnp.take(pages.scale, ids, axis=1)
-                g = dequantize_kv(QuantizedKV(gd, gs), dtype)
+                g = dequantize_kv(QuantizedKV(rows(pages.data, row),
+                                              rows(pages.scale, row[:-1])),
+                                  dtype)
             else:
-                g = jnp.take(pages, ids, axis=1)  # [L, k, H, ps, Dh]
-            l, _, h, ps, d = g.shape
-            g = jnp.moveaxis(g, 2, 3).reshape(l, 1, -1, h, d)
+                g = rows(pages, row)
             pad = width - g.shape[2]
             return jnp.pad(
-                g, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+                g, ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * len(row))
 
         return one(k_pages), one(v_pages)
 
@@ -3359,22 +3326,17 @@ def audit_programs():
         # collective appearing here means the splice started paying
         # communication for what placement already did).
         def pref_sds(eng, width):
+            # the standalone prefill cache [L, 1, W, H, Dh] in the
+            # pool's element type (int8: + its scales [L, 1, W, H]),
+            # whatever the pool's own layout
             pool = eng.pool
             cache = pool.k_pages if eng._paged else pool.k_caches
-            if eng._paged:
-                # pages [L, P, H, ps, Dh] -> standalone prefill
-                # cache [L, 1, W, H, Dh] (scale [L, 1, W, H])
-                def leaf(c):
-                    return jax.ShapeDtypeStruct(
-                        (c.shape[0], 1, width, c.shape[2])
-                        + c.shape[4:], c.dtype)
-            else:
-                # cache [L, S, s_max, H, Dh] -> [L, 1, W, H, Dh]
-                def leaf(c):
-                    return jax.ShapeDtypeStruct(
-                        (c.shape[0], 1, width) + c.shape[3:],
-                        c.dtype)
-            return jax.tree.map(leaf, cache)
+            shape = pref_cache_shapes(eng.model, width)[0]
+            if isinstance(cache, QuantizedKV):
+                return QuantizedKV(
+                    jax.ShapeDtypeStruct(shape, cache.data.dtype),
+                    jax.ShapeDtypeStruct(shape[:-1], cache.scale.dtype))
+            return jax.ShapeDtypeStruct(shape, cache.dtype)
 
         def insert_args(eng, width):
             pool = eng.pool

@@ -3,7 +3,7 @@
 :class:`~.kv_slots.SlotPool` pays worst-case HBM per request — a dense
 ``[layers, max_slots, s_max, heads, head_dim]`` block reserves ``s_max``
 columns for a 16-token request. This module replaces the dense block
-with **pages**: K/V live in ``[layers, num_pages, heads, page_size,
+with **pages**: K/V live in ``[layers, num_pages, page_size, heads *
 head_dim]`` arrays, and each slot maps its logical columns onto pages
 through an ``[max_slots, pages_per_slot]`` int32 page table. A request
 holding ``L + g`` tokens pins ``ceil((L + g) / page_size)`` pages — so
@@ -13,15 +13,21 @@ dense worst case: the capacity multiplier graftmeter's
 ``per_slot_kv_bytes`` ledger exists to measure.
 
 The two pools are shaped by the model family's ``cache_rows``
-(``inference.generate.serving_family``): K and V rows of ``(heads,
-head_dim)`` for GPT; for a latent-attention family one row all heads
-share, ``[layers, num_pages, page_size, width]``, and a zero-width
-placeholder.
+(``inference.generate.serving_family``), by ONE rule for every family:
+``[layers, num_pages, page_size, prod(row)]`` — a token's row flat in
+the lanes. K and V rows of ``(heads, head_dim)`` for GPT (head ``h`` in
+lanes ``h * head_dim .. (h + 1) * head_dim``); for a latent-attention
+family one row all heads share, and a zero-width placeholder.
 
-Layout note: per-head pages keep heads BEFORE the column offset
-(``[..., heads, page_size, head_dim]``) so the Pallas paged decode
-kernel's per-(slot, head) block is ``[page_size, head_dim]`` — the
-TPU-tileable trailing pair (:mod:`...ops.pallas.decode_attention`).
+Layout note: a page is ``page_size`` whole rows, so it is lane-dense
+(``heads * head_dim`` is a multiple of 128 for the registry's serving
+sizes; a 64-wide minor dimension would be padded to 128 lanes and
+copied), one contiguous DMA, and the layer is the pool's LEADING axis:
+the decode program carries the whole pool through its layers, each
+writing its new rows at ``[layer, page, offset]`` and its Pallas kernel
+reading ``(1, 1, page_size, heads * head_dim)`` blocks of it in place
+(:mod:`...ops.pallas.decode_attention`) — so the donated pool is never
+copied.
 
 Allocation is **host-mirrored**: the free list, refcounts and the page
 table live in host numpy; alloc/free never touch the device. The
@@ -65,6 +71,12 @@ from ..runtime import hbm, life
 from ..runtime import scope as graftscope
 
 
+# where the ``model`` mesh axis shards a page pool ``[L, P, ps, H *
+# Dh]`` (and an int8 pool's ``[L, P, ps, H]`` scales): the last axis,
+# in contiguous head groups
+PAGE_SPEC = P(None, None, None, "model")
+
+
 class PagePoolExhausted(RuntimeError):
     """Raised when an allocation asks for more free pages than the
     pool holds. The ENGINE never lets this escape admission for a
@@ -98,10 +110,11 @@ class PagePool:
         worst-case parity. The capacity win comes from passing LESS
         than worst case while raising ``max_slots``.
       mesh: optional ``Mesh`` with a ``model`` axis — pages are then
-        resident head-sharded (``[L, P, H/tp, ps, Dh]`` per chip).
+        resident head-sharded (``[L, P, ps, (H/tp) * Dh]`` per chip:
+        contiguous head groups of the lanes).
       kv_dtype: ``"model"`` or ``"int8"`` (graftquant: pages become a
         :class:`...ops.kv_quant.QuantizedKV` pair — int8 data + a
-        ``[L, P, H, ps]`` f32 scale sidecar beside the page table).
+        ``[L, P, ps, H]`` f32 scale sidecar beside the page table).
     """
 
     def __init__(self, model, max_slots: int, s_max: Optional[int] = None,
@@ -138,9 +151,7 @@ class PagePool:
         # the family's two cache rows (K and V; the latent and the
         # position key), each one pool of pages
         self.k_pages, self.v_pages = (
-            self._cache_sharded(self._empty_pages(
-                self.page_shape(row, self.num_pages, page_size,
-                                model.num_layers)))
+            self._cache_sharded(self._empty_pages(row))
             for _, row, _ in serving_family(model).cache_rows(model))
         # per-slot decode state — identical to SlotPool's (the decode
         # horizon's freeze gates do not care where the columns live)
@@ -187,29 +198,28 @@ class PagePool:
                          category="kv")
             self._note_pages_ledger()
 
-    def _empty_pages(self, shape):
-        """Zeroed pages in the pool's element layout: model dtype, or
-        the graftquant ``(int8 data, f32 scale)`` pair (scale = ones —
-        untouched pages dequantize to the zeros dense pages hold)."""
+    def _empty_pages(self, row):
+        """Zeroed pages for one cache row in the pool's element
+        layout: model dtype, or the graftquant ``(int8 data, f32
+        scale)`` pair — one scale per trailing-dimension group, ``[L,
+        P, ps, H]`` (scale = ones — untouched pages dequantize to the
+        zeros dense pages hold)."""
+        shape = self.page_shape(row, self.num_pages, self.page_size,
+                                self.model.num_layers)
         if self.kv_dtype == "int8":
-            return QuantizedKV(jnp.zeros(shape, jnp.int8),
-                               jnp.ones(shape[:-1], jnp.float32))
+            groups = int(np.prod(row[:-1], dtype=int))
+            return QuantizedKV(
+                jnp.zeros(shape, jnp.int8),
+                jnp.ones(shape[:-1] + (groups,), jnp.float32))
         return jnp.zeros(shape, self.model.dtype)
 
     def _cache_sharded(self, c):
         if self.mesh is None:
             return c
-        # heads live at axis 2 in the paged layout — in BOTH leaves of
-        # a quantized pair (scale only drops the trailing head_dim)
-        if isinstance(c, QuantizedKV):
-            return QuantizedKV(
-                jax.device_put(c.data, NamedSharding(
-                    self.mesh, P(None, None, "model", None, None))),
-                jax.device_put(c.scale, NamedSharding(
-                    self.mesh, P(None, None, "model", None))))
+        # heads are contiguous groups of the LAST axis — in both leaves
+        # of a quantized pair (H * Dh lanes of data, H scales)
         return jax.device_put(
-            c, NamedSharding(self.mesh,
-                             P(None, None, "model", None, None)))
+            c, NamedSharding(self.mesh, PAGE_SPEC))
 
     def _replicated(self, a):
         if self.mesh is None:
@@ -218,13 +228,12 @@ class PagePool:
 
     @staticmethod
     def page_shape(row, num_pages: int, page_size: int, layers: int):
-        """One pool's shape for a cache row: a per-head row ``(H, Dh)``
-        keeps heads BEFORE the column offset (``[L, P, H, ps, Dh]``,
-        the paged kernel's tileable trailing pair); a row all heads
-        share ``(R,)`` is ``[L, P, ps, R]``."""
-        row = tuple(row)
-        return ((layers, num_pages) + row[:-1] + (int(page_size),)
-                + row[-1:])
+        """One pool's shape for a cache row, the same rule for every
+        family: ``[L, P, ps, prod(row)]`` — a token's row flat in the
+        lanes (a per-head row ``(H, Dh)``: the heads side by side; a
+        row all heads share ``(R,)``: itself)."""
+        return (layers, num_pages, int(page_size),
+                int(np.prod(tuple(row), dtype=int)))
 
     # ---- capacity accounting (graftmeter) ------------------------------
     @staticmethod

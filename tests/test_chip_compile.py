@@ -101,11 +101,23 @@ def _sds(shape, dtype):
 
 
 def _kv(shape, kv_dtype):
-    """A K or V operand: bf16 array, or the int8 + f32-scale pair whose
-    scale drops the trailing head_dim axis (ops/kv_quant.py)."""
+    """A K or V operand ``[..., heads, D]``: bf16 array, or the int8 +
+    f32-scale pair whose scale drops the trailing head_dim axis
+    (ops/kv_quant.py)."""
     if kv_dtype == "int8":
         return QuantizedKV(_sds(shape, jnp.int8),
                            _sds(shape[:-1], jnp.float32))
+    return _sds(shape, BF16)
+
+
+def _pool(layers, pages, page, heads, kv_dtype):
+    """A paged pool ``[L, P, ps, H * D]``: every head's row side by
+    side in the lanes; the int8 pair keeps one scale a token and head,
+    ``[L, P, ps, H]``."""
+    shape = (layers, pages, page, heads * D)
+    if kv_dtype == "int8":
+        return QuantizedKV(_sds(shape, jnp.int8),
+                           _sds(shape[:-1] + (heads,), jnp.float32))
     return _sds(shape, BF16)
 
 
@@ -120,12 +132,13 @@ def _decode_case(layout, kv_dtype, k1, page, slots=B, heads=H):
                                         interpret=False),
                 (q, kv, kv, pos))
     n_win = S // page
-    pages = _kv((slots * n_win + 1, heads, page, D), kv_dtype)
+    # layer 1 of a two-layer pool: the kernel reads it in place
+    pages = _pool(2, slots * n_win + 1, page, heads, kv_dtype)
     tab = _sds((slots, n_win), jnp.int32)
     kern = (da.paged_decode_attention if k1 == 1
             else da.paged_verify_decode_attention)
-    return (lambda q, k, v, t, p: kern(q, k, v, t, p, impl="pallas",
-                                       interpret=False),
+    return (lambda q, k, v, t, p: kern(q, k, v, t, p, layer=1,
+                                       impl="pallas", interpret=False),
             (q, pages, pages, tab, pos))
 
 
@@ -313,6 +326,57 @@ def test_xing4_decode_program_compiles_at_one_layer(topo):
     # temporaries far below one copy of the pools: written in place
     assert mem.temp_size_in_bytes < pools // 4, mem
     assert mem.alias_size_in_bytes >= pools
+
+
+def test_gpt_decode_and_insert_programs_write_the_pools_in_place(topo):
+    """The decode and insert programs of the cell ``gpt2-medium.serve.
+    closed`` (``gpt_medium`` widths, 32 slots, window 1,024, pages of
+    16, bfloat16; depth cut to 2 layers to stay in tier-1), through
+    ``ServingEngine``: the paged kernel compiles inside the decode
+    program, and both programs write the donated ``[L, P, ps, H * Dh]``
+    pools in place — no layer sliced out and stacked back, no padded
+    second copy (``ROADMAP.md`` A2 + A3)."""
+    from perf.rehearse import as_chip
+    from pytorch_multiprocessing_distributed_tpu import models
+    from pytorch_multiprocessing_distributed_tpu.inference.generate import (
+        pref_cache_shapes)
+    from pytorch_multiprocessing_distributed_tpu.serving import ServingEngine
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    model = models.get_model("gpt_medium", dtype=BF16, num_layers=2)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"])
+    with as_chip(chip):
+        engine = ServingEngine(model, params, max_slots=32, s_max=S,
+                               kv_layout="paged", page_size=16)
+        assert engine.decode_attn == "pallas"
+        pool = engine.pool
+
+        def sds(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+
+        state = (sds(pool.positions), sds(pool.last_tokens),
+                 sds(pool.active), sds(pool.budgets), sds(pool.eos_ids))
+        decode = engine._decode.lower(
+            jax.tree.map(sds, params), sds(pool.k_pages),
+            sds(pool.v_pages), sds(pool.device_table()), *state,
+            jax.ShapeDtypeStruct((2,), jnp.uint32), window=S,
+            horizon=1).compile()
+        pref = jax.ShapeDtypeStruct(pref_cache_shapes(model, S)[0], BF16)
+        scalar = jax.ShapeDtypeStruct((), jnp.int32)
+        insert = engine._insert_jit.lower(
+            sds(pool.k_pages), sds(pool.v_pages), *state, pref, pref,
+            jax.ShapeDtypeStruct((S // 16,), jnp.int32),
+            *(scalar,) * 5).compile()
+    assert pool.k_pages.shape == (2, 32 * 64 + 1, 16, 16 * D)
+    assert "paged_decode_attention" in _mosaic_names(decode.as_text())
+    pools = pool.k_pages.nbytes + pool.v_pages.nbytes
+    for program in (decode, insert):
+        mem = program.memory_analysis()
+        # temporaries far below one copy of the pools: written in place
+        assert mem.temp_size_in_bytes < pools // 4, mem
+        assert mem.alias_size_in_bytes >= pools
 
 
 @pytest.mark.parametrize("positions", [[0, 37, 95], [95, 95, 16]])

@@ -28,7 +28,8 @@ from pytorch_multiprocessing_distributed_tpu.analysis.meter import (
 from pytorch_multiprocessing_distributed_tpu.inference import (
     generate, teacher_forced_logits)
 from pytorch_multiprocessing_distributed_tpu.ops.kv_quant import (
-    QuantizedKV, dequantize_kv, quantize_kv, quantize_kv_np)
+    QuantizedKV, dequantize_kv, flatten_heads, quantize_kv,
+    quantize_kv_np)
 from pytorch_multiprocessing_distributed_tpu.runtime import hbm
 from pytorch_multiprocessing_distributed_tpu.serving import (
     RemoteReplica, ReplicaServer, Router, ServingEngine, SlotPool,
@@ -378,25 +379,32 @@ def test_pallas_quant_kernels_match_xla():
     np.testing.assert_allclose(np.asarray(p_q), np.asarray(ref),
                                atol=2e-5)
 
-    # paged: [n_pages, h, ps, d] pages + a page table per row
+    # paged: [L, n_pages, ps, h * d] pools (layer 1 of 2 is read) + a
+    # page table per row; the int8 pair keeps one scale a token and
+    # head, [L, n_pages, ps, h]
     n_pages = b * (s // ps) + 1
     table = jnp.asarray(
         np.arange(1, n_pages).reshape(b, s // ps), jnp.int32)
 
-    def paginate(c):
-        blocks = np.asarray(c).reshape(b, s // ps, ps, h, d)
-        pages = np.zeros((n_pages, h, ps, d), np.float32)
-        pages[1:] = blocks.transpose(0, 1, 3, 2, 4).reshape(
-            -1, h, ps, d)
+    def paginate(c):  # -> [2, n_pages, ps, h, d], layer 0 left empty
+        pages = np.zeros((2, n_pages, ps, h, d), np.float32)
+        pages[1, 1:] = np.asarray(c).reshape(-1, ps, h, d)
         return jnp.asarray(pages)
 
-    kp, vp = quantize_kv(paginate(k)), quantize_kv(paginate(v))
-    ref_p = da.paged_decode_attention(
-        q, dequantize_kv(kp, jnp.float32),
-        dequantize_kv(vp, jnp.float32), table, pos, impl="xla")
-    xp = da.paged_decode_attention(q, kp, vp, table, pos, impl="xla")
+    def quant_pool(pages):  # (h, d) merged into the pool's lanes
+        pair = quantize_kv(pages)
+        return flatten_heads(pair), flatten_heads(
+            dequantize_kv(pair, jnp.float32))
+
+    (kp, k_deq), (vp, v_deq) = (quant_pool(paginate(k)),
+                                quant_pool(paginate(v)))
+    ref_p = da.paged_decode_attention(q, k_deq, v_deq, table, pos,
+                                      layer=1, impl="xla")
+    np.testing.assert_array_equal(np.asarray(ref_p), np.asarray(ref))
+    xp = da.paged_decode_attention(q, kp, vp, table, pos, layer=1,
+                                   impl="xla")
     np.testing.assert_array_equal(np.asarray(xp), np.asarray(ref_p))
-    pp = da.paged_decode_attention(q, kp, vp, table, pos,
+    pp = da.paged_decode_attention(q, kp, vp, table, pos, layer=1,
                                    impl="pallas", interpret=True)
     np.testing.assert_allclose(np.asarray(pp), np.asarray(ref_p),
                                atol=2e-5)

@@ -59,12 +59,15 @@ def _decode(paged, k1, int8=False):
         kern = da.decode_attention if k1 == 1 else da.verify_decode_attention
         return (lambda q, k, v, p: kern(q, k, v, p, impl="pallas",
                                         interpret=True), (q, kv, kv, pos))
-    pages = _kv((B * (S // PAGE) + 1, H, PAGE, D), int8)
+    # a two-layer pool [L, P, ps, H * D] (int8: + scales [L, P, ps, H])
+    shape = (2, B * (S // PAGE) + 1, PAGE, H * D)
+    pages = (QuantizedKV(_sds(shape, jnp.int8), _sds(shape[:-1] + (H,)))
+             if int8 else _sds(shape))
     table = _sds((B, S // PAGE), jnp.int32)
     kern = (da.paged_decode_attention if k1 == 1
             else da.paged_verify_decode_attention)
-    return (lambda q, k, v, t, p: kern(q, k, v, t, p, impl="pallas",
-                                       interpret=True),
+    return (lambda q, k, v, t, p: kern(q, k, v, t, p, layer=1,
+                                       impl="pallas", interpret=True),
             (q, pages, pages, table, pos))
 
 

@@ -1,7 +1,8 @@
 """The paged decode kernel against its XLA pin, shape by shape.
 
-``_pallas_paged_decode`` folds all heads of a block of G pages in one
-grid step and names only live pages to the pipeline
+``_pallas_paged_attention`` folds all heads of a block of G pages in
+one grid step, reads layer ``LAYER`` of the whole ``[L, P, ps, H * Dh]``
+pool in place and names only live pages to the pipeline
 (``ops/pallas/decode_attention.py``). Here it runs in interpret mode
 against ``xla_paged_decode_attention`` over the page sizes, head counts
 and window lengths the engine can hand it, and every batch carries the
@@ -18,13 +19,14 @@ import numpy as np
 import pytest
 
 from pytorch_multiprocessing_distributed_tpu.ops.kv_quant import (
-    QuantizedKV, quantize_kv)
+    QuantizedKV, flatten_heads, quantize_kv)
 
 # the module, not the same-named function ops.pallas re-exports
 da = importlib.import_module(
     "pytorch_multiprocessing_distributed_tpu.ops.pallas.decode_attention")
 
 D = 16
+L, LAYER = 2, 1     # pools of two layers; the kernel reads the second
 # page_size -> (a window that is a multiple of G, one that is not);
 # G = 128 // page_size pages a step (1 at 128: every window is one)
 WINDOWS = {8: (32, 20), 16: (16, 11), 128: (2, 3)}
@@ -45,29 +47,37 @@ def _case(ps, heads, n_win, seed):
     b = len(pos)
     n_pages = b * n_win + 1
     q = jnp.asarray(rng.standard_normal((b, 1, heads, D)), jnp.float32)
-    k = rng.standard_normal((n_pages, heads, ps, D)).astype(np.float32)
-    v = rng.standard_normal((n_pages, heads, ps, D)).astype(np.float32)
+    k, v = (rng.standard_normal((L, n_pages, ps, heads * D)).astype(
+        np.float32) for _ in range(2))
     table = rng.permutation(np.arange(1, n_pages)).reshape(b, n_win)
     table[-1] = 0          # released slot: every entry the scratch page
     return q, k, v, table.astype(np.int32), pos
 
 
 def _pages(x, int8):
+    """The pool, or its int8 pair: one scale a token and head, the
+    data back in the pool's lane-dense rows."""
     x = jnp.asarray(x)
-    return quantize_kv(x) if int8 else x
+    if not int8:
+        return x
+    return flatten_heads(quantize_kv(x.reshape(x.shape[:-1] + (-1, D))))
 
 
 def _poison(pages, table, pos, ps):
-    """NaN in every page beyond each slot's position (the scale
-    sidecar of an int8 page: its data cannot hold one). Page 0 stays:
-    the inactive slot reads it as its live pages."""
+    """NaN in every page beyond each slot's position and in every
+    page of the layer the kernel must not read (the scale sidecar of
+    an int8 page: its data cannot hold one). Page 0 stays: the
+    inactive slot reads it as its live pages."""
     dead = np.unique(np.concatenate(
         [row[p // ps + 1:] for row, p in zip(table, pos)]))
     dead = dead[dead != 0]
+
+    def nan(x):
+        return x.at[LAYER, dead].set(jnp.nan).at[1 - LAYER].set(jnp.nan)
+
     if isinstance(pages, QuantizedKV):
-        return QuantizedKV(pages.data,
-                           pages.scale.at[dead].set(jnp.nan))
-    return pages.at[dead].set(jnp.nan)
+        return QuantizedKV(pages.data, nan(pages.scale))
+    return nan(pages)
 
 
 _CASES = [
@@ -92,7 +102,8 @@ def test_pallas_paged_decode_matches_xla(ps, heads, n_win, int8, poison):
                                 seed=ps * 1000 + heads * 10 + n_win)
     kp, vp = _pages(k, int8), _pages(v, int8)
     ref = da.paged_decode_attention(q, kp, vp, jnp.asarray(table),
-                                    jnp.asarray(pos), impl="xla")
+                                    jnp.asarray(pos), layer=LAYER,
+                                    impl="xla")
     assert np.isfinite(np.asarray(ref)).all()
     if poison:
         # nothing beyond a position may be folded: the reference saw
@@ -100,9 +111,37 @@ def test_pallas_paged_decode_matches_xla(ps, heads, n_win, int8, poison):
         kp, vp = (_poison(kp, table, pos, ps),
                   _poison(vp, table, pos, ps))
     got = da.paged_decode_attention(q, kp, vp, jnp.asarray(table),
-                                    jnp.asarray(pos), impl="pallas",
-                                    interpret=True)
+                                    jnp.asarray(pos), layer=LAYER,
+                                    impl="pallas", interpret=True)
     # tests/test_graftquant.py's tolerance for the same pair of paths
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("ps, heads, n_win, k1", [
+    (8, 2, 20, 3), (16, 12, 11, 5), (16, 16, 16, 2), (128, 2, 3, 5)])
+def test_pallas_paged_verify_matches_xla(ps, heads, n_win, k1, int8):
+    """The k-query verify pass is the same kernel body with ``K1 * H``
+    query rows: row ``i`` of a slot reaches column ``pos + i``, the
+    pages up to the LAST row's reach are live, and everything beyond
+    it — and the other layer — is poisoned."""
+    q, k, v, table, pos = _case(ps, heads, n_win,
+                                seed=ps * 100 + heads + k1)
+    # the K1 columns a pass writes lie inside the window
+    pos = np.minimum(pos, n_win * ps - k1)
+    rng = np.random.default_rng(k1)
+    q = jnp.asarray(rng.standard_normal((len(pos), k1, heads, D)),
+                    jnp.float32)
+    kp, vp = _pages(k, int8), _pages(v, int8)
+    args = (jnp.asarray(table), jnp.asarray(pos))
+    ref = da.paged_verify_decode_attention(q, kp, vp, *args, layer=LAYER,
+                                           impl="xla")
+    assert np.isfinite(np.asarray(ref)).all()
+    reach = pos + k1 - 1
+    got = da.paged_verify_decode_attention(
+        q, _poison(kp, table, reach, ps), _poison(vp, table, reach, ps),
+        *args, layer=LAYER, impl="pallas", interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-5)
 
@@ -120,7 +159,7 @@ def test_live_page_ids_name_live_pages_only(ps, n_win):
                     int(rng.integers(0, window))], np.int32)
     table = np.arange(len(pos) * n_win, dtype=np.int32).reshape(
         len(pos), n_win) + 1          # entry -> slot and logical page
-    group = da._pages_per_step(ps, n_win, 2 * ps * D * 2)
+    group = da._pages_per_step(ps, n_win, ps * 2 * D * 2)
     assert group == min(max(1, 128 // ps), n_win)
     named = np.asarray(da._live_page_ids(
         jnp.asarray(table), jnp.asarray(pos), group, ps)).reshape(

@@ -147,6 +147,26 @@ def test_paged_tp_matches_single_shard(served):
     assert engine.pool.pages_in_use == 0
 
 
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_paged_pool_shards_contiguous_head_groups(served, kv_dtype):
+    """On a ``model`` mesh a ``[L, P, ps, H * Dh]`` pool (and an int8
+    pool's ``[L, P, ps, H]`` scales) is split on its LAST axis: each
+    chip holds a contiguous group of heads' lanes of every page."""
+    from pytorch_multiprocessing_distributed_tpu.parallel import make_mesh
+    from pytorch_multiprocessing_distributed_tpu.serving.kv_pages import (
+        PAGE_SPEC)
+
+    model, _, _ = served
+    pool = PagePool(model, 2, 32, make_mesh(4, 2), page_size=8,
+                    kv_dtype=kv_dtype)
+    leaves = jax.tree.leaves((pool.k_pages, pool.v_pages))
+    assert len(leaves) == (4 if kv_dtype == "int8" else 2)
+    for leaf in leaves:
+        assert leaf.sharding.spec == PAGE_SPEC
+        assert leaf.addressable_shards[0].data.shape == (
+            leaf.shape[:3] + (leaf.shape[3] // 2,))
+
+
 # --------------------------------------------------------- prefix cache
 
 def test_prefix_cache_full_hit(served):

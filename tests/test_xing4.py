@@ -352,13 +352,20 @@ def test_page_kv_bytes_equals_the_allocation(tiny):
     assert pool.v_pages.shape == (3, 25, 8, 0)
     assert PagePool.page_kv_bytes(model, 8) == 3 * 8 * (32 + 128) * 4
     assert PagePool.page_kv_bytes(model, 8) * pool.num_pages == held
-    # the GPT family's pages keep their shape and their byte count
+    # the GPT family's pages follow the same rule, [L, P, ps, H * Dh]
+    # (4 heads of 32 side by side), and keep their byte count
     gpt = models.get_model("gpt_tiny")
     pool = PagePool(gpt, 2, 32, page_size=8)
-    assert pool.k_pages.shape == (4, 9, 4, 8, 32)
+    assert pool.k_pages.shape == pool.v_pages.shape == (4, 9, 8, 4 * 32)
     assert (PagePool.page_kv_bytes(gpt, 8) * pool.num_pages
             == pool.k_pages.nbytes + pool.v_pages.nbytes)
     assert PagePool.page_kv_bytes(gpt, 8, "int8") == 2 * 4 * 4 * 8 * 36
+    # int8: one scale a token and head beside the data's lanes
+    pool = PagePool(gpt, 2, 32, page_size=8, kv_dtype="int8")
+    assert pool.k_pages.data.shape == (4, 9, 8, 4 * 32)
+    assert pool.k_pages.scale.shape == (4, 9, 8, 4)
+    assert (PagePool.page_kv_bytes(gpt, 8, "int8") * pool.num_pages
+            == pool.k_pages.nbytes + pool.v_pages.nbytes)
 
 
 @pytest.mark.parametrize("options, named", [
