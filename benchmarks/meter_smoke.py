@@ -9,7 +9,7 @@ graftmeter stack end-to-end:
    budgets (the full 15-program gate is ``make check``; this is the
    fast canary that the comparison machinery itself works);
 2. **planner round-trip** — ``plan_capacity``'s slot prediction is
-   validated against a REAL CPU-backend :class:`SlotPool` allocation:
+   validated against a REAL CPU-backend :class:`PagePool` allocation:
    predicted per-slot/pool bytes must match the arrays actually
    allocated within 0.5% (in practice they are byte-exact — the
    planner and the allocator share one shape x dtype product);
@@ -43,7 +43,7 @@ import benchmarks._common as _common  # noqa: E402
 CANARY_PROGRAMS = ("collectives_all_reduce", "moe_mlp_ep")
 
 # planner-vs-allocation tolerance, pinned by the tier-1 twin of this
-# smoke: the planner and SlotPool share one shape x dtype product, so
+# smoke: the planner and PagePool share one shape x dtype product, so
 # the match is byte-exact in practice; 0.5% absorbs a future dtype/
 # padding surprise without letting a real drift (a forgotten cache
 # copy doubles bytes) through.
@@ -62,8 +62,8 @@ def run(out_dir: str) -> dict:
     from pytorch_multiprocessing_distributed_tpu.runtime import hbm
     from pytorch_multiprocessing_distributed_tpu.serving import (
         ServingEngine, init_params)
-    from pytorch_multiprocessing_distributed_tpu.serving.kv_slots import (
-        SlotPool)
+    from pytorch_multiprocessing_distributed_tpu.serving.kv_pages import (
+        PagePool)
     from pytorch_multiprocessing_distributed_tpu.serving.scheduler import (
         DONE)
     from pytorch_multiprocessing_distributed_tpu.utils.plotting import (
@@ -88,13 +88,16 @@ def run(out_dir: str) -> dict:
     params_bytes = hbm.tree_nbytes(params)
     s_max = 32
     budget = params_bytes + 4 * (
-        SlotPool.per_slot_kv_bytes(model, s_max)
-        + SlotPool.per_slot_state_bytes()) + 1000
+        PagePool.per_slot_kv_bytes(model, s_max)
+        + PagePool.per_slot_state_bytes()) + 1000
     plan = meter.plan_capacity(model, s_max, budget, params=params)
     assert plan["max_slots"] == 4, plan
-    pool = SlotPool(model, plan["max_slots"], s_max)
+    # pages at the default num_pages hold every slot's worst case,
+    # plus the scratch page and the int32 page table
+    pool = PagePool(model, plan["max_slots"], s_max, page_size=8)
     predicted = plan["max_slots"] * plan["per_slot_bytes"]
-    actual = pool.hbm_bytes
+    actual = (pool.hbm_bytes - pool.page_bytes
+              - 4 * pool.max_slots * pool.pages_per_slot)
     rel_err = abs(predicted - actual) / actual
     assert rel_err <= PLAN_TOLERANCE, (
         f"plan_capacity predicted {predicted} bytes for "
@@ -134,7 +137,7 @@ def run(out_dir: str) -> dict:
     # the ledger saw every allocation site: params, KV pool, slot
     # state, and at least one per-bucket decode-program temp
     assert "params" in breakdown and "kv" in breakdown, breakdown
-    assert "serving.kv_pool" in breakdown["kv"], breakdown
+    assert "serving.kv_pages" in breakdown["kv_pages"], breakdown
     assert any(n.startswith("serving.decode_temp_w")
                for n in breakdown.get("temps", {})), breakdown
     samples = {}

@@ -41,7 +41,7 @@ def run_smoke():
     from pytorch_multiprocessing_distributed_tpu.runtime import (
         hbm as hbm_ledger)
     from pytorch_multiprocessing_distributed_tpu.serving import (
-        ServingEngine, SlotPool, init_params)
+        PagePool, ServingEngine, init_params)
     from pytorch_multiprocessing_distributed_tpu.serving.scheduler import (
         Request)
 
@@ -53,13 +53,13 @@ def run_smoke():
     prompts = [rng.integers(0, 61, (n,)).tolist() for n in (3, 12, 7)]
     s_max = 32
 
-    # ---- 1: transcript equality, dense and paged int8
+    # ---- 1: transcript equality, int8 pages at worst-case capacity
+    # and under it
     ref_eng = ServingEngine(model, params, max_slots=2, s_max=s_max,
                             min_bucket=8)
     ref = ref_eng.serve([(p, 6) for p in prompts])
-    for tag, kw in (("dense", {}),
-                    ("paged", {"kv_layout": "paged", "page_size": 8,
-                               "num_pages": 9})):
+    for tag, kw in (("parity", {}),
+                    ("paged", {"page_size": 8, "num_pages": 9})):
         eng = ServingEngine(model, params, max_slots=2, s_max=s_max,
                             min_bucket=8, kv_dtype="int8", **kw)
         got = eng.serve([(p, 6) for p in prompts])
@@ -67,25 +67,26 @@ def run_smoke():
             assert a.tokens == b.tokens, (
                 f"int8 {tag} stream diverged (prompt len {len(p)}): "
                 f"{a.tokens} vs {b.tokens}")
-    print("quant smoke: int8 dense + paged transcripts byte-equal vs "
+    print("quant smoke: int8 parity + paged transcripts byte-equal vs "
           "model-dtype engine OK")
 
     # ---- 2: the residency claim, byte-exact at a live ledger
-    kv_model = SlotPool.per_slot_kv_bytes(model, s_max)
-    kv_int8 = SlotPool.per_slot_kv_bytes(model, s_max, "int8")
+    kv_model = PagePool.per_slot_kv_bytes(model, s_max)
+    kv_int8 = PagePool.per_slot_kv_bytes(model, s_max, "int8")
     with hbm_ledger.scoped_ledger() as ledger:
-        pool = SlotPool(model, 4, s_max, kv_dtype="int8")
-        kv_entry = ledger.entries()["serving.kv_pool"]
-    assert kv_entry[1] == 4 * kv_int8, (
-        "quantized SlotPool bytes diverge from per_slot_kv_bytes")
+        pool = PagePool(model, 4, s_max, page_size=8, kv_dtype="int8")
+        kv_entry = ledger.entries()["serving.kv_pages"]
+    # every slot's worst case plus the scratch page
+    assert kv_entry[1] == 4 * kv_int8 + pool.page_bytes, (
+        "quantized PagePool bytes diverge from per_slot_kv_bytes")
     del pool
     # bf16 twin of the same geometry: the TPU headline ratio (byte
     # math only — per_slot_kv_bytes reads geometry, no allocation)
     bf16 = models.GPT(vocab_size=61, max_seq_len=64, hidden_size=128,
                       num_layers=2, num_heads=2, mlp_dim=64,
                       attn_impl="xla", dtype=jnp.bfloat16)
-    r_bf16 = (SlotPool.per_slot_kv_bytes(bf16, s_max)
-              / SlotPool.per_slot_kv_bytes(bf16, s_max, "int8"))
+    r_bf16 = (PagePool.per_slot_kv_bytes(bf16, s_max)
+              / PagePool.per_slot_kv_bytes(bf16, s_max, "int8"))
     r_f32 = kv_model / kv_int8
     assert r_bf16 >= 1.8, f"bf16 head_dim=64 ratio {r_bf16:.3f} < 1.8"
     assert r_f32 >= 3.5, f"f32 head_dim=64 ratio {r_f32:.3f} < 3.5"

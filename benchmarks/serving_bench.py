@@ -522,7 +522,7 @@ def run_paged_sweep(model, params, args, rng):
     from pytorch_multiprocessing_distributed_tpu.runtime import (
         hbm as hbm_ledger)
     from pytorch_multiprocessing_distributed_tpu.serving import (
-        ServingEngine, SlotPool)
+        PagePool, ServingEngine)
 
     new_tokens = args.new_tokens
     # the pool must ADMIT up to the model's own max length (that is
@@ -535,7 +535,7 @@ def run_paged_sweep(model, params, args, rng):
     # FIXED budget: params + exactly the dense pool's worst-case KV
     # bytes — the planner charges params first, so the page pool gets
     # precisely the bytes the dense slots occupied
-    kv_budget = slots_dense * SlotPool.per_slot_kv_bytes(model, s_max)
+    kv_budget = slots_dense * PagePool.per_slot_kv_bytes(model, s_max)
     budget = hbm_ledger.tree_nbytes(params) + kv_budget
     results = []
     for dist in args.len_dist.split(","):
@@ -669,7 +669,7 @@ def run_quant_sweep(model, params, args, rng):
     from pytorch_multiprocessing_distributed_tpu.runtime import (
         hbm as hbm_ledger)
     from pytorch_multiprocessing_distributed_tpu.serving import (
-        ServingEngine, SlotPool)
+        PagePool, ServingEngine)
 
     new_tokens = args.new_tokens
     s_max = model.max_seq_len
@@ -681,8 +681,8 @@ def run_quant_sweep(model, params, args, rng):
     results = []
 
     # ---- point (a): the byte claim, planner == allocator both modes
-    kv_model = SlotPool.per_slot_kv_bytes(model, s_max)
-    kv_int8 = SlotPool.per_slot_kv_bytes(model, s_max, "int8")
+    kv_model = PagePool.per_slot_kv_bytes(model, s_max)
+    kv_int8 = PagePool.per_slot_kv_bytes(model, s_max, "int8")
     kv_ratio = kv_model / kv_int8
     head_dim = model.hidden_size // model.num_heads
     itemsize = jnp.dtype(model.dtype).itemsize
@@ -702,7 +702,7 @@ def run_quant_sweep(model, params, args, rng):
     # fits in the same bytes. N >= 5 so integer slot-count floors
     # cannot mask the gain at small --slots
     slots_dense = max(int(args.slots.split(",")[0]), 5)
-    per_slot_full = kv_model + SlotPool.per_slot_state_bytes()
+    per_slot_full = kv_model + PagePool.per_slot_state_bytes()
     budget = (hbm_ledger.tree_nbytes(params)
               + slots_dense * per_slot_full)
     plan_ref = plan_capacity(model, s_max, budget, params=params)
@@ -715,13 +715,14 @@ def run_quant_sweep(model, params, args, rng):
         f"at a {slots_dense}-slot budget")
     # planner-vs-allocation byte-exactness pin (the graftmeter
     # contract, quantized mode): a real int8 pool of the planned slot
-    # count registers exactly the planned KV bytes
+    # count (one page of s_max rows a slot) registers exactly the
+    # planned KV bytes plus the scratch page
     with hbm_ledger.scoped_ledger() as ledger:
-        pool = SlotPool(model, plan_q["max_slots"], s_max,
-                        kv_dtype="int8")
-        kv_entry = ledger.entries()["serving.kv_pool"]
-    assert kv_entry[1] == plan_q["max_slots"] * kv_int8, (
-        "planner and quantized SlotPool disagree on the KV bytes")
+        pool = PagePool(model, plan_q["max_slots"], s_max,
+                        page_size=s_max, kv_dtype="int8")
+        kv_entry = ledger.entries()["serving.kv_pages"]
+    assert kv_entry[1] == (plan_q["max_slots"] + 1) * kv_int8, (
+        "planner and quantized PagePool disagree on the KV bytes")
     del pool
 
     # ---- point (b): measured residency + throughput at the budget

@@ -50,15 +50,13 @@ TINY = dict(model="gpt_tiny", dtype="float32", batch=8, seq=64,
             steps=10, lr=0.01, resnet_batch=64, resnet_samples=128,
             max_new=8, dp_steps=3)
 
-# serve_lm.py variants: between them the four decode/verify kernels and
-# the int8 branch of the paged pair each run once
+# serve_lm.py variants: between them the paged decode and verify kernels
+# and the int8 branch of each run once
 SERVE_VARIANTS = {
-    "dense": [],
-    "dense_spec": ["--draft_k", "4"],
-    "paged": ["--kv_layout", "paged"],
-    "paged_int8": ["--kv_layout", "paged", "--kv_dtype", "int8"],
-    "paged_int8_spec": ["--kv_layout", "paged", "--kv_dtype", "int8",
-                        "--draft_k", "4"],
+    "paged": [],
+    "paged_spec": ["--draft_k", "4"],
+    "paged_int8": ["--kv_dtype", "int8"],
+    "paged_int8_spec": ["--kv_dtype", "int8", "--draft_k", "4"],
 }
 
 # Greedy streams of a Pallas run against the XLA run of the same
@@ -389,16 +387,18 @@ def child_kernels(platform):
         # a short row, block edges, and (nearly) the whole window
         pos = jnp.asarray(([3, 15, 16, s // 2] + [s - k1] * b)[:b],
                           jnp.int32)
-        dense, paged = ((da.decode_attention, da.paged_decode_attention)
-                        if k1 == 1 else (da.verify_decode_attention,
-                                         da.paged_verify_decode_attention))
-        paged = functools.partial(paged, layer=1)
-        for page in (None, 16, 128 if platform == "tpu" else 32):
+        # the verify pass exists on pages only
+        paged = functools.partial(
+            da.paged_decode_attention if k1 == 1
+            else da.paged_verify_decode_attention, layer=1)
+        for page in ((None,) if k1 == 1 else ()) + (
+                16, 128 if platform == "tpu" else 32):
             for kv in ("bf16", "int8"):
                 if page is None:
                     kk, vv = ((quantize_kv(k), quantize_kv(v))
                               if kv == "int8" else (k, v))
-                    got, want = (dense(q, kk, vv, pos, impl=impl)
+                    got, want = (da.decode_attention(q, kk, vv, pos,
+                                                     impl=impl)
                                  for impl in ("pallas", "xla"))
                 else:
                     def pages(x):  # slot j's block n is page j*n_win+n+1

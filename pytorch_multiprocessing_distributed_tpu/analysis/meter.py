@@ -266,14 +266,15 @@ def plan_capacity(model, s_max: int, hbm_budget: int, *,
       kv_dtype: ``"model"`` or ``"int8"`` (graftquant) — the pool's
         element layout; int8 charges 1 byte per KV element plus the
         4-byte f32 per-token-per-head scale, the exact bytes the
-        quantized ``SlotPool``/``PagePool`` allocates, so the
+        quantized ``PagePool`` allocates, so the
         inversion stays byte-exact in BOTH modes (meter smoke pins
         it against a real pool).
 
     Returns the plan dict: ``params_bytes``, ``opt_state_bytes``,
     ``per_slot_bytes`` (dense worst-case KV + per-slot scalar state —
-    the exact bytes ``SlotPool`` allocates, validated against a real
-    CPU-backend pool in the meter smoke), ``max_slots``,
+    the exact bytes a ``PagePool`` at dense parity allocates beside
+    its scratch page, validated against a real CPU-backend pool in the
+    meter smoke), ``max_slots``,
     ``kv_bytes_at_max`` and ``headroom_bytes`` (what is left after
     params + optimizer + reserved + max_slots slots),
     ``max_generate_batch`` (the one-shot ``generate`` twin: rows of a
@@ -282,7 +283,7 @@ def plan_capacity(model, s_max: int, hbm_budget: int, *,
     import jax
     import jax.numpy as jnp
 
-    from ..serving.kv_slots import SlotPool
+    from ..serving.kv_pages import PagePool
 
     if hbm_budget <= 0:
         raise ValueError(f"hbm_budget must be > 0, got {hbm_budget}")
@@ -303,12 +304,12 @@ def plan_capacity(model, s_max: int, hbm_budget: int, *,
     else:
         per_moment = params_bytes
     opt_bytes = int(optimizer_moments) * per_moment
-    per_slot = (SlotPool.per_slot_kv_bytes(model, s_max, kv_dtype)
-                + SlotPool.per_slot_state_bytes())
+    per_slot = (PagePool.per_slot_kv_bytes(model, s_max, kv_dtype)
+                + PagePool.per_slot_state_bytes())
     fixed = params_bytes + opt_bytes + int(reserved_bytes)
     free = hbm_budget - fixed
     max_slots = max(0, free // per_slot)
-    per_row = SlotPool.per_slot_kv_bytes(model, s_max, kv_dtype)
+    per_row = PagePool.per_slot_kv_bytes(model, s_max, kv_dtype)
     plan = {
         "hbm_budget": int(hbm_budget),
         "params_bytes": params_bytes,
@@ -330,8 +331,6 @@ def plan_capacity(model, s_max: int, hbm_budget: int, *,
     # page_bytes is the ONE shape x dtype product PagePool allocates,
     # so planner == allocator byte-for-byte (pinned in the meter
     # smoke); the scratch page is charged before pages are counted.
-    from ..serving.kv_pages import PagePool
-
     page_bytes = PagePool.page_kv_bytes(model, page_size, kv_dtype)
     max_pages = max(0, (free - page_bytes) // page_bytes)  # - scratch
     plan.update({

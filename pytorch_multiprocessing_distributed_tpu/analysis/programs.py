@@ -19,7 +19,7 @@ Each hook returns specs of the shape::
         # ---- inline invariants (checked live, NOT refreshable by
         #      `make check-update` — the hand-written contract):
         "expect_collectives": {..},# exact jaxpr-level budget
-        "expect_grad_psums": int,  # psum eqns sized == params_bytes
+        "expect_grad_psums": int,  # psum bytes // params_bytes
         "expect_collective_subset": {..},  # exact count+bytes for
                                    # SELECTED budget keys (graftzero's
                                    # reduce-scatter/all-gather pin)
@@ -197,14 +197,18 @@ def audit_program(spec: ProgramSpec
 
     n_grad = built.get("expect_grad_psums")
     if n_grad is not None:
+        # whole parameter trees' worth of psum bytes: a ``pmean`` of
+        # the gradient tree is ONE equation under some jax versions
+        # and one a leaf under others, the same bytes either way (the
+        # statistic and metric psums beside it are far below a tree)
         pb = int(built["params_bytes"])
-        got = sum(1 for s in ir.psum_sizes(closed) if s == pb)
+        got = sum(ir.psum_sizes(closed)) // pb
         record["grad_sized_psums"] = got
         if got != n_grad:
             add("GC101",
-                f"{got} psum(s) sized exactly like the parameter tree "
-                f"({pb} bytes), expected {n_grad} — the gradient "
-                "all-reduce contract moved")
+                f"psums move {got} parameter tree(s) of bytes ({pb} "
+                f"each), expected {n_grad} — the gradient all-reduce "
+                "contract moved")
 
     subset = built.get("expect_collective_subset")
     if subset is not None:

@@ -30,7 +30,6 @@ from ..ops.kv_quant import (QuantizedKV, flatten_heads, kv_slice_in_dim,
 from ..ops.pallas.decode_attention import (decode_attention,
                                            paged_decode_attention,
                                            paged_verify_decode_attention,
-                                           verify_decode_attention,
                                            xla_decode_attention)
 
 # flax-default fallback for models predating the ln_eps field; every
@@ -361,28 +360,27 @@ def draft_bucket(tokens, n_buckets: int):
 
 def _block_verify_slots(p, x_t, k_cache, v_cache, positions, h, dtype,
                         eps, cs=_no_cs, top_k=1, window=None,
-                        attn_impl="xla", block_k=256, interpret=None,
-                        page_table=None, page_size=None, layer=None):
-    """k-query VERIFY variant of :func:`_block_decode_slots`
-    (graftspec): ``x_t`` is ``[N, K1, D]`` — each slot's pending token
-    plus its ``K1 - 1`` draft proposals. Row ``i``'s K/V is written at
-    column ``positions + i`` (all K1 columns, BEFORE the attention, so
-    later rows see earlier rows' keys — the same write-then-attend
-    order as the single-query step), then row ``i`` attends
-    ``[0, positions + i]`` through the k-query flash kernel or its XLA
-    reference (:func:`...ops.pallas.decode_attention.
-    verify_decode_attention`).
+                        attn_impl="xla", interpret=None, *,
+                        page_table, page_size, layer):
+    """k-query VERIFY variant of :func:`_block_decode_slots`'s paged
+    arm (graftspec): ``x_t`` is ``[N, K1, D]`` — each slot's pending
+    token plus its ``K1 - 1`` draft proposals. Row ``i``'s K/V is
+    written at column ``positions + i`` (all K1 columns, BEFORE the
+    attention, so later rows see earlier rows' keys — the same
+    write-then-attend order as the single-query step), then row ``i``
+    attends ``[0, positions + i]`` through the k-query paged kernel or
+    its XLA reference (:func:`...ops.pallas.decode_attention.
+    paged_verify_decode_attention`).
 
     Rejected/overflow draft columns follow the stale-column
     invariant: a column beyond the slot's accepted frontier is masked
     by every later read until the frontier's own (correct) write
-    overwrites it. Dense writes past the cache bound are DROPPED
-    (``mode="drop"`` — such a column could never be emitted anyway:
-    ``position + remaining <= s_max - 1``); paged writes whose column
-    falls beyond the slot's table land on the scratch page 0, so a
-    draft write can never touch a page owned by another tenant or a
-    shared read-only prefix page. Paged mode carries the whole pools
-    and a static ``layer``, as :func:`_block_decode_slots` does."""
+    overwrites it. Writes whose column falls beyond the slot's table
+    land on the scratch page 0 (such a column could never be emitted
+    anyway: ``position + remaining <= s_max - 1``), so a draft write
+    can never touch a page owned by another tenant or a shared
+    read-only prefix page. The whole pools and a static ``layer`` are
+    carried, as :func:`_block_decode_slots` does."""
     n, k1, _ = x_t.shape
     hn = _ln(x_t, p["ln1"], eps).astype(dtype)
     q, k, v = jnp.split(_dense(hn, p["attn"]["wqkv"], dtype), 3, axis=-1)
@@ -390,43 +388,19 @@ def _block_verify_slots(p, x_t, k_cache, v_cache, positions, h, dtype,
     k = cs(_split_heads(k, h), None, None, "model", None)
     v = cs(_split_heads(v, h), None, None, "model", None)
     cols = positions[:, None] + jnp.arange(k1)[None, :]     # [N, K1]
-    if page_table is not None:
-        ps = int(page_size)
-        blk = cols // ps
-        n_tab = page_table.shape[1]
-        page_ids = jnp.take_along_axis(
-            page_table, jnp.clip(blk, 0, n_tab - 1), axis=1)
-        page_ids = jnp.where(blk < n_tab, page_ids, 0)
-        offs = cols % ps
-        k_cache = _write_pages(k_cache, layer, page_ids, offs, k)
-        v_cache = _write_pages(v_cache, layer, page_ids, offs, v)
-        att = paged_verify_decode_attention(
-            q, k_cache, v_cache, _window_table(page_table, window, ps),
-            positions, layer=layer, window=window, impl=attn_impl,
-            interpret=interpret)
-    else:
-        rows = jnp.arange(n)[:, None]
-        if isinstance(k_cache, QuantizedKV):
-            qk, qv = quantize_kv(k), quantize_kv(v)
-            k_cache = QuantizedKV(
-                k_cache.data.at[rows, cols].set(qk.data, mode="drop"),
-                k_cache.scale.at[rows, cols].set(qk.scale,
-                                                 mode="drop"))
-            v_cache = QuantizedKV(
-                v_cache.data.at[rows, cols].set(qv.data, mode="drop"),
-                v_cache.scale.at[rows, cols].set(qv.scale,
-                                                 mode="drop"))
-        else:
-            k_cache = k_cache.at[rows, cols].set(k, mode="drop")
-            v_cache = v_cache.at[rows, cols].set(v, mode="drop")
-        if window is not None and window < k_cache.shape[1]:
-            k_win = kv_slice_in_dim(k_cache, 0, window, axis=1)
-            v_win = kv_slice_in_dim(v_cache, 0, window, axis=1)
-        else:
-            k_win, v_win = k_cache, v_cache
-        att = verify_decode_attention(q, k_win, v_win, positions,
-                                      impl=attn_impl, block_k=block_k,
-                                      interpret=interpret)
+    ps = int(page_size)
+    blk = cols // ps
+    n_tab = page_table.shape[1]
+    page_ids = jnp.take_along_axis(
+        page_table, jnp.clip(blk, 0, n_tab - 1), axis=1)
+    page_ids = jnp.where(blk < n_tab, page_ids, 0)
+    offs = cols % ps
+    k_cache = _write_pages(k_cache, layer, page_ids, offs, k)
+    v_cache = _write_pages(v_cache, layer, page_ids, offs, v)
+    att = paged_verify_decode_attention(
+        q, k_cache, v_cache, _window_table(page_table, window, ps),
+        positions, layer=layer, window=window, impl=attn_impl,
+        interpret=interpret)
     att = att.reshape(n, k1, -1).astype(dtype)
     x_t = x_t + _dense(att, p["attn"]["wo"], dtype)
     return (x_t + _ffn(p, x_t, dtype, eps, top_k), k_cache, v_cache)
@@ -438,8 +412,8 @@ class GPTServing:
     """What the serving engine asks of a MODEL FAMILY, answered for the
     GPT family with the block functions above, untouched.
 
-    The engine (``serving.engine``), its pools (``serving.kv_pages``,
-    ``serving.kv_slots``) and the shared decode core
+    The engine (``serving.engine``), its pool (``serving.kv_pages``)
+    and the shared decode core
     (:func:`_decode_horizon`) know a family only through this surface;
     a model of another family carries its own as ``model.
     serving_family`` (:func:`serving_family`). The two cache operands
@@ -650,14 +624,15 @@ def _decode_horizon(model, params, k_caches, v_caches, positions,
                 "draft_k > 0 needs exactly one draft source: "
                 "draft_table (self-drafting) or draft_model (+ params "
                 "and caches)")
-        if kv_valid is not None or uniform_positions:
+        if kv_valid is not None or uniform_positions or page_table is None:
             raise ValueError(
                 "speculative decode composes with neither kv_valid "
-                "nor uniform_positions (serving slots only)")
+                "nor uniform_positions and verifies on pages (the "
+                "serving engine's slots only)")
         return _decode_horizon_spec(
             model, params, k_caches, v_caches, positions, last_tokens,
             active, remaining, eos_ids, keys, cs=cs, cs_cache=cs_cache,
-            window=window, attn_impl=attn_impl, block_k=block_k,
+            window=window, attn_impl=attn_impl,
             page_table=page_table, page_size=page_size,
             draft_k=int(draft_k), draft_table=draft_table,
             draft_model=draft_model, draft_params=draft_params,
@@ -705,7 +680,7 @@ def _decode_horizon(model, params, k_caches, v_caches, positions,
 
 def _decode_horizon_spec(model, params, k_caches, v_caches, positions,
                          last_tokens, active, remaining, eos_ids, keys,
-                         *, cs, cs_cache, window, attn_impl, block_k,
+                         *, cs, cs_cache, window, attn_impl,
                          page_table, page_size, draft_k, draft_table,
                          draft_model, draft_params, draft_k_caches,
                          draft_v_caches):
@@ -791,21 +766,11 @@ def _decode_horizon_spec(model, params, k_caches, v_caches, positions,
         verify = partial(
             _block_verify_slots, positions=positions, h=h, dtype=dtype,
             eps=eps, cs=cs, top_k=moe_k, window=window,
-            attn_impl=attn_impl, block_k=block_k, page_table=page_table,
+            attn_impl=attn_impl, page_table=page_table,
             page_size=page_size)
-        if page_table is not None:  # whole pools, written in place
-            for i in range(n_layers):
-                x_t, k_caches, v_caches = verify(
-                    params[f"block_{i}"], x_t, k_caches, v_caches,
-                    layer=i)
-        else:
-            new_k, new_v = [], []
-            for i in range(n_layers):
-                x_t, kc, vc = verify(params[f"block_{i}"], x_t,
-                                     k_caches[i], v_caches[i])
-                new_k.append(kc)
-                new_v.append(vc)
-            k_caches, v_caches = stack_kv(new_k), stack_kv(new_v)
+        for i in range(n_layers):  # whole pools, written in place
+            x_t, k_caches, v_caches = verify(
+                params[f"block_{i}"], x_t, k_caches, v_caches, layer=i)
         logits = _logits(params, x_t, eps, cs)        # [N, k+1, V]
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
@@ -1324,12 +1289,12 @@ def generate_kv_bytes(model, batch: int, s_max: int,
     resident: the exact ``[L, B, s_max, H, Dh]`` x2 allocation
     ``_prefill`` makes — ``batch`` rows of the SAME per-slot product
     the serving pool allocates, so the ONE copy of the shape x dtype
-    math lives in ``SlotPool.per_slot_kv_bytes`` (a KV-layout change
+    math lives in ``PagePool.per_slot_kv_bytes`` (a KV-layout change
     there moves the planner's ``max_generate_batch`` and this ledger
     entry together). Lazy import: ``serving`` imports this module."""
-    from ..serving.kv_slots import SlotPool
+    from ..serving.kv_pages import PagePool
 
-    return int(batch) * SlotPool.per_slot_kv_bytes(model, int(s_max),
+    return int(batch) * PagePool.per_slot_kv_bytes(model, int(s_max),
                                                    kv_dtype)
 
 

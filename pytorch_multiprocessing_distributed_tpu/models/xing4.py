@@ -447,7 +447,6 @@ class Xing4Serving:
     # engine options this family does not support yet: option -> what
     # is missing (the engine refuses them at construction, by name)
     refuses = {
-        "kv_layout=dense": "the latent cache exists only as pages",
         "kv_dtype=int8": "no quantised latent page yet",
         "draft_k": "no verify pass over latent pages yet",
         "prefix_cache": "no page fork or gather for latent pages yet",

@@ -50,8 +50,8 @@ dtype after the residual add, matching the XLA path's dtypes exactly).
 KV operand as a :class:`...kv_quant.QuantizedKV` pair — int8 data plus
 a per-(token, head) f32 scale streamed beside it (dense: a ``[B*H,
 1, S]`` row per block; paged: the ``[ps, H]`` sidecar of the SAME page,
-``[L, P, ps, H]``, through the same page ids). The dense kernels
-dequant each block in the VMEM stream (ONE multiply, before the MXU
+``[L, P, ps, H]``, through the same page ids). The dense kernel
+dequants each block in the VMEM stream (ONE multiply, before the MXU
 dot); the paged kernels feed the int8 lanes to the MXU as they are
 (exact in the compute dtype) and apply the scale where it is one number
 a head and column: to the scores (K) and to the probabilities (V) —
@@ -85,7 +85,7 @@ from .flash_attention import NEG_INF
 
 __all__ = ["decode_attention", "paged_decode_attention",
            "mla_paged_decode_attention", "xla_mla_paged_decode_attention",
-           "verify_decode_attention", "paged_verify_decode_attention",
+           "paged_verify_decode_attention",
            "xla_decode_attention", "xla_paged_decode_attention",
            "xla_verify_decode_attention",
            "xla_paged_verify_decode_attention"]
@@ -795,124 +795,8 @@ def decode_attention(
 # in-flight keys of the preceding draft queries, exactly the causal
 # set a future single-query step would see. The XLA reference is the
 # same einsum/masked-softmax math as xla_decode_attention with the
-# row-staggered mask; the Pallas kernels are the flash recurrence
-# with a [K1, d] query block instead of [1, d].
-
-
-def _verify_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale, block_k,
-                   heads, k1, quant):
-    """One (slot*head, k-block) grid cell; the softmax state is [K1]
-    rows of the same online recurrence as :func:`_decode_kernel`
-    (``pos_ref``: the scalar-prefetched ``[B]`` positions, as there).
-    ``quant`` (static): dequant each K/V block in-stream — the verify
-    pass reads the SAME quantized pages one decode step reads, so
-    spec-decode bandwidth halves with it."""
-    if quant:
-        ks_ref, vs_ref, o_ref, acc, m_scr, l_scr = rest
-    else:
-        o_ref, acc, m_scr, l_scr = rest
-    i = pl.program_id(0)
-    kb = pl.program_id(1)
-    n_k = pl.num_programs(1)
-
-    @pl.when(kb == 0)
-    def _():
-        acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-
-    pos = pos_ref[i // heads]
-
-    # the block matters to SOME query row iff its first column is
-    # within the last row's reach (pos + k1 - 1); per-row masking
-    # below keeps earlier rows exact
-    @pl.when(kb * block_k <= pos + k1 - 1)
-    def _():
-        q = q_ref[0]          # [K1, d]
-        kblk = k_ref[0]       # [bk, d]
-        vblk = v_ref[0]
-        if quant:
-            kblk = _kernel_dequant(kblk, ks_ref[0, 0], q.dtype)
-            vblk = _kernel_dequant(vblk, vs_ref[0, 0], q.dtype)
-        s = jnp.dot(q, kblk.T,
-                    preferred_element_type=jnp.float32) * scale  # [K1, bk]
-        col = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (k1, block_k), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (k1, block_k), 0)
-        s = jnp.where(col <= pos + row, s, NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc[:] = acc[:] * corr + jnp.dot(
-            p.astype(vblk.dtype), vblk,
-            preferred_element_type=jnp.float32)
-
-    @pl.when(kb == n_k - 1)
-    def _():
-        o_ref[0] = acc[:] / jnp.maximum(l_scr[:], 1e-30)
-
-
-def _pallas_verify(q, k, v, positions, scale, block_k, interpret,
-                   k_scale=None, v_scale=None):
-    """q [B, K1, H, Dh]; k/v [B, S, H, Dh]; positions [B] -> f32
-    [B, K1, H, Dh]. graftquant: ``k_scale``/``v_scale`` ([B, S, H]
-    f32) mark the K/V operands int8, dequanted per block in VMEM."""
-    b, k1, h, d = q.shape
-    s = k.shape[1]
-    quant = k_scale is not None
-    block_k = max(8, min(block_k, ((s + 7) // 8) * 8))
-    pad = (-s) % block_k
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        if quant:
-            k_scale = jnp.pad(k_scale, ((0, 0), (0, pad), (0, 0)))
-            v_scale = jnp.pad(v_scale, ((0, 0), (0, pad), (0, 0)))
-    n_k = k.shape[1] // block_k
-
-    def merge(x):  # [B, S, H, Dh] -> [B*H, S, Dh]
-        return jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1], d)
-
-    def merge_scale(x):  # [B, S, H] -> [B*H, 1, S]
-        return jnp.moveaxis(x, 2, 1).reshape(b * h, 1, x.shape[1])
-
-    q3 = merge(q)                      # [B*H, K1, Dh]
-    k3, v3 = merge(k), merge(v)
-
-    in_specs = [
-        pl.BlockSpec((1, k1, d), lambda i, kb, pos: (i, 0, 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, kb, pos: (i, kb, 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, kb, pos: (i, kb, 0)),
-    ]
-    operands = [q3, k3, v3]
-    if quant:
-        in_specs += [pl.BlockSpec((1, 1, block_k),
-                                  lambda i, kb, pos: (i, 0, kb))] * 2
-        operands += [merge_scale(k_scale), merge_scale(v_scale)]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # positions
-        grid=(b * h, n_k),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, k1, d), lambda i, kb, pos: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((k1, d), jnp.float32),   # output accumulator
-            pltpu.VMEM((k1, 1), jnp.float32),   # running max
-            pltpu.VMEM((k1, 1), jnp.float32),   # running denominator
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_verify_kernel, scale=scale, block_k=block_k,
-                          heads=h, k1=k1, quant=quant),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, k1, d), jnp.float32),
-        interpret=interpret,
-        name="verify_decode_attention",
-    )(positions.astype(jnp.int32), *operands)
-    return jnp.moveaxis(out.reshape(b, h, k1, d), 1, 2)  # [B, K1, H, Dh]
+# row-staggered mask; the Pallas kernel is the paged kernel's body
+# with K1 * H query rows (the engine verifies on pages only).
 
 
 def xla_verify_decode_attention(q, k, v, positions):
@@ -947,55 +831,6 @@ def xla_paged_verify_decode_attention(q, k_pages, v_pages, page_table,
     return xla_verify_decode_attention(q, k_win, v_win, positions)
 
 
-def verify_decode_attention(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    positions: jax.Array,
-    *,
-    impl: str = "auto",
-    block_k: int = 256,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    """Speculative-verify attention: ``K1 = k_draft + 1`` query tokens
-    per slot over one KV window.
-
-    Args:
-      q: ``[B, K1, H, Dh]`` — row ``i`` is the query at column
-        ``positions[b] + i`` (the pending token, then the k drafts).
-      k, v: ``[B, S, H, Dh]`` KV window (the caller has already
-        written the K1 in-flight columns, so row ``i`` sees its
-        predecessors' keys — the causal verify set). May be
-        :class:`...ops.kv_quant.QuantizedKV` (graftquant int8 +
-        scale) — dequantized in the kernel's VMEM stream.
-      positions: ``[B]`` int — row ``i`` attends ``[0, positions[b]
-        + i]`` inclusive.
-      impl / block_k / interpret: as :func:`decode_attention`.
-
-    Returns ``[B, K1, H, Dh]`` f32 (caller casts)."""
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if impl == "pallas":
-        if interpret is None:
-            from . import default_interpret
-
-            interpret = default_interpret()
-        scale = q.shape[-1] ** -0.5
-        if isinstance(k, QuantizedKV):
-            return _pallas_verify(q, k.data, v.data, positions, scale,
-                                  int(block_k), bool(interpret),
-                                  k_scale=k.scale, v_scale=v.scale)
-        return _pallas_verify(q, k, v, positions, scale, int(block_k),
-                              bool(interpret))
-    if impl != "xla":
-        raise ValueError(
-            f"impl must be 'pallas', 'xla' or 'auto', got {impl!r}")
-    if isinstance(k, QuantizedKV):
-        k = dequantize_kv(k, q.dtype)
-        v = dequantize_kv(v, q.dtype)
-    return xla_verify_decode_attention(q, k, v, positions)
-
-
 def paged_verify_decode_attention(
     q: jax.Array,
     k_pages: jax.Array,
@@ -1008,12 +843,16 @@ def paged_verify_decode_attention(
     impl: str = "auto",
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Paged twin of :func:`verify_decode_attention` (graftspec x
-    graftpage): the k-query verify reads layer ``layer`` of the same
-    ``[L, P, page_size, H * Dh]`` pools through the same windowed
-    page-table slice the single-query paged step uses — the same
-    kernel body with ``K1 * H`` query rows. Pages may be
-    :class:`...ops.kv_quant.QuantizedKV` (graftquant)."""
+    """Speculative-verify attention (graftspec x graftpage): ``q``
+    is ``[B, K1, H, Dh]`` — row ``i`` is the query at column
+    ``positions[b] + i`` (the pending token, then the k drafts; the
+    caller has already written the K1 in-flight columns) and attends
+    ``[0, positions[b] + i]`` inclusive. It reads layer ``layer`` of
+    the same ``[L, P, page_size, H * Dh]`` pools through the same
+    windowed page-table slice the single-query paged step uses — the
+    same kernel body with ``K1 * H`` query rows. Pages may be
+    :class:`...ops.kv_quant.QuantizedKV` (graftquant). Returns
+    ``[B, K1, H, Dh]`` f32 (caller casts)."""
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "pallas":

@@ -3,8 +3,8 @@
 graftscope (``runtime/scope.py``) made the stack observable in *time*;
 this module is its sibling in *space*: a host-side ledger of every
 long-lived device allocation the framework makes — parameters,
-optimizer state, the serving KV :class:`~..serving.kv_slots.SlotPool`
-(dense worst-case bytes per slot — the number paged KV will shrink),
+optimizer state, the serving KV :class:`~..serving.kv_pages.PagePool`
+(``num_pages`` x page bytes, with the pages in use as live gauges),
 per-bucket decode-program temporaries — registered AT the allocation
 site and exposed as ``hbm_*`` gauges beside the serving/training
 metrics on ``/metrics`` and ``snapshot.json``.

@@ -4,8 +4,9 @@ The inference half of the north star ("serve heavy traffic"): a
 slot-based engine (``engine``) whose jitted decode keeps a SMALL
 FIXED compiled-program set — one per (length bucket, horizon rung),
 never per batch composition — with per-step attention cost tracking
-the longest ACTIVE sequence instead of the cache capacity
-(``kv_slots``), steady-state decode fused H steps per dispatch with
+the longest ACTIVE sequence instead of the cache capacity, over ONE
+paged KV pool (``kv_pages``), steady-state decode fused H steps per
+dispatch with
 ONE overlapped token-block readback per horizon (``decode_horizon`` —
 host syncs/token = 1/H, on-device EOS/budget freezing keeps it
 token-exact), prompts admitted whole or in fixed-size chunks
@@ -29,7 +30,6 @@ from .autoscale import (AutoscaleError, EngineReplicaSpawner,
                         RollingRollout, ScaleEvent, SpawnFailed)
 from .engine import ServingEngine
 from .kv_pages import PagePool, PagePoolExhausted, PrefixCache
-from .kv_slots import SlotPool
 from .params import init_params, load_params
 from .remote import (RemoteReplica, ReplicaServer,
                      fleet_from_directory)
@@ -42,8 +42,8 @@ from .scheduler import (DONE, FAILED, FIFOScheduler, PrefillPlan,
 from .spec import NgramDrafter, ngram_bucket
 
 __all__ = [
-    "ServingEngine", "SlotPool", "PagePool", "PagePoolExhausted",
-    "PrefixCache", "FIFOScheduler", "PrefillPlan", "NgramDrafter",
+    "ServingEngine", "PagePool", "PagePoolExhausted", "PrefixCache",
+    "FIFOScheduler", "PrefillPlan", "NgramDrafter",
     "QueueFull", "Request", "bucket_length", "init_params",
     "load_params", "ngram_bucket", "pick_draft_k", "pick_horizon",
     "DONE", "FAILED",

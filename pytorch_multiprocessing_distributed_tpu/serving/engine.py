@@ -9,9 +9,10 @@ same ``_prefill``/cached-attention machinery into a persistent loop
 whose compiled-program set is SMALL and FIXED, and whose per-step cost
 tracks the work actually resident:
 
-- the KV cache is a :class:`~.kv_slots.SlotPool` — fixed
-  ``[layers, max_slots, s_max, heads, head_dim]`` arrays, per-slot
-  position counters, an active mask;
+- the KV cache is ONE :class:`~.kv_pages.PagePool` — fixed-size
+  pages ``[layers, num_pages, page_size, heads * head_dim]`` mapped
+  per slot through a page table, per-slot position counters, an
+  active mask;
 - **length-bucketed decode**: each step attends over the cache prefix
   ``[0, W)`` where ``W`` is the smallest configured bucket covering the
   longest ACTIVE sequence (tracked host-side by the pool, no device
@@ -57,7 +58,7 @@ tracks the work actually resident:
   and its next program;
 - finished slots (EOS / ``max_new_tokens``) are recycled in place —
   stale cache columns are masked until the next tenant overwrites them
-  (see ``kv_slots`` invariants). Finish detection is on-device; the
+  (see ``kv_pages`` invariants). Finish detection is on-device; the
   host replays the same rules on the drained block (the mirror the
   realized per-slot position advances come from).
 
@@ -117,8 +118,7 @@ from ..inference.generate import (
     _decode_horizon, _make_cs, _prefill, _sample, pref_cache_shapes,
     serving_family)
 from ..ops.kv_quant import (KV_DTYPES, QuantizedKV, dequantize_kv,
-                            kv_slice_in_dim, quantize_kv,
-                            quantize_kv_np)
+                            quantize_kv, quantize_kv_np)
 from ..runtime import hbm
 from ..runtime import heal
 from ..runtime import life
@@ -134,7 +134,6 @@ from ..utils.metrics import ServingMetrics
 from ..utils import profiler  # noqa: F401 (sets graftscope's annotator)
 from .kv_pages import (PAGE_SPEC, PagePool, PagePoolExhausted,
                        PrefixCache)
-from .kv_slots import SlotPool
 from .scheduler import (DONE, FAILED, RUNNING, FIFOScheduler,
                         PrefillPlan, QueueFull, Request,
                         RequestWithdrawn, bucket_length, pick_draft_k,
@@ -196,11 +195,11 @@ class _PendingPrefill:
     """Host-side state of the one request currently mid-chunked-prefill:
     its chunk plan plus the standalone caches the chunks accumulate
     into (spliced into a pool slot after the last chunk). ``prep`` is
-    the paged engine's page reservation (None on the dense engine)."""
+    its page reservation."""
 
     __slots__ = ("request", "plan", "k_pref", "v_pref", "prep")
 
-    def __init__(self, request, plan, k_pref, v_pref, prep=None):
+    def __init__(self, request, plan, k_pref, v_pref, prep):
         self.request = request
         self.plan = plan
         self.k_pref = k_pref
@@ -312,21 +311,22 @@ class ServingEngine:
         degradation: smaller blast radius + faster drain while the
         fault domain is suspect); each forced collapse is counted in
         ``ServingMetrics.horizon_collapses``.
-      kv_layout: ``"dense"`` (the :class:`~.kv_slots.SlotPool` —
-        worst-case ``s_max`` columns reserved per slot) or ``"paged"``
-        (graftpage: a :class:`~.kv_pages.PagePool` of fixed-size pages
-        mapped per slot through an ``[max_slots, pages_per_slot]``
-        page table — a request pins ``ceil((L + max_new) /
-        page_size)`` pages, so ``num_pages`` sizes HBM to the expected
-        length distribution while ``max_slots`` raises concurrency
-        past the dense worst case). Token-exact with the dense engine
-        and ``generate()`` (test-pinned); the page table rides as ONE
-        extra jit-traced operand, so the decode compile ladder does
-        NOT grow (still ``buckets x {1, H}``).
-      page_size: paged mode's columns per page (default:
-        ``min_bucket``; multiples of 8 for the TPU Pallas kernel).
-      num_pages: paged mode's total page count INCLUDING the reserved
-        scratch page (default: dense worst-case parity,
+      kv_layout: only ``"paged"`` (the one layout: a
+        :class:`~.kv_pages.PagePool` of fixed-size pages mapped per
+        slot through an ``[max_slots, pages_per_slot]`` page table — a
+        request pins ``ceil((L + max_new) / page_size)`` pages, so
+        ``num_pages`` sizes HBM to the expected length distribution
+        while ``max_slots`` raises concurrency past the dense worst
+        case). Token-exact with ``generate()`` (test-pinned); the page
+        table rides as ONE jit-traced operand, so the decode compile
+        ladder is ``buckets x {1, H}``. ``"dense"`` (the slot pool
+        removed in PR 31) is refused by name. The keyword itself stays
+        only until ``perf/drivers/serve.py`` stops passing it
+        (ROADMAP).
+      page_size: columns per page (default: ``min_bucket``; multiples
+        of 8 for the TPU Pallas kernel).
+      num_pages: total page count INCLUDING the reserved scratch page
+        (default: dense worst-case parity,
         ``max_slots * ceil(s_max / page_size) + 1``). When the FIFO
         head needs more free pages than exist, admission HOLDS it
         (``ServingMetrics.page_holds``; prefix-cache entries are shed
@@ -334,7 +334,7 @@ class ServingEngine:
         (:class:`~.kv_pages.PagePoolExhausted`) only when nothing in
         flight could ever free enough.
       prefix_cache: > 0 arms the shared-prefix cache with that many
-        LRU entries (paged + greedy only — the cached first token is
+        LRU entries (greedy only — the cached first token is
         replayed, which only a deterministic stream allows). A
         prompt's page-aligned prefix is prefilled ONCE; identical
         prompts are FULL hits (no prefill compute — TTFT drops to a
@@ -371,8 +371,8 @@ class ServingEngine:
       draft_model / draft_params: optional small registry GPT (+ its
         params) proposing the k tokens autoregressively inside the
         scan instead of self-drafting; must share the target's vocab
-        and cover ``s_max`` positions. Its dense ``[L_d, slots,
-        s_max, H_d, Dh_d]`` caches ride the pool (prefilled
+        and cover ``s_max`` positions. Its private dense ``[L_d,
+        slots, s_max, H_d, Dh_d]`` caches ride the engine (prefilled
         whole-prompt at every admission — also under chunked/prefix-
         hit admission: the draft model is the cheap side). Default
         (None with ``draft_k > 0``): self-drafting via per-slot
@@ -408,7 +408,7 @@ class ServingEngine:
                  readback_timeout_s: Optional[float] = None,
                  fault_cooldown: int = 8,
                  journal=None,
-                 kv_layout: str = "dense",
+                 kv_layout: str = "paged",
                  kv_dtype: str = "model",
                  page_size: Optional[int] = None,
                  num_pages: Optional[int] = None,
@@ -474,20 +474,16 @@ class ServingEngine:
         if fault_cooldown < 0:
             raise ValueError(
                 f"fault_cooldown must be >= 0, got {fault_cooldown}")
-        if kv_layout not in ("dense", "paged"):
+        if kv_layout != "paged":
             raise ValueError(
-                f"kv_layout must be 'dense' or 'paged', got "
-                f"{kv_layout!r}")
+                f"kv_layout must be 'paged', got {kv_layout!r}: the "
+                "dense slot pool (kv_layout='dense') was removed — "
+                "pages at the default num_pages hold the same "
+                "capacity")
         if kv_dtype not in KV_DTYPES:
             raise ValueError(
                 f"kv_dtype must be one of {KV_DTYPES}, got "
                 f"{kv_dtype!r}")
-        if kv_layout == "dense" and (page_size is not None
-                                     or num_pages is not None
-                                     or prefix_cache):
-            raise ValueError(
-                "page_size/num_pages/prefix_cache apply only with "
-                "kv_layout='paged'")
         if prefix_cache < 0:
             raise ValueError(
                 f"prefix_cache must be >= 0, got {prefix_cache}")
@@ -524,8 +520,7 @@ class ServingEngine:
         # what this model's family does not support yet is refused
         # here, by the option's name: nothing falls back silently
         family = serving_family(model)
-        for option, asked in (("kv_layout=dense", kv_layout == "dense"),
-                              ("kv_dtype=int8", kv_dtype == "int8"),
+        for option, asked in (("kv_dtype=int8", kv_dtype == "int8"),
                               ("draft_k", draft_k > 0),
                               ("prefix_cache", prefix_cache > 0),
                               ("mesh", mesh is not None)):
@@ -539,20 +534,15 @@ class ServingEngine:
         self.mesh = mesh
         self.eos_id = eos_id
         self.min_bucket = int(min_bucket)
-        self._paged = kv_layout == "paged"
         # graftquant: int8 pool caches; prefill/transfer blocks stay
         # model dtype until the insert-time quantize (the ONE quantize
         # site, so local and transferred admissions share the formula)
         self._kv_quant = kv_dtype == "int8"
-        if self._paged:
-            self.pool = PagePool(
-                model, max_slots, s_max, mesh,
-                page_size=int(page_size if page_size is not None
-                              else min_bucket),
-                num_pages=num_pages, kv_dtype=kv_dtype)
-        else:
-            self.pool = SlotPool(model, max_slots, s_max, mesh,
-                                 kv_dtype=kv_dtype)
+        self.pool = PagePool(
+            model, max_slots, s_max, mesh,
+            page_size=int(page_size if page_size is not None
+                          else min_bucket),
+            num_pages=num_pages, kv_dtype=kv_dtype)
         self._prefix_cache = (PrefixCache(self.pool, prefix_cache)
                               if prefix_cache else None)
         # graftspec state (all host-side; spec disarmed == draft_k 0)
@@ -631,24 +621,15 @@ class ServingEngine:
         # second call silently specializes a second executable,
         # breaking the bucketed compile budget on a mesh
         if mesh is not None:
-            # dense caches shard heads at axis 3 ([L, N, S, H, Dh]);
-            # pages at their last axis ([L, P, ps, H * Dh]: contiguous
-            # head groups of the lanes); the standalone prefill caches
-            # keep the dense layout in BOTH modes. graftquant caches
-            # are the (data, scale) pytree pair, so the cache
-            # out-sharding is the matching pair — the dense scale
-            # sidecar drops the trailing Dh axis, heads stay put; a
-            # page's scales [L, P, ps, H] shard like its data
-            if self._paged:
-                cache_data_sh = cache_scale_sh = NamedSharding(
-                    mesh, PAGE_SPEC)
-            else:
-                cache_data_sh = NamedSharding(
-                    mesh, P(None, None, None, "model", None))
-                cache_scale_sh = NamedSharding(
-                    mesh, P(None, None, None, "model"))
-            cache_sh = (QuantizedKV(cache_data_sh, cache_scale_sh)
-                        if self._kv_quant else cache_data_sh)
+            # pages shard at their last axis ([L, P, ps, H * Dh]:
+            # contiguous head groups of the lanes; a graftquant page's
+            # scales [L, P, ps, H] shard like its data, so the (data,
+            # scale) pytree pair takes the matching pair); the
+            # standalone prefill caches [L, 1, W, H, Dh] shard heads
+            # at axis 3
+            page_sh = NamedSharding(mesh, PAGE_SPEC)
+            cache_sh = (QuantizedKV(page_sh, page_sh)
+                        if self._kv_quant else page_sh)
             pref_sh = NamedSharding(
                 mesh, P(None, None, None, "model", None))
             rep = NamedSharding(mesh, P())
@@ -676,9 +657,7 @@ class ServingEngine:
         self._decode = jax.jit(
             self._make_decode_horizon(), out_shardings=decode_out,
             static_argnames=("window", "horizon"),
-            donate_argnums=(((1, 2, 4, 5, 6, 7) if self._paged
-                             else (1, 2, 3, 4, 5, 6))
-                            if donate_cache else ()))
+            donate_argnums=(1, 2, 4, 5, 6, 7) if donate_cache else ())
         self._prefill_jit = jax.jit(self._make_prefill(),
                                     out_shardings=prefill_out)
         self._chunk_jit = jax.jit(
@@ -687,8 +666,7 @@ class ServingEngine:
         self._tok0_jit = jax.jit(self._make_tok0(),
                                  out_shardings=tok0_out)
         self._insert_jit = jax.jit(
-            self._paged_insert_fn if self._paged else self._insert_fn,
-            out_shardings=insert_out,
+            self._paged_insert_fn, out_shardings=insert_out,
             donate_argnums=(0, 1, 2, 3, 4, 5, 6) if donate_cache
             else ())
         # graftquant: model-dtype standalone prefill block -> the
@@ -708,23 +686,22 @@ class ServingEngine:
             self._quant_pref_jit = jax.jit(
                 lambda kp, vp: (quantize_kv(kp), quantize_kv(vp)),
                 out_shardings=quant_pref_out)
-        if self._paged:
-            # graftpage's three host-boundary helpers. State-only
-            # splice (full prefix hits: the cached pages already hold
-            # every prefill column); COW page fork (one page copy —
-            # compiles once, traced src/dst); page gather (prefix
-            # pages -> the standalone chunk-prefill cache on a partial
-            # hit; compiles per (pages, width) pair, pages NOT donated
-            # — the shared prefix must survive).
-            self._state_insert_jit = jax.jit(
-                self._state_insert_fn, out_shardings=state_insert_out,
-                donate_argnums=(0, 1, 2, 3, 4) if donate_cache else ())
-            self._copy_page_jit = jax.jit(
-                self._copy_page_fn, out_shardings=copy_out,
-                donate_argnums=(0, 1) if donate_cache else ())
-            self._gather_jit = jax.jit(
-                self._gather_pages_fn, out_shardings=gather_out,
-                static_argnames=("width",))
+        # graftpage's three host-boundary helpers. State-only splice
+        # (full prefix hits: the cached pages already hold every
+        # prefill column); COW page fork (one page copy — compiles
+        # once, traced src/dst); page gather (prefix pages -> the
+        # standalone chunk-prefill cache on a partial hit; compiles
+        # per (pages, width) pair, pages NOT donated — the shared
+        # prefix must survive).
+        self._state_insert_jit = jax.jit(
+            self._state_insert_fn, out_shardings=state_insert_out,
+            donate_argnums=(0, 1, 2, 3, 4) if donate_cache else ())
+        self._copy_page_jit = jax.jit(
+            self._copy_page_fn, out_shardings=copy_out,
+            donate_argnums=(0, 1) if donate_cache else ())
+        self._gather_jit = jax.jit(
+            self._gather_pages_fn, out_shardings=gather_out,
+            static_argnames=("width",))
         # quarantine/deadline eviction: clear a slot's on-device finish
         # gates so the frozen row stops advancing. Compiled lazily on
         # the FIRST eviction — the fault-free path never traces it
@@ -740,12 +717,9 @@ class ServingEngine:
         self._draft_prefill_jit = None
         self._draft_insert_jit = None
         if self._draft_k:
-            if self._draft_model is not None:
-                spec_donate = ((2, 3, 5, 6, 7, 8, 9, 10) if self._paged
-                               else (2, 3, 4, 5, 6, 7, 8, 9))
-            else:
-                spec_donate = ((1, 2, 4, 5, 6, 7) if self._paged
-                               else (1, 2, 3, 4, 5, 6))
+            spec_donate = ((2, 3, 5, 6, 7, 8, 9, 10)
+                           if self._draft_model is not None
+                           else (1, 2, 4, 5, 6, 7))
             self._decode_spec = jax.jit(
                 self._make_decode_spec(), out_shardings=spec_out,
                 static_argnames=("window", "horizon", "draft_k"),
@@ -807,20 +781,22 @@ class ServingEngine:
         temperature, top_k, top_p = self._sampling
         attn_impl = self._attn_impl
         block_k = self._decode_block_k
-        paged = self._paged
-        page_size = self.pool.page_size if paged else None
-
+        page_size = self.pool.page_size
         cs_cache = self._make_cs_cache(cs)
 
-        def horizon_step(params, k_caches, v_caches, positions,
-                         last_tokens, active, remaining, eos_ids, key,
-                         *, window, horizon, page_table=None):
+        def paged_horizon_step(params, k_pages, v_pages, page_table,
+                               positions, last_tokens, active,
+                               remaining, eos_ids, key, *, window,
+                               horizon):
+            # the table is ONE traced operand beside the (window,
+            # horizon) statics, read-only inside the scan (allocation
+            # is host-side, pre-jit)
             if temperature > 0.0:
                 keys = jax.random.split(key, horizon)
             else:  # greedy ignores keys; keep ONE signature per ladder
                 keys = jnp.zeros((horizon, 2), jnp.uint32)
             tokens, carry = _decode_horizon(
-                model, params, k_caches, v_caches, positions,
+                model, params, k_pages, v_pages, positions,
                 last_tokens, active, remaining, eos_ids, keys, cs=cs,
                 cs_cache=cs_cache, window=window, attn_impl=attn_impl,
                 block_k=block_k, temperature=temperature, top_k=top_k,
@@ -828,40 +804,16 @@ class ServingEngine:
                 page_size=page_size)
             return (tokens,) + carry
 
-        if not paged:
-            return horizon_step
-
-        def paged_horizon_step(params, k_pages, v_pages, page_table,
-                               positions, last_tokens, active,
-                               remaining, eos_ids, key, *, window,
-                               horizon):
-            # the table is ONE extra traced operand — same (window,
-            # horizon) static signature, so the compile ladder stays
-            # buckets x {1, H}; the table itself is read-only inside
-            # the scan (allocation is host-side, pre-jit)
-            return horizon_step(params, k_pages, v_pages, positions,
-                                last_tokens, active, remaining,
-                                eos_ids, key, window=window,
-                                horizon=horizon, page_table=page_table)
-
         return paged_horizon_step
 
-    def _make_cs_cache(self, cs):
+    @staticmethod
+    def _make_cs_cache(cs):
         """The sharding constraint that pins a decode program's cache
         operands to the pool's placement: pages ``[L, P, ps, H * Dh]``
         (and an int8 pool's ``[L, P, ps, H]`` scales) on their last
-        axis, dense slots ``[L, N, S, H, Dh]`` on the heads (the dense
-        scale sidecar drops the trailing Dh axis only)."""
-        paged = self._paged
-
+        axis."""
         def cs_cache(c):
-            if paged:
-                return jax.tree.map(lambda leaf: cs(leaf, *PAGE_SPEC), c)
-            if isinstance(c, QuantizedKV):
-                return QuantizedKV(
-                    cs(c.data, None, None, None, "model", None),
-                    cs(c.scale, None, None, None, "model"))
-            return cs(c, None, None, None, "model", None)
+            return jax.tree.map(lambda leaf: cs(leaf, *PAGE_SPEC), c)
 
         return cs_cache
 
@@ -878,19 +830,17 @@ class ServingEngine:
         cs = _make_cs(self.mesh)
         attn_impl = self._attn_impl
         block_k = self._decode_block_k
-        paged = self._paged
-        page_size = self.pool.page_size if paged else None
+        page_size = self.pool.page_size
         draft_model = self._draft_model
-
         cs_cache = self._make_cs_cache(cs)
 
-        def run(params, k_caches, v_caches, positions, last_tokens,
-                active, remaining, eos_ids, *, window, horizon,
-                draft_k, page_table=None, draft_table=None,
-                draft_params=None, dk=None, dv=None):
+        def run(params, k_pages, v_pages, page_table, positions,
+                last_tokens, active, remaining, eos_ids, *, window,
+                horizon, draft_k, draft_table=None, draft_params=None,
+                dk=None, dv=None):
             keys = jnp.zeros((horizon, 2), jnp.uint32)  # greedy
             tokens, carry = _decode_horizon(
-                model, params, k_caches, v_caches, positions,
+                model, params, k_pages, v_pages, positions,
                 last_tokens, active, remaining, eos_ids, keys, cs=cs,
                 cs_cache=cs_cache, window=window, attn_impl=attn_impl,
                 block_k=block_k, page_table=page_table,
@@ -903,44 +853,23 @@ class ServingEngine:
             return (tokens,) + carry
 
         if draft_model is not None:
-            if paged:
-                def spec_step(params, draft_params, k_pages, v_pages,
-                              page_table, dk, dv, positions,
-                              last_tokens, active, remaining, eos_ids,
-                              *, window, horizon, draft_k):
-                    return run(params, k_pages, v_pages, positions,
-                               last_tokens, active, remaining,
-                               eos_ids, window=window, horizon=horizon,
-                               draft_k=draft_k, page_table=page_table,
-                               draft_params=draft_params, dk=dk, dv=dv)
-            else:
-                def spec_step(params, draft_params, k_caches, v_caches,
-                              dk, dv, positions, last_tokens, active,
-                              remaining, eos_ids, *, window, horizon,
-                              draft_k):
-                    return run(params, k_caches, v_caches, positions,
-                               last_tokens, active, remaining,
-                               eos_ids, window=window, horizon=horizon,
-                               draft_k=draft_k,
-                               draft_params=draft_params, dk=dk, dv=dv)
-            return spec_step
-        if paged:
+            def spec_step(params, draft_params, k_pages, v_pages,
+                          page_table, dk, dv, positions, last_tokens,
+                          active, remaining, eos_ids, *, window,
+                          horizon, draft_k):
+                return run(params, k_pages, v_pages, page_table,
+                           positions, last_tokens, active, remaining,
+                           eos_ids, window=window, horizon=horizon,
+                           draft_k=draft_k, draft_params=draft_params,
+                           dk=dk, dv=dv)
+        else:
             def spec_step(params, k_pages, v_pages, page_table,
                           positions, last_tokens, active, remaining,
                           eos_ids, draft_table, *, window, horizon,
                           draft_k):
-                return run(params, k_pages, v_pages, positions,
-                           last_tokens, active, remaining, eos_ids,
-                           window=window, horizon=horizon,
-                           draft_k=draft_k, page_table=page_table,
-                           draft_table=draft_table)
-        else:
-            def spec_step(params, k_caches, v_caches, positions,
-                          last_tokens, active, remaining, eos_ids,
-                          draft_table, *, window, horizon, draft_k):
-                return run(params, k_caches, v_caches, positions,
-                           last_tokens, active, remaining, eos_ids,
-                           window=window, horizon=horizon,
+                return run(params, k_pages, v_pages, page_table,
+                           positions, last_tokens, active, remaining,
+                           eos_ids, window=window, horizon=horizon,
                            draft_k=draft_k, draft_table=draft_table)
         return spec_step
 
@@ -1075,71 +1004,29 @@ class ServingEngine:
         return tok0_fn
 
     @staticmethod
-    def _insert_fn(k_caches, v_caches, positions, last_tokens, active,
-                   budgets, eos_ids, k_pref, v_pref, slot, length, tok0,
-                   budget, eos):
-        """Splice a prefilled request into slot ``slot``: cache columns
-        ``[0, bucket)`` overwrite the previous tenant's, the position
-        counter starts at the prompt length, the pending token is the
-        prefill's first sample, and the on-device finish gates arm —
-        ``budget`` decode tokens remaining (``max_new_tokens - 1``; the
-        first token came from prefill) and the stop id (``-1`` = none).
-        Pad/stale columns beyond ``length`` are masked until the decode
-        position reaches (and overwrites) them. A chunk-plan cache may
-        be up to ``chunk - 1`` pad columns wider than ``s_max``; the
-        overshoot is sliced off here (valid columns end at the prompt
-        length, which admission bounds by ``s_max``).
-
-        graftquant: when the pool is int8, ``k_pref``/``v_pref``
-        arrive ALREADY quantized (``_quant_pref_jit`` or a quantized
-        transfer) and both pair leaves splice at the same columns —
-        one signature either way, the pair just flattens to two
-        operands.
-        """
-        s_max = k_caches.shape[2]
-        if k_pref.shape[2] > s_max:
-            k_pref = kv_slice_in_dim(k_pref, 0, s_max, axis=2)
-            v_pref = kv_slice_in_dim(v_pref, 0, s_max, axis=2)
-        if isinstance(k_caches, QuantizedKV):
-            k_caches = QuantizedKV(
-                jax.lax.dynamic_update_slice(
-                    k_caches.data, k_pref.data, (0, slot, 0, 0, 0)),
-                jax.lax.dynamic_update_slice(
-                    k_caches.scale, k_pref.scale, (0, slot, 0, 0)))
-            v_caches = QuantizedKV(
-                jax.lax.dynamic_update_slice(
-                    v_caches.data, v_pref.data, (0, slot, 0, 0, 0)),
-                jax.lax.dynamic_update_slice(
-                    v_caches.scale, v_pref.scale, (0, slot, 0, 0)))
-        else:
-            k_caches = jax.lax.dynamic_update_slice(
-                k_caches, k_pref, (0, slot, 0, 0, 0))
-            v_caches = jax.lax.dynamic_update_slice(
-                v_caches, v_pref, (0, slot, 0, 0, 0))
-        positions = positions.at[slot].set(length)
-        last_tokens = last_tokens.at[slot].set(tok0)
-        active = active.at[slot].set(True)
-        budgets = budgets.at[slot].set(budget)
-        eos_ids = eos_ids.at[slot].set(eos)
-        return (k_caches, v_caches, positions, last_tokens, active,
-                budgets, eos_ids)
-
-    @staticmethod
     def _paged_insert_fn(k_pages, v_pages, positions, last_tokens,
                          active, budgets, eos_ids, k_pref, v_pref,
                          write_ids, slot, length, tok0, budget, eos):
-        """Paged splice (graftpage): the standalone prefill cache
-        ``[L, 1, W, *row]`` is cut into page blocks (a reshape: a page
-        is ``ps`` whole rows, ``[ps, prod(row)]``) and scattered into
-        the donated pools, in place, at ``write_ids`` — the
-        column-ordered page targets the HOST chose (fresh pages for
-        the columns this request computed; the SCRATCH page 0 for
-        columns a shared prefix already holds — their stale re-write
-        is discarded — and for pure-pad overshoot). The slot's decode state arms exactly as
-        the dense splice. Compiles once per prefill width (the
-        ``write_ids`` length is width-derived), like the dense
-        per-bucket splice. An int8 pair's scales ``[L, 1, W, H]``
-        follow the same rule into ``[L, P, ps, H]``."""
+        """Splice a prefilled request into slot ``slot`` (graftpage):
+        the standalone prefill cache ``[L, 1, W, *row]`` is cut into
+        page blocks (a reshape: a page is ``ps`` whole rows, ``[ps,
+        prod(row)]``) and scattered into the donated pools, in place,
+        at ``write_ids`` — the column-ordered page targets the HOST
+        chose (fresh pages for the columns this request computed; the
+        SCRATCH page 0 for columns a shared prefix already holds —
+        their stale re-write is discarded — and for pure-pad
+        overshoot). The position counter starts at the prompt length,
+        the pending token is the prefill's first sample, and the
+        on-device finish gates arm — ``budget`` decode tokens
+        remaining (``max_new_tokens - 1``; the first token came from
+        prefill) and the stop id (``-1`` = none). Pad/stale columns
+        beyond ``length`` are masked until the decode position reaches
+        (and overwrites) them. Compiles once per prefill width (the
+        ``write_ids`` length is width-derived). graftquant: when the
+        pool is int8, ``k_pref``/``v_pref`` arrive ALREADY quantized
+        (``_quant_pref_jit`` or a quantized transfer); the pair's
+        scales ``[L, 1, W, H]`` follow the same rule into ``[L, P,
+        ps, H]``."""
         ps = k_pages.shape[2]
         n = write_ids.shape[0]
         pad = n * ps - k_pref.shape[2]
@@ -1397,7 +1284,6 @@ class ServingEngine:
             from ..analysis.meter import costs_record
             from ..utils.compile_cache import lowered_program_analysis
 
-            pool = self.pool
             # under TP the executed program's GSPMD partition is part
             # of its identity: carry each arg's real sharding into the
             # abstract avals, or the metered program (collectives,
@@ -1413,29 +1299,25 @@ class ServingEngine:
                                                 sharding=sharding)
                 return jax.ShapeDtypeStruct(x.shape, x.dtype)
 
-            # cache args go through tree.map: a graftquant pool's
-            # caches are QuantizedKV pairs (two aval leaves), a
-            # model-dtype pool's are plain single-leaf arrays
-            if self._paged:
-                args = (jax.tree.map(sds, self.params),
-                        jax.tree.map(sds, pool.k_pages),
-                        jax.tree.map(sds, pool.v_pages),
-                        sds(pool.device_table()), sds(pool.positions),
-                        sds(pool.last_tokens), sds(pool.active),
-                        sds(pool.budgets), sds(pool.eos_ids),
-                        jax.ShapeDtypeStruct((2,), jnp.uint32))
-            else:
-                args = (jax.tree.map(sds, self.params),
-                        jax.tree.map(sds, pool.k_caches),
-                        jax.tree.map(sds, pool.v_caches),
-                        sds(pool.positions), sds(pool.last_tokens),
-                        sds(pool.active), sds(pool.budgets),
-                        sds(pool.eos_ids),
-                        jax.ShapeDtypeStruct((2,), jnp.uint32))
+            args = self._decode_avals(
+                sds, jax.tree.map(sds, self.params))
             _compiled, cost, memory = lowered_program_analysis(
                 self._decode, *args, window=key[0], horizon=key[1])
             self._program_costs[key] = costs_record(cost, memory)
         return self._program_costs[key]
+
+    def _decode_avals(self, sds, params) -> tuple:
+        """The ``_decode`` program's operands as abstract values
+        (``sds``: array -> ``jax.ShapeDtypeStruct``). The pools go
+        through ``tree.map``: a graftquant pool's are QuantizedKV
+        pairs (two aval leaves), a model-dtype pool's plain arrays."""
+        pool = self.pool
+        return (params, jax.tree.map(sds, pool.k_pages),
+                jax.tree.map(sds, pool.v_pages),
+                sds(pool.device_table()), sds(pool.positions),
+                sds(pool.last_tokens), sds(pool.active),
+                sds(pool.budgets), sds(pool.eos_ids),
+                jax.ShapeDtypeStruct((2,), jnp.uint32))
 
     def _note_decode_program(self, window: int, horizon: int) -> None:
         """A decode signature just compiled: put its temp HBM on the
@@ -1616,13 +1498,13 @@ class ServingEngine:
             raise ValueError(
                 f"prompt token ids must be in [0, vocab_size="
                 f"{self.model.vocab_size})")
-        if self._paged and request.prompt:
+        total = len(request.prompt) + request.max_new_tokens
+        if request.prompt and total <= self.pool.s_max:
             # never-fits for the PAGE pool is a submission error, like
-            # the scheduler's s_max check (transient pressure is the
-            # admission gate's hold, not this)
-            need = PagePool.pages_for(
-                len(request.prompt) + request.max_new_tokens,
-                self.pool.page_size)
+            # the scheduler's s_max check below, which speaks first
+            # (transient pressure is the admission gate's hold, not
+            # this)
+            need = PagePool.pages_for(total, self.pool.page_size)
             if need > self.pool.num_pages - 1:
                 raise ValueError(
                     f"request needs {need} page(s); the pool holds "
@@ -1803,8 +1685,6 @@ class ServingEngine:
     def _abort_prep(self, prep) -> None:
         """Return a reservation's pages (quarantined admission,
         finished-at-first-token, failed prefill)."""
-        if prep is None:
-            return
         pool = self.pool
         pool.decref(prep.shared_ids)
         pool.decref(prep.fresh_ids)
@@ -1818,7 +1698,7 @@ class ServingEngine:
         goes through here)."""
         pend = self._pending
         self._pending = None
-        if pend is not None and pend.prep is not None:
+        if pend is not None:
             self._abort_prep(pend.prep)
         return pend
 
@@ -1999,41 +1879,37 @@ class ServingEngine:
         events: List[Tuple[Request, int, bool]] = []
         pool = self.pool
         while pool.free_slots > 0:
-            prep = None
-            if self._paged:
-                prep = self._paged_prep_head()
-                if prep is None or prep == "hold":
-                    break
-                if prep == "retry":
-                    continue
+            prep = self._paged_prep_head()
+            if prep is None or prep == "hold":
+                break
+            if prep == "retry":
+                continue
             request = self._pop_admission()
             if request is None:
                 break
-            if prep is not None:
-                request.prefix_hit = (None if prep.mode == "miss"
-                                      else prep.mode)
-                if self._prefix_cache is not None:
-                    # a miss only counts against an ARMED cache
-                    self.metrics.record_prefix_outcome(
-                        request.prefix_hit)
-                if prep.mode == "full":
-                    self._admit_full_hit(request, prep, events)
+            request.prefix_hit = (None if prep.mode == "miss"
+                                  else prep.mode)
+            if self._prefix_cache is not None:
+                # a miss only counts against an ARMED cache
+                self.metrics.record_prefix_outcome(request.prefix_hit)
+            if prep.mode == "full":
+                self._admit_full_hit(request, prep, events)
+                continue
+            if prep.mode == "partial":
+                # suffix-only prefill through the chunk machinery,
+                # driven to completion within this admission (the
+                # whole-prompt engine has no pending interleave)
+                try:
+                    pend = self._seed_partial_pending(
+                        request, prep,
+                        self._prefill_chunk or pool.page_size)
+                except Exception as e:
+                    self._abort_prep(prep)
+                    self._poisoned(request, e)
                     continue
-                if prep.mode == "partial":
-                    # suffix-only prefill through the chunk machinery,
-                    # driven to completion within this admission (the
-                    # whole-prompt engine has no pending interleave)
-                    try:
-                        pend = self._seed_partial_pending(
-                            request, prep,
-                            self._prefill_chunk or pool.page_size)
-                    except Exception as e:
-                        self._abort_prep(prep)
-                        self._poisoned(request, e)
-                        continue
-                    while self._drive_pending(pend, events):
-                        pass
-                    continue
+                while self._drive_pending(pend, events):
+                    pass
+                continue
             length = len(request.prompt)
             bucket = bucket_length(length, self.min_bucket, pool.s_max)
             padded = np.zeros((1, bucket), np.int32)
@@ -2074,13 +1950,13 @@ class ServingEngine:
         return events
 
     def _insert(self, request: Request, slot: int, k_pref, v_pref,
-                length: int, tok0, prep=None) -> None:
+                length: int, tok0, prep: _PagedPrep) -> None:
         """Splice a prefilled request into ``slot`` and arm its
         on-device finish gates (budget = decode tokens still owed; the
         prefill token is already appended, so ``max_new_tokens - 1``).
-        Paged mode scatters the standalone cache's page blocks at the
+        The standalone cache's page blocks scatter at the
         reservation's fresh pages (shared-prefix columns and pure-pad
-        overshoot land in scratch) and binds the slot's table row —
+        overshoot land in scratch) and the slot's table row is bound —
         page ownership transfers from ``prep`` to the row."""
         pool = self.pool
         eos = -1 if request.eos_id is None else int(request.eos_id)
@@ -2096,15 +1972,12 @@ class ServingEngine:
 
             k_pref, v_pref = self._attempted(quant_once)
 
-        if prep is not None:
-            width = k_pref.shape[2]
-            ps = pool.page_size
-            n_w = -(-width // ps)
-            write_ids = np.zeros((n_w,), np.int32)
-            for j, page in enumerate(prep.fresh_ids):
-                col = prep.k + j  # column-order page index
-                if col < n_w:
-                    write_ids[col] = page
+        n_w = -(-k_pref.shape[2] // pool.page_size)
+        write_ids = np.zeros((n_w,), np.int32)
+        for j, page in enumerate(prep.fresh_ids):
+            col = prep.k + j  # column-order page index
+            if col < n_w:
+                write_ids[col] = page
 
         def insert_once():
             # the injected site fires BEFORE the jitted call, so a
@@ -2114,39 +1987,26 @@ class ServingEngine:
             maybe_fault(_SITE_INSERT)
             with expected_transfer("slot/length/budget control upload "
                                    "at admission (scalar H2D)"):
-                if prep is not None:
-                    return self._donated(lambda: self._insert_jit(
-                        pool.k_pages, pool.v_pages, pool.positions,
-                        pool.last_tokens, pool.active, pool.budgets,
-                        pool.eos_ids, k_pref, v_pref,
-                        jnp.asarray(write_ids), jnp.int32(slot),
-                        jnp.int32(length), tok0,
-                        jnp.int32(request.max_new_tokens - 1),
-                        jnp.int32(eos)))
                 return self._donated(lambda: self._insert_jit(
-                    pool.k_caches, pool.v_caches, pool.positions,
+                    pool.k_pages, pool.v_pages, pool.positions,
                     pool.last_tokens, pool.active, pool.budgets,
-                    pool.eos_ids, k_pref, v_pref, jnp.int32(slot),
+                    pool.eos_ids, k_pref, v_pref,
+                    jnp.asarray(write_ids), jnp.int32(slot),
                     jnp.int32(length), tok0,
                     jnp.int32(request.max_new_tokens - 1),
                     jnp.int32(eos)))
 
         with graftscope.span("serving.slot_insert", cat="serving",
                              req=request.uid, slot=slot):
-            if prep is not None:
-                (pool.k_pages, pool.v_pages, pool.positions,
-                 pool.last_tokens, pool.active, pool.budgets,
-                 pool.eos_ids) = self._attempted(insert_once)
-                page_ids = prep.page_ids
-                pool.bind_slot(slot, page_ids)
-                # ownership now lives in the table row: neutralize the
-                # reservation so a later abort cannot double-release
-                prep.shared_ids, prep.fresh_ids = [], []
-                self._register_prefix(request, page_ids)
-            else:
-                (pool.k_caches, pool.v_caches, pool.positions,
-                 pool.last_tokens, pool.active, pool.budgets,
-                 pool.eos_ids) = self._attempted(insert_once)
+            (pool.k_pages, pool.v_pages, pool.positions,
+             pool.last_tokens, pool.active, pool.budgets,
+             pool.eos_ids) = self._attempted(insert_once)
+            page_ids = prep.page_ids
+            pool.bind_slot(slot, page_ids)
+            # ownership now lives in the table row: neutralize the
+            # reservation so a later abort cannot double-release
+            prep.shared_ids, prep.fresh_ids = [], []
+            self._register_prefix(request, page_ids)
         pool.note_insert(slot, length)
         if self._draft_k:
             # raises into the caller's quarantine path on failure
@@ -2186,9 +2046,9 @@ class ServingEngine:
                   file=sys.stderr)
 
     def _pref_sharded(self, c):
-        """Place a standalone prefill cache (dense ``[L, 1, W, H,
-        Dh]`` layout in BOTH kv layouts; graftquant pairs place both
-        leaves) head-sharded on the mesh."""
+        """Place a standalone prefill cache (``[L, 1, W, H, Dh]``;
+        graftquant pairs place both leaves) head-sharded on the
+        mesh."""
         if self.mesh is None:
             return c
         if isinstance(c, QuantizedKV):
@@ -2205,24 +2065,19 @@ class ServingEngine:
         events: List[Tuple[Request, int, bool]] = []
         pool = self.pool
         if self._pending is None and pool.free_slots > 0:
-            prep = None
-            admit = True
-            if self._paged:
-                prep = self._paged_prep_head()
-                admit = prep is not None and prep not in ("hold",
-                                                          "retry")
+            prep = self._paged_prep_head()
+            admit = prep is not None and prep not in ("hold", "retry")
             request = self._pop_admission() if admit else None
             if request is not None:
-                if prep is not None:
-                    request.prefix_hit = (None if prep.mode == "miss"
-                                          else prep.mode)
-                    if self._prefix_cache is not None:
-                        self.metrics.record_prefix_outcome(
-                            request.prefix_hit)
-                if prep is not None and prep.mode == "full":
+                request.prefix_hit = (None if prep.mode == "miss"
+                                      else prep.mode)
+                if self._prefix_cache is not None:
+                    self.metrics.record_prefix_outcome(
+                        request.prefix_hit)
+                if prep.mode == "full":
                     self._admit_full_hit(request, prep, events)
                     return events
-                if prep is not None and prep.mode == "partial":
+                if prep.mode == "partial":
                     try:
                         self._pending = self._seed_partial_pending(
                             request, prep, self._prefill_chunk)
@@ -2333,21 +2188,18 @@ class ServingEngine:
             window, h, k = self._pick_schedule()
             key = self._next_key()
 
-            if self._paged:
-                # lazy page-table upload: device_table() re-uploads (under
-                # its own expected_transfer) only when the host mirror
-                # changed at an admission/release boundary — steady state
-                # re-uses the device copy, so the armed-sentinel
-                # 0-transfer pin holds
-                caches = (pool.k_pages, pool.v_pages, pool.device_table())
-                # the share of the window's pages the decode kernel has
-                # to read (host mirror: no device read)
-                dispatch_span.note(
-                    kv_pages_live=pool.live_pages,
-                    kv_pages_window=pool.max_slots
-                    * -(-window // pool.page_size))
-            else:
-                caches = (pool.k_caches, pool.v_caches)
+            # lazy page-table upload: device_table() re-uploads (under
+            # its own expected_transfer) only when the host mirror
+            # changed at an admission/release boundary — steady state
+            # re-uses the device copy, so the armed-sentinel
+            # 0-transfer pin holds
+            caches = (pool.k_pages, pool.v_pages, pool.device_table())
+            # the share of the window's pages the decode kernel has
+            # to read (host mirror: no device read)
+            dispatch_span.note(
+                kv_pages_live=pool.live_pages,
+                kv_pages_window=pool.max_slots
+                * -(-window // pool.page_size))
 
             if k:
                 if self._drafter is not None:
@@ -2379,11 +2231,11 @@ class ServingEngine:
 
                 out = self._attempted_engine(launch, "decode dispatch")
                 if self._draft_model is not None:
-                    (tokens, k_out, v_out, pool.positions,
+                    (tokens, pool.k_pages, pool.v_pages, pool.positions,
                      pool.last_tokens, pool.active, pool.budgets,
                      self._draft_k_caches, self._draft_v_caches) = out
                 else:
-                    (tokens, k_out, v_out, pool.positions,
+                    (tokens, pool.k_pages, pool.v_pages, pool.positions,
                      pool.last_tokens, pool.active, pool.budgets) = out
                 record_jit_key(self._decode_spec,
                                ("decode_spec", window, h, k))
@@ -2395,18 +2247,15 @@ class ServingEngine:
                         pool.last_tokens, pool.active, pool.budgets,
                         pool.eos_ids, key, window=window, horizon=h))
 
-                (tokens, k_out, v_out, pool.positions, pool.last_tokens,
-                 pool.active, pool.budgets) = self._attempted_engine(
+                (tokens, pool.k_pages, pool.v_pages, pool.positions,
+                 pool.last_tokens, pool.active,
+                 pool.budgets) = self._attempted_engine(
                     launch, "decode dispatch")
                 if record_jit_key(self._decode, ("decode", window, h)):
                     # this dispatch just paid a compile anyway — the one
                     # moment measuring the program's temp HBM is off the
                     # steady-state path (no-op unless a ledger is armed)
                     self._note_decode_program(window, h)
-            if self._paged:
-                pool.k_pages, pool.v_pages = k_out, v_out
-            else:
-                pool.k_caches, pool.v_caches = k_out, v_out
             self._blocks.append(
                 _TokenBlock(tokens, h, window, dict(self._running), k=k))
             self.metrics.record_dispatch(h, overlapped)
@@ -2957,29 +2806,27 @@ class ServingEngine:
             raise QueueFull(
                 "no free slot for the transferred prefill; step this "
                 "engine and retry (graftroute holds the transfer)")
-        prep = None
-        if self._paged:
-            n_total = PagePool.pages_for(
-                length + request.max_new_tokens, pool.page_size)
-            if n_total > pool.num_pages - 1:
-                raise ValueError(
-                    f"transfer needs {n_total} page(s); the pool holds "
-                    f"{pool.num_pages - 1} allocatable")
-            while (pool.free_pages < n_total
-                   and self._prefix_cache is not None
-                   and self._prefix_cache.evict_lru()):
-                pass  # shed cache before holding a transfer
-            if pool.free_pages < n_total:
-                self.metrics.record_page_hold()
-                graftscope.emit("request.held", cat="request",
-                                req=request.uid, pages_needed=n_total,
-                                pages_free=pool.free_pages)
-                raise QueueFull(
-                    f"page pressure: transfer needs {n_total} page(s),"
-                    f" {pool.free_pages} free — retry after running "
-                    "work completes")
-            prep = _PagedPrep("miss", None, 0, [],
-                              pool.alloc_pages(n_total), None, n_total)
+        n_total = PagePool.pages_for(
+            length + request.max_new_tokens, pool.page_size)
+        if n_total > pool.num_pages - 1:
+            raise ValueError(
+                f"transfer needs {n_total} page(s); the pool holds "
+                f"{pool.num_pages - 1} allocatable")
+        while (pool.free_pages < n_total
+               and self._prefix_cache is not None
+               and self._prefix_cache.evict_lru()):
+            pass  # shed cache before holding a transfer
+        if pool.free_pages < n_total:
+            self.metrics.record_page_hold()
+            graftscope.emit("request.held", cat="request",
+                            req=request.uid, pages_needed=n_total,
+                            pages_free=pool.free_pages)
+            raise QueueFull(
+                f"page pressure: transfer needs {n_total} page(s),"
+                f" {pool.free_pages} free — retry after running "
+                "work completes")
+        prep = _PagedPrep("miss", None, 0, [],
+                          pool.alloc_pages(n_total), None, n_total)
         if request.submit_time is None:
             request.submit_time = time.perf_counter()
         if self.journal is not None:
@@ -3121,24 +2968,21 @@ def audit_programs():
     new f32 upcast — fails tier-1 with the program named, before any
     TPU time is burned on it.
 
-    The PAGED ladder (graftpage) is fingerprinted beside the dense one
-    on a reduced bucket set ({8, 32} x {1, 4} — the structural family;
-    every paged window shares one gather/scatter shape recipe): the
-    committed graftmeter budget records the argument-bytes drop of
-    pages-vs-dense (the pool's num_pages is sized BELOW dense worst
-    case here, as production would), and any drift in the table-driven
-    gather/scatter structure fails the gate.
+    Audited on a reduced bucket set ({8, 32} x {1, 4} — the structural
+    family; every window shares one gather/scatter shape recipe) with
+    ``num_pages`` sized BELOW dense worst case, as production would:
+    any drift in the table-driven gather/scatter structure fails the
+    gate.
 
     The SPEC ladder (graftspec) fingerprints the draft+verify
-    programs on the same reduced structural family: self-draft dense
-    at {8, 32} x {1, 4} x k=4, the paged twin and the draft-model
-    twin at (32, 4, 4). The committed costs.json budgets are the
-    bandwidth argument made enforceable: the verify pass must show
-    ~(k+1)x the non-spec program's FLOPs at ~1x its bytes accessed
-    (more MXU rows over the same weight/KV stream) — drift in either
-    direction fails tier-1 (``tests/test_graftspec.py`` pins the
-    ratio from the committed records). Spec OFF leaves the original
-    programs' fingerprints untouched (separate jitted function)."""
+    programs at (32, 4, 4): self-draft and the draft-model twin. The
+    committed costs.json budgets are the bandwidth argument made
+    enforceable: the verify pass must show ~(k+1)x the non-spec
+    program's FLOPs at ~1x its bytes accessed (more MXU rows over the
+    same weight/KV stream) — drift in either direction fails tier-1
+    (``tests/test_graftspec.py`` pins the ratio from the committed
+    records). Spec OFF leaves the original programs' fingerprints
+    untouched (separate jitted function)."""
     def specs():
         # ONE audit geometry across the LM-family hooks
         from ..analysis.programs import audit_tiny_gpt
@@ -3148,55 +2992,32 @@ def audit_programs():
             lambda: model.init(jax.random.PRNGKey(0),
                                jnp.zeros((1, 1), jnp.int32),
                                train=False))["params"]
-        engine = ServingEngine(model, params, max_slots=4, s_max=32,
-                               min_bucket=8, decode_horizon=4)
-        # paged twin: 4 slots x 4 pages/slot worst case would be 17
-        # pages; 13 (incl. scratch) is the capacity-lever shape —
-        # same ladder statics, ~25% less KV argument HBM, committed
-        paged = ServingEngine(model, params, max_slots=4, s_max=32,
-                              min_bucket=8, decode_horizon=4,
-                              kv_layout="paged", page_size=8,
-                              num_pages=13, decode_buckets=(8, 32))
+        # 4 slots x 4 pages/slot worst case would be 17 pages; 13
+        # (incl. scratch) is the capacity-lever shape, committed
+        geometry = dict(max_slots=4, s_max=32, min_bucket=8,
+                        decode_horizon=4, page_size=8, num_pages=13)
+        paged = ServingEngine(model, params, decode_buckets=(8, 32),
+                              **geometry)
 
         def sds(x):
             return jax.ShapeDtypeStruct(x.shape, x.dtype)
 
-        def decode_args(eng, p=params):
-            # cache args through tree.map: a graftquant pool's caches
-            # are (int8 data, f32 scale) pairs — two aval leaves
-            pool = eng.pool
-            if eng._paged:
-                return (p, jax.tree.map(sds, pool.k_pages),
-                        jax.tree.map(sds, pool.v_pages),
-                        sds(pool.device_table()), sds(pool.positions),
-                        sds(pool.last_tokens), sds(pool.active),
-                        sds(pool.budgets), sds(pool.eos_ids),
-                        jax.ShapeDtypeStruct((2,), jnp.uint32))
-            return (p, jax.tree.map(sds, pool.k_caches),
-                    jax.tree.map(sds, pool.v_caches),
-                    sds(pool.positions), sds(pool.last_tokens),
-                    sds(pool.active), sds(pool.budgets),
-                    sds(pool.eos_ids),
-                    jax.ShapeDtypeStruct((2,), jnp.uint32))
-
         out = []
-        for eng, tag in ((engine, ""), (paged, "paged_")):
-            args = decode_args(eng)
-            for window in eng.decode_buckets:
-                for horizon in sorted({1, eng.decode_horizon}):
-                    def build(e=eng, a=args, w=window, h=horizon):
-                        return {
-                            "fn": e._decode, "args": a,
-                            "kwargs": {"window": w, "horizon": h},
-                            # single-shard decode moves zero collective
-                            # bytes — that IS the serving cost model
-                            "expect_collectives": {},
-                        }
-                    out.append({
-                        "name": f"serving_decode_{tag}w{window}"
-                                f"_h{horizon}",
-                        "min_devices": 1, "build": build,
-                    })
+        args = paged._decode_avals(sds, params)
+        for window in paged.decode_buckets:
+            for horizon in sorted({1, paged.decode_horizon}):
+                def build(a=args, w=window, h=horizon):
+                    return {
+                        "fn": paged._decode, "args": a,
+                        "kwargs": {"window": w, "horizon": h},
+                        # single-shard decode moves zero collective
+                        # bytes — that IS the serving cost model
+                        "expect_collectives": {},
+                    }
+                out.append({
+                    "name": f"serving_decode_paged_w{window}_h{horizon}",
+                    "min_devices": 1, "build": build,
+                })
 
         # ---- graftquant: the int8-KV ladder ----
         # Audited at head_dim=64 (the smallest production-shaped head:
@@ -3205,8 +3026,8 @@ def audit_programs():
         # the residency claim rests on — at the default Dh=16 audit
         # geometry the 4-byte scale would eat the win and the audit
         # would pin a number nobody ships). One (window=32, horizon=4)
-        # rung per engine: the quant ladder shares the dense/paged
-        # structural recipes already fingerprinted above, so one rung
+        # rung per engine: the quant ladder shares the structural
+        # recipes already fingerprinted above, so one rung
         # pins the dtype story (convert counts + argument bytes) and a
         # bf16 twin at the SAME geometry makes the halving a committed
         # in-file comparison, not an across-geometry inference.
@@ -3215,19 +3036,10 @@ def audit_programs():
             lambda: qmodel.init(jax.random.PRNGKey(0),
                                 jnp.zeros((1, 1), jnp.int32),
                                 train=False))["params"]
-        quant_ladder = []
         for kv_dtype, qtag in (("int8", "quant"), ("model", "quantref")):
-            quant_ladder.append((qtag + "_", ServingEngine(
-                qmodel, qparams, max_slots=4, s_max=32, min_bucket=8,
-                decode_horizon=4, decode_buckets=(32,),
-                kv_dtype=kv_dtype)))
-            quant_ladder.append((qtag + "_paged_", ServingEngine(
-                qmodel, qparams, max_slots=4, s_max=32, min_bucket=8,
-                decode_horizon=4, kv_layout="paged", page_size=8,
-                num_pages=13, decode_buckets=(32,),
-                kv_dtype=kv_dtype)))
-        for qtag, eng in quant_ladder:
-            args = decode_args(eng, qparams)
+            eng = ServingEngine(qmodel, qparams, decode_buckets=(32,),
+                                kv_dtype=kv_dtype, **geometry)
+            args = eng._decode_avals(sds, qparams)
 
             def build(e=eng, a=args):
                 return {
@@ -3236,139 +3048,84 @@ def audit_programs():
                     "expect_collectives": {},
                 }
             out.append({
-                "name": f"serving_decode_{qtag}w32_h4",
+                "name": f"serving_decode_{qtag}_paged_w32_h4",
                 "min_devices": 1, "build": build,
             })
 
         # ---- graftspec: the draft+verify ladder ----
-        spec = ServingEngine(model, params, max_slots=4, s_max=32,
-                             min_bucket=8, decode_horizon=4,
-                             decode_buckets=(8, 32), draft_k=4)
-        spec_paged = ServingEngine(model, params, max_slots=4,
-                                   s_max=32, min_bucket=8,
-                                   decode_horizon=4, kv_layout="paged",
-                                   page_size=8, num_pages=13,
-                                   decode_buckets=(32,), draft_k=4)
+        spec_paged = ServingEngine(model, params, decode_buckets=(32,),
+                                   draft_k=4, **geometry)
         draft_model = audit_tiny_gpt(num_layers=1)
         draft_params = jax.eval_shape(
             lambda: draft_model.init(jax.random.PRNGKey(0),
                                      jnp.zeros((1, 1), jnp.int32),
                                      train=False))["params"]
-        spec_dm = ServingEngine(model, params, max_slots=4, s_max=32,
-                                min_bucket=8, decode_horizon=4,
-                                decode_buckets=(32,), draft_k=4,
-                                draft_model=draft_model,
-                                draft_params=draft_params)
-
-        def spec_args(eng, table=True):
-            base = decode_args(eng)[:-1]  # greedy spec takes no key
-            if table:
-                return base + (jax.ShapeDtypeStruct(
-                    eng._drafter._table.shape, jnp.int32),)
-            return base
-
-        # (8, 4) is the windowed-slice structural variant; (32, *) is
-        # the full-cache one — the {1, H} rungs ride the latter (a
-        # w8_h1 entry would duplicate both families)
-        for window, horizon in ((8, 4), (32, 1), (32, 4)):
-            def build(a=spec_args(spec), w=window, h=horizon):
-                return {
-                    "fn": spec._decode_spec, "args": a,
-                    "kwargs": {"window": w, "horizon": h,
-                               "draft_k": 4},
-                    # the verify pass moves zero collective bytes too
-                    # — speculation spends BANDWIDTH slack, it never
-                    # buys communication
-                    "expect_collectives": {},
-                }
-            out.append({
-                "name": f"serving_decode_spec_w{window}_h{horizon}_k4",
-                "min_devices": 1, "build": build,
-            })
+        spec_dm = ServingEngine(model, params, decode_buckets=(32,),
+                                draft_k=4, draft_model=draft_model,
+                                draft_params=draft_params, **geometry)
+        # greedy spec takes no key; the verify pass moves zero
+        # collective bytes too — speculation spends BANDWIDTH slack,
+        # it never buys communication
+        spec_kwargs = {"window": 32, "horizon": 4, "draft_k": 4}
 
         def build_spec_paged():
             return {
                 "fn": spec_paged._decode_spec,
-                "args": spec_args(spec_paged),
-                "kwargs": {"window": 32, "horizon": 4, "draft_k": 4},
-                "expect_collectives": {},
+                "args": spec_paged._decode_avals(sds, params)[:-1] + (
+                    jax.ShapeDtypeStruct(
+                        spec_paged._drafter._table.shape, jnp.int32),),
+                "kwargs": spec_kwargs, "expect_collectives": {},
             }
 
         out.append({"name": "serving_decode_spec_paged_w32_h4_k4",
                     "min_devices": 1, "build": build_spec_paged})
 
         def build_spec_dm():
-            pool = spec_dm.pool
-            args = (params, draft_params, sds(pool.k_caches),
-                    sds(pool.v_caches), sds(spec_dm._draft_k_caches),
-                    sds(spec_dm._draft_v_caches), sds(pool.positions),
-                    sds(pool.last_tokens), sds(pool.active),
-                    sds(pool.budgets), sds(pool.eos_ids))
+            # params, draft params, pools + table, draft caches, state
+            base = spec_dm._decode_avals(sds, params)[:-1]
             return {
-                "fn": spec_dm._decode_spec, "args": args,
-                "kwargs": {"window": 32, "horizon": 4, "draft_k": 4},
-                "expect_collectives": {},
+                "fn": spec_dm._decode_spec,
+                "args": (params, draft_params) + base[1:4] + (
+                    sds(spec_dm._draft_k_caches),
+                    sds(spec_dm._draft_v_caches)) + base[4:],
+                "kwargs": spec_kwargs, "expect_collectives": {},
             }
 
         out.append({"name": "serving_decode_spec_draft_w32_h4_k4",
                     "min_devices": 1, "build": build_spec_dm})
 
         # ---- graftlink: the transfer-splice ladder ----
-        # The device-resident PageTransfer path ends in exactly these
-        # programs: a detached prefill block (receiver-placed via
+        # The device-resident PageTransfer path ends in exactly this
+        # program: a detached prefill block (receiver-placed via
         # jax.device_put) splices into the decode pool through
-        # ``_insert_jit`` — dense overwrite, paged receiver-chosen
-        # scatter at write_ids, and the int8 pre-quantized pair.
-        # Committing their fingerprints + costs makes the DMA path's
+        # ``_insert_jit`` — a receiver-chosen scatter at write_ids.
+        # Committing its fingerprint + costs makes the DMA path's
         # budget auditable like every decode rung: the splice must
-        # move ZERO collective bytes (single-shard dynamic-update /
-        # page scatter — the device put IS the transfer; any
-        # collective appearing here means the splice started paying
-        # communication for what placement already did).
-        def pref_sds(eng, width):
-            # the standalone prefill cache [L, 1, W, H, Dh] in the
-            # pool's element type (int8: + its scales [L, 1, W, H]),
-            # whatever the pool's own layout
-            pool = eng.pool
-            cache = pool.k_pages if eng._paged else pool.k_caches
-            shape = pref_cache_shapes(eng.model, width)[0]
-            if isinstance(cache, QuantizedKV):
-                return QuantizedKV(
-                    jax.ShapeDtypeStruct(shape, cache.data.dtype),
-                    jax.ShapeDtypeStruct(shape[:-1], cache.scale.dtype))
-            return jax.ShapeDtypeStruct(shape, cache.dtype)
-
-        def insert_args(eng, width):
-            pool = eng.pool
+        # move ZERO collective bytes (single-shard page scatter — the
+        # device put IS the transfer; any collective appearing here
+        # means the splice started paying communication for what
+        # placement already did).
+        def build_xfer():
+            pool = paged.pool
             scalar = jax.ShapeDtypeStruct((), jnp.int32)
-            pref = pref_sds(eng, width)
-            caches = ((jax.tree.map(sds, pool.k_pages),
-                       jax.tree.map(sds, pool.v_pages))
-                      if eng._paged else
-                      (jax.tree.map(sds, pool.k_caches),
-                       jax.tree.map(sds, pool.v_caches)))
-            mid = (sds(pool.positions), sds(pool.last_tokens),
-                   sds(pool.active), sds(pool.budgets),
-                   sds(pool.eos_ids), pref, pref)
-            if eng._paged:
-                n_w = -(-width // pool.page_size)
-                mid = mid + (jax.ShapeDtypeStruct((n_w,), jnp.int32),)
-            # slot, length, tok0, budget, eos
-            return caches + mid + (scalar,) * 5
+            # the standalone prefill cache [L, 1, W, H, Dh]
+            pref = jax.ShapeDtypeStruct(
+                pref_cache_shapes(model, 32)[0], pool.k_pages.dtype)
+            return {
+                "fn": paged._insert_jit,
+                "args": (sds(pool.k_pages), sds(pool.v_pages),
+                         sds(pool.positions), sds(pool.last_tokens),
+                         sds(pool.active), sds(pool.budgets),
+                         sds(pool.eos_ids), pref, pref,
+                         jax.ShapeDtypeStruct(
+                             (-(-32 // pool.page_size),), jnp.int32))
+                # slot, length, tok0, budget, eos
+                + (scalar,) * 5,
+                "expect_collectives": {},
+            }
 
-        for xname, xeng in (
-                ("serving_transfer_insert_w32", engine),
-                ("serving_transfer_insert_paged_w32", paged),
-                ("serving_transfer_insert_quant_w32",
-                 quant_ladder[0][1])):
-            def build_xfer(e=xeng):
-                return {
-                    "fn": e._insert_jit,
-                    "args": insert_args(e, 32),
-                    "expect_collectives": {},
-                }
-            out.append({"name": xname, "min_devices": 1,
-                        "build": build_xfer})
+        out.append({"name": "serving_transfer_insert_paged_w32",
+                    "min_devices": 1, "build": build_xfer})
         return out
 
     return specs()

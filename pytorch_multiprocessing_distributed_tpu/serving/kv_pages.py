@@ -1,16 +1,46 @@
 """Paged KV cache: fixed-size pages + per-slot page tables (graftpage).
 
-:class:`~.kv_slots.SlotPool` pays worst-case HBM per request — a dense
-``[layers, max_slots, s_max, heads, head_dim]`` block reserves ``s_max``
-columns for a 16-token request. This module replaces the dense block
-with **pages**: K/V live in ``[layers, num_pages, page_size, heads *
-head_dim]`` arrays, and each slot maps its logical columns onto pages
-through an ``[max_slots, pages_per_slot]`` int32 page table. A request
-holding ``L + g`` tokens pins ``ceil((L + g) / page_size)`` pages — so
-``num_pages`` (the real HBM commitment) can be sized to the *expected*
-length distribution while ``max_slots`` (concurrency) grows past the
-dense worst case: the capacity multiplier graftmeter's
-``per_slot_kv_bytes`` ledger exists to measure.
+The serving engine's ONE cache pool. A dense ``[layers, max_slots,
+s_max, heads, head_dim]`` block would reserve ``s_max`` columns for a
+16-token request; here K/V live in **pages**, ``[layers, num_pages,
+page_size, heads * head_dim]`` arrays, and each slot maps its logical
+columns onto pages through an ``[max_slots, pages_per_slot]`` int32
+page table. A request holding ``L + g`` tokens pins ``ceil((L + g) /
+page_size)`` pages — so ``num_pages`` (the real HBM commitment) can be
+sized to the *expected* length distribution while ``max_slots``
+(concurrency) grows past the dense worst case: the capacity multiplier
+graftmeter's ``per_slot_kv_bytes`` ledger exists to measure.
+
+The pool also owns the per-slot scalars (position counter, last
+sampled token, active flag, remaining decode budget, stop id — the
+last two arm the fused horizon's on-device finish gating); the
+engine's jitted decode step runs over ALL slots every step with an
+active-mask — occupancy changes the mask's *values*, never any shape.
+
+Slot invariants (the correctness contract the engine's
+equivalence-with-``generate()`` pin rests on):
+
+- an ACTIVE slot holding a request with prompt length ``L`` that has
+  emitted ``g`` tokens has valid cache columns ``[0, L + g - 1)`` and
+  ``position == L + g - 1`` (the column its pending last token's K/V
+  will be written to by the next decode step);
+- attention in the decode step masks columns ``> position``, so stale
+  columns from a previous tenant are never read before the column is
+  overwritten: the step at position ``p`` writes column ``p`` *before*
+  attending to ``[0, p]``, exactly like ``inference.generate``'s
+  ``_block_decode``;
+- inactive rows keep a frozen position (the masked step re-writes the
+  same column each step), so no index ever grows past ``s_max``.
+
+The pool mirrors each ACTIVE slot's position counter on the host
+(``note_insert``/``note_advance_slots``, read via ``max_active_pos``):
+the engine's length-bucketed decode picks its attention window from
+the longest *active* sequence BEFORE launching the step, and a device
+read-back of the position vector there would serialize every step on a
+host sync. The mirror applies the same two updates the jitted step
+applies (set on insert, + realized steps per drained horizon), and
+inactive slots are excluded, so a long-finished tenant never inflates
+the window.
 
 The two pools are shaped by the model family's ``cache_rows``
 (``inference.generate.serving_family``), by ONE rule for every family:
@@ -90,16 +120,15 @@ class PagePoolExhausted(RuntimeError):
 class PagePool:
     """Paged KV storage + per-slot decode state for the serving engine.
 
-    Drop-in superset of :class:`~.kv_slots.SlotPool`'s engine surface
-    (``positions``/``last_tokens``/``active``/``budgets``/``eos_ids``,
-    ``acquire``/``release``, the host position mirror) with the dense
-    ``k_caches``/``v_caches`` replaced by ``k_pages``/``v_pages`` and
-    the page table.
+    The engine's surface: ``k_pages``/``v_pages`` and the page table,
+    ``positions``/``last_tokens``/``active``/``budgets``/``eos_ids``,
+    ``acquire``/``release``, the host position mirror.
 
     Args:
       model: the ``GPT`` the caches are shaped for.
       max_slots: concurrent requests decoded per step (the decode
-        batch dimension, exactly as in ``SlotPool``).
+        batch dimension: every step pays ``max_slots`` rows of compute
+        regardless of occupancy — the static-shape trade).
       s_max: per-slot LOGICAL column capacity (admission bound).
       page_size: columns per page. Every request pins
         ``ceil(total_tokens / page_size)`` pages. On a real TPU keep
@@ -153,8 +182,12 @@ class PagePool:
         self.k_pages, self.v_pages = (
             self._cache_sharded(self._empty_pages(row))
             for _, row, _ in serving_family(model).cache_rows(model))
-        # per-slot decode state — identical to SlotPool's (the decode
-        # horizon's freeze gates do not care where the columns live)
+        # per-slot decode state: next write column, pending token,
+        # live?, and the on-device finish gates (remaining budget, stop
+        # id) that freeze a finished row mid-scan. Mesh runs commit
+        # these replicated from the START — the jitted step returns
+        # them mesh-committed, and a first call with uncommitted arrays
+        # would be a second compile signature
         self.positions = self._replicated(
             jnp.zeros((self.max_slots,), jnp.int32))
         self.last_tokens = self._replicated(
@@ -173,7 +206,7 @@ class PagePool:
         self._refs[0] = 1  # scratch: never freed
         self._table_dev = None  # uploaded lazily, see device_table()
         self._table_dirty = True
-        # slot free list + host position mirror (SlotPool semantics)
+        # slot free list + host position mirror (module docstring)
         self._free_slots: List[int] = list(range(self.max_slots))
         self._positions_host: List[int] = [0] * self.max_slots
         self._active_host: List[bool] = [False] * self.max_slots
@@ -258,6 +291,21 @@ class PagePool:
         return total
 
     @staticmethod
+    def per_slot_kv_bytes(model, s_max: int,
+                          kv_dtype: str = "model") -> int:
+        """Cache bytes ONE slot reserves for ``s_max`` tokens (no page
+        rounding: a page of ``s_max`` rows) — the unit
+        :func:`...analysis.meter.plan_capacity` inverts and
+        :func:`...inference.generate.kv_cache_bytes` multiplies."""
+        return PagePool.page_kv_bytes(model, s_max, kv_dtype)
+
+    @staticmethod
+    def per_slot_state_bytes() -> int:
+        """Per-slot scalar decode state: four int32 rows (position,
+        last token, budget, eos id) + one bool (active)."""
+        return 4 * 4 + 1
+
+    @staticmethod
     def pages_for(total_tokens: int, page_size: int) -> int:
         """Pages a request holding ``total_tokens`` columns pins."""
         return -(-int(total_tokens) // int(page_size))
@@ -274,10 +322,8 @@ class PagePool:
         upper bound. Actual residency is ``pages_in_use x
         page_bytes``; the gap between the two is the capacity win the
         ledger gauges record."""
-        from .kv_slots import SlotPool
-
         return (self.pages_per_slot * self.page_bytes
-                + SlotPool.per_slot_state_bytes())
+                + self.per_slot_state_bytes())
 
     @property
     def hbm_bytes(self) -> int:
@@ -404,7 +450,7 @@ class PagePool:
             self._table_dirty = False
         return self._table_dev
 
-    # ---- slot accounting (SlotPool surface) ----------------------------
+    # ---- host-side slot accounting -------------------------------------
     @property
     def free_slots(self) -> int:
         return len(self._free_slots)
@@ -414,6 +460,8 @@ class PagePool:
         return self.max_slots - len(self._free_slots)
 
     def acquire(self) -> int:
+        """Claim a free slot index (lowest-numbered first, so re-use is
+        deterministic and tests can pin recycling)."""
         if not self._free_slots:
             raise RuntimeError("no free slots (acquire() without "
                                "checking free_slots)")
@@ -428,7 +476,11 @@ class PagePool:
         references (shared prefix pages survive while the cache or
         other slots still hold them). The row resets to scratch so
         the frozen row's masked re-writes land in page 0, never in a
-        page that has been handed to a new tenant."""
+        page that has been handed to a new tenant. The device-side
+        active flag is already False by then: the fused decode scan
+        clears it when the row's EOS or budget gate fires, and the
+        engine's quarantine/deadline eviction scrubs it
+        (``ServingEngine._evict_fn``) BEFORE releasing."""
         if slot in self._free_slots or not 0 <= slot < self.max_slots:
             raise ValueError(f"bad release of slot {slot}")
         self.decref(self.slot_pages(slot))
@@ -443,15 +495,24 @@ class PagePool:
 
     # ---- host position mirror (decode-window tracking) -----------------
     def note_insert(self, slot: int, position: int) -> None:
+        """Record a freshly spliced tenant: its next decode write lands
+        at ``position`` (= prompt length, per the slot invariants)."""
         self._positions_host[slot] = int(position)
         self._active_host[slot] = True
 
     def note_advance_slots(self, realized) -> None:
+        """Mirror one drained decode horizon: slot ``s`` advanced by
+        ``realized[s]`` device steps — the REALIZED count, not the
+        dispatched horizon length (rows the device froze mid-scan
+        advanced only up to their freeze, and the mirror must agree
+        with the device's frozen position exactly)."""
         for slot, steps in realized.items():
             self._positions_host[slot] += int(steps)
 
     @property
     def max_active_pos(self) -> int:
+        """Highest position any ACTIVE slot will write this step — the
+        high-water mark the decode window must cover. -1 when idle."""
         return max(
             (p for p, live in zip(self._positions_host,
                                   self._active_host) if live),

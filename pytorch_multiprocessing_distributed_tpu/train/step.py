@@ -768,11 +768,11 @@ def audit_programs():
     """graftcheck registration hook (``analysis/programs.py``): the
     canonical image DP train step — the parity moment for the
     reference's DDP loop, and the program whose communication contract
-    IS the design: gradients cross the wire exactly once per step, as
-    ONE mesh-wide psum the size of the parameter tree (the BN
-    statistic pmeans beside it are channel-sized). ``expect_grad_psums``
-    pins that inline; dropping the ``pmean(grads)``, reducing twice, or
-    switching to per-leaf reductions all move it. The donation audit
+    IS the design: gradients cross the wire exactly once per step:
+    the psums move ONE parameter tree of bytes (the BN statistic
+    pmeans beside it are channel-sized). ``expect_grad_psums`` pins
+    that inline; dropping the ``pmean(grads)`` or reducing twice
+    moves it. The donation audit
     (``min_donated``) pins that ``donate_argnums=(0,)`` still reaches
     the lowered module — deleting it doubles resident state HBM
     without failing a single numeric test.

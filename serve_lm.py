@@ -131,20 +131,14 @@ parser.add_argument('--decode_attn', default='auto',
                     help='decode-step attention: fused flash-decode '
                          'Pallas kernel or the XLA reference (auto = '
                          'pallas on single-shard TPU, xla elsewhere)')
-parser.add_argument('--kv_layout', default='dense',
-                    choices=['dense', 'paged'],
-                    help='KV cache layout: dense slots (worst-case '
-                         's_max columns per slot) or graftpage paged '
-                         'pages + per-slot page table — a request '
-                         'pins ceil(total/page_size) pages, so HBM '
-                         'follows real lengths and more requests fit '
-                         'per chip (token-exact with dense)')
 parser.add_argument('--page_size', default=0, type=int,
-                    help='paged mode: columns per KV page (0 = '
-                         'min_bucket; multiples of 8 on TPU)')
+                    help='columns per KV page (0 = min_bucket; '
+                         'multiples of 8 on TPU): a request pins '
+                         'ceil(total/page_size) pages, so HBM follows '
+                         'real lengths')
 parser.add_argument('--num_pages', default=0, type=int,
-                    help='paged mode: total pages incl. the scratch '
-                         'page (0 = dense worst-case parity; size it '
+                    help='total KV pages incl. the scratch page (0 = '
+                         'every slot at its worst case; size it '
                          'with `python -m ...analysis.meter --plan '
                          'MODEL --page_size N` to the real HBM '
                          'budget)')
@@ -160,7 +154,7 @@ parser.add_argument('--kv_dtype', default='model',
                          'pinned configs, logit delta budgeted in '
                          'tests — audited, not exact)')
 parser.add_argument('--prefix_cache', default=0, type=int,
-                    help='paged+greedy mode: LRU entries of the '
+                    help='greedy mode: LRU entries of the '
                          'shared-prefix cache — identical prompts '
                          'prefill ONCE and re-join copy-on-write '
                          '(TTFT(hit) ~ one decode step); 0 = off')
@@ -484,14 +478,10 @@ def main():
             prefill_chunk=args.prefill_chunk or None,
             decode_horizon=args.decode_horizon,
             decode_attn=args.decode_attn,
-            kv_layout=args.kv_layout,
             kv_dtype=args.kv_dtype,
-            page_size=(args.page_size or None
-                       if args.kv_layout == 'paged' else None),
-            num_pages=(args.num_pages or None
-                       if args.kv_layout == 'paged' else None),
-            prefix_cache=(args.prefix_cache
-                          if args.kv_layout == 'paged' else 0),
+            page_size=args.page_size or None,
+            num_pages=args.num_pages or None,
+            prefix_cache=args.prefix_cache,
             draft_k=args.draft_k,
             draft_model=draft_model,
             draft_params=draft_params,

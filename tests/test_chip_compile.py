@@ -79,7 +79,7 @@ def _compile(fn, args, sharding):
 KERNEL_NAMES = {
     "flash_attention_fwd", "flash_attention_bwd_dq",
     "flash_attention_bwd_dkv", "decode_attention",
-    "paged_decode_attention", "verify_decode_attention",
+    "paged_decode_attention",
     "paged_verify_decode_attention", "fused_sgd_update", "ring_all_reduce",
     "mla_paged_decode_attention",
 }
@@ -125,11 +125,10 @@ def _decode_case(layout, kv_dtype, k1, page, slots=B, heads=H):
     """(fn, args) of one decode/verify kernel call at serving shapes."""
     q = _sds((slots, k1, heads, D), BF16)
     pos = _sds((slots,), jnp.int32)
-    if layout == "dense":
+    if layout == "dense":  # generate()'s kernel; verify is paged only
         kv = _kv((slots, S, heads, D), kv_dtype)
-        kern = da.decode_attention if k1 == 1 else da.verify_decode_attention
-        return (lambda q, k, v, p: kern(q, k, v, p, impl="pallas",
-                                        interpret=False),
+        return (lambda q, k, v, p: da.decode_attention(
+            q, k, v, p, impl="pallas", interpret=False),
                 (q, kv, kv, pos))
     n_win = S // page
     # layer 1 of a two-layer pool: the kernel reads it in place
@@ -179,7 +178,7 @@ _DECODE = [
                     + (f"-page{pg}" if pg else ""))
     for lay, pages in (("dense", (None,)), ("paged", (16, 128)))
     for kv in ("bf16", "int8")
-    for k1 in (1, K1)
+    for k1 in ((1,) if lay == "dense" else (1, K1))
     for pg in pages
 ] + [
     # the benchmark's serving cell (gpt2-medium.serve.closed): 32 slots,

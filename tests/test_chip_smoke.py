@@ -21,8 +21,8 @@ SCRIPT = os.path.join(REPO, "chip_smoke.py")
 sys.path.insert(0, REPO)
 import chip_smoke  # noqa: E402  (jax-free by construction — see below)
 
-DEFAULT_PHASES = ["probe", "train", "resnet", "kernels", "serve_dense",
-                  "serve_dense_spec", "serve_paged", "serve_paged_int8",
+DEFAULT_PHASES = ["probe", "train", "resnet", "kernels", "serve_paged",
+                  "serve_paged_spec", "serve_paged_int8",
                   "serve_paged_int8_spec"]
 
 # the parent under a tripwire: ANY ``import jax`` in it raises, while
@@ -93,7 +93,7 @@ def test_failing_children_fail_the_run_with_ok_false(tmp_path):
                         cwd=str(alone))
     assert rc != 0
     assert [ln["phase"] for ln in lines[:-1]] == DEFAULT_PHASES
-    assert [ln["ok"] for ln in lines[:-1]] == [True] + [False] * 8
+    assert [ln["ok"] for ln in lines[:-1]] == [True] + [False] * 7
     assert lines[-1] == {"ok": False, "failed": DEFAULT_PHASES[1:]}
 
 

@@ -264,34 +264,21 @@ def test_serving_ladder_fingerprints_cover_decode_programs():
         audit_programs)
 
     names = {e["name"] for e in audit_programs()}
-    buckets, horizon = (8, 16, 32), 4  # the hook's engine geometry
-    expected = {f"serving_decode_w{w}_h{h}"
-                for w in buckets for h in (1, horizon)}
-    # graftpage: the paged twin's ladder is pinned on the reduced
+    # the hook's engine geometry: the ladder is pinned on the reduced
     # {8, 32} bucket set (one gather/scatter shape recipe per window)
-    expected |= {f"serving_decode_paged_w{w}_h{h}"
-                 for w in (8, 32) for h in (1, horizon)}
-    # graftspec: the draft+verify ladder — windowed-slice (w8) and
-    # full-cache (w32) structural variants, the {1, H} rungs on the
-    # latter, plus the paged and draft-model twins
-    expected |= {"serving_decode_spec_w8_h4_k4",
-                 "serving_decode_spec_w32_h1_k4",
-                 "serving_decode_spec_w32_h4_k4",
-                 "serving_decode_spec_paged_w32_h4_k4",
+    expected = {f"serving_decode_paged_w{w}_h{h}"
+                for w in (8, 32) for h in (1, 4)}
+    # graftspec: the draft+verify program, self-draft and draft-model
+    expected |= {"serving_decode_spec_paged_w32_h4_k4",
                  "serving_decode_spec_draft_w32_h4_k4"}
-    # graftquant: the int8-KV decode step (dense + paged) beside its
-    # model-dtype twin at the same geometry — the costs.json pair is
-    # what pins the KV argument-bytes halving
-    expected |= {"serving_decode_quant_w32_h4",
-                 "serving_decode_quantref_w32_h4",
-                 "serving_decode_quant_paged_w32_h4",
+    # graftquant: the int8-KV decode step beside its model-dtype twin
+    # at the same geometry — the costs.json pair is what pins the KV
+    # argument-bytes halving
+    expected |= {"serving_decode_quant_paged_w32_h4",
                  "serving_decode_quantref_paged_w32_h4"}
-    # graftlink: the transfer-splice ladder — admit_prefilled's
-    # insert programs (dense/paged/quant), budgeted at ZERO
+    # graftlink: admit_prefilled's insert program, budgeted at ZERO
     # collectives (the device put IS the transfer)
-    expected |= {"serving_transfer_insert_w32",
-                 "serving_transfer_insert_paged_w32",
-                 "serving_transfer_insert_quant_w32"}
+    expected |= {"serving_transfer_insert_paged_w32"}
     assert names == expected
     committed = graftcheck.load_fingerprints(
         graftcheck.default_fingerprints_path())
@@ -304,7 +291,7 @@ def test_tampered_fingerprint_turns_gate_red(tmp_path):
     delta in the message."""
     src = graftcheck.default_fingerprints_path()
     payload = json.load(open(src))
-    name = "serving_decode_w8_h1"
+    name = "serving_decode_paged_w8_h1"
     payload["programs"][name]["fingerprint"]["digest"] = "0" * 16
     doctored = tmp_path / "fingerprints.json"
     doctored.write_text(json.dumps(payload))
@@ -353,10 +340,10 @@ def test_unknown_program_name_is_a_usage_error():
 
 def test_cli_json_contract(capsys):
     rc = graftcheck.main(
-        ["--programs", "serving_decode_w8_h1", "--json"])
+        ["--programs", "serving_decode_paged_w8_h1", "--json"])
     payload = json.loads(capsys.readouterr().out)
     assert rc == 0 and payload["ok"]
-    assert payload["programs"] == ["serving_decode_w8_h1"]
+    assert payload["programs"] == ["serving_decode_paged_w8_h1"]
     assert payload["findings"] == []
 
 

@@ -21,7 +21,7 @@ What must stay true:
   happened, through AOT lowering the jit cache cannot see);
 - **the planner inverts the allocator**: ``plan_capacity``'s
   per-slot/pool byte prediction matches a real CPU-backend
-  ``SlotPool`` allocation within the documented 0.5% tolerance
+  ``PagePool`` allocation within the documented 0.5% tolerance
   (byte-exact in practice — pinned);
 - **roofline honesty**: efficiency attribution is null-safe — no
   peak, no cost model, no number.
@@ -50,8 +50,8 @@ from pytorch_multiprocessing_distributed_tpu.inference.generate import (  # noqa
 from pytorch_multiprocessing_distributed_tpu.runtime import hbm  # noqa: E402
 from pytorch_multiprocessing_distributed_tpu.serving import (  # noqa: E402
     ServingEngine, init_params)
-from pytorch_multiprocessing_distributed_tpu.serving.kv_slots import (  # noqa: E402
-    SlotPool)
+from pytorch_multiprocessing_distributed_tpu.serving.kv_pages import (  # noqa: E402
+    PagePool)
 from pytorch_multiprocessing_distributed_tpu.serving.scheduler import (  # noqa: E402
     DONE)
 from pytorch_multiprocessing_distributed_tpu.utils.compat import (  # noqa: E402
@@ -252,17 +252,20 @@ def test_nbytes_helpers():
 def test_slot_pool_per_slot_math_matches_allocation():
     model = _tiny()
     s_max = 32
-    pool = SlotPool(model, 4, s_max)
-    assert (SlotPool.per_slot_kv_bytes(model, s_max) * 4
-            == pool.k_caches.nbytes + pool.v_caches.nbytes)
+    # dense parity: every slot's worst case, plus the scratch page
+    pool = PagePool(model, 4, s_max, page_size=8)
+    assert (PagePool.per_slot_kv_bytes(model, s_max) * 4
+            + pool.page_bytes
+            == pool.k_pages.nbytes + pool.v_pages.nbytes)
     assert pool.per_slot_bytes == (
-        SlotPool.per_slot_kv_bytes(model, s_max)
-        + SlotPool.per_slot_state_bytes())
+        PagePool.per_slot_kv_bytes(model, s_max)
+        + PagePool.per_slot_state_bytes())
     assert pool.hbm_bytes == (
-        pool.k_caches.nbytes + pool.v_caches.nbytes
+        pool.k_pages.nbytes + pool.v_pages.nbytes
         + pool.positions.nbytes + pool.last_tokens.nbytes
         + pool.active.nbytes + pool.budgets.nbytes
-        + pool.eos_ids.nbytes)
+        + pool.eos_ids.nbytes
+        + 4 * pool.max_slots * pool.pages_per_slot)  # the int32 table
 
 
 def test_engine_ledger_sites_and_armed_steady_state_sentinels():
@@ -284,8 +287,8 @@ def test_engine_ledger_sites_and_armed_steady_state_sentinels():
                                min_bucket=16, decode_horizon=2)
         entries = ledger.entries()
         assert entries["serving.params"][1] == hbm.tree_nbytes(params)
-        assert entries["serving.kv_pool"][1] == (
-            engine.pool.k_caches.nbytes + engine.pool.v_caches.nbytes)
+        assert entries["serving.kv_pages"][1] == (
+            engine.pool.k_pages.nbytes + engine.pool.v_pages.nbytes)
         assert "serving.slot_state" in entries
         served = engine.serve([(p, 4) for p in prompts])  # warm
         assert all(r.state == DONE for r in served)
@@ -329,20 +332,24 @@ def test_plan_capacity_inverts_real_allocation():
     params = init_params(model, 0)
     params_bytes = hbm.tree_nbytes(params)
     s_max = 32
-    per_slot = (SlotPool.per_slot_kv_bytes(model, s_max)
-                + SlotPool.per_slot_state_bytes())
+    per_slot = (PagePool.per_slot_kv_bytes(model, s_max)
+                + PagePool.per_slot_state_bytes())
     plan = meter.plan_capacity(
         model, s_max, params_bytes + 5 * per_slot + 100, params=params)
     assert plan["max_slots"] == 5
     assert plan["per_slot_bytes"] == per_slot
     assert plan["headroom_bytes"] == 100
     assert plan["fits"]
-    pool = SlotPool(model, plan["max_slots"], s_max)
+    # pages at the default num_pages hold every slot's worst case,
+    # plus the scratch page and the int32 page table
+    pool = PagePool(model, plan["max_slots"], s_max, page_size=8)
     predicted = plan["max_slots"] * plan["per_slot_bytes"]
-    assert abs(predicted - pool.hbm_bytes) / pool.hbm_bytes <= 0.005
+    actual = (pool.hbm_bytes - pool.page_bytes
+              - 4 * pool.max_slots * pool.pages_per_slot)
+    assert abs(predicted - actual) / actual <= 0.005
     # byte-exact today — a drift past the pin means allocator and
     # planner no longer share their shape math
-    assert predicted == pool.hbm_bytes
+    assert predicted == actual
 
 
 def test_plan_capacity_abstract_params_and_edges():
@@ -443,11 +450,12 @@ def test_serving_bench_point_carries_hbm_and_mfu_fields():
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, model.vocab_size, (5,)).tolist()
                for _ in range(2)]
-    r = run_point(model, params, prompts, 3, 2, float("inf"), 24)
+    # s_max of whole pages (2 x 16): a slot's worst case is exact
+    r = run_point(model, params, prompts, 3, 2, float("inf"), 32)
     assert r["hbm_resident_bytes"] > 0
     assert r["hbm_per_slot_bytes"] == (
-        SlotPool.per_slot_kv_bytes(model, 24)
-        + SlotPool.per_slot_state_bytes())
+        PagePool.per_slot_kv_bytes(model, 32)
+        + PagePool.per_slot_state_bytes())
     assert "mfu" in r
     assert r["decode_flops_per_dispatch"] > 0
     if jax.devices()[0].platform != "tpu":

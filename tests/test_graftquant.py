@@ -32,7 +32,7 @@ from pytorch_multiprocessing_distributed_tpu.ops.kv_quant import (
     quantize_kv_np)
 from pytorch_multiprocessing_distributed_tpu.runtime import hbm
 from pytorch_multiprocessing_distributed_tpu.serving import (
-    RemoteReplica, ReplicaServer, Router, ServingEngine, SlotPool,
+    RemoteReplica, ReplicaServer, Router, ServingEngine,
     init_params)
 from pytorch_multiprocessing_distributed_tpu.serving.kv_pages import (
     PagePool)
@@ -70,10 +70,7 @@ def served():
 def _engine(model, params, kv_dtype="model", **kw):
     kw.setdefault("max_slots", 3)
     kw.setdefault("s_max", 32)
-    kw.setdefault("min_bucket", 8)
-    if kw.pop("paged", False):
-        kw.setdefault("kv_layout", "paged")
-        kw.setdefault("page_size", 8)
+    kw.setdefault("min_bucket", 8)  # and so pages of 8
     return ServingEngine(model, params, kv_dtype=kv_dtype, **kw)
 
 
@@ -134,28 +131,22 @@ def test_quantized_kv_pytree_and_duck_surface():
 
 # ------------------------------------------------ transcript equality
 
-def test_int8_dense_matches_model_dtype_engine(served):
+@pytest.mark.parametrize("num_pages", [None, 9], ids=["parity", "tight"])
+def test_int8_matches_model_dtype_engine(served, num_pages):
     """Canonical config pin: greedy transcripts byte-equal between the
-    int8 and model-dtype dense engines over ragged concurrent
-    requests — AND the compile ladder did not grow (the scale sidecar
-    rides the same programs as extra operands, not new ones)."""
+    int8 and model-dtype engines over ragged concurrent requests, with
+    every slot's worst case in pages and with fewer pages than the
+    three slots want at once (admission holds) — AND the compile
+    ladder did not grow (the scale sidecar rides the same programs as
+    extra operands, not new ones)."""
     model, params, prompts = served
-    dense = _engine(model, params)
-    ref = dense.serve([(p, 6) for p in prompts])
-    eng = _engine(model, params, kv_dtype="int8")
+    ref_eng = _engine(model, params, num_pages=num_pages)
+    ref = ref_eng.serve([(p, 6) for p in prompts])
+    eng = _engine(model, params, kv_dtype="int8", num_pages=num_pages)
     got = eng.serve([(p, 6) for p in prompts])
     assert _tokens(got) == _tokens(ref)
-    assert eng.decode_programs == dense.decode_programs
-    assert eng.decode_step_compiles == dense.decode_step_compiles
-
-
-def test_int8_paged_matches_model_dtype_engine(served):
-    model, params, prompts = served
-    ref = _engine(model, params, paged=True).serve(
-        [(p, 6) for p in prompts])
-    got = _engine(model, params, kv_dtype="int8", paged=True).serve(
-        [(p, 6) for p in prompts])
-    assert _tokens(got) == _tokens(ref)
+    assert eng.decode_programs == ref_eng.decode_programs
+    assert eng.decode_step_compiles == ref_eng.decode_step_compiles
 
 
 def test_int8_chunked_prefill_and_horizon(served):
@@ -307,17 +298,19 @@ def test_pool_bytes_and_planner_exact(served):
                      num_layers=2, num_heads=2, mlp_dim=64,
                      attn_impl="xla")  # head_dim=64
     for kv_dtype in ("model", "int8"):
-        pool = SlotPool(big, 4, 32, kv_dtype=kv_dtype)
-        assert (hbm.nbytes_of(pool.k_caches)
-                + hbm.nbytes_of(pool.v_caches)
-                == 4 * SlotPool.per_slot_kv_bytes(big, 32, kv_dtype))
+        # dense parity: every slot's worst case plus the scratch page
+        pool = PagePool(big, 4, 32, page_size=8, kv_dtype=kv_dtype)
+        assert (hbm.nbytes_of(pool.k_pages)
+                + hbm.nbytes_of(pool.v_pages)
+                == 4 * PagePool.per_slot_kv_bytes(big, 32, kv_dtype)
+                + pool.page_bytes)
         pages = PagePool(big, max_slots=4, page_size=8, num_pages=13,
                          kv_dtype=kv_dtype)
         assert (hbm.nbytes_of(pages.k_pages)
                 == 13 * PagePool.page_kv_bytes(big, 8, kv_dtype) // 2)
         # shard_nbytes walks the pair's leaves, not the aggregate
-        assert (hbm.shard_nbytes(pool.k_caches)
-                == hbm.nbytes_of(pool.k_caches))
+        assert (hbm.shard_nbytes(pool.k_pages)
+                == hbm.nbytes_of(pool.k_pages))
     budget = 1 << 24
     dense = plan_capacity(big, 32, budget)
     quant = plan_capacity(big, 32, budget, kv_dtype="int8")
@@ -354,8 +347,7 @@ def test_engine_rejects_unknown_kv_dtype(served):
 # ---------------------------------------------------- kernel fallbacks
 
 def test_pallas_quant_kernels_match_xla():
-    """All four decode-attention variants (dense/paged x plain/verify)
-    on quantized caches: the Pallas kernel (interpret mode) and the
+    """The dense and the paged decode kernel on quantized caches: the Pallas kernel (interpret mode) and the
     XLA fallback agree to float tolerance, and the XLA fallback is
     EXACTLY dequantize-then-reference (shared dequant expression)."""
     da = importlib.import_module(
@@ -413,7 +405,7 @@ def test_pallas_quant_kernels_match_xla():
 # ------------------------------------------------------------- smoke
 
 def test_quant_smoke_end_to_end():
-    """The ``make quant`` body, mirrored in tier-1 (dense + paged
+    """The ``make quant`` body, mirrored in tier-1 (parity + paged
     transcript equality, pool/planner byte-exactness with the 1.8x
     bf16 residency ratio, the nonzero bounded logit delta, and the
     quantized transfer splice at < 0.6x payload)."""

@@ -70,8 +70,8 @@ def test_spec_paged_chunked_horizon_eos(served):
     programs.
 
     Slow-marked (PR 14 tier-1 rebalance for the graftroute suite):
-    the heaviest spec-matrix variant — the dense spec pins and the
-    paged non-spec pins stay fast-marked; the full cross stays in
+    the heaviest spec-matrix variant — the whole-prompt spec pins and
+    the non-spec pins stay fast-marked; the full cross stays in
     `make test`."""
     model, params, prompts = served
     engine = _spec(model, params, max_slots=3, kv_layout="paged",
@@ -98,8 +98,9 @@ def test_spec_paged_chunked_horizon_eos(served):
 
 
 @pytest.mark.slow
-def test_spec_dense_bucket_boundary(served):
-    """Dense spec across a fine bucket ladder: the window pick must
+def test_spec_bucket_boundary(served):
+    """Spec across a fine bucket ladder (windows of 1, 2 and 4 pages
+    of 8): the window pick must
     reserve k+1 read columns per pass (a verify query reads past its
     write frontier), so streams that cross bucket boundaries stay
     token-exact."""
@@ -265,21 +266,18 @@ def test_costs_budget_verify_bandwidth():
         "costs.json")
     with open(path) as fh:
         programs = json.load(fh)["programs"]
-    for spec_name, base_name in (
-            ("serving_decode_spec_w32_h4_k4", "serving_decode_w32_h4"),
-            ("serving_decode_spec_paged_w32_h4_k4",
-             "serving_decode_paged_w32_h4")):
-        spec = programs[spec_name]
-        base = programs[base_name]
-        flops_ratio = spec["flops"] / base["flops"]
-        bytes_ratio = spec["bytes_accessed"] / base["bytes_accessed"]
-        assert flops_ratio > 3.0, (
-            f"{spec_name}: verify FLOPs only {flops_ratio:.2f}x — the "
-            "k-query pass lost its extra MXU rows")
-        assert bytes_ratio < 1.7, (
-            f"{spec_name}: verify bytes {bytes_ratio:.2f}x the "
-            "non-spec stream — speculation is supposed to REUSE the "
-            "weight/KV bytes, not multiply them")
+    spec_name = "serving_decode_spec_paged_w32_h4_k4"
+    spec = programs[spec_name]
+    base = programs["serving_decode_paged_w32_h4"]
+    flops_ratio = spec["flops"] / base["flops"]
+    bytes_ratio = spec["bytes_accessed"] / base["bytes_accessed"]
+    assert flops_ratio > 3.0, (
+        f"{spec_name}: verify FLOPs only {flops_ratio:.2f}x — the "
+        "k-query pass lost its extra MXU rows")
+    assert bytes_ratio < 1.7, (
+        f"{spec_name}: verify bytes {bytes_ratio:.2f}x the "
+        "non-spec stream — speculation is supposed to REUSE the "
+        "weight/KV bytes, not multiply them")
 
 
 # ------------------------------------------------------------- smoke
@@ -316,22 +314,17 @@ def test_spec_tp_matches_single_shard(served):
 
 @pytest.mark.slow
 def test_spec_full_matrix_slow(served):
-    """Full cross-product: {dense, paged} x {whole, chunked} x
-    {k=2, k=4} x H in {1, 4}, every stream byte-identical to
-    generate()."""
+    """Full cross-product: {whole, chunked} x {k=2, k=4} x H in
+    {1, 4}, every stream byte-identical to generate()."""
     model, params, prompts = served
-    for paged in (False, True):
-        for chunk in (None, 5):
-            for k in (2, 4):
-                for h in (1, 4):
-                    kw = dict(max_slots=3, prefill_chunk=chunk,
-                              decode_horizon=h, draft_k=k)
-                    if paged:
-                        kw.update(kv_layout="paged", page_size=8)
-                    engine = _spec(model, params, **kw)
-                    got = engine.serve([(p, 6) for p in prompts])
-                    for r, p in zip(got, prompts):
-                        assert r.tokens == _ref_tail(
-                            model, params, p, 6), (paged, chunk, k, h)
-                    if paged:
-                        assert engine.pool.pages_in_use == 0
+    for chunk in (None, 5):
+        for k in (2, 4):
+            for h in (1, 4):
+                engine = _spec(model, params, max_slots=3,
+                               prefill_chunk=chunk, decode_horizon=h,
+                               draft_k=k)
+                got = engine.serve([(p, 6) for p in prompts])
+                for r, p in zip(got, prompts):
+                    assert r.tokens == _ref_tail(
+                        model, params, p, 6), (chunk, k, h)
+                assert engine.pool.pages_in_use == 0

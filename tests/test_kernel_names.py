@@ -54,11 +54,10 @@ def _flash(grad):
 
 def _decode(paged, k1, int8=False):
     q, pos = _sds((B, k1, H, D)), _sds((B,), jnp.int32)
-    if not paged:
+    if not paged:  # generate()'s kernel; the verify pass is paged only
         kv = _kv((B, S, H, D), int8)
-        kern = da.decode_attention if k1 == 1 else da.verify_decode_attention
-        return (lambda q, k, v, p: kern(q, k, v, p, impl="pallas",
-                                        interpret=True), (q, kv, kv, pos))
+        return (lambda q, k, v, p: da.decode_attention(
+            q, k, v, p, impl="pallas", interpret=True), (q, kv, kv, pos))
     # a two-layer pool [L, P, ps, H * D] (int8: + scales [L, P, ps, H])
     shape = (2, B * (S // PAGE) + 1, PAGE, H * D)
     pages = (QuantizedKV(_sds(shape, jnp.int8), _sds(shape[:-1] + (H,)))
@@ -124,8 +123,6 @@ _SITES = [
      "decode_attention"),
     ("decode_attention.py paged", lambda: _decode(True, 1),
      "paged_decode_attention"),
-    ("decode_attention.py verify", lambda: _decode(False, K1),
-     "verify_decode_attention"),
     ("decode_attention.py paged verify", lambda: _decode(True, K1),
      "paged_verify_decode_attention"),
     ("decode_attention.py latent paged", _mla,
@@ -137,8 +134,6 @@ _SITES = [
      "decode_attention"),
     ("decode_attention.py paged int8", lambda: _decode(True, 1, True),
      "paged_decode_attention"),
-    ("decode_attention.py verify int8", lambda: _decode(False, K1, True),
-     "verify_decode_attention"),
     ("decode_attention.py paged verify int8",
      lambda: _decode(True, K1, True), "paged_verify_decode_attention"),
 ]
@@ -161,7 +156,7 @@ def test_kernel_metrics_match_the_names_the_kernels_carry():
     """The metric files that select by regex over ``mosaic:<name>``
     (PR 25's three, PR 27's and PR 29's rooflines, PR 29's latent
     kernel): each must pick out exactly its kernels."""
-    assert len(KERNEL_NAMES) == 10
+    assert len(KERNEL_NAMES) == 9
     labels = ["mosaic:" + name for name in KERNEL_NAMES]
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     picked = {}

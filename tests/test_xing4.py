@@ -368,17 +368,22 @@ def test_page_kv_bytes_equals_the_allocation(tiny):
             == pool.k_pages.nbytes + pool.v_pages.nbytes)
 
 
-@pytest.mark.parametrize("options, named", [
-    (dict(kv_layout="dense"), "kv_layout=dense"),
-    (dict(kv_layout="paged", page_size=8, kv_dtype="int8"), "kv_dtype=int8"),
-    (dict(kv_layout="paged", page_size=8, draft_k=2), "draft_k"),
-    (dict(kv_layout="paged", page_size=8, prefix_cache=4), "prefix_cache"),
-])
-def test_engine_refuses_by_name_what_the_family_lacks(tiny, options, named):
+@pytest.mark.parametrize("options, error, named", [
+    # the engine's own refusal, for any family: the dense pool is gone
+    (dict(kv_layout="dense"), ValueError, "dense slot pool"),
+    (dict(page_size=8, kv_dtype="int8"), NotImplementedError,
+     "kv_dtype=int8 is not supported for the xing4_0"),
+    (dict(page_size=8, draft_k=2), NotImplementedError,
+     "draft_k is not supported for the xing4_0"),
+    (dict(page_size=8, prefix_cache=4), NotImplementedError,
+     "prefix_cache is not supported for the xing4_0"),
+], ids=["kv_layout=dense", "kv_dtype=int8", "draft_k", "prefix_cache"])
+def test_engine_refuses_by_name_what_the_family_lacks(tiny, options, error,
+                                                      named):
     model, params = tiny
-    with pytest.raises(NotImplementedError) as e:
+    with pytest.raises(error) as e:
         ServingEngine(model, params, max_slots=2, s_max=64, **options)
-    assert named in str(e.value) and "xing4_0" in str(e.value)
+    assert named in str(e.value)
 
 
 def test_tensor_parallel_and_generate_are_refused_by_name(tiny):
