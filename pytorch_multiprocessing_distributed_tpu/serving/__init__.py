@@ -7,9 +7,11 @@ never per batch composition — with per-step attention cost tracking
 the longest ACTIVE sequence instead of the cache capacity, over ONE
 paged KV pool (``kv_pages``), steady-state decode fused H steps per
 dispatch with
-ONE overlapped token-block readback per horizon (``decode_horizon`` —
-host syncs/token = 1/H, on-device EOS/budget freezing keeps it
-token-exact), prompts admitted whole or in fixed-size chunks
+ONE token-block readback per horizon (``decode_horizon`` — host
+syncs/token = 1/H, on-device EOS/budget freezing keeps it
+token-exact), the step pipelined one block deep at every horizon (the
+next block is dispatched, admissions included, before this one is read
+back), prompts admitted whole or in fixed-size chunks
 interleaved with decode (``scheduler.PrefillPlan``), fed by a FIFO
 scheduler with admission control and the adaptive horizon policy
 (``scheduler``), loading trained checkpoints param-only (``params``).

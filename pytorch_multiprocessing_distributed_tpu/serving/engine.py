@@ -51,11 +51,22 @@ tracks the work actually resident:
   (:func:`~.scheduler.pick_horizon`: bucket-boundary distance,
   shortest remaining budget, queue pressure) and snaps it to the
   ``{1, H}`` ladder, bounding decode compiles by
-  ``|buckets touched| x 2``. The readback itself is OVERLAPPED: in
-  steady state horizon ``h+1`` is dispatched before horizon ``h``'s
-  block is synced (double-buffered pending blocks, the trainer's
-  deferred-metrics pattern), so the host never sits between the TPU
-  and its next program;
+  ``|buckets touched| x 2``;
+- **the step is a software pipeline one block deep**, at every horizon
+  (``H == 1`` included) and with admission work queued or pending:
+  ``step()`` admits, dispatches block ``k``, and only then reads block
+  ``k - 1`` back (double-buffered pending blocks, the trainer's
+  deferred-metrics pattern), so a decode program is queued on the
+  device while the host reads tokens, runs its per-token loop, returns
+  to its caller, schedules, reserves pages and dispatches. Admission
+  is two stages a step apart for the same reason: a prefill is
+  dispatched behind the block in flight and NOT waited for; the next
+  step reads its first token (long computed, the device already in
+  the next block) and queues the insert ahead of that step's dispatch.
+  The price: a finish is seen one drain later (the slot holds one
+  frozen row more), and a first token comes one step after its
+  prefill was dispatched, its slot idle meanwhile. What keeps it
+  exact is in ``_step_inner``'s note;
 - finished slots (EOS / ``max_new_tokens``) are recycled in place —
   stale cache columns are masked until the next tenant overwrites them
   (see ``kv_pages`` invariants). Finish detection is on-device; the
@@ -161,9 +172,8 @@ _SITE_CHUNK = register_site(
     "one [1, chunk] incremental-prefill step of a joining prompt")
 _SITE_TOK0 = register_site(
     "serving.prefill_tok0",
-    "first-token sample + readback after the LAST prefill chunk (the "
-    "chunked path's TTFT boundary; the whole-prompt path's is inside "
-    "serving.prefill)")
+    "first-token readback, one step after the prefill that computed it "
+    "was dispatched (the TTFT boundary of both admission paths)")
 _SITE_INSERT = register_site(
     "serving.slot_insert",
     "slot splice of a prefilled request (cache columns + finish gates)")
@@ -192,19 +202,26 @@ class _TokenBlock:
 
 
 class _PendingPrefill:
-    """Host-side state of the one request currently mid-chunked-prefill:
-    its chunk plan plus the standalone caches the chunks accumulate
-    into (spliced into a pool slot after the last chunk). ``prep`` is
-    its page reservation."""
+    """Host-side state of one admission in flight. Mid-chunked-prefill
+    (``engine._pending``, at most one): its chunk plan plus the
+    standalone caches the chunks accumulate into. Prefill all
+    dispatched (``engine._unread``, ``tok0`` set, ``plan`` None for a
+    whole prompt): the caches and the first token are device values
+    nobody has waited for; the NEXT step's admission reads the token
+    and splices the caches into a pool slot. ``prep`` is its page
+    reservation."""
 
-    __slots__ = ("request", "plan", "k_pref", "v_pref", "prep")
+    __slots__ = ("request", "plan", "k_pref", "v_pref", "prep", "length",
+                 "tok0")
 
-    def __init__(self, request, plan, k_pref, v_pref, prep):
+    def __init__(self, request, plan, k_pref, v_pref, prep, tok0=None):
         self.request = request
         self.plan = plan
         self.k_pref = k_pref
         self.v_pref = v_pref
         self.prep = prep
+        self.length = len(request.prompt)
+        self.tok0 = tok0
 
 
 class _PagedPrep:
@@ -279,9 +296,10 @@ class ServingEngine:
         ``{1, decode_horizon}`` ladder — H collapses to 1 while
         admission work is pending (bounded join latency), near a
         decode-bucket boundary, or when the shortest remaining budget
-        would waste most of the horizon. With H > 1 the engine also
-        overlaps readback: horizon ``h+1`` dispatches before horizon
-        ``h``'s block syncs. Sampled (``temperature > 0``) streams stay
+        would waste most of the horizon. H decides how many tokens a
+        program emits per readback, NOT whether the readback is
+        hidden: the next block dispatches before this one syncs at
+        every H (``_overlap_ok``). Sampled (``temperature > 0``) streams stay
         reproducible per engine run but depend on the horizon schedule
         (per-step keys split inside the program); greedy output is
         horizon-invariant (test-pinned).
@@ -585,12 +603,16 @@ class ServingEngine:
         self._sampling = (float(temperature), int(top_k), float(top_p))
         self._running: Dict[int, Request] = {}
         self._pending: Optional[_PendingPrefill] = None
+        # prefills dispatched LAST step, first token unread: a free
+        # slot is held back for each until this step's admission
+        # reads the token and splices (``_finish_prefills``)
+        self._unread: List[_PendingPrefill] = []
         self._prefill_chunk = (None if prefill_chunk is None
                                else int(prefill_chunk))
         self._horizon_max = int(decode_horizon)
-        # dispatched-but-unsynced token blocks (<= 2: double-buffered —
-        # the overlap depth that hides readback without letting the
-        # host run away from the device)
+        # dispatched-but-unsynced token blocks (<= 2 inside a step, 1
+        # between steps in steady state: the pipeline depth that hides
+        # the host's work without letting it run away from the device)
         self._blocks: Deque[_TokenBlock] = deque()
         self._buckets = self._build_buckets(decode_buckets)
         if decode_attn == "auto":
@@ -926,7 +948,7 @@ class ServingEngine:
             with expected_transfer("draft-model prompt upload at "
                                    "admission (graftspec)"):
                 k_pref, v_pref = self._draft_prefill_jit(
-                    self._draft_params, jnp.asarray(padded))
+                    self._draft_params, padded)
                 return k_pref, v_pref
 
         with graftscope.span("spec.draft_prefill", cat="serving",
@@ -940,7 +962,7 @@ class ServingEngine:
                                    "(graftspec, scalar H2D)"):
                 return self._donated(lambda: self._draft_insert_jit(
                     self._draft_k_caches, self._draft_v_caches,
-                    k_pref, v_pref, jnp.int32(slot)))
+                    k_pref, v_pref, np.int32(slot)))
 
         self._draft_k_caches, self._draft_v_caches = self._attempted(
             splice_once)
@@ -1231,11 +1253,12 @@ class ServingEngine:
                                "fault path only)"):
             pool.active, pool.budgets = self._donated(
                 lambda: self._evict_jit(
-                    pool.active, pool.budgets, jnp.int32(slot)))
+                    pool.active, pool.budgets, np.int32(slot)))
 
     def _expire_deadlines(self) -> None:
         """Fail every request past its per-request deadline — queued,
-        mid-chunked-prefill, or running (evicted + slot scrubbed).
+        mid-prefill (chunks pending or first token unread), or running
+        (evicted + slot scrubbed).
         Free when no deadline-bearing request was ever submitted (the
         default config): the sticky flag skips the per-step scans."""
         if not self._deadlines_seen:
@@ -1248,16 +1271,16 @@ class ServingEngine:
                     f"request {request.uid} exceeded its "
                     f"{request.deadline_s:.3g}s deadline in the queue"),
                 reason="deadline")
-        pend = self._pending
-        if pend is not None and pend.request.overdue(now):
-            self._drop_pending()
-            self._quarantine(
-                pend.request,
-                DeadlineExceeded(
-                    f"request {pend.request.uid} exceeded its "
-                    f"{pend.request.deadline_s:.3g}s deadline "
-                    f"mid-chunked-prefill"),
-                reason="deadline")
+        for pend in self._joining():
+            if pend.request.overdue(now):
+                self._drop_joining(pend)
+                self._quarantine(
+                    pend.request,
+                    DeadlineExceeded(
+                        f"request {pend.request.uid} exceeded its "
+                        f"{pend.request.deadline_s:.3g}s deadline "
+                        f"mid-prefill"),
+                    reason="deadline")
         for slot, request in list(self._running.items()):
             if request.overdue(now):
                 self._quarantine(
@@ -1591,21 +1614,70 @@ class ServingEngine:
         return slot
 
     def _admit(self) -> List[Tuple[Request, int, bool]]:
-        """Move FIFO-head requests toward slots. Whole-prompt mode
-        fills every free slot with one prefill call each; chunked mode
+        """Move FIFO-head requests toward slots, in two stages a step
+        apart so that the host never waits for a program it has just
+        dispatched. First the prefills dispatched LAST step give up
+        their first tokens and are spliced into slots
+        (:meth:`_finish_prefills`); then whole-prompt mode dispatches
+        one prefill call for every slot still free, and chunked mode
         advances the single in-flight :class:`PrefillPlan` by EXACTLY
-        one chunk (the bounded stall the mode exists for) and splices
-        on the final chunk. Requests past their deadline are failed
+        one chunk (the bounded stall the mode exists for) — dispatch
+        only, nothing is read. Requests past their deadline are failed
         first, inside the same ``engine.admit`` span: the span is all
         the scheduling a step does before it can dispatch."""
         with graftscope.span("engine.admit", cat="serving") as admit_span:
             self._expire_deadlines()
+            events: List[Tuple[Request, int, bool]] = []
+            self._finish_prefills(events)
             queued = self.scheduler.queue_depth
-            events = (self._admit_whole() if self._prefill_chunk is None
-                      else self._admit_chunked())
+            if self._prefill_chunk is None:
+                self._admit_whole(events)
+            else:
+                self._admit_chunked(events)
             depth = self.scheduler.queue_depth
             admit_span.note(admitted=queued - depth, queue_depth=depth)
         return events
+
+    def _finish_prefills(self, events: List) -> None:
+        """Stage two of an admission, one step after stage one: read
+        the first token of every prefill dispatched last step (the
+        TTFT boundary), retire the request there or acquire its slot,
+        and splice. The prefill ran right behind the block that was in
+        flight when it was dispatched, so the read waits for little or
+        nothing, and the device has the NEXT block to run meanwhile;
+        the insert queues behind that block and ahead of this step's
+        dispatch, so the tenant joins the block dispatched now. Shared
+        tail of both prefill paths."""
+        unread, self._unread = self._unread, []
+        for pend in unread:
+            request = pend.request
+
+            def tok0_once(pend=pend):
+                # per-request work on a value no program donates:
+                # retry, then quarantine just this request
+                maybe_fault(_SITE_TOK0)
+                with expected_transfer("first-token readback (the TTFT "
+                                       "boundary)"):
+                    return int(pend.tok0)
+
+            try:
+                with graftscope.span("serving.prefill_tok0",
+                                     cat="serving", req=request.uid):
+                    tok0_host = self._attempted(tok0_once)
+            except Exception as e:
+                self._abort_prep(pend.prep)
+                self._poisoned(request, e)
+                continue
+            slot = self._first_token(request, tok0_host, events)
+            if slot is None:
+                self._abort_prep(pend.prep)
+                continue
+            try:
+                self._insert(request, slot, pend.k_pref, pend.v_pref,
+                             pend.length, pend.tok0, prep=pend.prep)
+            except Exception as e:
+                self._abort_prep(pend.prep)
+                self._poisoned(request, e, slot=slot)
 
     # ---- paged admission (graftpage) ----------------------------------
     def _paged_prep_head(self):
@@ -1647,7 +1719,7 @@ class ServingEngine:
                     and self._prefix_cache.evict_lru()):
                 break
         if pool.free_pages < needed:
-            if (not self._running and self._pending is None
+            if (not self._running and not self._joining()
                     and not self._blocks
                     and not (self._prefix_cache
                              and len(self._prefix_cache))):
@@ -1692,15 +1764,22 @@ class ServingEngine:
             pool.decref([prep.fork_src])
         prep.shared_ids, prep.fresh_ids, prep.fork_src = [], [], None
 
-    def _drop_pending(self) -> Optional[_PendingPrefill]:
-        """Detach the in-flight chunked prefill, returning its pages
-        first (every quarantine/drain path that clears ``_pending``
-        goes through here)."""
-        pend = self._pending
-        self._pending = None
-        if pend is not None:
-            self._abort_prep(pend.prep)
-        return pend
+    def _joining(self) -> List[_PendingPrefill]:
+        """Admissions in flight, neither queued nor running: the
+        chunked prefill in progress and every request whose prefill is
+        dispatched and whose first token is unread."""
+        return (([] if self._pending is None else [self._pending])
+                + self._unread)
+
+    def _drop_joining(self, pend: _PendingPrefill) -> None:
+        """Detach one admission in flight, returning its pages (every
+        quarantine/drain path that clears one goes through here; its
+        device values are simply dropped)."""
+        if self._pending is pend:
+            self._pending = None
+        else:
+            self._unread.remove(pend)
+        self._abort_prep(pend.prep)
 
     def _copy_page(self, src: int, dst: int) -> None:
         """One COW page fork on the device (donated pages — engine-
@@ -1712,8 +1791,8 @@ class ServingEngine:
             with expected_transfer("page-fork control upload "
                                    "(scalar H2D, prefix-hit path)"):
                 return self._donated(lambda: self._copy_page_jit(
-                    pool.k_pages, pool.v_pages, jnp.int32(src),
-                    jnp.int32(dst)))
+                    pool.k_pages, pool.v_pages, np.int32(src),
+                    np.int32(dst)))
 
         pool.k_pages, pool.v_pages = self._attempted(copy_once)
 
@@ -1752,10 +1831,10 @@ class ServingEngine:
                         lambda: self._state_insert_jit(
                             pool.positions, pool.last_tokens,
                             pool.active, pool.budgets, pool.eos_ids,
-                            jnp.int32(slot), jnp.int32(length),
-                            jnp.int32(int(entry.tok0)),
-                            jnp.int32(request.max_new_tokens - 1),
-                            jnp.int32(eos)))
+                            np.int32(slot), np.int32(length),
+                            np.int32(int(entry.tok0)),
+                            np.int32(request.max_new_tokens - 1),
+                            np.int32(eos)))
 
             try:
                 (pool.positions, pool.last_tokens, pool.active,
@@ -1790,7 +1869,7 @@ class ServingEngine:
                                    "(partial-hit admission)"):
                 return self._gather_jit(
                     pool.k_pages, pool.v_pages,
-                    jnp.asarray(prep.shared_ids, jnp.int32),
+                    np.asarray(prep.shared_ids, np.int32),
                     width=plan.width)
 
         with graftscope.span("serving.prefix_hit", cat="serving",
@@ -1799,13 +1878,14 @@ class ServingEngine:
             k_pref, v_pref = self._attempted(gather_once)
         return _PendingPrefill(request, plan, k_pref, v_pref, prep)
 
-    def _drive_pending(self, pend: _PendingPrefill,
-                       events: List) -> bool:
+    def _drive_pending(self, pend: _PendingPrefill) -> bool:
         """Advance a pending chunked prefill by ONE chunk; on the last
-        chunk, sample tok0 and splice. Returns True while more chunks
-        remain. Shared by chunked admission (one call per step) and
-        the whole-prompt engine's partial-hit path (driven to
-        completion in a loop)."""
+        chunk, dispatch the first token's sample and hand the prefill
+        to ``_unread`` (read and spliced by the next step's
+        ``_finish_prefills``). Returns True while more chunks remain.
+        Shared by chunked admission (one call per step) and the
+        whole-prompt engine's partial-hit path (driven to completion in
+        a loop)."""
         start, valid, is_last = pend.plan.next_chunk()
         chunk = pend.plan.chunk
         padded = np.zeros((1, chunk), np.int32)
@@ -1819,7 +1899,7 @@ class ServingEngine:
                                    "shape)"):
                 return self._chunk_jit(
                     self.params, pend.k_pref, pend.v_pref,
-                    jnp.asarray(padded), jnp.int32(start))
+                    padded, np.int32(start))
 
         try:
             with graftscope.span("serving.prefill_chunk", cat="serving",
@@ -1829,9 +1909,8 @@ class ServingEngine:
                     chunk_once)
         except Exception as e:
             if self._pending is pend:
-                self._drop_pending()
-            else:
-                self._abort_prep(pend.prep)
+                self._pending = None
+            self._abort_prep(pend.prep)
             self._poisoned(pend.request, e)
             return False
         record_jit_key(self._chunk_jit,
@@ -1839,46 +1918,30 @@ class ServingEngine:
         if not is_last:
             return True
         if self._pending is pend:
-            self._pending = None  # prep ownership moves to the splice
+            self._pending = None
         key = self._next_key()
 
         def tok0_once():
-            # same fault domain as the whole-prompt path's first-token
-            # readback (there it lives inside serving.prefill):
-            # per-request work — retry, then quarantine just this
-            # request. _tok0_jit donates nothing, so retries are safe.
-            maybe_fault(_SITE_TOK0)
-            with expected_transfer("first-token readback (the TTFT "
-                                   "boundary)"):
-                t = self._tok0_jit(
+            # _tok0_jit donates nothing, so retries are safe
+            with expected_transfer("first-token sample's index upload "
+                                   "(scalar H2D)"):
+                return self._tok0_jit(
                     self.params, x,
-                    jnp.int32(pend.plan.length - 1 - start), key)
-                return t, int(t)
+                    np.int32(pend.plan.length - 1 - start), key)
 
         try:
-            with graftscope.span("serving.prefill_tok0", cat="serving",
-                                 req=pend.request.uid):
-                tok0, tok0_host = self._attempted(tok0_once)
+            pend.tok0 = self._attempted(tok0_once)
         except Exception as e:
             self._abort_prep(pend.prep)
             self._poisoned(pend.request, e)
             return False
-        slot = self._first_token(pend.request, tok0_host, events)
-        if slot is None:
-            self._abort_prep(pend.prep)
-            return False
-        try:
-            self._insert(pend.request, slot, pend.k_pref, pend.v_pref,
-                         pend.plan.length, tok0, prep=pend.prep)
-        except Exception as e:
-            self._abort_prep(pend.prep)
-            self._poisoned(pend.request, e, slot=slot)
+        self._unread.append(pend)  # read and spliced next step
         return False
 
-    def _admit_whole(self) -> List[Tuple[Request, int, bool]]:
-        events: List[Tuple[Request, int, bool]] = []
+    def _admit_whole(self, events: List) -> None:
         pool = self.pool
-        while pool.free_slots > 0:
+        # a free slot is held back for every prefill already on its way
+        while pool.free_slots > len(self._unread):
             prep = self._paged_prep_head()
             if prep is None or prep == "hold":
                 break
@@ -1907,7 +1970,7 @@ class ServingEngine:
                     self._abort_prep(prep)
                     self._poisoned(request, e)
                     continue
-                while self._drive_pending(pend, events):
+                while self._drive_pending(pend):
                     pass
                 continue
             length = len(request.prompt)
@@ -1918,36 +1981,26 @@ class ServingEngine:
 
             def prefill_once():
                 maybe_fault(_SITE_PREFILL)
-                with expected_transfer("prompt upload + first-token "
-                                       "readback (the TTFT boundary)"):
-                    tok0, k_pref, v_pref = self._prefill_jit(
-                        self.params, jnp.asarray(padded),
-                        jnp.int32(length), key)
+                with expected_transfer("prompt upload"):
+                    out = self._prefill_jit(
+                        self.params, padded,
+                        np.int32(length), key)
                     record_jit_key(self._prefill_jit,
                                    ("prefill", bucket))
-                    return tok0, k_pref, v_pref, int(tok0)
+                    return out
 
             try:
                 with graftscope.span("serving.prefill", cat="serving",
                                      req=request.uid, bucket=bucket,
                                      prompt_len=length):
-                    tok0, k_pref, v_pref, tok0_host = self._attempted(
-                        prefill_once)
+                    tok0, k_pref, v_pref = self._attempted(prefill_once)
             except Exception as e:
                 self._abort_prep(prep)
                 self._poisoned(request, e)
                 continue
-            slot = self._first_token(request, tok0_host, events)
-            if slot is None:
-                self._abort_prep(prep)
-                continue
-            try:
-                self._insert(request, slot, k_pref, v_pref, length,
-                             tok0, prep=prep)
-            except Exception as e:
-                self._abort_prep(prep)
-                self._poisoned(request, e, slot=slot)
-        return events
+            # dispatched, not waited for: read and spliced next step
+            self._unread.append(_PendingPrefill(
+                request, None, k_pref, v_pref, prep, tok0=tok0))
 
     def _insert(self, request: Request, slot: int, k_pref, v_pref,
                 length: int, tok0, prep: _PagedPrep) -> None:
@@ -1983,7 +2036,13 @@ class ServingEngine:
             # the injected site fires BEFORE the jitted call, so a
             # retried injection never re-runs against donated buffers;
             # a real mid-call failure consumed the donated pool —
-            # _donated classifies it engine-fatal (PoolPoisonedError)
+            # _donated classifies it engine-fatal (PoolPoisonedError).
+            # Scalars and ids go in as NUMPY values (here and at every
+            # jitted call of the engine): the call's own argument
+            # transfer carries them; ``jnp.int32(x)`` is a device
+            # program and ~0.4 ms of host time EACH on a v5e host
+            # (PERF.md section 6, PR 32), with the device idle behind
+            # the first token's read-back
             maybe_fault(_SITE_INSERT)
             with expected_transfer("slot/length/budget control upload "
                                    "at admission (scalar H2D)"):
@@ -1991,10 +2050,10 @@ class ServingEngine:
                     pool.k_pages, pool.v_pages, pool.positions,
                     pool.last_tokens, pool.active, pool.budgets,
                     pool.eos_ids, k_pref, v_pref,
-                    jnp.asarray(write_ids), jnp.int32(slot),
-                    jnp.int32(length), tok0,
-                    jnp.int32(request.max_new_tokens - 1),
-                    jnp.int32(eos)))
+                    write_ids, np.int32(slot),
+                    np.int32(length), tok0,
+                    np.int32(request.max_new_tokens - 1),
+                    np.int32(eos)))
 
         with graftscope.span("serving.slot_insert", cat="serving",
                              req=request.uid, slot=slot):
@@ -2061,10 +2120,10 @@ class ServingEngine:
             c, NamedSharding(self.mesh,
                              P(None, None, None, "model", None)))
 
-    def _admit_chunked(self) -> List[Tuple[Request, int, bool]]:
-        events: List[Tuple[Request, int, bool]] = []
+    def _admit_chunked(self, events: List) -> None:
         pool = self.pool
-        if self._pending is None and pool.free_slots > 0:
+        if (self._pending is None
+                and pool.free_slots > len(self._unread)):
             prep = self._paged_prep_head()
             admit = prep is not None and prep not in ("hold", "retry")
             request = self._pop_admission() if admit else None
@@ -2076,7 +2135,7 @@ class ServingEngine:
                         request.prefix_hit)
                 if prep.mode == "full":
                     self._admit_full_hit(request, prep, events)
-                    return events
+                    return
                 if prep.mode == "partial":
                     try:
                         self._pending = self._seed_partial_pending(
@@ -2084,7 +2143,7 @@ class ServingEngine:
                     except Exception as e:
                         self._abort_prep(prep)
                         self._poisoned(request, e)
-                        return events
+                        return
                 else:
                     plan = PrefillPlan(request, self._prefill_chunk,
                                        self.min_bucket, pool.s_max)
@@ -2097,11 +2156,8 @@ class ServingEngine:
                         self._pref_sharded(
                             jnp.zeros(v_shape, self.model.dtype)),
                         prep)
-        pend = self._pending
-        if pend is None:
-            return events
-        self._drive_pending(pend, events)
-        return events
+        if self._pending is not None:
+            self._drive_pending(self._pending)
 
     # ---- horizon scheduling / dispatch / drain ------------------------
     def _inflight_steps(self) -> int:
@@ -2113,17 +2169,18 @@ class ServingEngine:
         block counts ``h * (k + 1)`` rows."""
         return sum(block.rows for block in self._blocks)
 
-    def _min_remaining_eff(self) -> int:
-        """Shortest remaining decode budget over running requests,
-        discounted by in-flight rows already dispatched against each
-        slot (host knows only DRAINED tokens)."""
+    def _remaining_eff(self) -> List[int]:
+        """Remaining decode budget of every running request,
+        discounted by the in-flight rows already dispatched against
+        its slot (host knows only DRAINED tokens); never negative. A
+        block counts only for the tenant it was dispatched with."""
         rem = []
         for slot, request in self._running.items():
             assumed = sum(block.rows for block in self._blocks
                           if block.slots.get(slot) is request)
-            rem.append(request.max_new_tokens - len(request.tokens)
-                       - assumed)
-        return min(rem) if rem else 0
+            rem.append(max(0, request.max_new_tokens
+                           - len(request.tokens) - assumed))
+        return rem
 
     def _pick_k(self) -> int:
         """Realized draft length for the next dispatch, on the
@@ -2158,10 +2215,10 @@ class ServingEngine:
                 window = b
                 break
         admission_pending = (self.scheduler.queue_depth > 0
-                             or self._pending is not None)
+                             or bool(self._joining()))
         h = pick_horizon(self._horizon_max, window, max_eff,
-                         self._min_remaining_eff(), admission_pending,
-                         per_step=k + 1)
+                         min(self._remaining_eff(), default=0),
+                         admission_pending, per_step=k + 1)
         if self._cooldown > 0:
             # post-fault degradation: smaller blast radius per dispatch
             # (one token's work lost on a repeat, not a horizon's) and
@@ -2263,18 +2320,23 @@ class ServingEngine:
                                occupancy=pool.occupancy)
 
     def _overlap_ok(self) -> bool:
-        """Dispatch horizon h+1 before syncing horizon h's block?
-        Only in steady state: horizons enabled, exactly one block in
-        flight, no admission work wanting a slot or a chunk step, and
-        at least one running request with budget beyond what is
-        already dispatched (an all-frozen horizon would be pure
-        waste)."""
-        return (self._horizon_max > 1
-                and len(self._blocks) == 1
-                and bool(self._running)
-                and self.scheduler.queue_depth == 0
-                and self._pending is None
-                and self._min_remaining_eff() >= 1)
+        """Dispatch the next block before the block in flight is read
+        back? Whenever it is safe and useful, at every horizon and
+        with admission work queued or pending (the step is a software
+        pipeline one block deep): at most ONE older block is undrained
+        (the host never runs further ahead of the device than that),
+        something is running, and some running request has budget
+        beyond what is already dispatched — the device's own budget
+        gate would freeze every row of a block past that, pure waste.
+        Every dispatch path takes this one rule: a speculative block
+        counts its worst case of ``h * (k + 1)`` rows against the
+        budgets (so a request's tail falls back to dispatch-then-
+        drain), and its drafter's table is simply one block staler
+        (fewer accepted drafts, the verified stream unchanged);
+        ``admit_prefilled`` splices behind the block in flight like
+        any admission."""
+        return (len(self._blocks) == 1
+                and any(self._remaining_eff()))
 
     def _drain_one(self, events: List[Tuple[Request, int, bool]]
                    ) -> Tuple[int, int]:
@@ -2396,11 +2458,13 @@ class ServingEngine:
                         list(request.prompt) + list(request.tokens))
 
     def step(self) -> List[Tuple[Request, int, bool]]:
-        """One engine iteration: admit (a whole prompt per free slot,
-        or one chunk), dispatch a decode horizon over the pool at the
-        active-length bucket window (plus, in steady state, the NEXT
-        horizon before this one's readback — the overlap), then drain
-        exactly one token block. Returns the iteration's token events
+        """One engine iteration of the one-block-deep pipeline: admit
+        (splice last step's prefills; dispatch a whole prompt per
+        free slot, or one chunk) under the block still in flight,
+        dispatch the NEXT decode horizon over the
+        pool at the active-length bucket window, then drain exactly
+        one token block, the OLDER one (a cold start, with nothing in
+        flight, dispatches twice). Returns the iteration's token events
         as ``(request, token, finished)`` tuples (admission first
         tokens included; a quarantined request emits no event — read
         its ``state``/``error``)."""
@@ -2426,12 +2490,46 @@ class ServingEngine:
             raise
 
     def _step_inner(self) -> List[Tuple[Request, int, bool]]:
+        # Admit, dispatch block k, drain block k - 1: in steady state a
+        # decode program is queued on the device through all of it.
+        # Why admitting and evicting UNDER a block in flight is exact
+        # (the device runs its programs in dispatch order):
+        # - a slot is only free on the host after its tenant's finish
+        #   was DRAINED; the block in flight may still name that tenant,
+        #   but its row there is frozen (the device cleared ``active``
+        #   when the gate fired) and drain skips rows whose tenant
+        #   changed (``_running`` identity), so no token is misfiled;
+        # - that frozen row re-writes its pinned column through the
+        #   table the block was dispatched with, i.e. into a page since
+        #   released and perhaps handed on. The new owner's insert and
+        #   decode come LATER in device order and write every column
+        #   before reading it (``kv_pages`` invariants), so the stale
+        #   write is never read. The same holds for an ACTIVE row of a
+        #   tenant that ``_quarantine`` / ``_expire_deadlines`` /
+        #   ``withdraw`` / ``hard_reclaim`` evict under the block: the
+        #   scrub program and the successor's insert queue behind it;
+        # - pages for prompt + budget are reserved at admission
+        #   (``_paged_prep_head``) and the budget gate is on the device,
+        #   so no block can outrun its reservation however far ahead
+        #   it was dispatched; ``_blocks`` counts as work in flight in
+        #   the "nothing will ever free a page" test and in
+        #   ``in_flight``;
+        # - admission never waits for what it has just dispatched: a
+        #   prefill (or last chunk and first-token sample) dispatched in
+        #   step n runs behind block k - 1; step n + 1 reads its first
+        #   token while block k runs, and its insert queues behind
+        #   block k and ahead of block k + 1, the tenant's first block.
+        #   Until then a free slot is held back for it (``_unread``).
         events = self._admit()
         pool = self.pool
         if self._running or self._blocks:
+            # t0 .. end of drain spans this dispatch and the wait for
+            # the OLDER block: ``decode_step`` is no longer one
+            # program's time (the step's length is the caller's clock
+            # round ``step()``)
             t0 = time.perf_counter()
             if self._running and not self._blocks:
-                self._dispatch()
+                self._dispatch()  # cold start: nothing in flight
             if self._overlap_ok():
                 self._dispatch(overlapped=True)
             occupancy = pool.occupancy  # before releases, like PR 2
@@ -2464,12 +2562,16 @@ class ServingEngine:
 
     @property
     def in_flight(self) -> int:
-        """Work somewhere in the engine: queued, mid-chunked-prefill,
-        decoding, or a dispatched-but-unsynced token block (drive
-        loops should drain until 0)."""
+        """Work somewhere in the engine: requests queued, mid-prefill
+        (chunks pending or first token unread) or decoding — or, with
+        none of those left, a dispatched-but-unsynced token block
+        (drive loops should drain until 0). The block in flight under
+        running requests is their pipeline, not one more unit of load:
+        placement and admission windows (``serving/replica.py``) count
+        requests."""
         return (self.scheduler.queue_depth + len(self._running)
-                + (1 if self._pending is not None else 0)
-                + (1 if self._blocks else 0))
+                + len(self._joining())
+                or (1 if self._blocks else 0))
 
     def run(self) -> Iterable[Tuple[Request, int, bool]]:
         """Drive ``step`` until queue, pending prefill and pool drain,
@@ -2495,7 +2597,7 @@ class ServingEngine:
               ) -> List[Tuple[Request, int, bool]]:
         """Finish every in-flight request (admission stays closed),
         bounded by ``deadline_s``: past it, every unfinished request —
-        queued, mid-chunked-prefill, or running — is failed NAMED
+        queued, mid-prefill, or running — is failed NAMED
         (``DeadlineExceeded``, reason ``"drain"``), never silently
         dropped. The engine lands DEAD, its journal (if any) is
         compacted + closed (a clean full drain leaves it empty), and
@@ -2541,11 +2643,11 @@ class ServingEngine:
             self._quarantine(request, overdue_error(request, "queued"),
                              reason="drain")
             failed += 1
-        pend = self._drop_pending()
-        if pend is not None:
+        for pend in self._joining():
+            self._drop_joining(pend)
             self._quarantine(
                 pend.request,
-                overdue_error(pend.request, "mid-chunked-prefill"),
+                overdue_error(pend.request, "mid-prefill"),
                 reason="drain")
             failed += 1
         for slot, request in list(self._running.items()):
@@ -2641,8 +2743,8 @@ class ServingEngine:
                 with expected_transfer("prompt upload + first-token "
                                        "readback (detached prefill)"):
                     tok0, k_pref, v_pref = self._prefill_jit(
-                        self.params, jnp.asarray(padded),
-                        jnp.int32(length), key)
+                        self.params, padded,
+                        np.int32(length), key)
                     record_jit_key(self._prefill_jit,
                                    ("prefill", bucket))
                     return int(tok0), k_pref, v_pref
@@ -2672,8 +2774,8 @@ class ServingEngine:
                 with expected_transfer("chunk upload (detached "
                                        "prefill)"):
                     return self._chunk_jit(self.params, k, v,
-                                           jnp.asarray(p),
-                                           jnp.int32(s))
+                                           p,
+                                           np.int32(s))
 
             with graftscope.span("serving.prefill_chunk",
                                  cat="serving", req=request.uid,
@@ -2689,7 +2791,7 @@ class ServingEngine:
             with expected_transfer("first-token readback (detached "
                                    "prefill)"):
                 return int(self._tok0_jit(
-                    self.params, x, jnp.int32(length - 1 - start),
+                    self.params, x, np.int32(length - 1 - start),
                     key))
 
         with graftscope.span("serving.prefill_tok0", cat="serving",
@@ -2802,7 +2904,7 @@ class ServingEngine:
                 f"prompt {length} + max_new_tokens "
                 f"{request.max_new_tokens} exceeds the slot capacity "
                 f"s_max={pool.s_max}")
-        if pool.free_slots < 1:
+        if pool.free_slots <= len(self._unread):
             raise QueueFull(
                 "no free slot for the transferred prefill; step this "
                 "engine and retry (graftroute holds the transfer)")
@@ -2861,7 +2963,7 @@ class ServingEngine:
                     k_dev = self._pref_sharded(jnp.asarray(k_pref))
                     v_dev = self._pref_sharded(jnp.asarray(v_pref))
                 self._insert(request, slot, k_dev, v_dev, length,
-                             jnp.int32(int(tok0)), prep=prep)
+                             np.int32(int(tok0)), prep=prep)
             except Exception as e:
                 self._abort_prep(prep)
                 self._poisoned(request, e, slot=slot)
@@ -2871,7 +2973,7 @@ class ServingEngine:
 
     def withdraw(self, uid) -> bool:
         """Abandon one request NOW, wherever it is — QUEUED,
-        mid-chunked-prefill, or RUNNING (ROADMAP item 4: an
+        mid-prefill, or RUNNING (ROADMAP item 4: an
         abandoned request otherwise decodes to its full token budget,
         burning slot-steps nobody will read). Eviction rides the
         existing quarantine machinery: a running request's slot has
@@ -2891,11 +2993,11 @@ class ServingEngine:
                 self._quarantine(request, err, reason="withdraw",
                                  slot=slot)
                 return True
-        pend = self._pending
-        if pend is not None and pend.request.uid == uid:
-            self._drop_pending()
-            self._quarantine(pend.request, err, reason="withdraw")
-            return True
+        for pend in self._joining():
+            if pend.request.uid == uid:
+                self._drop_joining(pend)
+                self._quarantine(pend.request, err, reason="withdraw")
+                return True
         request = self.scheduler.withdraw_uid(uid)
         if request is not None:
             self._quarantine(request, err, reason="withdraw")
@@ -2930,8 +3032,8 @@ class ServingEngine:
         residency (slots, pages, chunked-prefill prep buffers) must
         go; marking the ``Request`` records here would corrupt the
         redelivery path that now owns them. Idempotent."""
-        if self._pending is not None:
-            self._drop_pending()
+        for pend in self._joining():
+            self._drop_joining(pend)
         for slot in list(self._running):
             self._scrub_slot(slot)
             del self._running[slot]

@@ -475,8 +475,13 @@ class PagePool:
         """Return ``slot`` to the free list AND drop its page
         references (shared prefix pages survive while the cache or
         other slots still hold them). The row resets to scratch so
-        the frozen row's masked re-writes land in page 0, never in a
-        page that has been handed to a new tenant. The device-side
+        the frozen row's masked re-writes land in page 0 from the NEXT
+        dispatch on. A block already in flight (the engine's step is
+        pipelined one block deep) still carries the old row: its
+        frozen re-write of the pinned column lands in a page this call
+        frees, before, in device order, any insert or decode write of
+        that page's next owner — which writes every column before
+        reading it, so the stale row is never read. The device-side
         active flag is already False by then: the fused decode scan
         clears it when the row's EOS or budget gate fires, and the
         engine's quarantine/deadline eviction scrubs it

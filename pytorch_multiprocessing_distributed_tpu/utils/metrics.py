@@ -99,7 +99,9 @@ class ServingMetrics:
       host sync for H emitted tokens, so ``host_syncs_per_token``
       collapses from 1 toward 1/H — the whole point of horizon decode;
       ``overlapped_dispatches`` counts horizons launched BEFORE the
-      previous block's readback (the deferred-sync overlap);
+      previous block's readback (the engine's one-block-deep
+      pipeline: in steady state every dispatch but a cold start's, at
+      every horizon);
     - ``occupancy``: live slots at each decode step (the utilization
       the slot count should be tuned against);
     - ``queue_depth``: queued requests at each decode step (sustained

@@ -11,8 +11,11 @@ step cost tracks the longest ACTIVE sequence, not ``--s_max``), long
 prompts can prefill in fixed chunks interleaved with decode
 (``--prefill_chunk`` — no resident request stalls longer than one
 chunk), steady-state decode can fuse H steps into one dispatched scan
-with one (overlapped) readback per horizon (``--decode_horizon`` —
-host syncs/token = 1/H), speculative decode can verify up to K
+with one readback per horizon (``--decode_horizon`` — host
+syncs/token = 1/H; at every H, 1 included, the next block is
+dispatched before this one is read back, so the readback and the
+host's own work hide under a running decode program), speculative
+decode can verify up to K
 drafted tokens per target pass (``--draft_k`` [+ ``--draft_model``],
 graftspec — greedy only, byte-identical streams, 1..K+1 tokens per
 weight stream), and per-request tokens stream to stdout as they are
@@ -114,9 +117,10 @@ parser.add_argument('--prefill_chunk', default=0, type=int,
 parser.add_argument('--decode_horizon', default=1, type=int,
                     help='fuse up to H decode steps into one '
                          'dispatched lax.scan with ONE token readback '
-                         'per horizon (and overlapped readback in '
-                         'steady state) — host syncs/token drops to '
-                         '1/H; the horizon collapses to 1 while '
+                         'per horizon — host syncs/token drops to 1/H '
+                         '(the readback is hidden under the next '
+                         'block at every H: that no longer depends on '
+                         'this flag); the horizon collapses to 1 while '
                          'admission work is pending, so join latency '
                          'stays bounded (1 = per-step decode). '
                          'Compile cost: the {1, H} rung of the '

@@ -712,12 +712,16 @@ def test_queue_shed_counted_and_submit_retrying(chaos):
     with pytest.raises(QueueFull):
         small.submit(prompts[1], 2)
     assert small.metrics.requests_shed == 1
+    # admission is two stages a step apart: one step dispatches the
+    # first request's prefill (no token yet) and the queue has room
+    small.step()
+    small.submit(prompts[1], 2)
     # retrying submission drains the queue via step() and lands; the
     # drain steps' token events surface through events_out — an
     # event-driven caller would otherwise never see completions those
     # steps emitted
     events = []
-    request = small.submit_retrying(prompts[1], 2, attempts=64,
+    request = small.submit_retrying(prompts[2], 2, attempts=64,
                                     events_out=events)
     assert request.state in ("queued", "running", "done")
     assert events, "drain steps must surface their token events"

@@ -300,10 +300,16 @@ class TestEngineSpans:
 
     def test_one_paged_run_shows_every_span_properly_nested(self):
         """A miss, then a partial prefix hit two steps later, then the
-        first request finishes two steps before the second: every span
-        of an engine step is annotated, each inside its parent, and the
-        page table is uploaded on exactly the steps that follow an
-        admission or a release."""
+        first request finishes three steps before the second: every
+        span of an engine step is annotated, each inside its parent,
+        and the page table is uploaded on exactly the steps that follow
+        an admission or a release. The step is pipelined one block
+        deep and admission is two stages a step apart: a step that
+        dispatches a prefill reads nothing; the NEXT step reads the
+        first token, splices, and (the table now changed) uploads; the
+        first decoding step dispatches twice, every later one
+        dispatches before it drains the OLDER block, and the last step
+        only drains."""
         engine, first, second = self._paged_engine()
         with annotator(FakeAnnotator()) as fake, scoped() as s:
             engine.submit(first, 6)
@@ -313,7 +319,7 @@ class TestEngineSpans:
                 steps += 1
                 if steps == 2:
                     engine.submit(second, 6)
-        assert steps == 7
+        assert steps == 9
         parents = fake.parents()          # also: properly bracketed
         assert ({name for name, _p in parents}
                 == {PREFIX + name for name in ENGINE_SPANS})
@@ -349,11 +355,16 @@ class TestEngineSpans:
                     .attrs["admitted"] for g in groups]
         released = [any(e.name == "request.done" for e in g)
                     for g in groups]
-        assert admitted == [1, 0, 1, 0, 0, 0, 0]
-        assert released == [False] * 4 + [True, False, True]
-        assert uploaded == [bool(admitted[i]) or (i > 0 and released[i - 1])
+        assert admitted == [1, 0, 1, 0, 0, 0, 0, 0, 0]
+        assert released == [False] * 5 + [True, False, False, True]
+        assert uploaded == [i > 0 and bool(admitted[i - 1]
+                                           or released[i - 1])
                             for i in range(steps)]
-        assert uploaded == [True, False, True, False, False, True, False]
+        assert uploaded == [False, True, False, True, False, False, True,
+                            False, False]
+        dispatches = [[e.attrs["overlapped"] for e in g
+                       if e.name == "decode.dispatch"] for g in groups]
+        assert dispatches == [[], [False, True]] + [[True]] * 6 + [[]]
 
     def test_annotated_steady_state_adds_no_compile_or_transfer(self):
         """With the profiler's annotator set and no scope armed — the

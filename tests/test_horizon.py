@@ -1,7 +1,9 @@
 """Fused multi-step decode horizon: token-exactness vs the per-step
 engine and generate() (including EOS/budget freezes mid-horizon and
 ragged join/leave churn), the buckets x {1, H} compile ladder, the
-overlapped-readback bookkeeping, and the pure horizon-pick policy."""
+one-block-deep pipeline of the step (the next block dispatched before
+this one is read back, at every horizon, admissions and evictions
+under the block in flight) and the pure horizon-pick policy."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -80,31 +82,207 @@ def test_eos_freezes_mid_horizon(served):
     assert any(h > 1 for _, h in engine.decode_programs)
 
 
-def test_steady_state_sync_and_dispatch_budget(served):
-    """The dispatch-overhead contract: a queue-empty steady state at
-    H=4 makes ONE dispatch and ONE host sync per horizon — syncs per
-    decode token = 1/H — with the readback overlapped (horizon h+1
-    launched before h's block synced), and re-serving the same shape
-    compiles nothing new."""
+@pytest.mark.parametrize("h", [1, 4])
+def test_steady_state_sync_and_dispatch_budget(served, h):
+    """The dispatch-overhead contract: a solo request makes ONE
+    dispatch and ONE host sync per horizon — syncs per decode token =
+    1/H — with the readback hidden at EVERY horizon, 1 included (every
+    block but the cold start's launched before the previous block
+    synced), and re-serving the same shape compiles nothing new."""
     model, params, prompts = served
     engine = ServingEngine(model, params, max_slots=1, s_max=32,
                            min_bucket=8, decode_buckets=(),
-                           decode_horizon=4)
+                           decode_horizon=h)
     (request,) = engine.serve([(prompts[0], 13)])
     assert list(request.tokens) == _ref_tail(model, params,
                                              prompts[0], 13)
     snap = engine.metrics.snapshot()
-    # 12 decode tokens = 3 fused horizons of 4: one dispatch + one
-    # sync each, horizons 2 and 3 dispatched before the previous sync
-    assert snap["decode_dispatches"] == 3
-    assert snap["decode_host_syncs"] == 3
-    assert snap["overlapped_dispatches"] == 2
-    assert snap["decode_horizon_avg"] == 4.0
-    assert snap["host_syncs_per_token"] == pytest.approx(0.25)
-    assert engine.decode_programs == ((32, 4),)
+    # 12 decode tokens = 12 / H horizons: one dispatch + one sync
+    # each, none past the budget, all but the first dispatched before
+    # the previous sync
+    assert snap["decode_dispatches"] == 12 // h
+    assert snap["decode_host_syncs"] == 12 // h
+    assert snap["overlapped_dispatches"] == 12 // h - 1
+    assert snap["decode_horizon_avg"] == float(h)
+    assert snap["host_syncs_per_token"] == pytest.approx(1 / h)
+    assert engine.decode_programs == ((32, h),)
     # steady state: the same request shape retraces nothing
     engine.serve([(prompts[0], 13)])
-    assert engine.decode_programs == ((32, 4),)
+    assert engine.decode_programs == ((32, h),)
+
+
+def _drive(engine):
+    """Step to the end, one list of token events a step."""
+    steps = []
+    while engine.in_flight:
+        steps.append(engine.step())
+    return steps
+
+
+@pytest.mark.parametrize("edge", ["one-token", "two-tokens",
+                                  "eos-first", "eos-in-flight"])
+def test_pipeline_edges(served, edge):
+    """The ends of a stream under the pipeline, two slots and more
+    requests than slots: a request of one token (retired at its first
+    token, never holds a slot), of two (its only decode block is the
+    cold start's), an EOS on the first token, and an EOS sitting in
+    the block IN FLIGHT when a successor is admitted beside it — the
+    host still believes the row alive and dispatches it once more,
+    frozen. Every stream equals generate()'s."""
+    model, params, prompts = served
+    refs = [_ref_tail(model, params, p, 8) for p in prompts]
+    engine = ServingEngine(model, params, max_slots=2, s_max=32,
+                           min_bucket=8, decode_horizon=1)
+    if edge in ("one-token", "two-tokens"):
+        n = 1 if edge == "one-token" else 2
+        lengths = [n, 8, n, n, 5]
+        reqs = [engine.submit(p, k) for p, k in zip(prompts, lengths)]
+        _drive(engine)
+        for r, ref, k in zip(reqs, refs, lengths):
+            assert r.finish_reason == "length"
+            assert list(r.tokens) == ref[:k]
+    elif edge == "eos-first":
+        reqs = [engine.submit(p, 8, eos_id=(ref[0] if i % 2 == 0
+                                            else None))
+                for i, (p, ref) in enumerate(zip(prompts, refs))]
+        _drive(engine)
+        for i, (r, ref) in enumerate(zip(reqs, refs)):
+            if i % 2 == 0:
+                assert r.finish_reason == "eos"
+                assert list(r.tokens) == ref[:1]
+            else:
+                assert list(r.tokens) == ref
+    else:
+        # c's last token comes back in block 2; a's EOS is token 3, in
+        # block 3, which is in flight when b takes c's slot
+        assert refs[0][3] not in refs[0][:3]
+        a = engine.submit(prompts[0], 8, eos_id=refs[0][3])
+        c = engine.submit(prompts[1], 3)
+        b = engine.submit(prompts[2], 8)
+        d = engine.submit(prompts[3], 8)
+        steps = _drive(engine)
+        assert a.finish_reason == "eos" and list(a.tokens) == refs[0][:4]
+        assert list(c.tokens) == refs[1][:3]
+        assert list(b.tokens) == refs[2] and list(d.tokens) == refs[3]
+        joined = next(i for i, ev in enumerate(steps)
+                      if any(r is b for r, _t, _fin in ev))
+        # b's first token is read a step after its prefill was
+        # dispatched, and that was the step that drained a's EOS:
+        # the prefill queued behind the block holding it
+        assert any(r is a and fin for r, _t, fin in steps[joined - 1])
+    assert engine.pool.occupancy == 0
+    assert engine.in_flight == 0 and not engine._blocks
+
+
+@pytest.mark.parametrize("driver", ["run", "serve", "drain"])
+def test_every_drive_loop_ends_with_nothing_in_flight(served, driver):
+    """run(), serve() and drain() all read the last block back: the
+    pipeline leaves no dispatched block behind, and ``in_flight``
+    counts requests (a block in flight under running requests is not
+    one more unit of load)."""
+    model, params, prompts = served
+    engine = ServingEngine(model, params, max_slots=2, s_max=32,
+                           min_bucket=8, decode_horizon=1)
+    if driver == "serve":
+        reqs = engine.serve([(p, 6) for p in prompts])
+    else:
+        reqs = [engine.submit(p, 6) for p in prompts]
+        engine.step()
+        # two prefills dispatched and unread, nothing running yet
+        assert not engine._blocks and engine.in_flight == 5
+        engine.step()
+        # two running under one block in flight, three queued
+        assert len(engine._blocks) == 1 and engine.in_flight == 5
+        if driver == "run":
+            for _ in engine.run():
+                pass
+        else:
+            engine.drain()
+    assert engine.in_flight == 0 and not engine._blocks
+    assert engine.pool.occupancy == 0
+    for r, p in zip(reqs, prompts):
+        assert list(r.tokens) == _ref_tail(model, params, p, 6)
+
+
+@pytest.mark.parametrize("how", ["withdraw", "deadline"])
+def test_eviction_of_a_slot_named_by_the_block_in_flight(served, how):
+    """A running request is evicted (client withdrawal; deadline
+    expiry) while the undrained block still names its slot with a
+    LIVE row: its scrub and its successor's insert queue behind that
+    block, the block's tokens for the slot are dropped at the drain,
+    and every other stream, the successor's in the same slot
+    included, equals generate()'s."""
+    from pytorch_multiprocessing_distributed_tpu.serving.scheduler import (
+        FAILED)
+
+    model, params, prompts = served
+    engine = ServingEngine(model, params, max_slots=2, s_max=32,
+                           min_bucket=8, decode_horizon=1)
+    engine.serve([(p, 3) for p in prompts[:2]])      # compiled, warm
+    doomed = engine.submit(prompts[0], 12, deadline_s=(
+        3600.0 if how == "deadline" else None))
+    peer = engine.submit(prompts[1], 12)
+    heir = engine.submit(prompts[2], 6)
+    for _ in range(3):
+        engine.step()
+    (block,) = engine._blocks
+    assert block.slots[doomed.slot] is doomed       # named, and alive
+    slot, had = doomed.slot, len(doomed.tokens)
+    if how == "withdraw":
+        assert engine.withdraw(doomed.uid)
+    else:
+        doomed.deadline_s = 0.0              # overdue at the next step
+    events = engine.step()
+    assert doomed.state == FAILED and len(doomed.tokens) == had
+    assert doomed.finish_reason == how
+    assert not any(r is doomed for r, _t, _f in events)
+    # the heir's prefill went out under that block; it takes the slot
+    # a step later, when its first token is read
+    assert heir.slot is None and engine.in_flight == 2
+    engine.step()
+    assert heir.slot == slot
+    _drive(engine)
+    assert list(peer.tokens) == _ref_tail(model, params, prompts[1], 12)
+    assert list(heir.tokens) == _ref_tail(model, params, prompts[2], 6)
+    assert engine.pool.occupancy == 0 and engine.pool.pages_in_use == 0
+    assert engine.in_flight == 0 and not engine._blocks
+
+
+@pytest.mark.parametrize("how", ["withdraw", "deadline"])
+def test_eviction_between_the_two_stages_of_an_admission(served, how):
+    """A request whose prefill is dispatched and whose first token is
+    still unread (admission's two stages are a step apart) is neither
+    queued nor running: withdrawal and deadline expiry find it there,
+    return its reserved pages and the slot held back for it, and it
+    never emits a token; its neighbour's stream is generate()'s."""
+    from pytorch_multiprocessing_distributed_tpu.serving.scheduler import (
+        FAILED)
+
+    model, params, prompts = served
+    engine = ServingEngine(model, params, max_slots=2, s_max=32,
+                           min_bucket=8, decode_horizon=1)
+    peer = engine.submit(prompts[1], 8)
+    engine.step()
+    engine.step()
+    held = engine.pool.pages_in_use
+    doomed = engine.submit(prompts[0], 8, deadline_s=(
+        3600.0 if how == "deadline" else None))
+    engine.step()
+    assert [p.request for p in engine._unread] == [doomed]
+    assert doomed.slot is None and engine.pool.pages_in_use > held
+    assert engine.in_flight == 2
+    if how == "withdraw":
+        assert engine.withdraw(doomed.uid)
+    else:
+        doomed.deadline_s = 0.0              # overdue at the next step
+        engine.step()
+    assert doomed.state == FAILED and doomed.finish_reason == how
+    assert not doomed.tokens and doomed.first_token_time is None
+    assert not engine._unread and engine.pool.free_slots == 1
+    _drive(engine)
+    assert list(peer.tokens) == _ref_tail(model, params, prompts[1], 8)
+    assert engine.pool.occupancy == 0 and engine.pool.pages_in_use == 0
+    assert engine.in_flight == 0 and not engine._blocks
 
 
 def test_queue_pressure_collapses_horizon(served):

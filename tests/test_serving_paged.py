@@ -81,6 +81,67 @@ def test_paged_matches_generate(served):
     assert paged.pool.pages_in_use == 0
 
 
+def _count_joins_under_a_block(engine):
+    """Wrap ``_admit`` on the instance (the engine looks it up at
+    every step, as the benchmark's traced runs rely on): counts the
+    tenants that joined while a dispatched block was still unread."""
+    seen = {"under_block": 0, "joined": 0}
+    inner = engine._admit
+
+    def admit():
+        blocks = len(engine._blocks)
+        before = list(engine._running.values())
+        events = inner()
+        joined = sum(all(r is not b for b in before)
+                     for r in engine._running.values())
+        seen["joined"] += joined
+        seen["under_block"] += joined if blocks else 0
+        return events
+
+    engine._admit = admit
+    return seen
+
+
+@pytest.mark.parametrize("options, holds", [
+    (dict(max_slots=2), False),
+    (dict(max_slots=2, prefill_chunk=4), False),
+    (dict(max_slots=3, page_size=4, num_pages=12), True),
+], ids=["whole-prompt", "chunked", "page-hold"])
+def test_pipelined_reuse_of_slots_and_pages_matches_generate(
+        served, options, holds):
+    """The step is pipelined one block deep at horizon 1: with more
+    requests than slots (and, in the last case, fewer pages than the
+    slots could use, so the head is HELD), slots and pages are handed
+    to the next tenant while a block dispatched for the previous one
+    is still in flight. Its frozen row's stale write precedes the
+    successor's insert in device order; every stream equals
+    generate()'s and nothing leaks."""
+    model, params, prompts = served
+    rng = np.random.default_rng(7)
+    work = [(p, n) for p, n in zip(
+        prompts + [rng.integers(0, 61, (k,)).tolist()
+                   for k in (9, 2, 14, 6)],
+        (12, 6, 9, 10, 8, 11, 1, 7, 9))]
+    engine = _paged(model, params, **options)
+    seen = _count_joins_under_a_block(engine)
+    got = engine.serve(work)
+    for r, (p, n) in zip(got, work):
+        np.testing.assert_array_equal(
+            np.asarray(r.tokens), _ref_tail(model, params, p, n),
+            err_msg=f"prompt len {len(p)}, {n} tokens")
+    snap = engine.metrics.snapshot()
+    # the one-token request never held a slot; most of the others
+    # joined under a block; every dispatch was made before the
+    # previous block was read but the cold starts' (the first, and one
+    # where the only running request was at its last token while its
+    # successor's first token was still unread)
+    assert seen["joined"] == 8 and seen["under_block"] >= 5
+    assert snap["decode_dispatches"] - snap["overlapped_dispatches"] <= 2
+    assert (snap["page_holds"] > 0) == holds
+    assert engine.pool.pages_in_use == 0
+    assert engine.in_flight == 0 and not engine._blocks
+
+
 @pytest.mark.slow
 def test_paged_chunked_horizon_eos(served):
     """Chunked admission + fused H=4 horizons + an EOS that fires

@@ -198,7 +198,8 @@ def test_absorbed_decode_equals_decompressed_prefill(tiny, impl):
 
 
 def _serve(model, params, requests, **kw):
-    engine = ServingEngine(model, params, max_slots=3, s_max=256,
+    kw.setdefault("max_slots", 3)
+    engine = ServingEngine(model, params, s_max=256,
                            kv_layout="paged", page_size=8, **kw)
     out = [engine.submit(list(p), n) for p, n in requests]
     while engine.in_flight:
@@ -237,6 +238,30 @@ def test_engine_prefill_then_paged_decode_float32(tiny, chunk):
         snap["decode_dispatches"] * 3 * model.moe_top_k
         * model.n_moe_layers)
     assert snap["moe_load_max_over_mean"] >= 1.0
+
+
+def test_engine_pipelined_step_reuses_slots_under_a_block(tiny):
+    """The engine's one-block-deep pipeline is one rule for every
+    family: five requests through two slots, chunked admission, every
+    dispatch but the cold start's made before the previous block (and
+    its expert counts) was read back, slots and latent pages handed on
+    while a block still names the previous tenant — and every token is
+    still the reference's own argmax."""
+    model, params = tiny
+    prompts = [(_tokens(40, 1), 9), (_tokens(33, 2), 14),
+               (_tokens(21, 3), 1), (_tokens(50, 4), 6),
+               (_tokens(17, 5), 8)]
+    engine, served = _serve(model, params, prompts, max_slots=2,
+                            prefill_chunk=16)
+    ref_fn = reference.make_logits_fn(_config(model))
+    for request, (_, n) in zip(served, prompts):
+        assert len(request.tokens) == n
+        assert _gaps(ref_fn, params, request).max() == 0.0
+    snap = engine.metrics.snapshot()
+    assert snap["overlapped_dispatches"] == snap["decode_dispatches"] - 1
+    assert snap["decode_host_syncs"] == snap["decode_dispatches"]
+    assert engine.in_flight == 0 and not engine._blocks
+    assert engine.pool.pages_in_use == 0
 
 
 def test_engine_bfloat16_within_its_tolerance(ref_logits):
