@@ -30,6 +30,8 @@ from .vit import ViT, ViT_B16, ViT_S16, ViT_Tiny
 from .convnext import ConvNeXt, ConvNeXt_T, ConvNeXt_S, ConvNeXt_B, ConvNeXt_L
 from .gpt import GPT, GPT_Small, GPT_Medium, GPT_Tiny
 from .xing4 import Xing4, Xing4_29B_A4B, Xing4_Tiny
+from .pangu_ultra_moe import (PanguUltraMoE, PanguUltraMoE_718B,
+                              PanguUltraMoE_Tiny)
 
 __all__ = [
     "BasicBlock",
@@ -48,5 +50,6 @@ __all__ = [
     "ConvNeXt", "ConvNeXt_T", "ConvNeXt_S", "ConvNeXt_B", "ConvNeXt_L",
     "GPT", "GPT_Small", "GPT_Medium", "GPT_Tiny", "LM_MODELS",
     "Xing4", "Xing4_29B_A4B", "Xing4_Tiny",
+    "PanguUltraMoE", "PanguUltraMoE_718B", "PanguUltraMoE_Tiny",
 ]
 

@@ -260,49 +260,74 @@ def route_sigmoid_topk(x32, router, e_bias, top_k: int,
     ``noaux_tc`` method with one group): ``s = sigmoid(x Wg)`` in
     float32 at the highest matmul precision (the top-k of near-equal
     scores must not turn on a bf16 pass of the MXU); the ``top_k`` of
-    ``s + e_bias`` are CHOSEN, the weights come from ``s`` alone,
-    normalised over the chosen and scaled. ``x32 [T, D]`` ->
-    ``(experts [T, k] int32, weights [T, k] f32)``."""
+    ``s + e_bias`` (of ``s`` alone where ``e_bias`` is None) are
+    CHOSEN, the weights come from ``s`` alone, normalised over the
+    chosen and scaled. The router scores ALL experts of the model,
+    whichever of them this chip holds. ``x32 [T, D]`` -> ``(experts
+    [T, k] int32, weights [T, k] f32)``."""
     scores = jax.nn.sigmoid(jnp.dot(
         x32.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(scores + e_bias.astype(jnp.float32), top_k)
+    biased = (scores if e_bias is None
+              else scores + e_bias.astype(jnp.float32))
+    _, chosen = jax.lax.top_k(biased, top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     weights = (picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
                * routed_scale)
     return chosen.astype(jnp.int32), weights
 
 
-def dropless_experts(x, chosen, weights, w_gate, w_up, w_down):
+def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, *,
+                     n_experts=None, offset: int = 0):
     """The ONE dropless routed-expert layer (prefill, chunk and decode
-    alike): every (token, choice) assignment is computed by the expert
-    it names — no capacity, nothing dropped, every shape static.
+    alike): every (token, choice) assignment to an expert HELD here is
+    computed by the expert it names — no capacity, nothing dropped,
+    every shape static.
 
-    The ``T * k`` assignments are sorted by expert (stable, so a
-    token's choices keep their order), each expert's rows then form one
-    contiguous group and the three gated-SiLU matmuls are grouped
-    matmuls over those groups (``jax.lax.ragged_dot``: on the TPU a
-    native kernel that does ``2 * T * k * D * F`` operations a matrix
-    and reads only the experts that have rows), and the outputs are
+    The weights are those of the ``held = w_gate.shape[0]`` experts
+    ``[offset, offset + held)`` of the ``n_experts`` the router chose
+    among (default: all of them are held). An assignment to any other
+    expert belongs to another chip: it is counted and left out of the
+    sum, and no code stands in for that chip or for the exchange with
+    it. The combine weights were normalised over all of a token's
+    choices, held or not, so the shares of all chips add up to the
+    whole layer.
+
+    The ``T * k`` assignments are sorted by expert, this chip's first
+    (stable, so a token's choices keep their order); each held expert's
+    rows then form one contiguous group from row 0 and the three
+    gated-SiLU matmuls are grouped matmuls over those groups
+    (``jax.lax.ragged_dot``: on the TPU a native kernel that reads only
+    the experts that have rows; the rows routed elsewhere lie behind
+    the last group, in none, and cost no matmul), and the outputs are
     weighted, put back in token order and summed over a token's
     choices.
 
     Args:
       x: ``[T, D]`` tokens in the compute dtype.
-      chosen: ``[T, k]`` int32 expert ids (:func:`route_sigmoid_topk`).
+      chosen: ``[T, k]`` int32 expert ids in ``[0, n_experts)``
+        (:func:`route_sigmoid_topk`).
       weights: ``[T, k]`` float32 combine weights.
-      w_gate, w_up: ``[E, D, F]``; w_down: ``[E, F, D]``.
+      w_gate, w_up: ``[held, D, F]``; w_down: ``[held, F, D]``.
 
-    Returns ``(y [T, D] float32, counts [E] int32)`` — ``counts`` is
-    the number of assignments each expert received (they sum to
-    ``T * k``: the dropless invariant, and the load the serving
-    metrics report).
+    Returns ``(y [T, D] float32, counts [held] int32, elsewhere int32)``
+    — the assignments each held expert received and the number routed
+    to experts not held here; together they sum to ``T * k`` (the
+    dropless invariant, and the load the serving metrics report).
     """
     t, k = chosen.shape
-    n_experts = w_gate.shape[0]
+    held = w_gate.shape[0]
+    n_experts = held if n_experts is None else int(n_experts)
+    if not 0 <= offset <= n_experts - held:
+        raise ValueError(
+            f"experts [{offset}, {offset + held}) are not among the "
+            f"{n_experts} the router chooses from")
     flat = chosen.reshape(t * k)
-    order = jnp.argsort(flat, stable=True)               # [T*k]
-    counts = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
+    # this chip's experts become 0 .. held - 1, the others follow
+    key = flat if offset == 0 else (flat - offset) % n_experts
+    order = jnp.argsort(key, stable=True)                # [T*k]
+    every = jnp.zeros((n_experts,), jnp.int32).at[key].add(1)
+    counts, elsewhere = every[:held], jnp.sum(every[held:])
     rows = jnp.take(x, order // k, axis=0)               # [T*k, D]
     gate = jax.lax.ragged_dot(rows, w_gate, counts,
                               preferred_element_type=jnp.float32)
@@ -312,7 +337,12 @@ def dropless_experts(x, chosen, weights, w_gate, w_up, w_down):
     out = jax.lax.ragged_dot(hidden, w_down, counts,
                              preferred_element_type=jnp.float32)
     out = out * jnp.take(weights.reshape(t * k), order)[:, None]
+    if held < n_experts:
+        # a row behind the last group is in no matmul: whatever the
+        # grouped kernel left there, it adds nothing
+        out = jnp.where((jnp.arange(t * k) < jnp.sum(counts))[:, None],
+                        out, 0.0)
     # back to (token, choice) order: the inverse permutation is a
     # scatter of whole rows, then a sum over each token's k choices
     y = jnp.zeros_like(out).at[order].set(out)
-    return jnp.sum(y.reshape(t, k, -1), axis=1), counts
+    return jnp.sum(y.reshape(t, k, -1), axis=1), counts, elsewhere

@@ -547,6 +547,12 @@ class ServingEngine:
                     f"{option} is not supported for the {family.name} "
                     f"family yet: {family.refuses[option]}")
         self._family = family
+        # a family with routed experts returns their load behind every
+        # token block, a column a held expert and one for the rest;
+        # every decode.dispatch event says how many it holds
+        aux = family.aux_shape(model)
+        self._expert_note = ({} if aux is None
+                             else {"experts_held": aux[-1] - 1})
         self.model = model
         self.params = params
         self.mesh = mesh
@@ -2317,7 +2323,8 @@ class ServingEngine:
                 _TokenBlock(tokens, h, window, dict(self._running), k=k))
             self.metrics.record_dispatch(h, overlapped)
             dispatch_span.note(window=window, horizon=h, draft_k=k,
-                               occupancy=pool.occupancy)
+                               occupancy=pool.occupancy,
+                               **self._expert_note)
 
     def _overlap_ok(self) -> bool:
         """Dispatch the next block before the block in flight is read

@@ -176,9 +176,13 @@ class ServingMetrics:
         self.tokens_accepted = 0
         self.accept_len = PercentileMeter()
         # routed-expert load of the decode steps (families with
-        # experts): assignments computed, and the busiest expert's
-        # load over the mean load, averaged over layers and blocks
+        # experts): assignments computed by the experts held here,
+        # assignments routed to experts this chip does not hold (a
+        # share of an expert-parallel deployment; 0 when every expert
+        # is held), and the busiest held expert's load over the held
+        # experts' mean load, averaged over layers and blocks
         self.moe_assignments = 0
+        self.moe_assignments_elsewhere = 0
         self.moe_load = AverageMeter()
         self._elapsed = 0.0
         self._occupancy_max = 0
@@ -304,10 +308,13 @@ class ServingMetrics:
 
     def record_moe(self, counts) -> None:
         """One drained block's per-layer expert assignment counts
-        (``[layers, experts]``; they came back in the token block's
-        own readback). Dropless: a layer's counts sum to tokens x
-        top-k."""
+        (``[layers, held + 1]``: a column a held expert, the last one
+        the assignments routed to experts held elsewhere; they came
+        back in the token block's own readback). Dropless: a layer's
+        row sums to tokens x top-k."""
+        counts, elsewhere = counts[:, :-1], counts[:, -1]
         self.moe_assignments += int(counts.sum())
+        self.moe_assignments_elsewhere += int(elsewhere.sum())
         means = counts.mean(axis=1)
         live = means > 0
         if live.any():
@@ -376,6 +383,11 @@ class ServingMetrics:
                 0.0 if self.accept_len.count == 0
                 else 1.0 + self.accept_len.avg),
             "moe_assignments": self.moe_assignments,
+            "moe_assignments_elsewhere": self.moe_assignments_elsewhere,
+            "moe_held_share": (
+                0.0 if self.moe_assignments == 0
+                else self.moe_assignments
+                / (self.moe_assignments + self.moe_assignments_elsewhere)),
             "moe_load_max_over_mean": self.moe_load.avg,
         }
         # graftscope percentile telemetry: the tail IS the SLO

@@ -68,11 +68,15 @@ parser = argparse.ArgumentParser(
     description="TPU-native continuous-batching LM serving")
 parser.add_argument('--model', default='gpt_tiny', type=str,
                     help='gpt_tiny | gpt_small | gpt_medium | '
-                         'xing4_tiny | xing4_29b_a4b')
+                         'xing4_tiny | xing4_29b_a4b | '
+                         'pangu_ultra_moe_tiny | pangu_ultra_moe_718b')
 parser.add_argument('--model_kwargs', default='', type=str,
                     help='JSON object of keywords for the registry '
                          'constructor, e.g. the depth one serving stage '
-                         'holds: \'{"num_layers": 5, "first_k_dense": 1}\'')
+                         'holds: \'{"num_layers": 5, "first_k_dense": 1}\', '
+                         'or with it one chip\'s share of an '
+                         'expert-parallel stage: \'{..., "experts_held": '
+                         '16, "expert_offset": 0, "vocab_size": 19200}\'')
 parser.add_argument('--ckpt', default='', type=str,
                     help='msgpack model_<epoch>.pth file, or an orbax '
                          'run directory (train_lm.py --save_path)')

@@ -207,6 +207,10 @@ def _mla_case(slots=64, heads=32, rank=512, rope=128, page=16,
 
 _CASES = _DECODE + [
     pytest.param(_mla_case, id="mla-paged-decode-xing4-64slots-w8192"),
+    # the cell openpangu-ultra-moe-718b.serve.closed-2k1k: 128 slots,
+    # 128 heads (242 operations a byte of cache), window 4,096
+    pytest.param(lambda: _mla_case(slots=128, heads=128, window=4096),
+                 id="mla-paged-decode-pangu-128slots-128heads-w4096"),
     pytest.param(lambda: _flash_case(B, S, False), id="flash-fwd-s1024"),
     pytest.param(lambda: _flash_case(B, S, True), id="flash-fwdbwd-s1024"),
     pytest.param(lambda: _flash_case(1, 4096, True),
@@ -286,23 +290,31 @@ def test_gpt_small_train_step_compiles_with_its_kernel(topo, monkeypatch,
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 16e9
 
 
-def test_xing4_decode_program_compiles_at_one_layer(topo):
-    """The decode program of the cell ``xing4-29b-a4b.serve.closed-
-    4k1k`` (64 slots, window 8,192, pages of 16, bfloat16) at ONE
-    expert layer of the published widths, through ``ServingEngine``:
-    the latent kernel compiles inside it beside the grouped expert
-    matmuls, and the donated page pools are written in place (the
-    program holds no second copy of them: ``ROADMAP.md`` A2)."""
+@pytest.mark.parametrize("name, kwargs, slots, s_max", [
+    ("xing4_29b_a4b", {}, 64, 8192),
+    ("pangu_ultra_moe_718b", dict(experts_held=16, vocab_size=19200),
+     128, 4096),
+], ids=["xing4-64slots-w8192", "pangu-16-of-256-experts-128slots-w4096"])
+def test_latent_decode_program_compiles_at_one_layer(topo, name, kwargs,
+                                                     slots, s_max):
+    """The decode programs of the cells ``xing4-29b-a4b.serve.closed-
+    4k1k`` (64 slots, window 8,192) and ``openpangu-ultra-moe-718b.
+    serve.closed-2k1k`` (128 slots, window 4,096, 16 of 256 experts
+    held), pages of 16, bfloat16, at ONE expert layer of the published
+    widths, through ``ServingEngine``: the latent kernel compiles
+    inside it beside the grouped expert matmuls, and the donated page
+    pools are written in place (the program holds no second copy of
+    them: ``ROADMAP.md`` A2)."""
     from perf.rehearse import as_chip
     from pytorch_multiprocessing_distributed_tpu import models
     from pytorch_multiprocessing_distributed_tpu.serving import ServingEngine
 
     chip = SingleDeviceSharding(topo.devices[0])
-    model = models.get_model("xing4_29b_a4b", dtype=BF16, num_layers=1,
-                             first_k_dense=0)
+    model = models.get_model(name, dtype=BF16, num_layers=1,
+                             first_k_dense=0, **kwargs)
     params = jax.eval_shape(lambda: model._init(jax.random.PRNGKey(0)))
     with as_chip(chip):
-        engine = ServingEngine(model, params, max_slots=64, s_max=8192,
+        engine = ServingEngine(model, params, max_slots=slots, s_max=s_max,
                                kv_layout="paged", page_size=16,
                                prefill_chunk=1024)
         assert engine.decode_attn == "pallas"
@@ -316,12 +328,17 @@ def test_xing4_decode_program_compiles_at_one_layer(topo):
                 sds(pool.positions), sds(pool.last_tokens),
                 sds(pool.active), sds(pool.budgets), sds(pool.eos_ids),
                 jax.ShapeDtypeStruct((2,), jnp.uint32))
-        compiled = engine._decode.lower(*args, window=8192,
+        compiled = engine._decode.lower(*args, window=s_max,
                                         horizon=1).compile()
-    assert "mla_paged_decode_attention" in _mosaic_names(compiled.as_text())
+    text = compiled.as_text()
+    assert "mla_paged_decode_attention" in _mosaic_names(text)
+    # the tokens and, behind them, the expert layer's load: a column a
+    # held expert and one for the assignments routed elsewhere
+    assert f"s32[{slots + model.n_held + 1}]" in text
     mem = compiled.memory_analysis()
     pools = pool.k_pages.nbytes + pool.v_pages.nbytes
-    assert pools == 32769 * 16 * (512 + 128) * 2 and not pool.v_pages.size
+    assert pools == ((slots * s_max // 16 + 1) * 16 * (512 + 128) * 2
+                     ) and not pool.v_pages.size
     # temporaries far below one copy of the pools: written in place
     assert mem.temp_size_in_bytes < pools // 4, mem
     assert mem.alias_size_in_bytes >= pools

@@ -6,10 +6,11 @@ test and measured end to end by the same drivers; refusals by name; the
 perf/tests``; its cases are collected here too, so that a change to the
 program that breaks the door fails tier-1. Beside them:
 ``harness.check_manifest`` over the tree as it stands, the files of
-every cell resolved, and the ``xing4_0`` configuration's file against
-the catalog row it was copied from (``tests/fixtures/
-xing4_0_catalog_row.json``: the row of the ``model-configs`` guide's
-``architectures.jsonl``, which is not in the repository).
+every cell resolved, and the ``xing4_0`` and ``pangu_ultra_moe``
+configurations' files against the catalog rows they were copied from
+(``tests/fixtures/xing4_0_catalog_row.json``,
+``openpangu_ultra_moe_catalog_row.json``: rows of the ``model-configs``
+guide's ``architectures.jsonl``, which is not in the repository).
 """
 
 import json
@@ -217,5 +218,216 @@ def test_the_float8_control_emits_tokens_the_reference_ranks_lower(
     # at this width (logits of standard deviation ~0.15 over 211
     # entries) few near-ties flip; that some do is what is pinned here,
     # the readings against the limits are the chip's (PERF.md)
+    assert control["checks"]["mean_logit_gap"] > 1e-4
+    assert control["checks"]["worst_logit_gap"] > 1e-2
+
+
+# ------------------------------------------- openpangu-ultra-moe-718b
+
+PANGU_CELL = "openpangu-ultra-moe-718b.serve.closed-2k1k"
+
+
+def test_the_pangu_cell_holds_the_parameters_issue_33_names():
+    serve = harness.load_cell(PANGU_CELL)
+    assert serve.chips == 1 and serve.kind == "serve"
+    want = {"dtype": "bfloat16", "max_slots": 128, "s_max": 4096,
+            "kv_dtype": "model", "page_size": 16, "num_pages": None,
+            "prefill_chunk": 1024, "decode_horizon": 1,
+            "decode_attn": "auto", "prefix_cache": 0, "draft_k": 0,
+            "temperature": 0.0}
+    assert {k: serve.options[k] for k in want} == want
+    mix = serve.traffic
+    assert (mix["loop"], mix["clients"]) == ("closed", "max_slots")
+    assert mix["prompt_len"] == {"dist": "fixed", "value": 2048}
+    assert mix["output_len"] == {"dist": "fixed", "value": 1024}
+    assert (mix["pool_requests"], mix["stagger_per_step"],
+            mix["first_turn"], mix["warmup_completions"]) == (
+                128, 2, "uniform_age", 16)
+    reported = {m["name"] for m in serve.end_to_end + serve.per_layer}
+    xing4 = harness.load_cell("xing4-29b-a4b.serve.closed-4k1k")
+    # every metric of the family it shares the latent kernel with
+    assert reported == {m["name"]
+                        for m in xing4.end_to_end + xing4.per_layer}
+    assert "routed_expert_matmul_ms.serve" in reported
+    assert "paged_decode_attn_ms.serve" not in reported
+
+
+def test_pangu_configuration_equals_its_catalog_row_but_for_the_cut():
+    with open(os.path.join(HERE, "fixtures",
+                           "openpangu_ultra_moe_catalog_row.json")) as f:
+        row = json.load(f)
+    with open(os.path.join(harness.ROOT, "perf", "configs",
+                           "openpangu-ultra-moe-718b.json")) as f:
+        held = json.load(f)
+    assert held["source"] == row["source_url"]
+    assert held["model_type"] == row["config"]["model_type"]
+    assert held["reduced"] == [
+        "num_hidden_layers", "first_k_dense_replace", "n_routed_experts",
+        "vocab_size", "num_nextn_predict_layers"]
+    assert set(held["published"]) == set(held["reduced"])
+    for key, value in row["config"].items():
+        if key in held["reduced"]:
+            assert held[key] != value
+            assert held["published"][key] == value, key
+        else:
+            assert held[key] == value, key
+    assert (held["num_hidden_layers"], held["first_k_dense_replace"],
+            held["n_routed_experts"], held["vocab_size"],
+            held["num_nextn_predict_layers"]) == (5, 1, 16, 19200, 0)
+    # the guide's floors: four expert layers, 8 experts, an eighth
+    assert held["num_hidden_layers"] - held["first_k_dense_replace"] >= 4
+    assert held["n_routed_experts"] >= 8
+    assert held["vocab_size"] * 8 >= held["published"]["vocab_size"]
+    for key in ("assumed", "departures", "deployment"):
+        assert held[key], key
+    assert "16 chips share each layer" in held["deployment"]
+    # no width is reduced
+    widths = {"hidden_size", "intermediate_size", "moe_intermediate_size",
+              "kv_lora_rank", "q_lora_rank", "qk_nope_head_dim",
+              "qk_rope_head_dim", "v_head_dim", "num_experts_per_tok",
+              "num_attention_heads"}
+    assert not widths & set(held["reduced"])
+
+
+def test_pangu_config_file_builds_the_registry_model():
+    """perf/families/pangu_ultra_moe.py holds the registry model to
+    every size of the configuration's file (the share and the slice
+    too), and counts what the share's mathematics requires."""
+    import jax.numpy as jnp
+
+    from pytorch_multiprocessing_distributed_tpu import models
+
+    config = harness.load_cell(PANGU_CELL).config
+    family = families.load(config)
+    model = family.build_model(config, "bfloat16", "cpu")
+    assert model == models.get_model(
+        "pangu_ultra_moe_718b", dtype=jnp.bfloat16, num_layers=5,
+        first_k_dense=1, experts_held=16, expert_offset=0, vocab_size=19200)
+    assert model.n_experts == family.router_width(config) == 256
+    assert family.kv_bytes_per_token(config) == 5760
+    work = family.kernel_work(config, "mla_paged_decode_attention", {
+        "context_lens": [1], "dtype": "bfloat16", "kv_dtype": "bfloat16"})
+    assert work["ops"] == 278528 * 5
+    assert work["bytes"] == 5 * (1152 + 128 * 1088 * 2)
+    # 242 operations a byte of cache: the chip's ridge is 240.5
+    assert 278528 / 1152 == pytest.approx(241.8, abs=0.1)
+    # a token's weights: attention, then dense or router + shared + the
+    # EXPECTED 0.5 held assignments of its 8
+    attention, expert = 196_575_232, 3 * 7680 * 2048
+    assert family.block_params_per_token(config) == (
+        5 * attention + 3 * 7680 * 18432
+        + 4 * (7680 * 256 + 1.5 * expert))
+    decode = family.kernel_work(config, "forward.decode",
+                                {"context_lens": [100]})
+    assert decode["ops"] == 2.0 * (family.block_params_per_token(config)
+                                   + 7680 * 19200) + 278528.0 * 100 * 5
+    for key, bad in (("kv_lora_rank", 256), ("sandwich_norm", False),
+                     ("num_nextn_predict_layers", 1)):
+        with pytest.raises(harness.ManifestError, match=key):
+            family.build_model({**config, key: bad}, "bfloat16", "cpu")
+    with pytest.raises(harness.ManifestError, match="served, not trained"):
+        family.compare_loss(config, None, None)
+
+
+def _tiny_pangu_cell():
+    """The new serving cell's files with the model swapped for
+    ``pangu_ultra_moe_tiny`` holding 4 of its 16 experts and every size
+    cut: the driver, the family door, the engine and the reference end
+    to end on the CPU. A rehearsal carries no metric."""
+    import dataclasses
+
+    from pytorch_multiprocessing_distributed_tpu import models
+
+    cell = harness.load_cell(PANGU_CELL)
+    model = models.get_model("pangu_ultra_moe_tiny")
+    config = {
+        **cell.config, "name": "pangu-ultra-moe-tiny",
+        "registry_name": "pangu_ultra_moe_tiny",
+        "vocab_size": model.vocab_size,
+        "max_position_embeddings": model.max_seq_len,
+        "hidden_size": model.hidden_size, "num_hidden_layers": 3,
+        "first_k_dense_replace": 1,
+        "num_attention_heads": model.num_heads,
+        "num_key_value_heads": model.num_heads,
+        "q_lora_rank": model.q_lora_rank,
+        "kv_lora_rank": model.kv_lora_rank,
+        "qk_nope_head_dim": model.qk_nope_head_dim,
+        "qk_rope_head_dim": model.qk_rope_head_dim,
+        "v_head_dim": model.v_head_dim, "intermediate_size": model.mlp_dim,
+        "moe_intermediate_size": model.moe_dim,
+        "n_routed_experts": 4, "expert_offset": 8,
+        "published": {"n_routed_experts": model.n_experts},
+        "num_experts_per_tok": model.moe_top_k,
+        "rope_theta": model.rope_theta}
+    return dataclasses.replace(
+        cell, config=config,
+        options={**cell.options, "dtype": "float32", "max_slots": 4,
+                 "s_max": 128, "page_size": 8, "prefill_chunk": 16,
+                 "trace_seconds": 0.5},
+        traffic={**cell.traffic, "pool_requests": 16,
+                 "warmup_completions": 4,
+                 "prompt_len": {"dist": "uniform", "min": 24, "max": 64},
+                 "output_len": {"dist": "uniform", "min": 6, "max": 16}})
+
+
+def test_the_pangu_family_serves_through_the_driver_at_tiny_size(
+        monkeypatch):
+    from perf import run
+    from perf.families import pangu_ultra_moe
+
+    # the reference's row block and padding at a size the tiny streams fill
+    monkeypatch.setattr(pangu_ultra_moe, "REFERENCE_BLOCK", 16)
+    monkeypatch.setattr(pangu_ultra_moe, "REFERENCE_PAD", 32)
+    line = run.measure("rehearsal", 2 ** 31 + 33, 1.0, True,
+                       cell=_tiny_pangu_cell(), allow_cpu=True)
+    checks = line["checks"]
+    assert line["correct"], checks
+    mean, over = checks["compared"][:2]
+    assert (mean["what"], over["what"]) == ("mean_logit_gap",
+                                            "share_of_gaps_over_half")
+    assert mean["limit"] == pangu_ultra_moe.MEAN_GAP_LIMIT
+    assert over["limit"] == pangu_ultra_moe.OVER_HALF_LIMIT
+    # float32 against float32: every token is the reference's argmax
+    assert mean["value"] < 1e-4 and over["value"] == 0
+    assert checks["worst_logit_gap"] < 1e-3
+    assert checks["checked_positions"] > 0
+    assert checks["reference"] == os.path.join("perf", "reference",
+                                               "pangu_ultra_moe.py")
+    assert checks["requests_failed"] == checks["compiles_in_window"] == 0
+    # one read-back a step: the share's load rides in the token block
+    per_token = line["rehearsal"]["per_layer"]["host_syncs_per_token.serve"]
+    assert 0 < per_token <= 1.0
+
+
+def test_the_pangu_float8_control_emits_tokens_the_reference_ranks_lower(
+        monkeypatch):
+    """The control that PERF.md reads on the chip, here at tiny size:
+    the reference rounded to float8_e4m3fn emits tokens the float32
+    reference does not rank first, while the float32 program's own
+    tokens read 0."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perf.families import pangu_ultra_moe
+    from pytorch_multiprocessing_distributed_tpu.serving import (
+        ServingEngine, init_params)
+
+    monkeypatch.setattr(pangu_ultra_moe, "REFERENCE_BLOCK", 16)
+    monkeypatch.setattr(pangu_ultra_moe, "REFERENCE_PAD", 32)
+    cell = _tiny_pangu_cell()
+    family = families.load(cell.config)
+    model = family.build_model(cell.config, "float32", "cpu")
+    params = init_params(model, 3)
+    engine = ServingEngine(model, params, max_slots=2, s_max=128,
+                           kv_layout="paged", page_size=8, prefill_chunk=16)
+    rng = np.random.default_rng(0)
+    served = [engine.submit(rng.integers(0, 211, size=n).tolist(), 40)
+              for n in (40, 56)]
+    while engine.in_flight:
+        engine.step()
+    ours = family.judge_gaps(family.stream_gaps(cell.config, params, served))
+    assert all(c["value"] == 0 for c in ours["compared"])
+    control = family.judge_gaps(family.control_gaps(cell.config, params,
+                                                    served))
     assert control["checks"]["mean_logit_gap"] > 1e-4
     assert control["checks"]["worst_logit_gap"] > 1e-2

@@ -34,7 +34,7 @@ from perf.reference import xing4_0 as reference
 from pytorch_multiprocessing_distributed_tpu import models
 from pytorch_multiprocessing_distributed_tpu.inference.generate import (
     GPT_SERVING, generate, serving_family)
-from pytorch_multiprocessing_distributed_tpu.models import xing4
+from pytorch_multiprocessing_distributed_tpu.models import latent, xing4
 from pytorch_multiprocessing_distributed_tpu.ops.moe import (
     dropless_experts, route_sigmoid_topk)
 from pytorch_multiprocessing_distributed_tpu.serving import (
@@ -185,9 +185,11 @@ def test_absorbed_decode_equals_decompressed_prefill(tiny, impl):
         page_table=table, page_size=8)
     got = np.asarray(family.logits(model, params, x)[0, 0])
     assert _rel(got, want) < F32_LIMIT
-    # dropless: every expert layer computed token x top-k assignments
-    assert counts.shape == (model.n_moe_layers, model.n_experts)
+    # dropless: every expert layer computed token x top-k assignments,
+    # none of them routed elsewhere (the last column): all are held
+    assert counts.shape == (model.n_moe_layers, model.n_experts + 1)
     assert np.asarray(counts).sum(axis=1).tolist() == [model.moe_top_k] * 2
+    assert np.asarray(counts)[:, -1].tolist() == [0, 0]
     # the new token's row went through the table: latent, rotated key,
     # zeros beyond the rotary width
     rank, rope = model.kv_lora_rank, model.qk_rope_head_dim
@@ -237,6 +239,8 @@ def test_engine_prefill_then_paged_decode_float32(tiny, chunk):
     assert snap["moe_assignments"] == (
         snap["decode_dispatches"] * 3 * model.moe_top_k
         * model.n_moe_layers)
+    assert snap["moe_assignments_elsewhere"] == 0
+    assert snap["moe_held_share"] == 1.0
     assert snap["moe_load_max_over_mean"] >= 1.0
 
 
@@ -321,8 +325,8 @@ def test_dropless_experts_equal_the_masked_loop(layout):
         p["e_bias"] = p["e_bias"].at[5].set(10.0)
     hp = {"top_k": k, "routed_scale": 2.0}
     chosen, weights = route_sigmoid_topk(x, p["router"], p["e_bias"], k, 2.0)
-    got, counts = dropless_experts(x, chosen, weights, p["w_gate"],
-                                   p["w_up"], p["w_down"])
+    got, counts, elsewhere = dropless_experts(
+        x, chosen, weights, p["w_gate"], p["w_up"], p["w_down"])
     shared = {name: jnp.zeros_like(p[name][0])
               for name in ("w_gate", "w_up", "w_down")}
     want = reference.experts(x, {**p, "shared": shared}, hp)
@@ -333,7 +337,7 @@ def test_dropless_experts_equal_the_masked_loop(layout):
     own = reference.experts_own_rows(x, {**p, "shared": shared}, hp, 8)
     np.testing.assert_allclose(np.asarray(own), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
-    assert int(counts.sum()) == t * k
+    assert int(counts.sum()) == t * k and int(elsewhere) == 0
     if layout == "all-to-one-expert":
         assert int(counts[5]) == t        # every token, none dropped
 
@@ -436,20 +440,20 @@ def _one_sinkhorn_iteration(model, monkeypatch):
 
 
 def _rotary_part_left_out_of_the_cache(model, monkeypatch):
-    inner = xing4._qkv
+    inner = latent._qkv
 
     def qkv(h, p, positions, m):
         q_nope, q_rope, row = inner(h, p, positions, m)
         return q_nope, q_rope, row.at[:, m.kv_lora_rank:].set(0)
 
-    monkeypatch.setattr(xing4, "_qkv", qkv)
+    monkeypatch.setattr(latent, "_qkv", qkv)
     return model
 
 
 def _bfloat16_router(model, monkeypatch):
-    inner = xing4.route_sigmoid_topk
+    inner = latent.route_sigmoid_topk
     monkeypatch.setattr(
-        xing4, "route_sigmoid_topk",
+        latent, "route_sigmoid_topk",
         lambda x, router, *rest: inner(
             x.astype(jnp.bfloat16).astype(jnp.float32),
             router.astype(jnp.bfloat16).astype(jnp.float32), *rest))
