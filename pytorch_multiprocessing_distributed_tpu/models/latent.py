@@ -338,10 +338,11 @@ def _gated(h, p, dt):
 
 def _ffn(h32, layer, model):
     """The layer's feed-forward of normed ``h32 [T, C]`` float32 ->
-    ``(y [T, C] float32, load [held + 1] int32 or None)``: the
-    assignments each held expert took and, last, those routed to
-    experts this chip does not hold. ``e_bias``, where the family has
-    one, moves the selection only."""
+    ``(y [T, C] float32, load [held + 2] int32 or None)``: the
+    assignments each held expert took, those routed to experts this
+    chip does not hold and, last, the rows the grouped matmuls were
+    given. ``e_bias``, where the family has one, moves the selection
+    only."""
     dt = model.dtype
     if "mlp" in layer:
         return _gated(h32, layer["mlp"], dt), None
@@ -349,12 +350,12 @@ def _ffn(h32, layer, model):
     chosen, weights = route_sigmoid_topk(
         h32, moe["router"], moe.get("e_bias"), model.moe_top_k,
         model.routed_scale)
-    y, counts, elsewhere = dropless_experts(
+    y, counts, elsewhere, given = dropless_experts(
         h32.astype(dt), chosen, weights, moe["w_gate"], moe["w_up"],
         moe["w_down"], n_experts=model.n_experts,
         offset=model.expert_offset)
     return (y + _gated(h32, moe["shared"], dt),
-            jnp.concatenate([counts, elsewhere[None]]))
+            jnp.concatenate([counts, elsewhere[None], given[None]]))
 
 
 # ---------------------------------------------------- the serving family
@@ -396,8 +397,9 @@ class LatentServing:
     def aux_shape(self, model):
         """Integers a decode horizon returns behind its token block,
         in the same readback: per expert layer the assignments of its
-        steps to each held expert, then those routed elsewhere."""
-        return (model.n_moe_layers, model.n_held + 1)
+        steps to each held expert, those routed elsewhere, then the
+        rows its grouped matmuls were given."""
+        return (model.n_moe_layers, model.n_held + 2)
 
     def chunk(self, model, params, pref, unused, tokens, start,
               cs=None, cs_cache=None):
@@ -435,7 +437,7 @@ class LatentServing:
                     uniform_positions=False, offsets=None, **_):
         """One pending token a slot through every layer, the whole
         page pool carried through; returns ``(x [N, 1, ...], pages,
-        unused, load [moe layers, held + 1])``."""
+        unused, load [moe layers, held + 2])``."""
         if (page_table is None or kv_valid is not None
                 or uniform_positions or offsets is not None):
             raise NotImplementedError(

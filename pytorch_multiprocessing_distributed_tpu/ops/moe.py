@@ -277,6 +277,25 @@ def route_sigmoid_topk(x32, router, e_bias, top_k: int,
     return chosen.astype(jnp.int32), weights
 
 
+# a rung of the ladder below is a whole number of the row tiles the
+# TPU's grouped-matmul kernel works in
+_ROW_TILE = 128
+
+
+def row_ladder(t: int, k: int, held: int, n_experts: int) -> tuple:
+    """The static row bounds :func:`dropless_experts` chooses among for
+    ``t`` tokens at top-``k``, ``held`` of ``n_experts`` experts held:
+    about twice the expected held rows ``t * k * held / n_experts`` in
+    whole row tiles, twice that, and last ``t * k`` itself (every row,
+    the bound that holds at any routing). Rising; a rung that would
+    reach ``t * k`` is left out, which leaves the one rung ``t * k``
+    where every expert is held. From static shapes only, so every rung
+    is a shape a program is compiled for once."""
+    total = t * k
+    first = -(-2 * total * held // (n_experts * _ROW_TILE)) * _ROW_TILE
+    return tuple(b for b in (first, 2 * first) if b < total) + (total,)
+
+
 def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, *,
                      n_experts=None, offset: int = 0):
     """The ONE dropless routed-expert layer (prefill, chunk and decode
@@ -298,10 +317,20 @@ def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, *,
     rows then form one contiguous group from row 0 and the three
     gated-SiLU matmuls are grouped matmuls over those groups
     (``jax.lax.ragged_dot``: on the TPU a native kernel that reads only
-    the experts that have rows; the rows routed elsewhere lie behind
-    the last group, in none, and cost no matmul), and the outputs are
-    weighted, put back in token order and summed over a token's
-    choices.
+    the experts that have rows). The rows routed elsewhere lie behind
+    the last group, in no matmul — but the kernel's time follows the
+    rows it is GIVEN, in a group or not (1.76 ms a call at 1,024 rows
+    against 0.99 at 128, 64 of them held: PERF.md), and so do the
+    gather before it and the unsort after it. So the layer works on the
+    first ``B`` sorted rows only, ``B`` the smallest rung of
+    :func:`row_ladder` that is ``>= sum(counts)``, chosen ON THE DEVICE
+    from the counts it has (``jax.lax.switch``: no host read, every
+    rung a static shape). In a 16-chip deployment the exchange hands a
+    chip the rows of its own experts and no others; this is that chip.
+    The last rung is ``T * k``: whatever the routing — every token to
+    one held expert included — some rung holds every held row, and
+    nothing is dropped. With every expert held the ladder has that one
+    rung and no conditional is traced.
 
     Args:
       x: ``[T, D]`` tokens in the compute dtype.
@@ -310,10 +339,12 @@ def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, *,
       weights: ``[T, k]`` float32 combine weights.
       w_gate, w_up: ``[held, D, F]``; w_down: ``[held, F, D]``.
 
-    Returns ``(y [T, D] float32, counts [held] int32, elsewhere int32)``
-    — the assignments each held expert received and the number routed
-    to experts not held here; together they sum to ``T * k`` (the
-    dropless invariant, and the load the serving metrics report).
+    Returns ``(y [T, D] float32, counts [held] int32, elsewhere int32,
+    given int32)`` — the assignments each held expert received, the
+    number routed to experts not held here (together they sum to
+    ``T * k``: the dropless invariant, and the load the serving metrics
+    report) and the rows the grouped matmuls were given (the rung
+    taken).
     """
     t, k = chosen.shape
     held = w_gate.shape[0]
@@ -328,21 +359,42 @@ def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, *,
     order = jnp.argsort(key, stable=True)                # [T*k]
     every = jnp.zeros((n_experts,), jnp.int32).at[key].add(1)
     counts, elsewhere = every[:held], jnp.sum(every[held:])
-    rows = jnp.take(x, order // k, axis=0)               # [T*k, D]
-    gate = jax.lax.ragged_dot(rows, w_gate, counts,
-                              preferred_element_type=jnp.float32)
-    up = jax.lax.ragged_dot(rows, w_up, counts,
-                            preferred_element_type=jnp.float32)
-    hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
-    out = jax.lax.ragged_dot(hidden, w_down, counts,
-                             preferred_element_type=jnp.float32)
-    out = out * jnp.take(weights.reshape(t * k), order)[:, None]
-    if held < n_experts:
-        # a row behind the last group is in no matmul: whatever the
-        # grouped kernel left there, it adds nothing
-        out = jnp.where((jnp.arange(t * k) < jnp.sum(counts))[:, None],
-                        out, 0.0)
-    # back to (token, choice) order: the inverse permutation is a
-    # scatter of whole rows, then a sum over each token's k choices
-    y = jnp.zeros_like(out).at[order].set(out)
-    return jnp.sum(y.reshape(t, k, -1), axis=1), counts, elsewhere
+    in_groups = jnp.sum(counts)
+
+    def over_first(b):
+        """The layer over the first ``b`` sorted rows (every held row
+        is among them) -> ``y [T, D]`` float32."""
+        first = order[:b]
+        rows = jnp.take(x, first // k, axis=0)           # [b, D]
+        gate = jax.lax.ragged_dot(rows, w_gate, counts,
+                                  preferred_element_type=jnp.float32)
+        up = jax.lax.ragged_dot(rows, w_up, counts,
+                                preferred_element_type=jnp.float32)
+        hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+        out = jax.lax.ragged_dot(hidden, w_down, counts,
+                                 preferred_element_type=jnp.float32)
+        out = out * jnp.take(weights.reshape(t * k), first)[:, None]
+        if held < n_experts:
+            # a row behind the last group is in no matmul, and what the
+            # grouped kernel leaves there is NOT zero on the chip
+            out = jnp.where((jnp.arange(b) < in_groups)[:, None], out, 0.0)
+        if b < t * k:
+            # each row is added to its token's sum: a one-hot matmul,
+            # exact in float32 at this precision (on the chip a
+            # scatter-add takes 2.8 us a row: 2.9 ms of a chunk's layer
+            # where this takes 0.3, PERF.md)
+            mine = (first // k)[None, :] == jnp.arange(t)[:, None]
+            return jnp.dot(mine.astype(out.dtype), out,
+                           precision=jax.lax.Precision.HIGHEST)
+        # every row: back to (token, choice) order (the inverse
+        # permutation is a scatter of whole rows), then a sum over each
+        # token's k choices
+        y = jnp.zeros_like(out).at[order].set(out)
+        return jnp.sum(y.reshape(t, k, -1), axis=1)
+
+    rungs = row_ladder(t, k, held, n_experts)
+    if len(rungs) == 1:
+        return over_first(t * k), counts, elsewhere, jnp.int32(t * k)
+    rung = jnp.sum(in_groups > jnp.array(rungs[:-1], jnp.int32))
+    y = jax.lax.switch(rung, [lambda b=b: over_first(b) for b in rungs])
+    return y, counts, elsewhere, jnp.array(rungs, jnp.int32)[rung]

@@ -548,11 +548,12 @@ class ServingEngine:
                     f"family yet: {family.refuses[option]}")
         self._family = family
         # a family with routed experts returns their load behind every
-        # token block, a column a held expert and one for the rest;
-        # every decode.dispatch event says how many it holds
+        # token block, a column a held expert, one for the rest and
+        # one for the rows the grouped matmuls were given; every
+        # decode.dispatch event says how many it holds
         aux = family.aux_shape(model)
         self._expert_note = ({} if aux is None
-                             else {"experts_held": aux[-1] - 1})
+                             else {"experts_held": aux[-1] - 2})
         self.model = model
         self.params = params
         self.mesh = mesh

@@ -179,11 +179,18 @@ class ServingMetrics:
         # experts): assignments computed by the experts held here,
         # assignments routed to experts this chip does not hold (a
         # share of an expert-parallel deployment; 0 when every expert
-        # is held), and the busiest held expert's load over the held
-        # experts' mean load, averaged over layers and blocks
+        # is held), the busiest held expert's load over the held
+        # experts' mean load, averaged over layers and blocks, the rows
+        # the grouped matmuls were given (ops/moe.py::row_ladder: a
+        # static bound over the held rows), and how many (layer, block)
+        # entries there were and how many of them ran at full width
+        # (every assignment given, held or not)
         self.moe_assignments = 0
         self.moe_assignments_elsewhere = 0
         self.moe_load = AverageMeter()
+        self.moe_rows_given = 0
+        self.moe_layer_blocks = 0
+        self.moe_full_width = 0
         self._elapsed = 0.0
         self._occupancy_max = 0
         self._queue_wait_max = 0.0
@@ -308,13 +315,20 @@ class ServingMetrics:
 
     def record_moe(self, counts) -> None:
         """One drained block's per-layer expert assignment counts
-        (``[layers, held + 1]``: a column a held expert, the last one
-        the assignments routed to experts held elsewhere; they came
-        back in the token block's own readback). Dropless: a layer's
-        row sums to tokens x top-k."""
-        counts, elsewhere = counts[:, :-1], counts[:, -1]
+        (``[layers, held + 2]``: a column a held expert, then the
+        assignments routed to experts held elsewhere, last the rows
+        the grouped matmuls were given; they came back in the token
+        block's own readback). Dropless: a layer's assignments sum to
+        tokens x top-k, and a layer that was given that many rows ran
+        at full width in every step of the block."""
+        counts, elsewhere, given = (counts[:, :-2], counts[:, -2],
+                                    counts[:, -1])
         self.moe_assignments += int(counts.sum())
         self.moe_assignments_elsewhere += int(elsewhere.sum())
+        self.moe_rows_given += int(given.sum())
+        self.moe_layer_blocks += len(given)
+        self.moe_full_width += int(
+            (given == counts.sum(axis=1) + elsewhere).sum())
         means = counts.mean(axis=1)
         live = means > 0
         if live.any():
@@ -389,6 +403,13 @@ class ServingMetrics:
                 else self.moe_assignments
                 / (self.moe_assignments + self.moe_assignments_elsewhere)),
             "moe_load_max_over_mean": self.moe_load.avg,
+            "moe_rows_given": self.moe_rows_given,
+            "moe_rows_given_over_held": (
+                0.0 if self.moe_assignments == 0
+                else self.moe_rows_given / self.moe_assignments),
+            "moe_full_width_share": (
+                0.0 if self.moe_layer_blocks == 0
+                else self.moe_full_width / self.moe_layer_blocks),
         }
         # graftscope percentile telemetry: the tail IS the SLO
         for name, meter in (("ttft", self.ttft),

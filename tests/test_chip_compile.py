@@ -333,8 +333,13 @@ def test_latent_decode_program_compiles_at_one_layer(topo, name, kwargs,
     text = compiled.as_text()
     assert "mla_paged_decode_attention" in _mosaic_names(text)
     # the tokens and, behind them, the expert layer's load: a column a
-    # held expert and one for the assignments routed elsewhere
-    assert f"s32[{slots + model.n_held + 1}]" in text
+    # held expert, one for the assignments routed elsewhere and one for
+    # the rows the grouped matmuls were given
+    assert f"s32[{slots + model.n_held + 2}]" in text
+    # a share of the experts: ONE conditional over the ladder's rungs
+    # (every branch's grouped matmuls compile for the chip); every
+    # expert held: none
+    assert text.count(" conditional(") == (model.n_held < model.n_experts)
     mem = compiled.memory_analysis()
     pools = pool.k_pages.nbytes + pool.v_pages.nbytes
     assert pools == ((slots * s_max // 16 + 1) * 16 * (512 + 128) * 2

@@ -28,10 +28,12 @@ from pytorch_multiprocessing_distributed_tpu.inference.generate import (
     generate, serving_family)
 from pytorch_multiprocessing_distributed_tpu.models import latent
 from pytorch_multiprocessing_distributed_tpu.ops.moe import (
-    dropless_experts, route_sigmoid_topk)
+    dropless_experts, route_sigmoid_topk, row_ladder)
 from pytorch_multiprocessing_distributed_tpu.runtime.scope import scoped
 from pytorch_multiprocessing_distributed_tpu.serving import (
     PagePool, ServingEngine, init_params)
+from pytorch_multiprocessing_distributed_tpu.utils.metrics import (
+    ServingMetrics)
 
 F32_LIMIT = 2e-5
 BF16_LIMIT = 0.05
@@ -150,7 +152,8 @@ def test_absorbed_decode_equals_decompressed_prefill(tiny, impl):
     """One decode step over the paged latent cache (absorbed, through
     the kernel in interpret mode or its XLA form) gives the logits the
     decompressed prefill gives for the same position, and returns the
-    share's load: held counts, then the assignments routed elsewhere."""
+    share's load: held counts, the assignments routed elsewhere, then
+    the rows the grouped matmuls were given."""
     model, params = tiny
     family = model.serving_family
     tokens = _tokens(41, seed=5)
@@ -170,9 +173,12 @@ def test_absorbed_decode_equals_decompressed_prefill(tiny, impl):
         page_table=table, page_size=8)
     got = np.asarray(family.logits(model, params, x)[0, 0])
     assert _rel(got, want) < F32_LIMIT
-    assert load.shape == family.aux_shape(model) == (2, 4 + 1)
-    # dropless: held + elsewhere = token x top-k in every expert layer
-    assert np.asarray(load).sum(axis=1).tolist() == [model.moe_top_k] * 2
+    assert load.shape == family.aux_shape(model) == (2, 4 + 2)
+    # dropless: held + elsewhere = token x top-k in every expert layer,
+    # and one token's four rows are the ladder's one rung
+    load = np.asarray(load)
+    assert load[:, :-1].sum(axis=1).tolist() == [model.moe_top_k] * 2
+    assert load[:, -1].tolist() == [model.moe_top_k] * 2
 
 
 def _serve(model, params, requests, **kw):
@@ -288,7 +294,7 @@ def test_the_shares_add_up_to_the_uncut_expert_layer(side, held):
     for offset in range(0, e, held):
         mine = _share_of(p, offset, held)
         if side == "program":
-            part, counts, elsewhere = dropless_experts(
+            part, counts, elsewhere, _ = dropless_experts(
                 x, chosen, weights, mine["w_gate"], mine["w_up"],
                 mine["w_down"], n_experts=e, offset=offset)
             assert int(counts.sum()) + int(elsewhere) == t * k
@@ -343,12 +349,12 @@ def test_every_expert_held_is_the_layer_xing4_ran_before(dtype):
                                          model.moe_top_k, model.routed_scale)
     args = (x.astype(dtype), chosen, weights, moe["w_gate"], moe["w_up"],
             moe["w_down"])
-    y, counts, elsewhere = dropless_experts(*args)
+    y, counts, elsewhere, given = dropless_experts(*args)
     want_y, want_counts = _dropless_experts_of_pr_29(*args)
     assert (np.asarray(y) == np.asarray(want_y)).all()
     assert (np.asarray(counts) == np.asarray(want_counts)).all()
     assert int(elsewhere) == 0
-    assert int(counts.sum()) == 40 * model.moe_top_k
+    assert int(counts.sum()) == int(given) == 40 * model.moe_top_k
 
 
 @pytest.mark.parametrize("layout", ["spread", "all-to-one-held-expert",
@@ -367,7 +373,7 @@ def test_a_share_drops_nothing_among_the_held(layout):
         bias = bias.at[jnp.array([0, 13])].set(10.0)
     chosen, weights = route_sigmoid_topk(x, p["router"], bias, k, 2.5)
     mine = _share_of(p, offset, held)
-    got, counts, elsewhere = dropless_experts(
+    got, counts, elsewhere, _ = dropless_experts(
         x, chosen, weights, mine["w_gate"], mine["w_up"], mine["w_down"],
         n_experts=e, offset=offset)
     dense = jnp.sum(jax.nn.one_hot(chosen, e) * weights[..., None], axis=1)
@@ -385,6 +391,232 @@ def test_a_share_drops_nothing_among_the_held(layout):
     with pytest.raises(ValueError, match="not among the 16"):
         dropless_experts(x, chosen, weights, mine["w_gate"], mine["w_up"],
                          mine["w_down"], n_experts=e, offset=13)
+
+
+# ----------------------------------------------------------- the ladder
+
+def _dropless_experts_of_pr_33(x, chosen, weights, w_gate, w_up, w_down, *,
+                               n_experts=None, offset: int = 0):
+    """``ops/moe.py::dropless_experts`` as it stood before the ladder
+    (every one of the ``T * k`` sorted rows given to the grouped
+    matmuls), kept word for word as the reference of the laddered
+    layer."""
+    t, k = chosen.shape
+    held = w_gate.shape[0]
+    n_experts = held if n_experts is None else int(n_experts)
+    if not 0 <= offset <= n_experts - held:
+        raise ValueError(
+            f"experts [{offset}, {offset + held}) are not among the "
+            f"{n_experts} the router chooses from")
+    flat = chosen.reshape(t * k)
+    # this chip's experts become 0 .. held - 1, the others follow
+    key = flat if offset == 0 else (flat - offset) % n_experts
+    order = jnp.argsort(key, stable=True)                # [T*k]
+    every = jnp.zeros((n_experts,), jnp.int32).at[key].add(1)
+    counts, elsewhere = every[:held], jnp.sum(every[held:])
+    rows = jnp.take(x, order // k, axis=0)               # [T*k, D]
+    gate = jax.lax.ragged_dot(rows, w_gate, counts,
+                              preferred_element_type=jnp.float32)
+    up = jax.lax.ragged_dot(rows, w_up, counts,
+                            preferred_element_type=jnp.float32)
+    hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+    out = jax.lax.ragged_dot(hidden, w_down, counts,
+                             preferred_element_type=jnp.float32)
+    out = out * jnp.take(weights.reshape(t * k), order)[:, None]
+    if held < n_experts:
+        # a row behind the last group is in no matmul: whatever the
+        # grouped kernel left there, it adds nothing
+        out = jnp.where((jnp.arange(t * k) < jnp.sum(counts))[:, None],
+                        out, 0.0)
+    # back to (token, choice) order: the inverse permutation is a
+    # scatter of whole rows, then a sum over each token's k choices
+    y = jnp.zeros_like(out).at[order].set(out)
+    return jnp.sum(y.reshape(t, k, -1), axis=1), counts, elsewhere
+
+
+def _routing(t, k, n_experts, offset, held, held_rows, seed=0):
+    """``chosen [t, k]`` with exactly ``held_rows`` assignments to the
+    experts ``[offset, offset + held)``, spread over them and over the
+    tokens, and combine weights that sum to 2.5 a token."""
+    rng = np.random.default_rng(seed)
+    mine = offset + np.arange(held_rows) % held
+    others = (offset + held + np.arange(t * k - held_rows)
+              % max(n_experts - held, 1)) % n_experts
+    chosen = rng.permutation(np.concatenate([mine, others]))
+    weights = rng.random((t, k)) + 0.1
+    return (jnp.asarray(chosen.reshape(t, k), jnp.int32),
+            jnp.asarray(weights / weights.sum(1, keepdims=True) * 2.5,
+                        jnp.float32))
+
+
+# 1,024 assignments, 2 of 16 experts held: 128 expected, rungs 256, 512
+# and 1,024
+@pytest.mark.parametrize("layout, k, given", [
+    ("spread", 2, 256), ("all-to-one-held-expert", 1, 1024),
+    ("all-routed-elsewhere", 2, 256), ("total-at-the-first-rung", 2, 256),
+    ("total-one-above-the-first-rung", 2, 512),
+    ("total-at-the-second-rung", 2, 512),
+    ("total-one-above-the-second-rung", 2, 1024)])
+def test_the_laddered_layer_is_the_layer_of_pr_33(layout, k, given):
+    """Whatever rung the counts choose, the layer over the first
+    ``given`` sorted rows is the layer over all of them: values to the
+    order of a float32 sum, the same counts, nothing dropped — every
+    row to ONE held expert (the last rung: every row is held)
+    included."""
+    e, offset, held = 16, 4, 2
+    t = 1024 // k
+    x, p = _layer(seed=3, t=t)
+    mine = _share_of(p, offset, held)
+    assert row_ladder(t, k, held, e) == (256, 512, 1024)
+    if layout in ("spread", "all-to-one-held-expert",
+                  "all-routed-elsewhere"):
+        bias = jnp.zeros((e,), jnp.float32)
+        if layout == "all-to-one-held-expert":
+            bias = bias.at[5].set(10.0)
+        elif layout == "all-routed-elsewhere":
+            bias = bias.at[jnp.array([0, 13])].set(10.0)
+        chosen, weights = route_sigmoid_topk(x, p["router"], bias, k, 2.5)
+    else:
+        rows = {"total-at-the-first-rung": 256,
+                "total-one-above-the-first-rung": 257,
+                "total-at-the-second-rung": 512,
+                "total-one-above-the-second-rung": 513}[layout]
+        chosen, weights = _routing(t, k, e, offset, held, rows)
+    args = (x, chosen, weights, mine["w_gate"], mine["w_up"],
+            mine["w_down"])
+    got, counts, elsewhere, rows_given = jax.jit(
+        lambda *a: dropless_experts(*a, n_experts=e, offset=offset))(*args)
+    want, want_counts, want_elsewhere = _dropless_experts_of_pr_33(
+        *args, n_experts=e, offset=offset)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
+    assert (np.asarray(counts) == np.asarray(want_counts)).all()
+    assert int(elsewhere) == int(want_elsewhere)
+    assert int(counts.sum()) + int(elsewhere) == t * k
+    assert int(rows_given) == given >= int(counts.sum())
+    if layout == "all-to-one-held-expert":
+        assert int(counts[1]) == t * k and float(jnp.abs(got).max()) > 0
+    if layout == "all-routed-elsewhere":
+        assert int(elsewhere) == t * k
+        assert float(jnp.abs(got).max()) == 0.0
+
+
+@pytest.mark.parametrize("t, k, held, e, rungs", [
+    (128, 8, 16, 256, (128, 256, 1024)),      # the cell's decode step
+    (1024, 8, 16, 256, (1024, 2048, 8192)),   # the cell's chunk
+    (256, 4, 2, 16, (256, 512, 1024)),
+    (48, 4, 4, 16, (128, 192)),               # the second rung reaches T*k
+    (3, 4, 4, 16, (12,)),                     # the first one does
+    (64, 4, 64, 64, (256,)),                  # every expert held
+], ids=["decode-128x8-16of256", "chunk-1024x8-16of256", "256x4-2of16",
+        "48x4-4of16", "3x4-4of16", "64x4-64of64"])
+def test_the_rung_taken_is_the_smallest_that_holds_the_held_rows(
+        t, k, held, e, rungs):
+    """The ladder from static shapes: about twice the expected held
+    rows in whole row tiles, twice that, and last ``T * k``; for totals
+    of 0, a rung, one above it and ``T * k`` the layer takes the
+    smallest rung that is ``>=`` the total (on the device: the function
+    is jitted and the total is data)."""
+    assert row_ladder(t, k, held, e) == rungs
+    assert rungs[-1] == t * k and list(rungs) == sorted(set(rungs))
+    assert rungs[0] >= min(t * k, 2 * t * k * held // e)
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.normal(size=(t, 8)), jnp.float32)
+    w = [jnp.asarray(rng.normal(size=shape) * .3, jnp.float32)
+         for shape in ((held, 8, 8), (held, 8, 8), (held, 8, 8))]
+    layer = jax.jit(lambda *a: dropless_experts(*a, n_experts=e))
+    totals = {0, t * k} | {b + d for b in rungs for d in (0, 1)
+                           if b + d <= t * k}
+    if held == e:
+        totals = {t * k}           # nothing can be routed elsewhere
+    for total in sorted(totals):
+        chosen, weights = _routing(t, k, e, 0, held, total)
+        _, counts, elsewhere, given = layer(x, chosen, weights, *w)
+        assert int(counts.sum()) == total
+        assert int(elsewhere) == t * k - total
+        assert int(given) == min(b for b in rungs if b >= total)
+
+
+def test_every_expert_held_traces_no_conditional():
+    """``held == n_experts`` (the ``xing4`` family): one rung, the layer
+    PR 29 wrote with no conditional in it; a share of the experts: ONE
+    conditional, a branch a rung."""
+    x, p = _layer(t=256)
+    chosen, weights = route_sigmoid_topk(x, p["router"], None, 4, 2.5)
+
+    def traced(held):
+        mine = _share_of(p, 0, held)
+        jaxpr = jax.make_jaxpr(lambda *a: dropless_experts(
+            *a, n_experts=16))(x, chosen, weights, mine["w_gate"],
+                               mine["w_up"], mine["w_down"])
+        return [eqn for eqn in jaxpr.jaxpr.eqns
+                if eqn.primitive.name == "cond"]
+
+    assert traced(16) == []
+    (switch,) = traced(2)
+    assert len(switch.params["branches"]) == len(
+        row_ladder(256, 4, 2, 16)) == 3
+
+
+# ---------------------------------------------------------- the counter
+
+def test_record_moe_splits_the_rows_given_off_the_load():
+    """``[layers, held + 2]``: held counts, elsewhere, rows given. A
+    layer given as many rows as it had assignments ran at full width."""
+    metrics = ServingMetrics()
+    assert metrics.snapshot()["moe_rows_given_over_held"] == 0.0
+    assert metrics.snapshot()["moe_full_width_share"] == 0.0
+    metrics.record_moe(np.array([[3, 1, 0, 4, 24, 16],
+                                 [9, 8, 2, 1, 12, 32]]))
+    metrics.record_moe(np.array([[2, 2, 2, 2, 24, 16],
+                                 [0, 0, 0, 0, 32, 16]]))
+    snap = metrics.snapshot()
+    assert snap["moe_assignments"] == 8 + 20 + 8 + 0
+    assert snap["moe_assignments_elsewhere"] == 24 + 12 + 24 + 32
+    assert snap["moe_rows_given"] == 16 + 32 + 16 + 16
+    assert snap["moe_rows_given_over_held"] == pytest.approx(80 / 36)
+    # one of the four (layer, block) entries was given all 32 rows
+    assert snap["moe_full_width_share"] == 0.25
+    assert snap["moe_held_share"] == pytest.approx(36 / 128)
+    # the busiest held expert over the mean, of the layers with a load
+    assert snap["moe_load_max_over_mean"] == pytest.approx(
+        ((4 / 2 + 9 / 5) / 2 + 1.0) / 2)
+
+
+def test_engine_reports_the_rung_every_layer_and_block_took(tiny):
+    """48 slots at top-4 are 192 rows a layer and step, 48 of them
+    expected at the 4 of 16 experts held: rungs 128 and 192. Every
+    (layer, block) was given a rung that holds its held rows, the
+    counters add them up, and they rode in the token block's one
+    read-back."""
+    model, params = tiny
+    rungs = row_ladder(48, model.moe_top_k, model.n_held, model.n_experts)
+    assert rungs == (128, 192)
+    engine = ServingEngine(model, params, max_slots=48, s_max=64,
+                           page_size=8)
+    blocks = []
+    record = engine.metrics.record_moe
+    engine.metrics.record_moe = lambda load: (blocks.append(load.copy()),
+                                              record(load))[1]
+    served = [engine.submit(list(_tokens(9 + i, i)), 6) for i in range(5)]
+    while engine.in_flight:
+        engine.step()
+    assert all(len(request.tokens) == 6 for request in served)
+    snap = engine.metrics.snapshot()
+    assert len(blocks) == snap["decode_dispatches"] > 0
+    for load in blocks:
+        assert load.shape == (model.n_moe_layers, model.n_held + 2)
+        held, elsewhere, given = (load[:, :-2].sum(axis=1), load[:, -2],
+                                  load[:, -1])
+        assert (held + elsewhere == 192).all()
+        assert all(g in rungs for g in given) and (given >= held).all()
+    assert snap["moe_rows_given"] == sum(b[:, -1].sum() for b in blocks)
+    assert snap["moe_rows_given_over_held"] == pytest.approx(
+        snap["moe_rows_given"] / snap["moe_assignments"])
+    assert snap["moe_full_width_share"] == pytest.approx(
+        np.mean([g == 192 for b in blocks for g in b[:, -1]]))
+    # still ONE read-back a block: the counter is a column of it
+    assert snap["decode_host_syncs"] == snap["decode_dispatches"]
 
 
 # ----------------------------------------------------- pool and refusals

@@ -186,10 +186,13 @@ def test_absorbed_decode_equals_decompressed_prefill(tiny, impl):
     got = np.asarray(family.logits(model, params, x)[0, 0])
     assert _rel(got, want) < F32_LIMIT
     # dropless: every expert layer computed token x top-k assignments,
-    # none of them routed elsewhere (the last column): all are held
-    assert counts.shape == (model.n_moe_layers, model.n_experts + 1)
-    assert np.asarray(counts).sum(axis=1).tolist() == [model.moe_top_k] * 2
-    assert np.asarray(counts)[:, -1].tolist() == [0, 0]
+    # none of them routed elsewhere (the last column but one): all are
+    # held, and all were given to the grouped matmuls (the last)
+    assert counts.shape == (model.n_moe_layers, model.n_experts + 2)
+    counts = np.asarray(counts)
+    assert counts[:, :-2].sum(axis=1).tolist() == [model.moe_top_k] * 2
+    assert counts[:, -2].tolist() == [0, 0]
+    assert counts[:, -1].tolist() == [model.moe_top_k] * 2
     # the new token's row went through the table: latent, rotated key,
     # zeros beyond the rotary width
     rank, rope = model.kv_lora_rank, model.qk_rope_head_dim
@@ -242,6 +245,10 @@ def test_engine_prefill_then_paged_decode_float32(tiny, chunk):
     assert snap["moe_assignments_elsewhere"] == 0
     assert snap["moe_held_share"] == 1.0
     assert snap["moe_load_max_over_mean"] >= 1.0
+    # every expert held: one rung, every row of every layer and block
+    assert snap["moe_rows_given"] == snap["moe_assignments"]
+    assert snap["moe_rows_given_over_held"] == 1.0
+    assert snap["moe_full_width_share"] == 1.0
 
 
 def test_engine_pipelined_step_reuses_slots_under_a_block(tiny):
@@ -325,7 +332,7 @@ def test_dropless_experts_equal_the_masked_loop(layout):
         p["e_bias"] = p["e_bias"].at[5].set(10.0)
     hp = {"top_k": k, "routed_scale": 2.0}
     chosen, weights = route_sigmoid_topk(x, p["router"], p["e_bias"], k, 2.0)
-    got, counts, elsewhere = dropless_experts(
+    got, counts, elsewhere, _ = dropless_experts(
         x, chosen, weights, p["w_gate"], p["w_up"], p["w_down"])
     shared = {name: jnp.zeros_like(p[name][0])
               for name in ("w_gate", "w_up", "w_down")}
