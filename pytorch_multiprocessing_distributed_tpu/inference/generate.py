@@ -427,7 +427,9 @@ class GPTServing:
 
     def cache_rows(self, model):
         """``((name, trailing shape, dtype), (...))``: what one token
-        of one layer keeps in each of the two caches."""
+        of one layer keeps in each of the two caches. A family whose
+        layers are of two kinds adds ``(layers, columns)`` to each
+        (:func:`cache_pools`)."""
         h = model.num_heads
         row = (h, model.hidden_size // h)
         return (("k", row, model.dtype), ("v", row, model.dtype))
@@ -513,11 +515,26 @@ def serving_family(model):
     return getattr(model, "serving_family", None) or GPT_SERVING
 
 
+def cache_pools(model):
+    """The family's two ``cache_rows`` in full: ``(name, row, dtype,
+    layers, columns)``. ``layers``: how many of the model's layers
+    keep this row (their pool's leading axis; the three-field form
+    means all of them). ``columns``: how many of a slot's columns the
+    pool holds at most (None: the whole context, pages under the page
+    table and the allocator; a number: a model's sliding window, held
+    as a ring of ``ceil(columns / page_size) + 1`` pages a slot)."""
+    return tuple(
+        tuple(entry) if len(entry) == 5
+        else tuple(entry) + (model.num_layers, None)
+        for entry in serving_family(model).cache_rows(model))
+
+
 def pref_cache_shapes(model, width: int):
-    """Shapes of the two standalone prefill caches ``[L, 1, width,
-    *row]`` a chunked prefill accumulates into."""
-    return tuple((model.num_layers, 1, int(width)) + tuple(row)
-                 for _, row, _ in serving_family(model).cache_rows(model))
+    """Shapes of the two standalone prefill caches ``[layers, 1,
+    width, *row]`` a chunked prefill accumulates into (every column of
+    the prompt in both; a ring's window is cut at the splice)."""
+    return tuple((layers, 1, int(width)) + tuple(row)
+                 for _, row, _, layers, _ in cache_pools(model))
 
 
 def _decode_horizon(model, params, k_caches, v_caches, positions,

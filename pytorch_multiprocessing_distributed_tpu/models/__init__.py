@@ -32,6 +32,7 @@ from .gpt import GPT, GPT_Small, GPT_Medium, GPT_Tiny
 from .xing4 import Xing4, Xing4_29B_A4B, Xing4_Tiny
 from .pangu_ultra_moe import (PanguUltraMoE, PanguUltraMoE_718B,
                               PanguUltraMoE_Tiny)
+from .afmoe import Afmoe, Afmoe_Tiny, Trinity_Large_Preview
 
 __all__ = [
     "BasicBlock",
@@ -51,5 +52,6 @@ __all__ = [
     "GPT", "GPT_Small", "GPT_Medium", "GPT_Tiny", "LM_MODELS",
     "Xing4", "Xing4_29B_A4B", "Xing4_Tiny",
     "PanguUltraMoE", "PanguUltraMoE_718B", "PanguUltraMoE_Tiny",
+    "Afmoe", "Afmoe_Tiny", "Trinity_Large_Preview",
 ]
 

@@ -437,7 +437,11 @@ class LatentServing:
                     uniform_positions=False, offsets=None, **_):
         """One pending token a slot through every layer, the whole
         page pool carried through; returns ``(x [N, 1, ...], pages,
-        unused, load [moe layers, held + 2])``."""
+        unused, load [moe layers, held + 2])``. ``window`` is the
+        engine's decode BUCKET: an upper bound on this step's columns
+        (the page table is cut to it), never a model's sliding window
+        (these families attend the whole context in every layer; a
+        family with window layers: ``models/afmoe.py``)."""
         if (page_table is None or kv_valid is not None
                 or uniform_positions or offsets is not None):
             raise NotImplementedError(

@@ -42,6 +42,16 @@ rows`` is ``[H, H * Dh]`` and head ``h``'s output is its diagonal block,
 taken outside the kernel. The k-query verify pass is the same kernel
 with ``K1 * H`` query rows and a row-staggered mask.
 
+The GROUPED kernel (:func:`gqa_paged_decode_attention`: fewer
+key/value heads than query heads, and layers that attend a sliding
+window) is the same scheme over a row that holds K and V side by side:
+a block-diagonal query over the KEY/VALUE heads' lanes, and a LOWER
+column bound ``reach`` that follows each slot's position
+(:func:`_reach_page_ids` names no page below it) beside the upper
+bound every paged kernel has. Two words, two things: ``window`` in
+this file is always the decode BUCKET's column bound, ``reach`` a
+model's sliding window.
+
 Matmuls stay in the input dtype (bf16 hits the MXU's native rate),
 accumulation is f32, outputs are f32 (the engine casts back to model
 dtype after the residual add, matching the XLA path's dtypes exactly).
@@ -85,6 +95,7 @@ from .flash_attention import NEG_INF
 
 __all__ = ["decode_attention", "paged_decode_attention",
            "mla_paged_decode_attention", "xla_mla_paged_decode_attention",
+           "gqa_paged_decode_attention", "xla_gqa_paged_decode_attention",
            "paged_verify_decode_attention",
            "xla_decode_attention", "xla_paged_decode_attention",
            "xla_verify_decode_attention",
@@ -512,9 +523,13 @@ def paged_decode_attention(
       positions: ``[B]`` int — slot ``b`` attends columns
         ``[0, positions[b]]`` inclusive.
       layer: static layer index into the pools.
-      window: optional logical column bound (< ``n_win * page_size``
-        trims the gathered tail on the XLA path; the Pallas path's
-        column mask makes it a no-op there).
+      window: the decode BUCKET's column bound, an UPPER bound the
+        engine picks for the whole step from the longest active
+        sequence (< ``n_win * page_size`` trims the gathered tail on
+        the XLA path; the Pallas path's column mask makes it a no-op
+        there). It is NOT a model's sliding window: a layer that
+        attends only the last so many columns names that LOWER bound
+        ``reach`` (:func:`gqa_paged_decode_attention`).
       impl / interpret: as :func:`decode_attention`.
 
     Returns ``[B, 1, H, Dh]`` f32 attention output (caller casts).
@@ -682,6 +697,9 @@ def mla_paged_decode_attention(
       positions: ``[B]`` — slot ``b`` attends columns ``[0, pos]``.
       layer: static layer index; rank: ``R``.
       scale: softmax scale (the YaRN ``m^2`` folded in).
+      window: the decode BUCKET's column bound (an upper bound on the
+        columns of this step, XLA path only), as in
+        :func:`paged_decode_attention`; never a model's sliding window.
 
     Returns ``[B, H, R]`` f32: ``softmax(scores) @ c`` per head, to be
     carried out of the latent space by ``W_uv`` in the caller.
@@ -702,6 +720,243 @@ def mla_paged_decode_attention(
     return xla_mla_paged_decode_attention(
         q, pages, page_table, positions, layer=layer, rank=rank,
         scale=scale, window=window)
+
+
+# ------------------------------------- grouped heads, a lower column bound
+
+# columns one grid step of the grouped kernel folds: G = this //
+# page_size pages, each ONE DMA of one contiguous [ps, 2 * Hkv * Dh]
+# page (K and V of a token side by side in one row)
+_GQA_BLOCK_COLUMNS = 512
+
+
+def _reach_page_ids(table, positions, group, page_size, reach):
+    """``[B, n_blocks * G]``: the pool page each (grid step, operand)
+    of the grouped kernel names, for a slot that attends columns
+    ``[max(0, pos - reach + 1), pos]`` (``reach`` None: from 0).
+    :func:`_live_page_ids` with a LOWER bound: the first grid step
+    starts at the page that holds the first column in reach, so a page
+    wholly below it is never named, never copied. Logical page ``g``
+    sits at table entry ``g % E``: the identity for a page table (``g <
+    E``), the wrap for a ring of ``E`` pages a slot (at most ``E``
+    logical pages are in reach at once). Dead operands repeat a live
+    page, which the pipeline does not copy twice."""
+    b, entries = table.shape
+    if reach is None:
+        pos = jnp.clip(positions, 0, entries * page_size - 1)
+        first = jnp.zeros_like(pos)
+    else:
+        pos = jnp.maximum(positions, 0)
+        first = jnp.maximum(pos - (reach - 1), 0) // page_size
+    live = (pos // page_size - first)[:, None, None]   # last live, from first
+    blk = jnp.minimum(
+        jnp.arange(pl.cdiv(entries, group))[None, :, None], live // group)
+    rel = blk * group + jnp.arange(group)[None, None, :]
+    rel = jnp.where(rel <= live, rel,
+                    jnp.where(blk > 0, rel - group, live))
+    logical = first[:, None, None] + rel
+    return jnp.take_along_axis(table, (logical % entries).reshape(b, -1),
+                               axis=1)
+
+
+def _gqa_paged_decode_kernel(pos_ref, ids_ref, q_ref, *rest, scale,
+                             page_size, group, reach):
+    """One (slot, block of ``group`` pages) cell of the GROUPED-head
+    paged decode, all query heads at once. A cache row holds K then V
+    of the ``Hkv`` key/value heads side by side (``[ps, 2 * Hkv *
+    Dh]`` a page: one DMA for both). The query is block-diagonal over
+    the KEY/VALUE heads (``[Hq, Hkv * Dh]``: row ``t`` holds ``q_t`` in
+    the lanes of head ``t // (Hq / Hkv)``), so one contraction with the
+    K half is every query head's scores against its own group's keys
+    and ``P @ V half`` holds head ``t``'s output in the same lanes.
+    Block ``kb`` starts at the page of the first column in reach, not
+    at column 0: a slot attends ``[max(0, pos - reach + 1), pos]``
+    (``reach`` None: ``[0, pos]``) and the first live page is masked
+    below that bound. Same online-softmax recurrence as
+    :func:`_mla_paged_decode_kernel`."""
+    row_refs = rest[:group]
+    o_ref, acc, m_scr, l_scr = rest[group:]
+    i = pl.program_id(0)
+    kb = pl.program_id(1)
+    half = q_ref.shape[-1]
+
+    @pl.when(kb == 0)
+    def _():
+        acc[:] = jnp.zeros_like(acc)
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+
+    pos = pos_ref[i]
+    low = 0 if reach is None else jnp.maximum(pos - (reach - 1), 0)
+    start = (low // page_size + kb * group) * page_size
+
+    @pl.when(start <= pos)
+    def _():
+        pages = [ref[0, 0] for ref in row_refs]      # [ps, 2 * Hkv * Dh]
+        rows = pages[0] if group == 1 else jnp.concatenate(pages, axis=0)
+        s = jax.lax.dot_general(
+            q_ref[0], rows[:, :half], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [Hq, G*ps]
+        col = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(jnp.logical_and(col >= low, col <= pos), s, NEG_INF)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        m_scr[:] = m_new
+        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc[:] = acc[:] * corr + jnp.dot(
+            p.astype(rows.dtype), rows[:, half:],
+            preferred_element_type=jnp.float32)      # [Hq, Hkv * Dh]
+
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _():
+        o_ref[0] = acc[:] / jnp.maximum(l_scr[:], 1e-30)
+
+
+def _group_mask(heads, kv_heads):
+    """``[Hq, Hkv]`` bool: query head ``t`` reads key/value head ``t //
+    (Hq / Hkv)``."""
+    return (jnp.arange(heads)[:, None] // (heads // kv_heads)
+            == jnp.arange(kv_heads)[None, :])
+
+
+def _pallas_gqa_paged_decode(q, pages, table, positions, layer, kv_heads,
+                             reach, scale, interpret):
+    b, heads, d = q.shape
+    ps, width = pages.shape[2], pages.shape[3]
+    half = width // 2                                   # Hkv * Dh
+    entries = table.shape[1]
+    group = max(1, min(_GQA_BLOCK_COLUMNS // ps, entries))
+    pick = _group_mask(heads, kv_heads)
+    q_bd = jnp.where(pick[None, :, :, None], q[:, :, None, :],
+                     jnp.zeros((), q.dtype)).reshape(b, heads, half)
+    slot_spec = pl.BlockSpec((1, heads, half),
+                             lambda i, kb, pos, ids: (i, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # positions, page ids
+        grid=(b, pl.cdiv(entries, group)),
+        in_specs=[slot_spec] + [
+            _page_spec((1, 1, ps, width), layer, g, group)
+            for g in range(group)],
+        out_specs=slot_spec,
+        scratch_shapes=[
+            pltpu.VMEM((heads, half), jnp.float32),  # output accumulator
+            pltpu.VMEM((heads, 1), jnp.float32),     # running max
+            pltpu.VMEM((heads, 1), jnp.float32),     # running denominator
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_gqa_paged_decode_kernel, scale=scale,
+                          page_size=ps, group=group, reach=reach),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, heads, half), jnp.float32),
+        interpret=interpret,
+        # one body, two names: the trace tells the layers that read a
+        # window from those that read the context
+        name=("gqa_paged_decode_attention_full" if reach is None
+              else "gqa_paged_decode_attention_window"),
+    )(positions.astype(jnp.int32),
+      _reach_page_ids(table.astype(jnp.int32), positions, group, ps, reach),
+      q_bd, *([pages] * group))
+    out = out.reshape(b, heads, kv_heads, d)
+    return jnp.sum(jnp.where(pick[None, :, :, None], out, 0.0), axis=2)
+
+
+def xla_gqa_paged_decode_attention(q, pages, table, positions, *, layer,
+                                   kv_heads, scale,
+                                   reach: Optional[int] = None):
+    """The grouped decode in plain XLA: ``take``-gather every table
+    entry of layer ``layer`` into ``[B, E * ps, .]`` rows, give each
+    entry the logical page it holds now (entry ``j`` holds the newest
+    page ``g <= pos // ps`` with ``g % E == j``: itself under a page
+    table, the wrap under a ring), mask columns outside ``[max(0, pos -
+    reach + 1), pos]`` and run grouped attention with a float32
+    softmax, K and V never repeated per query head."""
+    b, entries = table.shape
+    heads, d = q.shape[1], q.shape[2]
+    ps = pages.shape[2]
+    rows = jnp.take(pages[layer], table, axis=0)         # [B, E, ps, .]
+    last = (positions // ps)[:, None]
+    logical = last - jnp.mod(last - jnp.arange(entries)[None, :], entries)
+    col = (logical[:, :, None] * ps
+           + jnp.arange(ps)[None, None, :]).reshape(b, entries * ps)
+    low = (jnp.zeros_like(positions) if reach is None
+           else jnp.maximum(positions - (reach - 1), 0))
+    mask = jnp.logical_and(col >= low[:, None], col <= positions[:, None])
+    rows = rows.reshape(b, entries * ps, 2, kv_heads, d)
+    s = jnp.einsum("bkgd,bwkd->bkgw",
+                   q.reshape(b, kv_heads, heads // kv_heads, d),
+                   rows[:, :, 0],
+                   preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(mask[:, None, None, :], s, -jnp.inf),
+                       axis=-1)
+    out = jnp.einsum("bkgw,bwkd->bkgd", p.astype(rows.dtype),
+                     rows[:, :, 1], preferred_element_type=jnp.float32)
+    return out.reshape(b, heads, d)
+
+
+def gqa_paged_decode_attention(
+    q: jax.Array,
+    pages: jax.Array,
+    table: jax.Array,
+    positions: jax.Array,
+    *,
+    layer: int,
+    kv_heads: int,
+    scale: float,
+    reach: Optional[int] = None,
+    impl: str = "auto",
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Single-step attention of GROUPED query heads through a table of
+    pages, with an optional LOWER column bound (a sliding window).
+
+    Args:
+      q: ``[B, Hq, Dh]`` - one pending query token a slot; heads ``t``
+        with equal ``t // (Hq / Hkv)`` share a key/value head.
+      pages: ``[L, P, page_size, 2 * Hkv * Dh]`` - ALL the pool's
+        layers; a row is a token's K of every key/value head, then its
+        V (``Hkv * Dh`` lanes each), so a page is one contiguous DMA.
+        The kernel's index map picks ``layer``: the donated pool is
+        read in place.
+      table: ``[B, E]`` int32 - logical page ``g`` of slot ``b`` lives
+        at ``table[b, g % E]``. A PAGE TABLE (``g < E``: callers pass
+        the slice up to the decode BUCKET's column bound, as for
+        :func:`paged_decode_attention`) or a RING of ``E`` pages a slot
+        that token ``t`` writes at entry ``(t // page_size) % E``
+        (``E >= ceil(reach / page_size) + 1``, so no page in reach is
+        overwritten).
+      positions: ``[B]`` - slot ``b`` attends columns ``[max(0, pos -
+        reach + 1), pos]``: ``reach`` keys, its own included.
+      layer: static layer index into ``pages``; kv_heads: ``Hkv``.
+      scale: softmax scale.
+      reach: the model's sliding window in columns (a LOWER bound that
+        follows each slot's position), None for a layer that attends
+        the whole context. Not the decode bucket's ``window``, which
+        bounds a step's columns from above and is spent in the slice of
+        ``table`` the caller passes.
+
+    Only pages that hold a column in reach are copied; the first of
+    them is masked below the bound. Returns ``[B, Hq, Dh]`` f32.
+    """
+    if impl == "auto":
+        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+    reach = None if reach is None else int(reach)
+    if impl == "pallas":
+        if interpret is None:
+            from . import default_interpret
+
+            interpret = default_interpret()
+        return _pallas_gqa_paged_decode(
+            q, pages, table, positions, int(layer), int(kv_heads), reach,
+            float(scale), bool(interpret))
+    if impl != "xla":
+        raise ValueError(
+            f"impl must be 'pallas', 'xla' or 'auto', got {impl!r}")
+    return xla_gqa_paged_decode_attention(
+        q, pages, table, positions, layer=layer, kv_heads=kv_heads,
+        scale=scale, reach=reach)
 
 
 def xla_decode_attention(q, k, v, mask):

@@ -694,8 +694,12 @@ class ServingEngine:
             donate_argnums=(1, 2) if donate_cache else ())
         self._tok0_jit = jax.jit(self._make_tok0(),
                                  out_shardings=tok0_out)
+        # a family with sliding-window layers holds them as a ring of
+        # pages a slot (kv_pages): its splice cuts the prompt's last
+        # window into the ring; every other family's is the paged one
         self._insert_jit = jax.jit(
-            self._paged_insert_fn, out_shardings=insert_out,
+            (self._ring_insert_fn if self.pool.ring_pages
+             else self._paged_insert_fn), out_shardings=insert_out,
             donate_argnums=(0, 1, 2, 3, 4, 5, 6) if donate_cache
             else ())
         # graftquant: model-dtype standalone prefill block -> the
@@ -1079,6 +1083,43 @@ class ServingEngine:
         eos_ids = eos_ids.at[slot].set(eos)
         return (k_pages, v_pages, positions, last_tokens, active,
                 budgets, eos_ids)
+
+    @staticmethod
+    def _ring_insert_fn(full_pages, ring_pages, positions, last_tokens,
+                        active, budgets, eos_ids, full_pref, ring_pref,
+                        write_ids, slot, length, tok0, budget, eos):
+        """:meth:`_paged_insert_fn` for a family with two kinds of
+        layer (``kv_pages``): the full layers' standalone cache goes
+        into the paged pool at ``write_ids`` as there; of the sliding
+        layers' ``[L, 1, W, row]`` only the LAST WINDOW is kept: entry
+        ``j`` of slot ``slot``'s ring takes the newest page ``g <=
+        (length - 1) // ps`` of the prompt with ``g % ring == j`` (the
+        entry the decode steps will look for it at), one contiguous
+        block of ``ring`` pages into the donated ring pool. An entry
+        with no such page yet (a prompt shorter than the ring) takes
+        page 0's rows, which no step reads before it writes them."""
+        ps = full_pages.shape[2]
+        n = write_ids.shape[0]
+        ring = ring_pages.shape[1] // positions.shape[0]
+        pad = n * ps - full_pref.shape[2]
+
+        def to_pages(c):  # [L, 1, W, row] -> [L, n, ps, row]
+            if pad:
+                c = jnp.pad(c, ((0, 0), (0, 0), (0, pad), (0, 0)))
+            return c.reshape(c.shape[0], n, ps, c.shape[3])
+
+        full_pages = full_pages.at[:, write_ids].set(to_pages(full_pref))
+        last = (length - 1) // ps
+        newest = last - jnp.mod(last - jnp.arange(ring), ring)
+        ring_pages = jax.lax.dynamic_update_slice(
+            ring_pages,
+            jnp.take(to_pages(ring_pref), jnp.clip(newest, 0, n - 1),
+                     axis=1),
+            (0, slot * ring, 0, 0))
+        return ((full_pages, ring_pages)
+                + ServingEngine._state_insert_fn(
+                    positions, last_tokens, active, budgets, eos_ids,
+                    slot, length, tok0, budget, eos))
 
     @staticmethod
     def _state_insert_fn(positions, last_tokens, active, budgets,
@@ -2263,7 +2304,8 @@ class ServingEngine:
             dispatch_span.note(
                 kv_pages_live=pool.live_pages,
                 kv_pages_window=pool.max_slots
-                * -(-window // pool.page_size))
+                * -(-window // pool.page_size),
+                **(pool.live_pages_by_kind() if pool.ring_pages else {}))
 
             if k:
                 if self._drafter is not None:
@@ -2427,6 +2469,8 @@ class ServingEngine:
                         del self._running[slot]
                     events.append((request, token, reason is not None))
             pool.note_advance_slots(realized)
+            if pool.ring_pages:
+                self.metrics.record_kv_pages(pool)
             emitted = sum(realized.values())
             if block.k:
                 self._note_spec_drain(block, tokens, realized)

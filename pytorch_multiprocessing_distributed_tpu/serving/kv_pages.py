@@ -49,6 +49,29 @@ the lanes. K and V rows of ``(heads, head_dim)`` for GPT (head ``h`` in
 lanes ``h * head_dim .. (h + 1) * head_dim``); for a latent-attention
 family one row all heads share, and a zero-width placeholder.
 
+**Two kinds of layer** (a family whose ``cache_rows`` say so,
+``inference.generate.cache_pools``: sliding-window layers beside full
+ones). Each pool then holds ITS layers only. The pool of the layers
+that attend the whole context is the paged pool above, under the page
+table, the free list and the refcounts. The pool of the layers that
+attend a window is a **ring**: ``ring_pages = ceil(window / page_size)
++ 1`` pages a slot (``[layers, max_slots * ring_pages, page_size,
+row]``), token ``t`` of slot ``s`` at page ``s * ring_pages + (t //
+page_size) % ring_pages`` — at most ``ring_pages`` pages hold a column
+in reach, and the page that falls out of it is the one written over.
+Why a ring and not a second class in the allocator: its page ids follow
+from the slot and the position, so the decode programs need no second
+table operand and an admission no second reservation; its bytes are
+the window's whatever the context (the bound this kind of layer
+exists for); and nothing of it can leak (``release`` has nothing to
+return that the next tenant's splice does not overwrite). What it
+gives up: a short request still owns a whole ring (window-bounded, so
+at most ``ring_pages`` pages), and rings cannot be shared by a prefix
+cache (refused for such a family). Admission reasons over both kinds:
+the paged pool through ``free_pages``, the rings through the slot
+itself (a free slot IS a free ring). ``pages_in_use`` is then a share
+of what both kinds can hold, by bytes (see there).
+
 Layout note: a page is ``page_size`` whole rows, so it is lane-dense
 (``heads * head_dim`` is a multiple of 128 for the registry's serving
 sizes; a 64-wide minor dimension would be padded to 128 lanes and
@@ -95,7 +118,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..inference.generate import serving_family
+from ..inference.generate import cache_pools
 from ..ops.kv_quant import KV_DTYPES, QuantizedKV
 from ..runtime import hbm, life
 from ..runtime import scope as graftscope
@@ -105,6 +128,15 @@ from ..runtime import scope as graftscope
 # Dh]`` (and an int8 pool's ``[L, P, ps, H]`` scales): the last axis,
 # in contiguous head groups
 PAGE_SPEC = P(None, None, None, "model")
+
+
+def _ring_window(model) -> Optional[int]:
+    """The columns a slot's ring has to hold: the widest window among
+    the family's cache rows that hold a window only; None where every
+    row holds the whole context (no ring)."""
+    windows = [columns for *_, columns in cache_pools(model)
+               if columns is not None]
+    return max(windows) if windows else None
 
 
 class PagePoolExhausted(RuntimeError):
@@ -125,7 +157,8 @@ class PagePool:
     ``acquire``/``release``, the host position mirror.
 
     Args:
-      model: the ``GPT`` the caches are shaped for.
+      model: the model whose family (``inference.generate.
+        serving_family``) shapes the caches: its ``cache_rows``.
       max_slots: concurrent requests decoded per step (the decode
         batch dimension: every step pays ``max_slots`` rows of compute
         regardless of occupancy — the static-shape trade).
@@ -178,10 +211,20 @@ class PagePool:
                 f"num_pages must be >= 2 (scratch + 1), got "
                 f"{self.num_pages}")
         # the family's two cache rows (K and V; the latent and the
-        # position key), each one pool of pages
+        # position key; the full and the sliding layers' rows), each
+        # one pool of pages: ``num_pages`` under the page table, or a
+        # ring a slot where the row holds a window of columns only
+        self.ring_window = _ring_window(model)
+        self.ring_pages = (
+            0 if self.ring_window is None
+            else min(self.pages_per_slot,
+                     -(-self.ring_window // page_size) + 1))
+        self.ring_pages_overwritten = 0
         self.k_pages, self.v_pages = (
-            self._cache_sharded(self._empty_pages(row))
-            for _, row, _ in serving_family(model).cache_rows(model))
+            self._cache_sharded(self._empty_pages(
+                row, layers, self.num_pages if columns is None
+                else self.max_slots * self.ring_pages))
+            for _, row, _, layers, columns in cache_pools(model))
         # per-slot decode state: next write column, pending token,
         # live?, and the on-device finish gates (remaining budget, stop
         # id) that freeze a finished row mid-scan. Mesh runs commit
@@ -231,14 +274,13 @@ class PagePool:
                          category="kv")
             self._note_pages_ledger()
 
-    def _empty_pages(self, row):
+    def _empty_pages(self, row, layers, num_pages):
         """Zeroed pages for one cache row in the pool's element
         layout: model dtype, or the graftquant ``(int8 data, f32
         scale)`` pair — one scale per trailing-dimension group, ``[L,
         P, ps, H]`` (scale = ones — untouched pages dequantize to the
         zeros dense pages hold)."""
-        shape = self.page_shape(row, self.num_pages, self.page_size,
-                                self.model.num_layers)
+        shape = self.page_shape(row, num_pages, self.page_size, layers)
         if self.kv_dtype == "int8":
             groups = int(np.prod(row[:-1], dtype=int))
             return QuantizedKV(
@@ -272,21 +314,34 @@ class PagePool:
     @staticmethod
     def page_kv_bytes(model, page_size: int,
                       kv_dtype: str = "model") -> int:
-        """Bytes of ONE page over both cache rows — the exact shape x
-        dtype product ``__init__`` allocates per page (GPT: ``2 x
-        layers x heads x page_size x head_dim x itemsize``; graftquant
-        int8 charges 1 byte per element PLUS one f32 scale per
-        trailing-dimension group), the planner's paged-mode unit
-        (:func:`...analysis.meter.plan_capacity`), byte-exact in BOTH
-        modes."""
+        """Bytes of ONE page of the page table over the cache rows it
+        maps — the exact shape x dtype product ``__init__`` allocates
+        per page (GPT: ``2 x layers x heads x page_size x head_dim x
+        itemsize``; graftquant int8 charges 1 byte per element PLUS
+        one f32 scale per trailing-dimension group), the planner's
+        paged-mode unit (:func:`...analysis.meter.plan_capacity`),
+        byte-exact in BOTH modes. A row held as a ring a slot is not
+        under the table: :meth:`ring_page_kv_bytes`."""
+        return PagePool._page_bytes(model, page_size, kv_dtype, ring=False)
+
+    @staticmethod
+    def ring_page_kv_bytes(model, page_size: int) -> int:
+        """Bytes of ONE ring page over the rows held as a ring a slot
+        (a family with sliding-window layers; 0 for every other)."""
+        return PagePool._page_bytes(model, page_size, "model", ring=True)
+
+    @staticmethod
+    def _page_bytes(model, page_size, kv_dtype, ring: bool) -> int:
         total = 0
-        for _, row, dtype in serving_family(model).cache_rows(model):
+        for _, row, dtype, layers, columns in cache_pools(model):
+            if (columns is not None) != ring:
+                continue
             width = int(row[-1])
             if kv_dtype == "int8":
                 group_bytes = width * 1 + 4  # int8 lanes + f32 scale
             else:
                 group_bytes = width * jnp.dtype(dtype).itemsize
-            total += (model.num_layers * int(np.prod(row[:-1], dtype=int))
+            total += (layers * int(np.prod(row[:-1], dtype=int))
                       * int(page_size) * group_bytes)
         return total
 
@@ -294,10 +349,14 @@ class PagePool:
     def per_slot_kv_bytes(model, s_max: int,
                           kv_dtype: str = "model") -> int:
         """Cache bytes ONE slot reserves for ``s_max`` tokens (no page
-        rounding: a page of ``s_max`` rows) — the unit
+        rounding: a page of ``s_max`` rows; a row held as a ring: of
+        its window's columns where that is fewer) — the unit
         :func:`...analysis.meter.plan_capacity` inverts and
         :func:`...inference.generate.kv_cache_bytes` multiplies."""
-        return PagePool.page_kv_bytes(model, s_max, kv_dtype)
+        window = _ring_window(model)
+        return (PagePool.page_kv_bytes(model, s_max, kv_dtype)
+                + (0 if window is None else PagePool.ring_page_kv_bytes(
+                    model, min(int(s_max), window))))
 
     @staticmethod
     def per_slot_state_bytes() -> int:
@@ -316,6 +375,10 @@ class PagePool:
                                   self.kv_dtype)
 
     @property
+    def ring_page_bytes(self) -> int:
+        return self.ring_page_kv_bytes(self.model, self.page_size)
+
+    @property
     def per_slot_bytes(self) -> int:
         """WORST-CASE resident bytes one slot can pin
         (``pages_per_slot`` pages + scalar state) — the dense-parity
@@ -323,6 +386,7 @@ class PagePool:
         page_bytes``; the gap between the two is the capacity win the
         ledger gauges record."""
         return (self.pages_per_slot * self.page_bytes
+                + self.ring_pages * self.ring_page_bytes
                 + self.per_slot_state_bytes())
 
     @property
@@ -343,9 +407,8 @@ class PagePool:
         second time into ``hbm_total_bytes``."""
         if hbm.active_ledger() is None:
             return
-        used = self.pages_in_use
-        hbm.set_gauge("pages_in_use", used)
-        hbm.set_gauge("kv_pages_in_use_bytes", used * self.page_bytes)
+        hbm.set_gauge("pages_in_use", self.pages_in_use)
+        hbm.set_gauge("kv_pages_in_use_bytes", self.kv_bytes_held)
 
     # ---- page allocation (host-only) -----------------------------------
     @property
@@ -353,8 +416,61 @@ class PagePool:
         return len(self._free)
 
     @property
-    def pages_in_use(self) -> int:
-        return self.num_pages - 1 - len(self._free)
+    def pages_in_use(self):
+        """Pages held, as a count of the ``num_pages - 1`` allocatable
+        ones: ``pages_in_use / (num_pages - 1)`` is the share of the
+        pool's allocatable BYTES that requests hold. One kind of layer:
+        the pages off the free list, an integer. Two kinds: the bytes
+        held in both (the paged pool's pages and the ring pages of
+        every bound slot) over the bytes both can hold, scaled to the
+        same count — a share by bytes, not by pages, since a page of
+        the two pools spans different numbers of layers."""
+        used = self.num_pages - 1 - len(self._free)
+        if not self.ring_pages:
+            return used
+        return (self.kv_bytes_held / self.kv_bytes_allocatable
+                * (self.num_pages - 1))
+
+    def kv_usage(self) -> Dict[str, int]:
+        """What requests hold now, in ONE pass over the host mirror.
+        ``full``: pages of the page table off the free list (a
+        request's whole context, reserved at admission). ``sliding``:
+        ring pages of the bound slots — a slot whose request spans
+        ``n`` columns holds ``min(ceil(n / page_size), ring_pages)`` of
+        its ring, the window's worth at most whatever ``n`` is.
+        ``bytes_held``: both, in bytes. ``bytes_undivided``: what ONE
+        pool of every layer under the page table would hold for the
+        same requests (every held page of the table across all the
+        model's layers; with one kind of layer ``bytes_held``
+        itself)."""
+        full, sliding = self.num_pages - 1 - len(self._free), 0
+        page, ring_page = self.page_bytes, 0
+        if self.ring_pages:
+            bound = np.count_nonzero(self._table, axis=1)
+            sliding = int(np.minimum(bound, self.ring_pages).sum())
+            ring_page = self.ring_page_bytes
+        return {"full": full, "sliding": sliding,
+                "bytes_held": full * page + sliding * ring_page,
+                "bytes_undivided": full * (page + ring_page)}
+
+    def pages_held(self) -> Dict[str, int]:
+        """Pages held by kind (:meth:`kv_usage`)."""
+        usage = self.kv_usage()
+        return {"full": usage["full"], "sliding": usage["sliding"]}
+
+    @property
+    def kv_bytes_held(self) -> int:
+        """Cache bytes requests hold now, over both kinds of pool."""
+        return self.kv_usage()["bytes_held"]
+
+    @property
+    def kv_bytes_undivided(self) -> int:
+        return self.kv_usage()["bytes_undivided"]
+
+    @property
+    def kv_bytes_allocatable(self) -> int:
+        return ((self.num_pages - 1) * self.page_bytes
+                + self.max_slots * self.ring_pages * self.ring_page_bytes)
 
     def alloc_pages(self, n: int) -> List[int]:
         """Claim ``n`` free pages (refcount 1 each; lowest-numbered
@@ -511,8 +627,15 @@ class PagePool:
         dispatched horizon length (rows the device froze mid-scan
         advanced only up to their freeze, and the mirror must agree
         with the device's frozen position exactly)."""
+        ps, ring = self.page_size, self.ring_pages
         for slot, steps in realized.items():
-            self._positions_host[slot] += int(steps)
+            before = self._positions_host[slot]
+            self._positions_host[slot] = after = before + int(steps)
+            if ring:
+                # a page begun at or beyond the ring's length lands on
+                # an entry that held a page now out of reach
+                self.ring_pages_overwritten += max(
+                    0, after // ps - max(before // ps, ring - 1))
 
     @property
     def max_active_pos(self) -> int:
@@ -530,6 +653,19 @@ class PagePool:
         return sum(p // self.page_size + 1
                    for p, live in zip(self._positions_host,
                                       self._active_host) if live)
+
+    def live_pages_by_kind(self) -> Dict[str, int]:
+        """``live_pages`` for two kinds of layer: what ONE full
+        layer's kernel reads (every page up to the position) and what
+        one sliding layer's reads (the pages that hold a column in the
+        window's reach), off the host mirror."""
+        ps, window = self.page_size, self.ring_window
+        live = [p for p, on in zip(self._positions_host,
+                                   self._active_host) if on]
+        return {"kv_pages_live_full": sum(p // ps + 1 for p in live),
+                "kv_pages_live_window": sum(
+                    p // ps - max(p - window + 1, 0) // ps + 1
+                    for p in live)}
 
 
 class PrefixEntry:

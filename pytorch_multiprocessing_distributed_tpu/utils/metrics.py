@@ -191,6 +191,18 @@ class ServingMetrics:
         self.moe_rows_given = 0
         self.moe_layer_blocks = 0
         self.moe_full_width = 0
+        # two kinds of layer in one cache manager (a family with
+        # sliding-window layers): pages held by kind, the bytes both
+        # pools hold over what ONE pool of every layer would hold for
+        # the same requests, and ring pages written over since the
+        # first sample (each a page that fell out of the window's
+        # reach), sampled once a drained block
+        self.kv_pages_full = AverageMeter()
+        self.kv_pages_sliding = AverageMeter()
+        self.kv_bytes_held = AverageMeter()
+        self.kv_bytes_undivided = AverageMeter()
+        self.kv_ring_pages_overwritten = 0
+        self._ring_base = None
         self._elapsed = 0.0
         self._occupancy_max = 0
         self._queue_wait_max = 0.0
@@ -335,6 +347,19 @@ class ServingMetrics:
             self.moe_load.update(
                 float((counts.max(axis=1)[live] / means[live]).mean()))
 
+    def record_kv_pages(self, pool) -> None:
+        """One drained block's sample of a two-kind ``PagePool``
+        (host mirror only: no device read)."""
+        usage = pool.kv_usage()
+        self.kv_pages_full.update(usage["full"])
+        self.kv_pages_sliding.update(usage["sliding"])
+        self.kv_bytes_held.update(usage["bytes_held"])
+        self.kv_bytes_undivided.update(usage["bytes_undivided"])
+        if self._ring_base is None:
+            self._ring_base = pool.ring_pages_overwritten
+        self.kv_ring_pages_overwritten = (pool.ring_pages_overwritten
+                                          - self._ring_base)
+
     def record_page_hold(self) -> None:
         """One admission deferred because the page pool could not
         cover the FIFO head's demand — the head stays QUEUED (held,
@@ -410,6 +435,12 @@ class ServingMetrics:
             "moe_full_width_share": (
                 0.0 if self.moe_layer_blocks == 0
                 else self.moe_full_width / self.moe_layer_blocks),
+            "kv_pages_held_full": self.kv_pages_full.avg,
+            "kv_pages_held_sliding": self.kv_pages_sliding.avg,
+            "kv_bytes_held_over_undivided": (
+                0.0 if self.kv_bytes_undivided.avg == 0
+                else self.kv_bytes_held.avg / self.kv_bytes_undivided.avg),
+            "kv_ring_pages_overwritten": self.kv_ring_pages_overwritten,
         }
         # graftscope percentile telemetry: the tail IS the SLO
         for name, meter in (("ttft", self.ttft),

@@ -1,0 +1,712 @@
+"""The ``afmoe`` family against its plain reference
+(``perf/reference/afmoe.py``) at the tiny preset on the CPU, seeded
+random weights: grouped-query attention with QK norms and an output
+gate in its three forms (whole-prompt prefill, chunked prefill, decode
+through BOTH pools far beyond the window, the ring wrapping), the
+grouped paged kernel in interpret mode against its XLA twin round the
+window's edge, the share of the routed experts, a cache manager with
+two kinds of layer, what the engine refuses for the family, and
+planted faults that the comparison has to catch.
+
+``afmoe_tiny``: six query heads on two key/value heads of 16, a window
+of 8, 16 experts at top-4, kinds sliding-sliding-sliding-full-sliding;
+pages of 4, so a ring of ``ceil(8 / 4) + 1 = 3`` pages a slot.
+
+Tolerances are shares of the reference logits' standard deviation.
+Float32 program and reference do the same arithmetic in another order:
+the LARGEST error over all logits (``_rel``) reads 3e-7 to 2e-6 (limit
+2e-5). bfloat16 weights and matmul inputs, five layers deep with a
+selection bias of 0.01 among 16 experts, flip near-ties of the fourth
+and fifth expert, and one flipped choice moves a token's whole row (the
+largest error reads 0.3 to 0.5): what is held to a limit there is the
+MEAN error (``_mean_rel``; 0.007 to 0.020 over three seeds, limit
+0.05), as the benchmark's door compares the mean gap and not the worst.
+Each planted fault reads above that limit in the float32 program (0.076
+to 0.51), which passes it a hundred thousand times over.
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from perf.reference import afmoe as reference
+from pytorch_multiprocessing_distributed_tpu import models
+from pytorch_multiprocessing_distributed_tpu.inference.generate import (
+    cache_pools, generate, pref_cache_shapes, serving_family)
+from pytorch_multiprocessing_distributed_tpu.models import afmoe, latent
+from pytorch_multiprocessing_distributed_tpu.ops.moe import (
+    dropless_experts, route_sigmoid_topk)
+from pytorch_multiprocessing_distributed_tpu.runtime.scope import scoped
+from pytorch_multiprocessing_distributed_tpu.serving import (
+    PagePool, PagePoolExhausted, ServingEngine, init_params)
+from pytorch_multiprocessing_distributed_tpu.utils.metrics import (
+    ServingMetrics)
+
+# the module, not the same-named function ops.pallas re-exports
+da = importlib.import_module(
+    "pytorch_multiprocessing_distributed_tpu.ops.pallas.decode_attention")
+
+F32_LIMIT = 2e-5
+BF16_LIMIT = 0.05
+VOCAB = 211
+PS = 4                      # pages of 4: a ring of 3 for the window of 8
+# the tiny model routes over 16 experts at top-4; this chip holds four
+SHARE = dict(experts_held=4, expert_offset=8)
+
+
+def _config(model) -> dict:
+    """The published key names for a model's sizes: what the reference
+    is configured from (the experts held and the router's width it
+    reads off the weights)."""
+    return {
+        "num_hidden_layers": model.num_layers,
+        "layer_types": list(model.layer_types),
+        "sliding_window": model.sliding_window,
+        "rms_norm_eps": model.rms_eps,
+        "hidden_size": model.hidden_size,
+        "num_attention_heads": model.num_heads,
+        "num_key_value_heads": model.num_kv_heads,
+        "head_dim": model.head_dim,
+        "num_experts_per_tok": model.moe_top_k,
+        "route_norm": True, "route_scale": model.routed_scale,
+        "mup_enabled": True, "rope_theta": model.rope_theta,
+        "expert_offset": model.expert_offset}
+
+
+def _rel(got, want) -> float:
+    want = np.asarray(want, np.float32)
+    return float(np.max(np.abs(np.asarray(got, np.float32) - want))
+                 / np.std(want))
+
+
+def _mean_rel(got, want) -> float:
+    want = np.asarray(want, np.float32)
+    return float(np.mean(np.abs(np.asarray(got, np.float32) - want))
+                 / np.std(want))
+
+
+def _tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(0, VOCAB, size=n)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    model = models.get_model("afmoe_tiny", dtype=jnp.float32, **SHARE)
+    return model, init_params(model, 0)
+
+
+@pytest.fixture(scope="module")
+def ref_logits(tiny):
+    model, params = tiny
+    fn = reference.make_logits_fn(_config(model))
+    return lambda tokens: np.asarray(fn(params, jnp.asarray(tokens)))
+
+
+def _prefill_logits(model, params, tokens):
+    family = model.serving_family
+    x, _, _ = family.prefill(model, params, jnp.asarray(tokens)[None])
+    return np.asarray(family.logits(model, params, x)[0])
+
+
+def _chunked_logits(model, params, tokens, chunk=16):
+    """The chunk program over a standalone cache as wide as the
+    prompt, one chunk after the other."""
+    family = model.serving_family
+    full, sliding = (jnp.zeros(shape, jnp.float32)
+                     for shape in pref_cache_shapes(model, len(tokens)))
+    out = []
+    for start in range(0, len(tokens), chunk):
+        x, full, sliding = family.chunk(
+            model, params, full, sliding,
+            jnp.asarray(tokens[start:start + chunk])[None],
+            jnp.int32(start))
+        out.append(family.logits(model, params, x)[0])
+    return np.concatenate(out)
+
+
+def _pools(model, slots, pages_per_slot):
+    """Both pools for ``slots`` slots, empty: the full layers' behind
+    an identity page table (page 0 is scratch), the sliding layers'
+    rings; and the engine's splice into them."""
+    ring = -(-model.sliding_window // PS) + 1
+    full = jnp.zeros((model.n_full, slots * pages_per_slot + 1, PS,
+                      model.kv_row), jnp.float32)
+    rings = jnp.zeros((model.n_sliding, slots * ring, PS, model.kv_row),
+                      jnp.float32)
+    table = (1 + jnp.arange(slots * pages_per_slot, dtype=jnp.int32)
+             ).reshape(slots, pages_per_slot)
+    return full, rings, table, ServingEngine._ring_insert_fn
+
+
+def _decode_logits(model, params, tokens, prompt=8, impl="xla",
+                   slots=2):
+    """Prefill ``prompt`` tokens, splice them into both pools the
+    engine's way, then decode the rest ONE token a step through the
+    page table and the ring; returns the logits of every decoded
+    position (slot 0; slot 1 idles at position 0)."""
+    family = model.serving_family
+    n = len(tokens)
+    pages_per_slot = -(-n // PS)
+    width = -(-prompt // PS) * PS
+    padded = np.zeros((1, width), np.int32)
+    padded[0, :prompt] = tokens[:prompt]
+    _, pref_full, pref_sliding = family.prefill(model, params,
+                                                jnp.asarray(padded))
+    full, rings, table, insert = _pools(model, slots, pages_per_slot)
+    state = (jnp.zeros((slots,), jnp.int32),) * 2 + (
+        jnp.zeros((slots,), bool), jnp.zeros((slots,), jnp.int32),
+        jnp.full((slots,), -1, jnp.int32))
+    write_ids = table[0, :width // PS]
+    full, rings, positions, *_ = insert(
+        full, rings, *state, pref_full, pref_sliding, write_ids,
+        jnp.int32(0), jnp.int32(prompt), jnp.int32(0), jnp.int32(0),
+        jnp.int32(-1))
+    step = jax.jit(lambda full, rings, positions, last: family.decode_step(
+        model, params, full, rings, positions, last, window=n,
+        attn_impl=impl, page_table=table, page_size=PS)[:3])
+    out = []
+    for at in range(prompt, n):
+        last = jnp.zeros((slots,), jnp.int32).at[0].set(int(tokens[at]))
+        x, full, rings = step(full, rings, positions, last)
+        out.append(family.logits(model, params, x)[0, 0])
+        positions = positions.at[0].add(1)
+    return np.asarray(jnp.stack(out))
+
+
+# --------------------------------------------------------- the forward
+
+def test_registry_and_published_sizes():
+    """The stage the benchmark serves: one dense and four expert
+    layers, 32 of 256 experts, an eighth of the vocabulary: ISSUE 36's
+    4,321.8 M parameters, 8.64-8.65 GB as served; kinds by the
+    published rule; two pools of one 2,048-value row."""
+    model = models.get_model(
+        "trinity_large_preview", dtype=jnp.bfloat16, num_layers=5,
+        first_k_dense=1, experts_held=32, vocab_size=25024)
+    family = serving_family(model)
+    assert family.name == "afmoe"
+    assert (model.hidden_size, model.num_heads, model.num_kv_heads,
+            model.head_dim) == (3072, 48, 8, 128)
+    assert (model.n_experts, model.n_held, model.moe_top_k, model.moe_dim,
+            model.mlp_dim) == (256, 32, 4, 3072, 12288)
+    assert model.layer_types == ("sliding_attention",) * 3 + (
+        "full_attention", "sliding_attention")
+    assert (model.n_full, model.n_sliding, model.n_moe_layers) == (1, 4, 4)
+    assert cache_pools(model) == (
+        ("full", (2048,), jnp.bfloat16, 1, None),
+        ("sliding", (2048,), jnp.bfloat16, 4, 4096))
+    assert pref_cache_shapes(model, 8192) == ((1, 1, 8192, 2048),
+                                              (4, 1, 8192, 2048))
+    shapes = jax.eval_shape(lambda: model._init(jax.random.PRNGKey(0)))
+    leaves = jax.tree.leaves(shapes)
+    count = sum(int(np.prod(s.shape)) for s in leaves)
+    held = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in leaves)
+    assert abs(count - 4.3218e9) < 1e6          # ISSUE 36's own count
+    assert abs(held - 8.65e9) < 2e6
+    moe = shapes["layer_1"]["moe"]
+    assert moe["router"].shape == (3072, 256)
+    assert moe["e_bias"].shape == (256,)
+    assert moe["router"].dtype == moe["e_bias"].dtype == jnp.float32
+    assert moe["w_gate"].shape == (32, 3072, 3072)
+    assert shapes["layer_0"]["attn"]["wg"].shape == (3072, 48 * 128)
+    assert shapes["layer_0"]["attn"]["wk"].dtype == jnp.bfloat16
+    # the registry's default is the published model, whole
+    whole = models.get_model("trinity_large_preview")
+    assert (whole.num_layers, whole.first_k_dense, whole.n_held,
+            whole.vocab_size) == (60, 6, 256, 200192)
+    assert (whole.n_full, whole.n_sliding) == (15, 45)
+    # every other family says three fields and means every layer
+    gpt = models.get_model("gpt_tiny")
+    assert [p[3:] for p in cache_pools(gpt)] == [(gpt.num_layers, None)] * 2
+
+
+@pytest.mark.parametrize("form", ["whole-prompt", "chunked", "decode",
+                                  "decode-kernel"])
+def test_program_equals_the_reference(tiny, ref_logits, form):
+    """Attention in its three forms against the reference's full score
+    matrix under a band mask: 96 tokens are twelve windows; the decode
+    runs from position 8 to 47 through the page table AND the ring,
+    which wraps every three pages (context of almost six windows)."""
+    model, params = tiny
+    if form == "whole-prompt":
+        tokens = _tokens(96)
+        got, want = _prefill_logits(model, params, tokens), ref_logits(tokens)
+    elif form == "chunked":
+        tokens = _tokens(96, seed=1)
+        got, want = _chunked_logits(model, params, tokens), ref_logits(tokens)
+    else:
+        tokens = _tokens(48, seed=2)
+        got = _decode_logits(model, params, tokens, impl=(
+            "pallas" if form == "decode-kernel" else "xla"))
+        want = ref_logits(tokens)[8:]
+    assert _rel(got, want) < F32_LIMIT
+
+
+@pytest.mark.parametrize("share", [
+    {}, dict(experts_held=4, expert_offset=0),
+    dict(experts_held=4, expert_offset=12)],
+    ids=["every-expert", "experts-0-3", "experts-12-15"])
+def test_other_shares_equal_the_reference(share):
+    model = models.get_model("afmoe_tiny", dtype=jnp.float32, **share)
+    params = init_params(model, 0)
+    tokens = _tokens(64)
+    want = reference.make_logits_fn(_config(model))(params,
+                                                    jnp.asarray(tokens))
+    assert _rel(_prefill_logits(model, params, tokens), want) < F32_LIMIT
+
+
+# ----------------------------------------------------------- the kernel
+
+WINDOW, ENTRIES = 8, 3
+_POSITIONS = {
+    # the window of 8 reaches back to column 0 up to position 7
+    "before-the-edge": [0, 2, 5, 6],
+    "at-the-edge": [7, 7, 8, 8],
+    "after-the-edge": [9, 10, 11, 12],
+    # the ring of 3 pages wraps at 12, 24, 36, ...
+    "across-a-wrap": [11, 12, 23, 25, 35, 36, 37, 47],
+}
+
+
+@pytest.mark.parametrize("where", list(_POSITIONS))
+@pytest.mark.parametrize("kind", ["full", "sliding"])
+def test_kernel_in_interpret_mode_equals_its_xla_twin(kind, where):
+    """One body, two tables. ``full``: a page table, every column up
+    to the position. ``sliding``: a ring of 3 pages a slot holding the
+    LAST three pages written (older ones overwritten), the first live
+    page masked below ``pos - 7``. Both against dense attention over
+    the columns in reach, written out here."""
+    rng = np.random.default_rng(7)
+    positions = np.array(_POSITIONS[where])
+    b, heads, kv_heads, d, n_pages = len(positions), 6, 2, 16, 12
+    q = jnp.asarray(rng.normal(size=(b, heads, d)), jnp.float32)
+    rows = rng.normal(size=(b, n_pages * PS, 2, kv_heads, d)).astype(
+        np.float32)                        # every slot's whole history
+    if kind == "full":
+        reach, entries = None, n_pages
+        table = 1 + np.arange(b * n_pages).reshape(b, n_pages)
+        pool = np.zeros((2, b * n_pages + 1, PS, 2 * kv_heads * d),
+                        np.float32)
+        pool[1, 1:] = rows.reshape(b * n_pages, PS, -1)
+    else:
+        reach, entries = WINDOW, ENTRIES
+        table = np.arange(b * entries).reshape(b, entries)
+        pool = rng.normal(size=(2, b * entries, PS, 2 * kv_heads * d)
+                          ).astype(np.float32)
+        for s, pos in enumerate(positions):    # what decode left behind
+            for page in range(pos // PS + 1):  # later pages overwrite
+                pool[1, table[s, page % entries]] = rows[
+                    s, page * PS:(page + 1) * PS].reshape(PS, -1)
+    args = (q, jnp.asarray(pool), jnp.asarray(table, jnp.int32),
+            jnp.asarray(positions, jnp.int32))
+    kw = dict(layer=1, kv_heads=kv_heads, scale=d ** -0.5, reach=reach)
+    got = da.gqa_paged_decode_attention(*args, impl="pallas",
+                                        interpret=True, **kw)
+    twin = da.gqa_paged_decode_attention(*args, impl="xla", **kw)
+    want = np.zeros((b, heads, d), np.float32)
+    for s, pos in enumerate(positions):
+        lo = 0 if reach is None else max(0, pos - reach + 1)
+        k, v = rows[s, lo:pos + 1, 0], rows[s, lo:pos + 1, 1]
+        for t in range(heads):
+            g = t // (heads // kv_heads)
+            score = np.asarray(q[s, t]) @ k[:, g].T * d ** -0.5
+            p = np.exp(score - score.max())
+            want[s, t] = (p / p.sum()) @ v[:, g]
+    np.testing.assert_allclose(np.asarray(twin), want, rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-6)
+
+
+def test_only_pages_in_reach_are_named():
+    """The ids the kernel's index maps read: a sliding layer names the
+    pages that hold a column in ``[pos - 7, pos]`` and no other (dead
+    operands repeat a live one), from the page of ``pos - 7`` on."""
+    table = jnp.arange(12, dtype=jnp.int32).reshape(4, 3)
+    positions = jnp.array([2, 7, 12, 25], jnp.int32)
+    ids = np.asarray(da._reach_page_ids(table, positions, 2, PS, WINDOW))
+    assert ids.shape == (4, 4)              # two blocks of two pages
+    # slot 0: page 0 only; slot 1: pages 0-1; slot 2 (columns 5..12):
+    # logical pages 1, 2, 3 at ring entries 1, 2, 0; slot 3 (columns
+    # 18..25): logical pages 4, 5, 6 at entries 1, 2, 0
+    assert ids[0].tolist() == [0, 0, 0, 0]
+    assert ids[1].tolist() == [3, 4, 3, 4]
+    assert ids[2].tolist() == [7, 8, 6, 8]
+    assert ids[3].tolist() == [10, 11, 9, 11]
+    # a full layer names every page up to the position, from page 0
+    full = np.asarray(da._reach_page_ids(
+        jnp.arange(1, 9, dtype=jnp.int32).reshape(1, 8),
+        jnp.array([13], jnp.int32), 2, PS, None))
+    assert full[0].tolist() == [1, 2, 3, 4, 3, 4, 3, 4]
+
+
+# ------------------------------------------------------------ the share
+
+def _layer(seed=1, t=48, d=16, f=24, e=16):
+    rng = np.random.default_rng(seed)
+
+    def mat(*shape, scale=.3):
+        return jnp.asarray(rng.normal(size=shape) * scale, jnp.float32)
+
+    return (mat(t, d, scale=1.0),
+            {"router": mat(d, e, scale=1.0), "e_bias": mat(e, scale=0.5),
+             "w_gate": mat(e, d, f), "w_up": mat(e, d, f),
+             "w_down": mat(e, f, d),
+             "shared": {"w_gate": mat(d, f), "w_up": mat(d, f),
+                        "w_down": mat(f, d)}})
+
+
+@pytest.mark.parametrize("side", ["program", "reference"])
+def test_the_eight_shares_add_up_to_the_uncut_expert_layer(side):
+    """The guide's share test at the deployment's count: the parts
+    that all 8 shares of 2 experts give, with the shared expert (which
+    every chip computes alike) counted once, add up to the uncut
+    reference's expert layer, selection bias and all; and each share
+    is dropless: held + elsewhere = T x k."""
+    x, p = _layer()
+    t, e, k, held = x.shape[0], 16, 4, 2
+    hp = {"top_k": k, "route_scale": 2.448}
+    want = reference.experts(x, p, {**hp, "offset": 0})
+    shared = reference.gated(x, p["shared"]["w_gate"], p["shared"]["w_up"],
+                             p["shared"]["w_down"], {})
+    chosen, weights = route_sigmoid_topk(x, p["router"], p["e_bias"], k,
+                                         2.448)
+    total, every = shared, []
+    for offset in range(0, e, held):
+        mine = {**p, **{name: p[name][offset:offset + held]
+                        for name in ("w_gate", "w_up", "w_down")}}
+        if side == "program":
+            part, counts, elsewhere, _ = dropless_experts(
+                x, chosen, weights, mine["w_gate"], mine["w_up"],
+                mine["w_down"], n_experts=e, offset=offset)
+            assert int(counts.sum()) + int(elsewhere) == t * k
+            every.append(np.asarray(counts))
+        else:
+            part = reference.experts(x, mine, {**hp, "offset": offset}
+                                     ) - shared
+        total = total + part
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
+    if side == "program":
+        # every assignment was computed by exactly one share
+        assert (np.concatenate(every) == np.bincount(
+            np.asarray(chosen).ravel(), minlength=e)).all()
+
+
+# --------------------------------------------- two kinds of layer, a pool
+
+def _reserve(pool, slot_tokens):
+    """What an admission does on the host: a slot, the pages of the
+    request's whole context, the table row."""
+    slot = pool.acquire()
+    pool.bind_slot(slot, pool.alloc_pages(
+        PagePool.pages_for(slot_tokens, pool.page_size)))
+    return slot
+
+
+@pytest.mark.parametrize("case", [
+    "shapes", "pages-held-at-context-n", "release-returns-all",
+    "exhaustion", "share-by-bytes", "ring-pages-overwritten"])
+def test_page_pool_accounts_for_two_kinds(tiny, case):
+    model, _ = tiny
+    pool = PagePool(model, 3, 64, page_size=PS)
+    row_bytes = model.kv_row * 4                 # float32 here
+    if case == "shapes":
+        # the full layer under the table at dense parity; the four
+        # sliding layers a ring of ceil(8 / 4) + 1 = 3 pages a slot
+        assert pool.ring_pages == 3 and pool.pages_per_slot == 16
+        assert pool.k_pages.shape == (1, 3 * 16 + 1, PS, model.kv_row)
+        assert pool.v_pages.shape == (4, 3 * 3, PS, model.kv_row)
+        assert pool.page_bytes == 1 * PS * row_bytes
+        assert pool.ring_page_bytes == 4 * PS * row_bytes
+        assert (pool.page_bytes * pool.num_pages
+                + pool.ring_page_bytes * 3 * 3
+                == pool.k_pages.nbytes + pool.v_pages.nbytes)
+        # one undivided pool of five layers would hold 16 pages a slot
+        # in every layer: 80 layer-pages against 16 + 4 x 3 = 28
+        assert (PagePool.per_slot_kv_bytes(model, 64)
+                == (64 + 4 * 8) * row_bytes)
+        # a short s_max never needs the whole ring
+        assert PagePool(model, 2, 8, page_size=PS).ring_pages == 2
+    elif case == "pages-held-at-context-n":
+        for n, full, sliding in ((3, 1, 1), (8, 2, 2), (9, 3, 3),
+                                 (40, 10, 3)):
+            one = PagePool(model, 3, 64, page_size=PS)
+            _reserve(one, n)
+            assert one.pages_held() == {"full": full, "sliding": sliding}
+            assert one.kv_bytes_held == (full + 4 * sliding) * PS * row_bytes
+            assert one.kv_bytes_undivided == 5 * full * PS * row_bytes
+    elif case == "release-returns-all":
+        slots = [_reserve(pool, n) for n in (40, 9, 64)]
+        assert pool.pages_held() == {"full": 10 + 3 + 16, "sliding": 9}
+        for slot in slots:
+            pool.release(slot)
+        assert pool.pages_held() == {"full": 0, "sliding": 0}
+        assert pool.pages_in_use == 0 and pool.free_slots == 3
+        assert pool.free_pages == pool.num_pages - 1
+    elif case == "exhaustion":
+        small = PagePool(model, 3, 64, page_size=PS, num_pages=20)
+        _reserve(small, 64)                      # 16 of 19 pages
+        with pytest.raises(PagePoolExhausted):
+            small.alloc_pages(4)
+        assert small.free_pages == 3
+    elif case == "share-by-bytes":
+        _reserve(pool, 40)                       # 10 full + 3 ring pages
+        held = (10 * 1 + 3 * 4) * PS * row_bytes
+        room = (48 * 1 + 9 * 4) * PS * row_bytes
+        assert pool.kv_bytes_held == held
+        assert pool.kv_bytes_allocatable == room
+        # what perf/drivers/serve.py divides: a share of what both
+        # kinds can hold, by bytes, not 10 / 48 of the table's pages
+        assert pool.pages_in_use / (pool.num_pages - 1) == pytest.approx(
+            held / room)
+    else:
+        slot = _reserve(pool, 64)
+        pool.note_insert(slot, 9)                # in page 2: the ring is full
+        pool.note_advance_slots({slot: 2})       # to 11: the same page
+        assert pool.ring_pages_overwritten == 0
+        pool.note_advance_slots({slot: 1})       # page 3 lands on entry 0
+        assert pool.ring_pages_overwritten == 1
+        pool.note_advance_slots({slot: 20})      # pages 4 .. 8
+        assert pool.ring_pages_overwritten == 6
+        assert pool.live_pages_by_kind() == {
+            "kv_pages_live_full": 9, "kv_pages_live_window": 3}
+
+
+@pytest.mark.parametrize("name", ["gpt_tiny", "pangu_ultra_moe_tiny"])
+def test_one_pool_families_keep_their_pool(name):
+    """A family that declares nothing: both pools hold every layer at
+    ``num_pages`` pages, no ring, and ``pages_in_use`` is the count of
+    pages off the free list, an integer, as before."""
+    model = models.get_model(name, dtype=jnp.float32)
+    pool = PagePool(model, 3, 64, page_size=8)
+    assert pool.ring_pages == 0 and pool.ring_page_bytes == 0
+    assert pool.k_pages.shape[:3] == pool.v_pages.shape[:3] == (
+        model.num_layers, 3 * 8 + 1, 8)
+    assert (PagePool.page_kv_bytes(model, 8) * pool.num_pages
+            == pool.k_pages.nbytes + pool.v_pages.nbytes)
+    slot = _reserve(pool, 20)
+    assert pool.pages_in_use == 3 and isinstance(pool.pages_in_use, int)
+    assert pool.pages_held() == {"full": 3, "sliding": 0}
+    assert pool.kv_bytes_held == pool.kv_bytes_undivided == 3 * pool.page_bytes
+    pool.release(slot)
+    assert pool.pages_in_use == 0
+
+
+@pytest.mark.parametrize("prompt", [3, 8, 9, 13, 30])
+def test_the_splice_keeps_the_last_window_in_the_ring(tiny, prompt):
+    """After the insert, ring entry ``g % 3`` holds page ``g`` of the
+    prompt's sliding caches for the newest three pages up to the one
+    that holds column ``prompt - 1``; the full pool holds every page."""
+    model, _ = tiny
+    width = -(-prompt // 16) * 16
+    rng = np.random.default_rng(prompt)
+    pref_full, pref_sliding = (
+        jnp.asarray(rng.normal(size=shape), jnp.float32)
+        for shape in pref_cache_shapes(model, width))
+    full, rings, table, insert = _pools(model, 2, width // PS)
+    state = (jnp.zeros((2,), jnp.int32),) * 2 + (
+        jnp.zeros((2,), bool), jnp.zeros((2,), jnp.int32),
+        jnp.full((2,), -1, jnp.int32))
+    full, rings, positions, last, active, budgets, eos = insert(
+        full, rings, *state, pref_full, pref_sliding, table[1],
+        jnp.int32(1), jnp.int32(prompt), jnp.int32(5), jnp.int32(7),
+        jnp.int32(-1))
+    assert (positions[1], last[1], bool(active[1]), budgets[1]) == (
+        prompt, 5, True, 7)
+    pages = np.asarray(pref_sliding).reshape(4, width // PS, PS, -1)
+    newest = (prompt - 1) // PS
+    for g in range(max(0, newest - 2), newest + 1):
+        assert (np.asarray(rings[:, 3 + g % 3]) == pages[:, g]).all()
+    assert (np.asarray(rings[:, :3]) == 0).all()     # slot 0 untouched
+    assert (np.asarray(full[:, np.asarray(table[1])]).reshape(
+        1, width, -1) == np.asarray(pref_full)[:, 0]).all()
+
+
+# ------------------------------------------------------------ the engine
+
+def _serve(model, params, requests, **kw):
+    kw.setdefault("max_slots", 3)
+    engine = ServingEngine(model, params, s_max=128, page_size=PS, **kw)
+    out = []
+    for prompt, n in requests:              # staggered: one a step
+        out.append(engine.submit(list(prompt), n))
+        engine.step()
+    while engine.in_flight:
+        engine.step()
+    return engine, out
+
+
+def _gaps(ref_fn, params, request):
+    """Per emitted position: the reference's largest logit minus its
+    logit for the emitted token, in reference standard deviations."""
+    stream = np.array(list(request.prompt) + list(request.tokens))
+    logits = np.asarray(ref_fn(params, jnp.asarray(stream)))
+    first = len(request.prompt) - 1
+    rows = logits[first:len(stream) - 1]
+    picked = rows[np.arange(len(rows)), stream[first + 1:]]
+    return (rows.max(axis=1) - picked) / logits.std()
+
+
+@pytest.mark.parametrize("chunk, impl", [(8, "xla"), (None, "xla"),
+                                         (8, "pallas")],
+                         ids=["chunked", "whole-prompt", "chunked-kernel"])
+def test_engine_staggered_admissions_agree_with_the_reference(tiny, chunk,
+                                                              impl):
+    """Through ServingEngine, the two-kind PagePool and the scheduler
+    (the pipelined step, admissions a step apart, slots handed on, five
+    requests over three slots): every emitted token is the reference's
+    own argmax at its position, to contexts of eleven windows; the
+    meters carry both kinds of page and the share of the experts."""
+    model, params = tiny
+    prompts = [(_tokens(70, 1), 20), (_tokens(33, 2), 12),
+               (_tokens(50, 3), 9), (_tokens(5, 4), 30),
+               (_tokens(17, 5), 8)]
+    with scoped() as scope:
+        engine, served = _serve(model, params, prompts,
+                                prefill_chunk=chunk, decode_attn=impl)
+    ref_fn = reference.make_logits_fn(_config(model))
+    for request, (_, n) in zip(served, prompts):
+        assert len(request.tokens) == n
+        assert _gaps(ref_fn, params, request).max() == 0.0
+    snap = engine.metrics.snapshot()
+    assert snap["decode_host_syncs"] == snap["decode_dispatches"]
+    assert (snap["moe_assignments"] + snap["moe_assignments_elsewhere"]
+            == snap["decode_dispatches"] * 3 * model.moe_top_k
+            * model.n_moe_layers)
+    # a slot past the window holds 3 ring pages in each of 4 sliding
+    # layers and its whole context in the one full layer
+    assert snap["kv_pages_held_full"] > snap["kv_pages_held_sliding"] > 0
+    assert 0 < snap["kv_bytes_held_over_undivided"] < 1
+    assert snap["kv_ring_pages_overwritten"] > 0
+    dispatches = [e for e in scope.events() if e.name == "decode.dispatch"]
+    assert len(dispatches) == snap["decode_dispatches"]
+    assert all(e.attrs["experts_held"] == 4 for e in dispatches)
+    assert all(0 <= e.attrs["kv_pages_live_window"]
+               <= e.attrs["kv_pages_live_full"] for e in dispatches)
+    assert max(e.attrs["kv_pages_live_window"] for e in dispatches) <= 9
+    pool = engine.pool
+    assert engine.in_flight == 0 and pool.pages_in_use == 0
+    assert pool.pages_held() == {"full": 0, "sliding": 0}
+
+
+def test_engine_bfloat16_within_its_tolerance():
+    """bfloat16 weights and matmuls against the float32 reference of
+    the SAME (bfloat16-valued) weights: the prefill logits' mean error
+    and the emitted tokens' mean gap within BF16_LIMIT."""
+    model = models.get_model("afmoe_tiny", dtype=jnp.bfloat16, **SHARE)
+    params = init_params(model, 0)
+    assert params["layer_0"]["attn"]["wg"].dtype == jnp.bfloat16
+    assert params["layer_1"]["moe"]["e_bias"].dtype == jnp.float32
+    ref_fn = reference.make_logits_fn(_config(model))
+    tokens = _tokens(96)
+    got = _prefill_logits(model, params, tokens)
+    assert _mean_rel(got, ref_fn(params, jnp.asarray(tokens))) < BF16_LIMIT
+    _, served = _serve(model, params, [(_tokens(70, 1), 20),
+                                       (_tokens(33, 2), 12)],
+                       prefill_chunk=8)
+    for request in served:
+        assert _gaps(ref_fn, params, request).mean() < BF16_LIMIT
+
+
+def test_record_kv_pages_reads_the_pool(tiny):
+    model, _ = tiny
+    metrics = ServingMetrics()
+    assert metrics.snapshot()["kv_bytes_held_over_undivided"] == 0.0
+    pool = PagePool(model, 3, 64, page_size=PS)
+    slot = _reserve(pool, 40)
+    pool.note_insert(slot, 9)
+    metrics.record_kv_pages(pool)
+    pool.note_advance_slots({slot: 8})          # pages 3 and 4 begun
+    metrics.record_kv_pages(pool)
+    snap = metrics.snapshot()
+    assert snap["kv_pages_held_full"] == 10
+    assert snap["kv_pages_held_sliding"] == 3
+    # 10 + 4 x 3 layer-pages against 5 x 10 in one undivided pool
+    assert snap["kv_bytes_held_over_undivided"] == pytest.approx(22 / 50)
+    assert snap["kv_ring_pages_overwritten"] == 2
+
+
+@pytest.mark.parametrize("options, named", [
+    (dict(kv_dtype="int8"), "kv_dtype=int8 is not supported for the afmoe"),
+    (dict(draft_k=2), "draft_k is not supported for the afmoe"),
+    (dict(prefix_cache=4), "prefix_cache is not supported for the afmoe"),
+    (dict(mesh=True), "mesh is not supported for the afmoe"),
+], ids=["kv_dtype=int8", "draft_k", "prefix_cache", "mesh"])
+def test_engine_refuses_by_name_what_the_family_lacks(tiny, options, named):
+    from jax.sharding import Mesh
+
+    model, params = tiny
+    if "mesh" in options:
+        options = dict(mesh=Mesh(np.array(jax.devices()[:2]).reshape(1, 2),
+                                 ("data", "model")))
+    with pytest.raises(NotImplementedError) as e:
+        ServingEngine(model, params, max_slots=2, s_max=64, page_size=PS,
+                      **options)
+    assert named in str(e.value)
+
+
+def test_generate_is_refused_by_name(tiny):
+    model, params = tiny
+    with pytest.raises(NotImplementedError, match="afmoe"):
+        generate(model, params, jnp.zeros((1, 4), jnp.int32),
+                 max_new_tokens=2)
+
+
+# ------------------------------------------------------- planted faults
+
+def _decode_ignores_the_window(monkeypatch):
+    """The sliding layers' kernel told no lower bound: it attends
+    whatever its ring still holds (up to 12 columns for 8)."""
+    inner = afmoe.gqa_paged_decode_attention
+    monkeypatch.setattr(
+        afmoe, "gqa_paged_decode_attention",
+        lambda *a, reach=None, **kw: inner(*a, reach=None, **kw))
+
+
+def _rotary_on_the_full_layer(monkeypatch):
+    inner = afmoe._qkvg
+    monkeypatch.setattr(
+        afmoe, "_qkvg",
+        lambda h, p, positions, rotate, model: inner(h, p, positions, True,
+                                                     model))
+
+
+def _gate_dropped(monkeypatch):
+    inner = afmoe._qkvg
+
+    def qkvg(*a):
+        q, row, gate = inner(*a)
+        return q, row, jnp.ones_like(gate)
+
+    monkeypatch.setattr(afmoe, "_qkvg", qkvg)
+
+
+def _expert_bias_dropped(monkeypatch):
+    inner = latent.route_sigmoid_topk
+    monkeypatch.setattr(
+        latent, "route_sigmoid_topk",
+        lambda x, router, e_bias, *a: inner(x, router, None, *a))
+
+
+@pytest.mark.parametrize("plant", [
+    _decode_ignores_the_window, _rotary_on_the_full_layer, _gate_dropped,
+    _expert_bias_dropped,
+], ids=["decode-ignores-the-window", "rotary-on-the-full-layer",
+        "gate-dropped", "expert-bias-dropped"])
+def test_planted_fault_exceeds_the_limit(tiny, ref_logits, monkeypatch,
+                                         plant):
+    """Each fault, planted in the float32 program from outside it,
+    reads above the family's tolerance (the bfloat16 limit on the mean
+    error) on the decode path (prefill of 8, 40 steps through both
+    pools), which the true program passes a hundred thousand times
+    over."""
+    model, params = tiny
+    tokens = _tokens(48, seed=2)
+    want = ref_logits(tokens)[8:]
+    assert _rel(_decode_logits(model, params, tokens), want) < F32_LIMIT
+    assert _mean_rel(_decode_logits(model, params, tokens), want) < 1e-5
+    plant(monkeypatch)
+    assert _mean_rel(_decode_logits(model, params, tokens),
+                     want) > BF16_LIMIT

@@ -82,6 +82,7 @@ KERNEL_NAMES = {
     "paged_decode_attention",
     "paged_verify_decode_attention", "fused_sgd_update", "ring_all_reduce",
     "mla_paged_decode_attention",
+    "gqa_paged_decode_attention_full", "gqa_paged_decode_attention_window",
 }
 
 
@@ -205,7 +206,36 @@ def _mla_case(slots=64, heads=32, rank=512, rope=128, page=16,
         interpret=False), args)
 
 
+def _gqa_case(reach, slots=48, heads=48, kv_heads=8, dim=128, page=16,
+              s_max=9216, window=4096):
+    """The grouped decode kernel at the shapes of the cell
+    ``trinity-large-preview.serve.closed-8k1k``: 48 slots, 48 query
+    heads on 8 key/value heads of 128, rows of 2,048 values (K then
+    V), pages of 16. ``reach`` None: the one full layer's pool under
+    the page table of a 9,216-column bucket; a number: layer 2 of the
+    four sliding layers' ring pool, 257 pages a slot."""
+    row = 2 * kv_heads * dim
+    if reach is None:
+        entries, layers, layer = s_max // page, 1, 0
+        pages = slots * entries + 1
+    else:
+        entries, layers, layer = -(-window // page) + 1, 4, 2
+        pages = slots * entries
+    args = (_sds((slots, heads, dim), BF16),
+            _sds((layers, pages, page, row), BF16),
+            _sds((slots, entries), jnp.int32), _sds((slots,), jnp.int32))
+    return (lambda q, c, t, p: da.gqa_paged_decode_attention(
+        q, c, t, p, layer=layer, kv_heads=kv_heads, scale=dim ** -0.5,
+        reach=reach, impl="pallas", interpret=False), args)
+
+
 _CASES = _DECODE + [
+    # the cell trinity-large-preview.serve.closed-8k1k: one body, two
+    # names (the full layer's page table; a sliding layer's ring)
+    pytest.param(lambda: _gqa_case(None),
+                 id="gqa-paged-decode-full-48slots-w9216"),
+    pytest.param(lambda: _gqa_case(4096),
+                 id="gqa-paged-decode-window-48slots-ring257"),
     pytest.param(_mla_case, id="mla-paged-decode-xing4-64slots-w8192"),
     # the cell openpangu-ultra-moe-718b.serve.closed-2k1k: 128 slots,
     # 128 heads (242 operations a byte of cache), window 4,096
