@@ -24,8 +24,9 @@ values side by side in the lanes (head ``h`` in lanes ``h * Dh .. (h +
 1) * Dh``), so it is lane-dense whatever ``Dh`` is, one contiguous DMA,
 and the layer is a STATIC index of the block's index map: no layer is
 ever sliced out of the pool, so the donated pool is written and read in
-place. The grid is ``(slot, block of G pages)``: one step folds ALL
-heads of ``G * ps`` columns (G = 8 pages of 16, 1 page of 128). Each
+place. The grid of the GPT and the grouped kernels is ``(slot, block
+of G pages)``: one step folds ALL heads of ``G * ps`` columns (G = 8
+pages of 16, 1 page of 128). Each
 pool is passed G times with plain ``BlockSpec``s — operand ``g`` of a
 step is page ``g`` of its block — and the pipeline double-buffers them,
 the next step's pages (the next slot's first block at a slot's end) in
@@ -41,6 +42,23 @@ scores in one contraction (the extra products are exact zeros), ``P
 rows`` is ``[H, H * Dh]`` and head ``h``'s output is its diagonal block,
 taken outside the kernel. The k-query verify pass is the same kernel
 with ``K1 * H`` query rows and a row-staggered mask.
+
+The LATENT kernel (:func:`mla_paged_decode_attention`: one row a token
+that every head shares) reads the same kind of pool and COPIES ITS OWN
+PAGES. The pool is passed once and stays in HBM (``pl.ANY``), the grid
+is the slots, and a slot is a loop over its LIVE blocks of G pages
+(``pos // (G * ps) + 1`` of them, G = 64 pages of 16): one
+``make_async_copy`` a live page into one half of a two-block VMEM
+buffer while the block before is folded out of the other half, the
+next slot's first block in flight at a slot's end. What a page copy
+costs decides between the two forms (a TPU v5e, PERF.md PR 37): as a
+pipelined operand — an index map, a descriptor and a semaphore wait a
+grid step and operand, a dead step's index maps included — ~0.1 us
+whatever the page holds; as a manual copy 19 scalar bundles to issue
+(~20 ns), 12 of them the compiler's two bounds checks, ~7 ns with the
+checks off and the ids clipped outside, and ONE wait a whole block.
+The copy loop (:func:`_live_page_copies`) is written for the other
+paged kernels to adopt.
 
 The GROUPED kernel (:func:`gqa_paged_decode_attention`: fewer
 key/value heads than query heads, and layers that attend a sliding
@@ -554,97 +572,185 @@ def paged_decode_attention(
 
 # ---------------------------------------------------- latent (MLA) pages
 
-# columns one grid step of the latent kernel folds: G = this // page_size
-# pages, each ONE DMA of one contiguous [ps, R + Rw] page (the kernel's
-# time is the DMAs' issue, ~0.1 us each, not their bytes: PERF.md)
-_MLA_BLOCK_COLUMNS = 512
+def _mla_block_pages(page_size, n_win, heads, row_bytes):
+    """G, the pages one block of the latent kernel copies and folds:
+    the most (a power of two, no more than the window has) whose
+    columns fit ``_PAGE_BLOCK_BYTES`` of VMEM as two buffers of rows
+    and a float32 score a head. 64 pages of 16 (1,024 columns) at 32
+    and at 128 heads: a block costs ~0.4-0.5 us beside its columns (the
+    chain matmul, row maximum, exponent, matmul), so on the chip 1,024
+    columns beat 512 and 256 at both head counts, and 2,048 lose at 128
+    heads to the dead columns of a slot's last block (PERF.md, PR 37)."""
+    fit = _PAGE_BLOCK_BYTES // ((2 * row_bytes + 4 * heads) * page_size)
+    return min(1 << max(fit, 1).bit_length() - 1, n_win)
 
 
-def _mla_paged_decode_kernel(pos_ref, ids_ref, q_ref, *rest, scale,
-                             page_size, group, rank):
-    """One (slot, block of ``group`` pages) cell of the ABSORBED latent
-    decode, all heads at once. The cache holds one row a token that
-    every head shares: the normed latent ``c`` (``rank`` values), then
-    the rotated ``k_rope``, zero-padded to whole lanes. The query is
-    laid out the same way (``q_lat | q_rope | 0``), so the scores
-    ``q_lat . c + q_rope . k_rope`` are ONE contraction over the row,
-    and the output is ``P c`` — still in the latent space; the caller
-    applies the per-head value up-projection. Same online-softmax
-    recurrence and the same live-page indirection as
-    :func:`_paged_decode_kernel`; a row is read once and used as key
-    and (its first ``rank`` lanes) as value."""
-    row_refs = rest[:group]
-    o_ref, acc, m_scr, l_scr = rest[group:]
+def _live_page_copies(pool_ref, layer, ids_ref, first, count, buf_ref, sem,
+                      *, group, page_size, wait):
+    """A paged kernel that copies its own pages: start (``wait``: wait
+    for) the copies of the ``count`` live pages (``1 <= count <=
+    group``) that the FLAT table ``ids_ref`` (SMEM) names from entry
+    ``first`` on, out of layer ``layer`` of the ``[L, P, ps, .]`` pool
+    where it lies in HBM, into the ``[group * ps, .]`` VMEM buffer
+    ``buf_ref``, page ``g`` under page ``g - 1``, all on the one DMA
+    semaphore ``sem``. A whole block is ``group`` copies issued as
+    straight-line code and ONE wait (a DMA semaphore counts bytes, so
+    a wait for the buffer's size is a wait for all its pages); a
+    slot's last, partial block is a loop of ``count`` either way.
+    Nothing beyond ``count`` is copied: what the rest of the buffer
+    holds is the caller's to mask."""
+    def copy(g):
+        return pltpu.make_async_copy(
+            pool_ref.at[layer, ids_ref[first + g]],
+            buf_ref.at[pl.ds(g * page_size, page_size)], sem)
+
+    @pl.when(count == group)
+    def _():
+        if wait:
+            pltpu.make_async_copy(buf_ref, buf_ref, sem).wait()
+        else:
+            for g in range(group):
+                copy(g).start()
+
+    @pl.when(count < group)
+    def _():
+        def one(g, carry):
+            if wait:
+                copy(g).wait()
+            else:
+                copy(g).start()
+            return carry
+        jax.lax.fori_loop(0, count, one, 0)
+
+
+def _mla_paged_decode_kernel(pos_ref, ids_ref, q_ref, pool_ref, o_ref,
+                             buf_ref, sem, acc_ref, m_ref, l_ref, done_ref,
+                             *, scale, layer, page_size, group, n_win, rank):
+    """One SLOT of the ABSORBED latent decode, all heads at once. The
+    cache holds one row a token that every head shares: the normed
+    latent ``c`` (``rank`` values), then the rotated ``k_rope``,
+    zero-padded to whole lanes. The query is laid out the same way
+    (``q_lat | q_rope | 0``), so the scores ``q_lat . c + q_rope .
+    k_rope`` are ONE contraction over the row, and the output is ``P
+    c`` — still in the latent space; the caller applies the per-head
+    value up-projection. A row is read once and used as key and (its
+    first ``rank`` lanes) as value.
+
+    The kernel copies its own pages (:func:`_live_page_copies`): the
+    pool stays in HBM and the grid is the slots. A slot is a loop over
+    its LIVE blocks of ``group`` pages, ``pos // (group * ps) + 1`` of
+    them: the block's pages arrive in one half of ``buf_ref`` while the
+    block before is folded out of the other, the next slot's first
+    block at a slot's end (``done_ref`` counts the blocks folded so far,
+    whose parity names the half). The fold is the online-softmax
+    recurrence of :func:`_paged_attention_kernel`; the columns beyond
+    the position are masked, and what lies behind the last live page
+    is a block copied earlier or the zeros of the first step, so a
+    masked column is ``0 x`` a finite number."""
     i = pl.program_id(0)
-    kb = pl.program_id(1)
     block_k = group * page_size
 
-    @pl.when(kb == 0)
-    def _():
-        acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+    def last_page(slot):
+        return jnp.clip(pos_ref[slot], 0, n_win * page_size - 1) // page_size
 
+    def copies(slot, block, half, wait=False):
+        _live_page_copies(
+            pool_ref, layer, ids_ref, slot * n_win + block * group,
+            jnp.minimum(last_page(slot) + 1 - block * group, group),
+            buf_ref.at[half], sem.at[half], group=group,
+            page_size=page_size, wait=wait)
+
+    @pl.when(i == 0)
+    def _():
+        buf_ref[:] = jnp.zeros_like(buf_ref)
+        done_ref[0] = 0
+        copies(0, 0, 0)
+
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
     pos = pos_ref[i]
+    n_blocks = last_page(i) // group + 1
+    first = done_ref[0]
 
-    @pl.when(kb * block_k <= pos)
-    def _():
-        pages = [ref[0, 0] for ref in row_refs]          # [ps, R + Rw]
-        rows = pages[0] if group == 1 else jnp.concatenate(pages, axis=0)
+    def fold(kb, carry):
+        half = (first + kb) % 2
+
+        @pl.when(kb + 1 < n_blocks)
+        def _():
+            copies(i, kb + 1, 1 - half)
+
+        @pl.when(jnp.logical_and(kb + 1 == n_blocks,
+                                 i + 1 < pl.num_programs(0)))
+        def _():
+            copies(i + 1, 0, 1 - half)
+
+        copies(i, kb, half, wait=True)
+        rows = buf_ref[half]                             # [G*ps, R + Rw]
         s = jax.lax.dot_general(
             q_ref[0], rows, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [H, G*ps]
         col = kb * block_k + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         s = jnp.where(col <= pos, s, NEG_INF)
-        m_prev = m_scr[:]
+        m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc[:] = acc[:] * corr + jnp.dot(
+        m_ref[:] = m_new
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * corr + jnp.dot(
             p.astype(rows.dtype), rows[:, :rank],
             preferred_element_type=jnp.float32)
+        return carry
 
-    @pl.when(kb == pl.num_programs(1) - 1)
-    def _():
-        o_ref[0] = acc[:] / jnp.maximum(l_scr[:], 1e-30)
+    jax.lax.fori_loop(0, n_blocks, fold, 0)
+    done_ref[0] = first + n_blocks
+    o_ref[0] = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
 
 
 def _pallas_mla_paged_decode(q, pages, page_table, positions, layer,
                              rank, scale, interpret):
     b, h, width = q.shape
-    ps = pages.shape[2]
+    n_pages, ps = pages.shape[1], pages.shape[2]
     n_win = page_table.shape[1]
-    group = max(1, min(_MLA_BLOCK_COLUMNS // ps, n_win))
+    group = _mla_block_pages(ps, n_win, h, width * pages.dtype.itemsize)
 
     def slot_spec(last):
-        return pl.BlockSpec((1, h, last), lambda i, kb, pos, ids: (i, 0, 0))
+        return pl.BlockSpec((1, h, last), lambda i, pos, ids: (i, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # positions, page ids
-        grid=(b, pl.cdiv(n_win, group)),
-        in_specs=[slot_spec(width)] + [
-            _page_spec((1, 1, ps, width), layer, g, group)
-            for g in range(group)],
+        num_scalar_prefetch=2,  # positions, the windowed page table
+        grid=(b,),
+        in_specs=[slot_spec(width), pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=slot_spec(rank),
         scratch_shapes=[
+            pltpu.VMEM((2, group * ps, width), pages.dtype),  # two blocks
+            pltpu.SemaphoreType.DMA((2,)),        # one a block in flight
             pltpu.VMEM((h, rank), jnp.float32),   # latent accumulator
             pltpu.VMEM((h, 1), jnp.float32),      # running max
             pltpu.VMEM((h, 1), jnp.float32),      # running denominator
+            pltpu.SMEM((1,), jnp.int32),          # blocks folded so far
         ],
     )
     return pl.pallas_call(
         functools.partial(_mla_paged_decode_kernel, scale=scale,
-                          page_size=ps, group=group, rank=rank),
+                          layer=layer, page_size=ps, group=group,
+                          n_win=n_win, rank=rank),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, rank), jnp.float32),
+        # slots in order (a slot's end starts its successor's copies).
+        # The compiler's two bounds checks a copy are 12 of the 19
+        # scalar bundles a copy costs to issue, and the issue does not
+        # run under the fold: off, because every id is held inside the
+        # pool below and a copy's VMEM side is static
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), disable_bounds_checks=True),
         interpret=interpret,
         name="mla_paged_decode_attention",
     )(positions.astype(jnp.int32),
-      _live_page_ids(page_table.astype(jnp.int32), positions, group, ps),
-      q, *([pages] * group))
+      jnp.clip(page_table.astype(jnp.int32), 0, n_pages - 1).reshape(-1),
+      q, pages)
 
 
 def xla_mla_paged_decode_attention(q, pages, page_table, positions, *,
@@ -689,12 +795,14 @@ def mla_paged_decode_attention(
         the layout of a cache row.
       pages: ``[L, P, page_size, R + Rw]`` — ALL layers' pages, a row a
         token: the normed latent, then the rotated shared position key,
-        zero-padded to whole lanes. The kernel's index map picks
-        ``layer``, so no layer is ever sliced out of (and copied from)
-        the pool, and a page is one contiguous DMA.
+        zero-padded to whole lanes. The kernel reads the pool where it
+        lies in HBM, ``layer`` a static index of each page copy: no
+        layer is ever sliced out of (and copied from) the pool, and a
+        live page is one contiguous DMA.
       page_table: ``[B, n_win]`` int32, windowed (see
         :func:`paged_decode_attention`).
-      positions: ``[B]`` — slot ``b`` attends columns ``[0, pos]``.
+      positions: ``[B]``, none negative — slot ``b`` attends columns
+        ``[0, pos]``; no page beyond ``pos`` is read.
       layer: static layer index; rank: ``R``.
       scale: softmax scale (the YaRN ``m^2`` folded in).
       window: the decode BUCKET's column bound (an upper bound on the

@@ -362,6 +362,12 @@ def test_latent_decode_program_compiles_at_one_layer(topo, name, kwargs,
                                         horizon=1).compile()
     text = compiled.as_text()
     assert "mla_paged_decode_attention" in _mosaic_names(text)
+    # one call a layer (one layer here); the pool it reads is the
+    # program's donated argument where it lies (``pl.ANY``), never a
+    # slice or a copy: the temporaries below
+    assert sum("tpu_custom_call" in line
+               and "mla_paged_decode_attention" in line
+               for line in text.splitlines()) == 1
     # the tokens and, behind them, the expert layer's load: a column a
     # held expert, one for the assignments routed elsewhere and one for
     # the rows the grouped matmuls were given
@@ -430,31 +436,75 @@ def test_gpt_decode_and_insert_programs_write_the_pools_in_place(topo):
         assert mem.alias_size_in_bytes >= pools
 
 
-@pytest.mark.parametrize("positions", [[0, 37, 95], [95, 95, 16]])
-@pytest.mark.parametrize("columns", [512, 32])
+# (positions, a block's columns, page, table entries, heads, NaN in dead pages)
+_MLA_VALUES = [
+    pytest.param(positions, columns, 8, 12, 4, False,
+                 id=f"columns{columns}-positions{i}")
+    for columns in (512, 32)
+    for i, positions in enumerate(([0, 37, 95], [95, 95, 16]))
+] + [
+    # a page's last and first column, in a block of two pages and in one
+    # block a slot
+    pytest.param([0, 15, 16], 32, 16, 8, 4, False, id="page-edges-2-pages"),
+    pytest.param([0, 15, 16], 512, 16, 8, 4, False, id="page-edges-1-block"),
+    # a block's last and first column; a slot of one block before one of
+    # two and one of four (the halves' parity carries over the slots)
+    pytest.param([31, 32, 127], 32, 16, 8, 4, False, id="block-edges"),
+    pytest.param([127, 0, 127], 32, 16, 8, 4, False, id="window-end"),
+    pytest.param([127, 64, 126], 64, 16, 8, 4, False,
+                 id="window-end-2-blocks"),
+    pytest.param([5, 100, 47], 64, 16, 8, 128, False, id="128-heads"),
+    pytest.param([0, 40, 127], 32, 16, 8, 4, True, id="nan-dead-pages"),
+    pytest.param([17, 99, 64], 512, 16, 8, 32, True,
+                 id="nan-dead-pages-1-block-32-heads"),
+]
+
+
+@pytest.mark.parametrize("positions, columns, page, n_win, heads, nan",
+                         _MLA_VALUES)
 def test_mla_kernel_in_interpret_mode_equals_the_xla_form(
-        monkeypatch, positions, columns):
+        monkeypatch, positions, columns, page, n_win, heads, nan):
     """Values, on the CPU: the kernel under the Pallas interpreter
     against the gather-and-softmax form, live pages only, in one block
-    of pages a slot and in three (the online softmax across blocks)."""
-    monkeypatch.setattr(da, "_MLA_BLOCK_COLUMNS", columns)
+    of pages a slot and in several (the online softmax across blocks,
+    the two halves of the buffer across slots). ``nan``: every page
+    that is live for no slot holds NaN, and the result is the clean
+    pool's: no page beyond a position is ever copied."""
+    monkeypatch.setattr(da, "_mla_block_pages",
+                        lambda ps, entries, *_: min(columns // ps, entries))
     rng = np.random.default_rng(0)
-    slots, heads, rank, rope, page, n_win, layers = 3, 4, 32, 128, 8, 12, 2
+    slots, rank, rope, layers = 3, 32, 128, 2
     pages = slots * n_win + 1
 
     def rand(*shape):
         return jnp.asarray(rng.normal(size=shape), jnp.float32)
 
-    table = jnp.asarray(rng.permutation(np.arange(1, pages)).reshape(
-        slots, n_win), jnp.int32)
-    args = (rand(slots, heads, rank + rope),
-            rand(layers, pages, page, rank + rope), table,
-            jnp.asarray(positions, jnp.int32))
+    table = rng.permutation(np.arange(1, pages)).reshape(slots, n_win)
+    pool = rand(layers, pages, page, rank + rope)
+    kernel_pool = pool
+    if nan:
+        live = np.zeros(pages, bool)
+        for row, pos in zip(table, positions):
+            live[row[:pos // page + 1]] = True
+        kernel_pool = jnp.where(live[None, :, None, None], pool, jnp.nan)
+    q, table = rand(slots, heads, rank + rope), jnp.asarray(table, jnp.int32)
+    positions = jnp.asarray(positions, jnp.int32)
     want = da.mla_paged_decode_attention(
-        *args, layer=1, rank=rank, scale=0.2, window=page * n_win,
-        impl="xla")
+        q, pool, table, positions, layer=1, rank=rank, scale=0.2,
+        window=page * n_win, impl="xla")
     got = da.mla_paged_decode_attention(
-        *args, layer=1, rank=rank, scale=0.2, impl="pallas",
-        interpret=True)
+        q, kernel_pool, table, positions, layer=1, rank=rank, scale=0.2,
+        impl="pallas", interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("page, n_win, heads, want", [
+    (16, 512, 32, 64),     # xing4-29b-a4b.serve.closed-4k1k: 1,024 columns
+    (16, 256, 128, 64),    # openpangu-ultra-moe-718b.serve.closed-2k1k
+    (16, 24, 32, 24),      # no more than the window has
+    (128, 64, 32, 8),      # pages of 128: the same 1,024 columns
+], ids=["xing4", "pangu", "short-window", "page128"])
+def test_mla_block_is_chosen_from_heads_rows_and_the_vmem_budget(
+        page, n_win, heads, want):
+    assert da._mla_block_pages(page, n_win, heads, 640 * 2) == want
