@@ -336,6 +336,10 @@ def run(cell: harness.Cell, *, seed: int, seconds: float, trace: bool,
     rec.count("serve.host_syncs", window_metrics.host_syncs)
     rec.count("serve.decode_tokens", window_metrics.decode_tokens)
     rec.count("serve.dispatches", window_metrics.dispatches)
+    # and every number the engine kept over the window, as engine.<key>
+    for key, value in window_metrics.snapshot().items():
+        if isinstance(value, (int, float)):
+            rec.count(f"engine.{key}", value)
     if hasattr(pool, "num_pages"):
         rec.count("serve.pages_peak_share",
                   pages_peak / (pool.num_pages - 1))

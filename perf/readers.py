@@ -22,6 +22,31 @@ leaves the metric out of the line.
                                            work.<kernel>.ops|bytes;
                                            peak_ops, peak_bytes: keys
                                            of perf/peaks.json
+    "trace"                   program_call_median
+                                           program: regex over the names
+                                           of the device's programs
+    "trace"                   program_ops_per_call
+                                           program; match: regex over
+                                           the op labels INSIDE them
+    "trace"                   program_ratio
+                                           field: "calls" or "seconds";
+                                           program, over: two regexes;
+                                           beside (optional): a third
+
+The three ``program_*`` reducers read the reduction's ``programs`` (the
+trace's "XLA Modules" line: one entry a jitted function, under the name
+it was jitted with): the median seconds of ONE call of the programs
+whose name matches; the seconds under matching labels inside them, a
+call of them; their ``field`` (calls, or seconds) over that of the
+programs matching ``over`` (every program where ``over`` is ""). Where
+the trace names no program they read nothing. Where it names some and
+none matches ``program``, the first two read nothing and the ratio
+reads 0, which is a count (a tail that drew no prefill), not a share of
+a peak; where none matches ``over``, or none matches ``beside``, the
+ratio reads nothing: a metric that counts "every program but the
+decode program" names the decode program under ``beside``, so that a
+rename in the engine drops the metric off the line and does not turn
+it into 100 %.
 
 ``roofline_share`` is the least time the chip could take for the work
 over the time it took: max(ops / peak_ops, bytes / peak_bytes_per_s)
@@ -68,6 +93,14 @@ def _matching_seconds(rec: Recording, pattern: str) -> Optional[float]:
     return sum(matched) if matched else None
 
 
+def _programs(rec: Recording, pattern: str = "") -> list:
+    """The entries of the reduction's ``programs`` whose name matches
+    (all of them without a pattern)."""
+    rx = re.compile(pattern)
+    return [p for name, p in rec.trace.get("programs", {}).items()
+            if rx.search(name)]
+
+
 def read(spec: dict, rec: Recording) -> Optional[float]:
     """The metric's value in its own unit, or None."""
     reducer, reads = spec["reducer"], spec["reads"]
@@ -112,6 +145,25 @@ def read(spec: dict, rec: Recording) -> Optional[float]:
                  else max(w / p for w, p in zip(work, peaks)) / seconds)
     elif reducer == "value":
         value = rec.trace.get(args["key"])
+    elif reducer == "program_call_median":
+        calls = [s for p in _programs(rec, args["program"])
+                 for s in p["call_s"]]
+        value = stats.median(calls) if calls else None
+    elif reducer == "program_ops_per_call":
+        mine = _programs(rec, args["program"])
+        rx = re.compile(args["match"])
+        matched = [s for p in mine for label, s in p["ops"].items()
+                   if rx.search(label)]
+        value = (sum(matched) / sum(p["calls"] for p in mine)
+                 if matched else None)
+    elif reducer == "program_ratio":
+        top, bottom = (sum(p[args["field"]] for p in _programs(rec, args[k]))
+                       for k in ("program", "over"))
+        # no call of ``program`` beside calls of ``over`` is a count of
+        # 0, not a thing that could not be read; a ``beside`` that is
+        # not in the trace is a name that changed
+        value = (top / bottom if bottom
+                 and _programs(rec, args.get("beside", "")) else None)
     else:
         raise ValueError(f"unknown reducer {reducer!r}")
     if value is None or not math.isfinite(value):

@@ -19,8 +19,9 @@ printed as the last lines of stderr.
 ``--trace 0``: ``metrics`` are the cell's end-to-end metrics.
 ``--trace 1``: ``metrics`` are its per-layer metrics, read by
 ``perf/readers.py`` from the run's spans, counters and a short profiler
-trace taken after the window; ``device`` gains ``busy_s``/``window_s``
-and the line a ``breakdown``.
+trace taken after the window; ``device`` gains ``busy_s``/``window_s``,
+the line a ``breakdown``, and ``checks.trace`` the device's
+``programs``: ``[name, calls, seconds, median seconds of a call]``.
 
 This process is the only one that touches jax. Without a TPU, or with
 fewer chips than the cell asks for, it prints no result and exits 2.
@@ -33,6 +34,7 @@ T_PROCESS = time.time()     # set-up is counted from here
 import argparse             # noqa: E402
 import json                 # noqa: E402
 import os                   # noqa: E402
+import statistics           # noqa: E402
 import sys                  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -85,6 +87,11 @@ def measure(workload: str, seed: int, seconds: float, trace: bool,
         checks["trace"] = {
             k: rec.trace[k] for k in ("steps", "planes", "collective_s",
                                       "collective_exposed_s")}
+        # the device's programs, not under ``breakdown``, whose two
+        # lists the ledger copies as they are
+        checks["trace"]["programs"] = [
+            [name, p["calls"], p["seconds"], statistics.median(p["call_s"])]
+            for name, p in rec.trace["programs"].items()]
     if not on_chip:
         line["rehearsal"] = {"end_to_end": result["end_to_end"],
                              "per_layer": values if trace else None}
