@@ -67,7 +67,24 @@ Pieces:
 
 Timestamps are ``time.perf_counter`` seconds — the same clock every
 ``Request`` lifecycle stamp and engine meter already uses, so scope
-events and ``ServingMetrics`` percentiles line up exactly.
+events and ``ServingMetrics`` percentiles line up exactly. A ``Scope``
+also notes an ``anchor``, one ``(perf_counter(), time.time_ns())``
+pair read at its construction: the profiler stamps its host events on
+the wall clock in nanoseconds, so :func:`to_chrome_trace` given the
+anchor (``--trace_out`` passes it) writes the events where the XLA
+trace has them, and a flight dump's header carries it.
+
+**The collector's pauses** (:func:`host_pauses`). One ``gc.callbacks``
+hook, installed when this module is imported, times every collection
+of Python's cyclic garbage collector with a ``perf_counter`` pair and
+keeps process-wide totals; with an annotator set it opens
+``perf:host.gc`` for the collection's length, on whichever thread ran
+it, and with a scope armed it files a ``host.gc`` Event (cat
+``"host"``, attrs ``generation`` and ``collected``). A collection
+starts at any allocation, so it may start inside :meth:`Scope.record`
+with the scope's lock held on the same thread: the hook takes no lock,
+leaves the Event's fields in a lock-free pending deque, and the next
+``record`` (or read of the log) files them. It never raises.
 
 Env hook: ``PMDT_SCOPE=1`` (or ``PMDT_SCOPE=/path/for/flight.jsonl``)
 arms a scope at import for chaos drills on a live CLI, the same shape
@@ -79,6 +96,7 @@ from the fault layer and the schedulers without dragging a runtime in.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
@@ -87,12 +105,12 @@ import threading
 import time
 from collections import deque
 from typing import (Callable, ContextManager, Deque, Dict, List, Optional,
-                    Sequence)
+                    Sequence, Tuple)
 
 __all__ = [
     "Event", "Scope", "arm", "disarm", "active_scope", "scoped",
     "set_identity", "get_identity",
-    "ANNOTATION_PREFIX", "set_annotator",
+    "ANNOTATION_PREFIX", "set_annotator", "host_pauses",
     "emit", "span", "emit_span", "flight_dump",
     "to_chrome_trace", "write_chrome_trace", "write_jsonl",
     "events_from_jsonl", "prometheus_text", "scope_events_fn",
@@ -142,6 +160,12 @@ class Event:
 
 _SEQ = itertools.count()
 
+# host.gc Events the collector hook could not file itself (it takes no
+# lock): (ts, dur, tid, seq, generation, collected), filed by the next
+# Scope.record or read of the log
+_GC_PENDING: Deque[tuple] = deque(maxlen=4096)
+GC_SPAN = "host.gc"
+
 # graftfleet: process-wide identity tags ((host, rank, run_uid) — set
 # by runtime.fleet.arm) merged into every RECORDED event's attrs, so a
 # fleet collector can lane-split a merged timeline by rank. One module
@@ -185,6 +209,8 @@ class Scope:
         self.keep = bool(keep)
         self.flight_path = flight_path
         self.t0 = time.perf_counter()
+        # the same instant on the profiler's host clock (wall ns)
+        self.anchor: Tuple[float, int] = (self.t0, time.time_ns())
         self.ring: Deque[Event] = deque(maxlen=int(flight_capacity))
         self.log: List[Event] = []
         self.dropped = 0  # events that exist only in (or fell off) the ring
@@ -196,16 +222,39 @@ class Scope:
             for key, value in identity.items():
                 event.attrs.setdefault(key, value)
         with self._mu:
-            if self.keep:
-                self.log.append(event)
-            elif len(self.ring) == self.ring.maxlen:
-                self.dropped += 1  # oldest ring entry evicted for good
-            self.ring.append(event)
+            self._file_pending()
+            self._append(event)
+
+    def _append(self, event: Event) -> None:
+        # the caller holds _mu
+        if self.keep:
+            self.log.append(event)
+        elif len(self.ring) == self.ring.maxlen:
+            self.dropped += 1  # oldest ring entry evicted for good
+        self.ring.append(event)
+
+    def _file_pending(self) -> None:
+        """File the collector's pauses the hook left pending, which
+        belong to the ARMED scope (the caller holds _mu; a collection
+        during this loop only appends to the deque, which the loop
+        drains too)."""
+        while _GC_PENDING and self is _SCOPE:
+            try:
+                ts, dur, tid, seq, gen, collected = _GC_PENDING.popleft()
+            except IndexError:  # another thread filed it first
+                break
+            attrs = {"generation": gen, "collected": collected}
+            if _IDENTITY is not None:
+                attrs.update((k, v) for k, v in _IDENTITY.items()
+                             if k not in attrs)
+            self._append(Event(GC_SPAN, "host", "X", ts, dur, tid, seq,
+                               attrs))
 
     def events(self) -> List[Event]:
         """Snapshot of the recorded events (full log, or the ring when
         ``keep=False``), in record order."""
         with self._mu:
+            self._file_pending()
             return list(self.log) if self.keep else list(self.ring)
 
     def events_since(self, start: int):
@@ -218,6 +267,7 @@ class Scope:
         is left (downstream seq cursors make that a visible
         undercount, never a double count)."""
         with self._mu:
+            self._file_pending()
             if self.keep:
                 return self.log[start:], len(self.log)
             base = self.dropped
@@ -227,6 +277,7 @@ class Scope:
     def tail(self) -> List[Event]:
         """The flight-recorder window: the most recent events."""
         with self._mu:
+            self._file_pending()
             return list(self.ring)
 
     def counts(self) -> Dict[str, int]:
@@ -240,14 +291,26 @@ class Scope:
 _SCOPE: Optional[Scope] = None
 
 
+def _file_pending_into_armed() -> None:
+    """The collector's pauses still pending belong to the scope armed
+    when they happened: file them there before it is swapped out."""
+    s = _SCOPE
+    if s is not None and _GC_PENDING:
+        with s._mu:
+            s._file_pending()
+    _GC_PENDING.clear()
+
+
 def arm(scope: Scope) -> Scope:
     global _SCOPE
+    _file_pending_into_armed()
     _SCOPE = scope
     return scope
 
 
 def disarm() -> None:
     global _SCOPE
+    _file_pending_into_armed()
     _SCOPE = None
 
 
@@ -269,6 +332,72 @@ def set_annotator(
     profiler annotation for a name; see the module docstring."""
     global _ANNOTATOR
     _ANNOTATOR = annotator
+
+
+# ------------------------------------------------- the collector's pauses
+
+# process-wide totals since import: collections by generation, their
+# seconds, the longest one (read through host_pauses())
+_gc_collections = [0, 0, 0]
+_gc_pause_s = 0.0
+_gc_longest_s = 0.0
+_gc_t0 = 0.0
+_gc_annotation = None
+# the last error the hook kept from the collector (None: none yet)
+gc_hook_error: Optional[BaseException] = None
+
+
+def _on_gc(phase: str, info: Dict) -> None:
+    """The ``gc.callbacks`` hook (module docstring). Takes no lock,
+    never raises, and allocates nothing but the annotation and the
+    clock's floats (and, armed, the pending tuple)."""
+    global _gc_t0, _gc_annotation, _gc_pause_s, _gc_longest_s
+    global gc_hook_error
+    try:
+        if phase == "start":
+            # the clock first: a pause is counted even where the
+            # annotation fails
+            _gc_t0 = time.perf_counter()
+            annotator = _ANNOTATOR
+            if annotator is not None:
+                _gc_annotation = annotator(ANNOTATION_PREFIX + GC_SPAN)
+                _gc_annotation.__enter__()
+            return
+        dur = time.perf_counter() - _gc_t0
+        gen = info["generation"]
+        _gc_collections[gen] += 1
+        _gc_pause_s += dur
+        if dur > _gc_longest_s:
+            _gc_longest_s = dur
+        annotation, _gc_annotation = _gc_annotation, None
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
+        if _SCOPE is not None:
+            _GC_PENDING.append((_gc_t0, dur, threading.get_ident(),
+                                next(_SEQ), gen, info["collected"]))
+    except Exception as e:  # noqa: BLE001 — a collection must never fail
+        gc_hook_error = e
+
+
+def host_pauses() -> Tuple[int, int, int, float, float]:
+    """``(generation-0, -1, -2 collections, pause seconds, longest
+    pause seconds)`` of this process's cyclic collector since this
+    module was imported: cumulative, so a reader takes deltas
+    (``ServingMetrics`` at each engine step)."""
+    n0, n1, n2 = _gc_collections
+    return n0, n1, n2, _gc_pause_s, _gc_longest_s
+
+
+def _install_gc_hook() -> None:
+    """Once per process: a re-import replaces its own earlier hook."""
+    gc.callbacks[:] = [cb for cb in gc.callbacks
+                       if not (getattr(cb, "__module__", None) == __name__
+                               and getattr(cb, "__name__", None)
+                               == "_on_gc")]
+    gc.callbacks.append(_on_gc)
+
+
+_install_gc_hook()
 
 
 class scoped:
@@ -420,6 +549,7 @@ def flight_dump(reason: str, path: Optional[str] = None
               "events": len(tail),
               "events_before_window": before_window,
               "t0": s.t0,
+              "anchor": list(s.anchor),
               "wall_time": time.time()}
     tmp = f"{target}.tmp.{os.getpid()}"
     try:
@@ -468,16 +598,22 @@ class flight_recorder:
 
 def to_chrome_trace(events: Sequence[Event],
                     t0: Optional[float] = None,
-                    pid: Optional[int] = None) -> Dict:
+                    pid: Optional[int] = None,
+                    anchor: Optional[Tuple[float, int]] = None) -> Dict:
     """Chrome-trace/Perfetto JSON object from events.
 
     Timestamps are shifted to start at 0 (``t0`` defaults to the
     earliest event, or the armed/arming scope's ``t0``) and converted
     to microseconds — load the file in ``chrome://tracing`` or
     https://ui.perfetto.dev next to the XLA trace from
-    ``utils.profiler.trace``.
+    ``utils.profiler.trace``. Given a scope's ``anchor`` instead, they
+    are microseconds on the profiler's host clock (the wall clock),
+    where the XLA trace has the same instants.
     """
-    if t0 is None:
+    base_us = 0.0
+    if anchor is not None:
+        t0, base_us = anchor[0], anchor[1] / 1e3
+    elif t0 is None:
         t0 = min((ev.ts for ev in events),
                  default=_SCOPE.t0 if _SCOPE is not None else 0.0)
     if pid is None:
@@ -488,7 +624,7 @@ def to_chrome_trace(events: Sequence[Event],
             "name": ev.name,
             "cat": ev.cat,
             "ph": ev.ph,
-            "ts": (ev.ts - t0) * 1e6,
+            "ts": base_us + (ev.ts - t0) * 1e6,
             "pid": pid,
             "tid": ev.tid,
         }
@@ -503,9 +639,10 @@ def to_chrome_trace(events: Sequence[Event],
 
 
 def write_chrome_trace(path: str, events: Sequence[Event],
-                       t0: Optional[float] = None) -> str:
+                       t0: Optional[float] = None,
+                       anchor: Optional[Tuple[float, int]] = None) -> str:
     with open(path, "w") as fh:
-        json.dump(to_chrome_trace(events, t0), fh)
+        json.dump(to_chrome_trace(events, t0, anchor=anchor), fh)
     return path
 
 
@@ -718,7 +855,8 @@ def export_from_args(args, echo=print) -> None:
         return
     events = s.events()
     if args.trace_out:
-        write_chrome_trace(args.trace_out, events, t0=s.t0)
+        # on the profiler's clock: beside an XLA trace of the same run
+        write_chrome_trace(args.trace_out, events, anchor=s.anchor)
         echo(f"graftscope trace: {args.trace_out} "
              f"({len(events)} events)")
     if args.events_out:

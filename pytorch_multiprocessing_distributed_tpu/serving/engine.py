@@ -1955,6 +1955,7 @@ class ServingEngine:
                                  chunk=chunk):
                 x, pend.k_pref, pend.v_pref = self._attempted(
                     chunk_once)
+                self.metrics.record_prompt_dispatch(chunk=True)
         except Exception as e:
             if self._pending is pend:
                 self._pending = None
@@ -2042,6 +2043,7 @@ class ServingEngine:
                                      req=request.uid, bucket=bucket,
                                      prompt_len=length):
                     tok0, k_pref, v_pref = self._attempted(prefill_once)
+                    self.metrics.record_prompt_dispatch(chunk=False)
             except Exception as e:
                 self._abort_prep(prep)
                 self._poisoned(request, e)
@@ -2519,7 +2521,13 @@ class ServingEngine:
         flight, dispatches twice). Returns the iteration's token events
         as ``(request, token, finished)`` tuples (admission first
         tokens included; a quarantined request emits no event — read
-        its ``state``/``error``)."""
+        its ``state``/``error``).
+
+        Metered from inside at entry and exit (``metrics.record_step``:
+        wall and thread-CPU seconds, the collector's pauses, the
+        caller's time since the previous step's exit)."""
+        t_in, gc_in = time.perf_counter(), graftscope.host_pauses()
+        cpu_in = time.thread_time()
         try:
             with graftscope.span("engine.step", cat="serving"):
                 return self._step_inner()
@@ -2540,6 +2548,11 @@ class ServingEngine:
             # this replica dead the moment its step loop is
             self.health.to_dead(type(e).__name__)
             raise
+        finally:
+            cpu_s = time.thread_time() - cpu_in
+            self.metrics.record_step(t_in, gc_in, cpu_s,
+                                     time.perf_counter(),
+                                     graftscope.host_pauses())
 
     def _step_inner(self) -> List[Tuple[Request, int, bool]]:
         # Admit, dispatch block k, drain block k - 1: in steady state a
@@ -2804,7 +2817,9 @@ class ServingEngine:
             with graftscope.span("serving.prefill", cat="serving",
                                  req=request.uid, bucket=bucket,
                                  prompt_len=length, detached=True):
-                return self._attempted(prefill_once)
+                out = self._attempted(prefill_once)
+                self.metrics.record_prompt_dispatch(chunk=False)
+                return out
         plan = PrefillPlan(request, int(chunk), self.min_bucket,
                            pool.s_max)
         model = self.model
@@ -2834,6 +2849,7 @@ class ServingEngine:
                                  start=start, chunk=plan.chunk,
                                  detached=True):
                 x, k_pref, v_pref = self._attempted(chunk_once)
+                self.metrics.record_prompt_dispatch(chunk=True)
             record_jit_key(self._chunk_jit,
                            ("prefill_chunk", plan.chunk, plan.width))
         key = self._next_key()

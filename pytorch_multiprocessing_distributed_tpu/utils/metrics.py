@@ -16,11 +16,13 @@ training loops report through.
 
 from __future__ import annotations
 
+import time
 from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..runtime.scope import host_pauses
 from .meters import AverageMeter, PercentileMeter
 
 
@@ -119,7 +121,30 @@ class ServingMetrics:
       detected and failed fast), ``horizon_collapses`` (dispatches
       forced to H=1 during a post-fault cooldown). A fault that is
       absorbed must still be VISIBLE — silent recovery is how fleets
-      rot.
+      rot;
+    - the engine's own loop, metered from inside ``step()`` at its
+      entry and exit (:meth:`record_step`): ``step`` (the whole call's
+      wall seconds, a percentile meter), ``step_wall_s`` and
+      ``step_max_s`` with the longest step's ``step_max_cpu_s`` (this
+      thread's CPU seconds) and ``step_max_gc_s`` (collector seconds):
+      of the longest step, whether it was the collector, host work, or
+      waiting (on the device, a lock, I/O or the machine);
+      ``step_gap_s`` / ``step_gap_max_s``, the caller's time from the
+      previous step's exit (or this object's construction) to the
+      next entry; ``loop_s`` from construction to the last step's exit
+      (= ``step_wall_s + step_gap_s``); and the process's collector
+      activity over that same interval (``gc_pause_s``,
+      ``gc_collections``, ``gc_gen2_collections``, and
+      ``gc_pause_max_s``: the most collector seconds inside one step
+      or one gap, one long collection where there was one), from
+      ``runtime.scope.host_pauses()``, based at construction and
+      advanced at step exits only, never at ``snapshot()``. The
+      collector is the PROCESS's: several engines in one process each
+      book the pauses that fell inside their own loop, so their sums
+      may count one pause more than once;
+    - ``prefill_dispatches`` / ``chunk_dispatches``: the prompt
+      programs dispatched (a whole prompt; one chunk), in admission
+      and in ``prefill_detached``; ``prompt_dispatches`` their sum.
 
     The latency meters (``ttft``/``queue_wait``/``decode_step``, plus
     per-request generated-token counts) are
@@ -203,6 +228,19 @@ class ServingMetrics:
         self.kv_bytes_undivided = AverageMeter()
         self.kv_ring_pages_overwritten = 0
         self._ring_base = None
+        # the engine's own loop (record_step)
+        self.step = PercentileMeter()
+        self.step_wall_s = 0.0
+        self.step_max_s = 0.0
+        self.step_max_cpu_s = 0.0
+        self.step_max_gc_s = 0.0
+        self.step_gap_s = 0.0
+        self.step_gap_max_s = 0.0
+        self.gc_pause_max_s = 0.0
+        self.prefill_dispatches = 0
+        self.chunk_dispatches = 0
+        self._t_base = self._t_exit = time.perf_counter()
+        self._gc_base = self._gc_exit = host_pauses()
         self._elapsed = 0.0
         self._occupancy_max = 0
         self._queue_wait_max = 0.0
@@ -217,8 +255,34 @@ class ServingMetrics:
         behind a long-running stats server; tests and short benches
         keep the uncapped default."""
         for meter in (self.ttft, self.queue_wait, self.decode_step,
-                      self.request_tokens, self.accept_len):
+                      self.request_tokens, self.accept_len, self.step):
             meter.bound(max_samples)
+
+    def record_step(self, t_in: float, gc_in: tuple, cpu_s: float,
+                    t_out: float, gc_out: tuple) -> None:
+        """One ``ServingEngine.step()`` call: its entry and exit on
+        ``time.perf_counter``, its thread's CPU seconds, and
+        :func:`~..runtime.scope.host_pauses` read at entry and exit."""
+        gap = t_in - self._t_exit
+        self.step_gap_s += gap
+        self.step_gap_max_s = max(self.step_gap_max_s, gap)
+        wall = t_out - t_in
+        gc_step = gc_out[3] - gc_in[3]
+        self.step.update(wall)
+        self.step_wall_s += wall
+        if wall > self.step_max_s:
+            self.step_max_s, self.step_max_cpu_s, self.step_max_gc_s = (
+                wall, cpu_s, gc_step)
+        self.gc_pause_max_s = max(self.gc_pause_max_s, gc_step,
+                                  gc_in[3] - self._gc_exit[3])
+        self._t_exit, self._gc_exit = t_out, gc_out
+
+    def record_prompt_dispatch(self, chunk: bool) -> None:
+        """One prompt program dispatched: a chunk, or a whole prompt."""
+        if chunk:
+            self.chunk_dispatches += 1
+        else:
+            self.prefill_dispatches += 1
 
     def record_first_token(self, ttft_seconds: float) -> None:
         self.ttft.update(ttft_seconds)
@@ -441,11 +505,29 @@ class ServingMetrics:
                 0.0 if self.kv_bytes_undivided.avg == 0
                 else self.kv_bytes_held.avg / self.kv_bytes_undivided.avg),
             "kv_ring_pages_overwritten": self.kv_ring_pages_overwritten,
+            "steps": self.step.count,
+            "step_wall_s": self.step_wall_s,
+            "step_max_s": self.step_max_s,
+            "step_max_cpu_s": self.step_max_cpu_s,
+            "step_max_gc_s": self.step_max_gc_s,
+            "step_gap_s": self.step_gap_s,
+            "step_gap_max_s": self.step_gap_max_s,
+            "loop_s": self._t_exit - self._t_base,
+            "gc_pause_s": self._gc_exit[3] - self._gc_base[3],
+            "gc_pause_max_s": self.gc_pause_max_s,
+            "gc_collections": (sum(self._gc_exit[:3])
+                               - sum(self._gc_base[:3])),
+            "gc_gen2_collections": self._gc_exit[2] - self._gc_base[2],
+            "prefill_dispatches": self.prefill_dispatches,
+            "chunk_dispatches": self.chunk_dispatches,
+            "prompt_dispatches": (self.prefill_dispatches
+                                  + self.chunk_dispatches),
         }
         # graftscope percentile telemetry: the tail IS the SLO
         for name, meter in (("ttft", self.ttft),
                             ("queue_wait", self.queue_wait),
-                            ("decode_step", self.decode_step)):
+                            ("decode_step", self.decode_step),
+                            ("step", self.step)):
             for q, v in meter.percentiles((50, 90, 95, 99)).items():
                 snap[f"{name}_{q}_s"] = v
         for q, v in self.request_tokens.percentiles((50, 95)).items():

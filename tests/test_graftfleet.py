@@ -25,6 +25,7 @@ What must stay true:
   retained window and bit-identical to uncapped while under the cap.
 """
 
+import gc
 import json
 import threading
 import time
@@ -44,6 +45,20 @@ from pytorch_multiprocessing_distributed_tpu.runtime.store import (
     MemStore)
 from pytorch_multiprocessing_distributed_tpu.utils.meters import (
     PercentileMeter, exact_percentile)
+
+
+@pytest.fixture(autouse=True)
+def no_automatic_collections():
+    """An armed scope files every collection of Python's cyclic
+    collector as a ``host.gc`` Event; these tests compare whole event
+    logs, so automatic collection is held off for each of them."""
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was:
+            gc.enable()
 
 
 # --------------------------------------------------- harness helpers

@@ -21,12 +21,20 @@ What must stay true:
   exposition parses, the stats endpoint serves both live;
 - **crash truth**: engine-fatal paths (an injected
   ``PoolPoisonedError`` included) leave the flight ring on disk, with
-  the events leading into the failure.
+  the events leading into the failure;
+- **the collector named**: every collection of Python's cyclic
+  collector is counted (``host_pauses``), annotated ``perf:host.gc``
+  and, armed, filed as a ``host.gc`` Event without a lock; the engine
+  meters its own step, the caller's time between steps and the pauses
+  inside both.
 """
 
 import contextlib
+import gc
 import importlib.util
 import json
+import math
+import time
 import os
 import subprocess
 import sys
@@ -53,6 +61,22 @@ from pytorch_multiprocessing_distributed_tpu.utils.meters import (
     AverageMeter, PercentileMeter, exact_percentile)
 from pytorch_multiprocessing_distributed_tpu.utils.metrics import (
     ServingMetrics)
+
+
+@pytest.fixture(autouse=True)
+def no_automatic_collections():
+    """The collector's hook annotates every collection and files it
+    into an armed scope, so a collection that happened to fall inside
+    a test would add ``host.gc`` to the logs these tests compare
+    whole: automatic collection is held off for each test (an explicit
+    ``gc.collect()`` still runs, and the hook with it)."""
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was:
+            gc.enable()
 
 
 def _tiny(**kw):
@@ -266,6 +290,97 @@ class TestAnnotator:
                     1 / 0
         assert [what for what, _n, _t in fake.log] == ["enter", "exit"]
         assert s.events()[0].attrs["error"] == "ZeroDivisionError"
+
+
+# ------------------------------------------------- the collector's pauses
+
+class TestCollectorPauses:
+    def test_a_forced_collection_is_counted_with_its_generation(self):
+        before = graftscope.host_pauses()
+        gc.collect()
+        gc.collect(0)
+        after = graftscope.host_pauses()
+        assert [b - a for a, b in zip(before[:3], after[:3])] == [1, 0, 1]
+        paused = after[3] - before[3]
+        assert paused > 0.0
+        assert after[4] >= before[4] and after[4] > 0.0
+        # the hook is in gc.callbacks once, however often it is put in
+        graftscope._install_gc_hook()
+        graftscope._install_gc_hook()
+        assert sum(cb is graftscope._on_gc for cb in gc.callbacks) == 1
+
+    def test_a_collection_is_annotated_on_the_thread_that_ran_it(self):
+        with annotator(FakeAnnotator()) as fake:
+            gc.collect()
+            t = threading.Thread(target=gc.collect)
+            t.start()
+            t.join()
+        name = PREFIX + graftscope.GC_SPAN
+        assert name == "perf:host.gc"
+        assert [(what, n) for what, n, _t in fake.log] == [
+            ("enter", name), ("exit", name)] * 2
+        assert fake.log[0][2] == fake.log[1][2] == threading.get_ident()
+        assert fake.log[2][2] == fake.log[3][2] == t.ident
+
+    def test_a_collection_inside_record_neither_deadlocks_nor_is_lost(self):
+        """A collection starts at an allocation, so it can start inside
+        ``Scope.record`` with the scope's lock held on the same thread:
+        the hook must take no lock, and the Event must still land."""
+
+        class CollectingLog(list):
+            collected = False
+
+            def append(self, item):
+                if not CollectingLog.collected:
+                    CollectingLog.collected = True
+                    gc.collect()          # inside record, _mu held
+                super().append(item)
+
+        s = Scope()
+        s.log = CollectingLog()
+        with scoped(s):
+            t = threading.Thread(target=graftscope.emit, args=("first",),
+                                 daemon=True)
+            t.start()
+            t.join(timeout=60)
+            assert not t.is_alive(), "the collector hook took the lock"
+            graftscope.emit("second")
+        names = [e.name for e in s.events()]
+        assert names == ["first", "host.gc", "second"]
+        (pause,) = [e for e in s.events() if e.name == "host.gc"]
+        assert (pause.cat, pause.ph, pause.tid) == ("host", "X", t.ident)
+        assert pause.attrs["generation"] == 2
+        assert isinstance(pause.attrs["collected"], int)
+        assert pause.dur > 0.0
+        first = s.events()[0]
+        assert first.ts <= pause.ts <= first.ts + first.dur + 1.0
+
+    def test_a_failing_annotator_never_fails_a_collection(self):
+        def broken(name):
+            raise RuntimeError("no profiler here")
+
+        graftscope.gc_hook_error = None
+        before = graftscope.host_pauses()
+        with annotator(broken):
+            gc.collect()
+        assert isinstance(graftscope.gc_hook_error, RuntimeError)
+        graftscope.gc_hook_error = None
+        # the pause of a collection whose annotation failed still counts
+        after = graftscope.host_pauses()
+        assert after[2] == before[2] + 1
+        assert 0.0 < after[3] - before[3] < 60.0
+
+    def test_disarmed_without_an_annotator_a_collection_records_nothing(
+            self):
+        graftscope.disarm()
+        with annotator(None):
+            gc.collect()
+            assert graftscope.span("a") is graftscope._NULL_SPAN
+        assert not graftscope._GC_PENDING
+        # a scope armed later does not inherit pauses from before it
+        with scoped() as s:
+            graftscope.emit("x")
+        assert [e.name for e in s.events()] == ["x"]
 
 
 # every span one engine step can open, and what it must sit inside
@@ -513,6 +628,67 @@ class TestServingMetrics:
         # 1 prefill token each; the rest drained from decode blocks
         assert snap["decode_tokens"] == survivors - 2
 
+    @pytest.mark.parametrize("prefill_chunk", [None, 8])
+    def test_the_engine_meters_its_own_loop(self, prefill_chunk):
+        """A fresh ``ServingMetrics`` over a few steps of a tiny CPU
+        engine, one forced collection between two steps: the loop is
+        the steps plus the gaps between them, the collection is in
+        the collector's totals, every prompt program dispatched is
+        counted, and the five metric files that read these counters
+        each give a number from a recording filled the serve driver's
+        way (``engine.<key>`` for every numeric snapshot item)."""
+        from perf import harness, readers
+        from perf.spans import Recording
+
+        model = _tiny()
+        engine = ServingEngine(model, init_params(model, 5), max_slots=2,
+                               s_max=64, min_bucket=8, page_size=8,
+                               prefill_chunk=prefill_chunk)
+        rng = np.random.default_rng(1)
+        with scoped() as s:
+            for n in (11, 19, 6):
+                engine.submit(rng.integers(0, 61, (n,)).tolist(), 5)
+            engine.metrics = fresh = ServingMetrics()
+            engine.step()
+            engine.step()
+            gc.collect()
+            while engine.in_flight:
+                engine.step()
+        snap = fresh.snapshot()
+        assert snap["steps"] >= 3
+        assert snap["loop_s"] == pytest.approx(
+            snap["step_wall_s"] + snap["step_gap_s"], abs=1e-6)
+        assert snap["gc_gen2_collections"] >= 1
+        assert snap["gc_collections"] >= snap["gc_gen2_collections"]
+        assert 0.0 < snap["gc_pause_s"] <= snap["loop_s"]
+        assert snap["step_gap_max_s"] >= snap["gc_pause_max_s"] > 0.0
+        assert snap["step_max_s"] >= snap["step_p50_s"] > 0.0
+        assert 0.0 <= snap["step_max_gc_s"] <= snap["step_max_s"]
+        assert snap["step_max_cpu_s"] >= 0.0
+        prompts = [e for e in s.events()
+                   if e.name in ("serving.prefill", "serving.prefill_chunk")]
+        assert snap["prompt_dispatches"] == len(prompts) >= 3
+        assert snap["prompt_dispatches"] == (snap["prefill_dispatches"]
+                                             + snap["chunk_dispatches"])
+        assert (snap["chunk_dispatches"] > 0) == (prefill_chunk is not None)
+        # the deltas are taken at step exits: a snapshot later on does
+        # not stretch the loop
+        time.sleep(0.01)
+        assert fresh.snapshot()["loop_s"] == snap["loop_s"]
+
+        rec = Recording()
+        for key, value in snap.items():
+            if isinstance(value, (int, float)):
+                rec.count(f"engine.{key}", value)
+        for name in ("step_ms_p50.serve", "step_ms_max.serve",
+                     "gc_pause_share.serve", "between_steps_share.serve",
+                     "prompt_dispatches_per_decode.serve"):
+            value = readers.read(harness.load_layer_metric(name), rec)
+            assert value is not None and math.isfinite(value), name
+        assert readers.read(harness.load_layer_metric(
+            "step_ms_p50.serve"), rec) == pytest.approx(
+                snap["step_p50_s"] * 1000)
+
     def test_snapshot_delta_windows(self):
         m = ServingMetrics()
         m.record_first_token(0.1)
@@ -565,6 +741,39 @@ class TestExporters:
         assert inst["s"] == "t"  # instant scope marker
         assert inst["args"]["site"] == "x"
         assert span_ev["args"]["req"] == 1
+
+    def test_chrome_trace_on_the_profilers_clock(self, tmp_path):
+        """Given the scope's anchor, timestamps are microseconds on the
+        wall clock, which the profiler stamps its host events on; the
+        zero-based form above is what ``t0`` still gives."""
+        s = Scope()
+        with scoped(s):
+            wall_us = time.time_ns() / 1e3
+            graftscope.emit("mark")
+        assert s.anchor[0] == s.t0 and isinstance(s.anchor[1], int)
+        (ev,) = to_chrome_trace(s.events(), anchor=s.anchor)["traceEvents"]
+        assert ev["ts"] == pytest.approx(wall_us, abs=5e3)
+        (zero,) = to_chrome_trace(s.events(), t0=s.t0)["traceEvents"]
+        assert 0.0 <= zero["ts"] < 5e3
+        assert ev["ts"] - zero["ts"] == pytest.approx(s.anchor[1] / 1e3,
+                                                      abs=1.0)
+
+    def test_trace_out_writes_on_the_profilers_clock(self, tmp_path):
+        import argparse
+
+        parser = argparse.ArgumentParser()
+        graftscope.add_cli_args(parser)
+        path = tmp_path / "t.json"
+        args = parser.parse_args(["--trace_out", str(path)])
+        try:
+            graftscope.arm_from_args(args)
+            graftscope.emit("mark")
+            wall_us = time.time_ns() / 1e3
+            graftscope.export_from_args(args, echo=lambda *_a: None)
+        finally:
+            graftscope.disarm()
+        (ev,) = json.loads(path.read_text())["traceEvents"]
+        assert ev["ts"] == pytest.approx(wall_us, abs=5e3)
 
     def test_jsonl_roundtrip(self, tmp_path):
         s = self._sample_scope()
@@ -678,6 +887,8 @@ class TestFlightRecorder:
                  target.read_text().splitlines()]
         header, events = lines[0], lines[1:]
         assert header["graftscope_flight"] == "test reason"
+        # the scope's instant on perf_counter and on the profiler's clock
+        assert len(header["anchor"]) == 2
         assert header["events"] == 3
         assert header["events_before_window"] == 4
         assert [e["i"] for e in events] == [4, 5, 6]  # oldest-first
