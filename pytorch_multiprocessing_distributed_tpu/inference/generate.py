@@ -257,8 +257,8 @@ def _block_decode_slots(p, x_t, k_cache, v_cache, positions, h, dtype,
     page_table[row, p // page_size], p % page_size)``. The write
     scatters through the table; attention gathers through it
     (:func:`...ops.pallas.decode_attention.paged_decode_attention` —
-    take-based XLA reference, or the Pallas kernel whose index map
-    does the indirection, layer included, before the DMA). A
+    take-based XLA reference, or the Pallas kernel that copies each
+    live page itself out of layer ``layer`` of the pool). A
     released slot's table row points at the scratch page 0, so the
     frozen-row re-write invariant (masked rows re-hit "their own
     column" each step) lands in scratch instead of a page since

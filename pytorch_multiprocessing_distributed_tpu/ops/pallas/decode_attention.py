@@ -22,53 +22,52 @@ The PAGED kernels read the pool the engine holds, whole:
 ``[L, P, ps, H * Dh]`` — a page is ``ps`` rows of every head's ``Dh``
 values side by side in the lanes (head ``h`` in lanes ``h * Dh .. (h +
 1) * Dh``), so it is lane-dense whatever ``Dh`` is, one contiguous DMA,
-and the layer is a STATIC index of the block's index map: no layer is
-ever sliced out of the pool, so the donated pool is written and read in
-place. The grid of the GPT and the grouped kernels is ``(slot, block
-of G pages)``: one step folds ALL heads of ``G * ps`` columns (G = 8
-pages of 16, 1 page of 128). Each
-pool is passed G times with plain ``BlockSpec``s — operand ``g`` of a
-step is page ``g`` of its block — and the pipeline double-buffers them,
-the next step's pages (the next slot's first block at a slot's end) in
-flight while this one is folded. Which pool page a step's operand names
-is worked out once, in XLA, from positions and table
-(:func:`_live_page_ids`): only pages at or before the slot's position,
-and a block beyond it names its predecessor's pages again, which the
-pipeline does not copy twice — so a slot costs its live pages, not its
-window. Heads are kept apart by the QUERY: the caller's ``[H, Dh]``
-query becomes block-diagonal ``[H, H * Dh]`` (row ``h`` holds ``q_h`` in
-head ``h``'s lanes, zeros elsewhere), so ``Q rows^T`` is every head's
-scores in one contraction (the extra products are exact zeros), ``P
-rows`` is ``[H, H * Dh]`` and head ``h``'s output is its diagonal block,
-taken outside the kernel. The k-query verify pass is the same kernel
-with ``K1 * H`` query rows and a row-staggered mask.
-
-The LATENT kernel (:func:`mla_paged_decode_attention`: one row a token
-that every head shares) reads the same kind of pool and COPIES ITS OWN
-PAGES. The pool is passed once and stays in HBM (``pl.ANY``), the grid
-is the slots, and a slot is a loop over its LIVE blocks of G pages
-(``pos // (G * ps) + 1`` of them, G = 64 pages of 16): one
-``make_async_copy`` a live page into one half of a two-block VMEM
-buffer while the block before is folded out of the other half, the
-next slot's first block in flight at a slot's end. What a page copy
-costs decides between the two forms (a TPU v5e, PERF.md PR 37): as a
+and the layer is an index of every page read (static, or a scalar in
+SMEM): no layer is ever sliced out of the pool, so the donated pool is
+written and read in place. Two kernels COPY THEIR OWN PAGES, the GPT kernel
+(:func:`paged_decode_attention`, :func:`paged_verify_decode_attention`)
+and the LATENT one (:func:`mla_paged_decode_attention`: one row a
+token that every head shares). The pools are passed once and stay in
+HBM (``pl.ANY``), the grid is the slots, and a slot is a loop over its
+LIVE blocks of G pages (up to the last query row's reach; G = 16 pages
+of 16 in the GPT kernel, 64 in the latent one): one
+``make_async_copy`` a live page (:func:`_live_page_copies`) into a VMEM
+buffer while the block before is folded out of another, the next
+slot's first block in flight at a slot's end, so a slot costs its live
+pages, not its window. The latent kernel holds two blocks; the GPT
+kernel, whose slots are short (a few hundred columns), a ring of four,
+so that the copies of the next three blocks, the next slots' too, are
+in flight under a fold. What a page copy costs decides between this
+form and the pipelined one (a TPU v5e, PERF.md PRs 37 and 40): as a
 pipelined operand — an index map, a descriptor and a semaphore wait a
-grid step and operand, a dead step's index maps included — ~0.1 us
-whatever the page holds; as a manual copy 19 scalar bundles to issue
-(~20 ns), 12 of them the compiler's two bounds checks, ~7 ns with the
-checks off and the ids clipped outside, and ONE wait a whole block.
-The copy loop (:func:`_live_page_copies`) is written for the other
-paged kernels to adopt.
+grid step and operand, a dead step's index maps included — ~0.05-0.1
+us whatever the page holds; as a manual copy 19 scalar bundles to
+issue (~20 ns), 12 of them the compiler's two bounds checks, ~7 ns with
+the checks off and the ids clipped outside, and ONE wait a whole
+block.
+
+In the GPT kernel heads are kept apart by the QUERY: the caller's
+``[H, Dh]`` query becomes block-diagonal ``[H, H * Dh]`` (row ``h``
+holds ``q_h`` in head ``h``'s lanes, zeros elsewhere), so ``Q rows^T``
+is every head's scores in one contraction (the extra products are
+exact zeros), ``P rows`` is ``[H, H * Dh]`` and head ``h``'s output is
+its diagonal block, taken outside the kernel. The k-query verify pass
+is the same kernel with ``K1 * H`` query rows and a row-staggered mask.
 
 The GROUPED kernel (:func:`gqa_paged_decode_attention`: fewer
 key/value heads than query heads, and layers that attend a sliding
-window) is the same scheme over a row that holds K and V side by side:
-a block-diagonal query over the KEY/VALUE heads' lanes, and a LOWER
-column bound ``reach`` that follows each slot's position
-(:func:`_reach_page_ids` names no page below it) beside the upper
-bound every paged kernel has. Two words, two things: ``window`` in
-this file is always the decode BUCKET's column bound, ``reach`` a
-model's sliding window.
+window) is PIPELINED: its grid is ``(slot, block of G pages)`` and each
+pool is passed G times with plain ``BlockSpec``s (operand ``g`` of a
+step is page ``g`` of its block, one page of 64 KiB whose copy the
+pipeline's price per operand does not bound), the page each operand
+names worked out in XLA (:func:`_reach_page_ids`), a dead step naming
+its predecessor's pages, which the pipeline does not copy twice. It
+reads a row that holds K and V side by side: a block-diagonal query
+over the KEY/VALUE heads' lanes, and a LOWER column bound ``reach``
+that follows each slot's position (no page below it is named) beside
+the upper bound every paged kernel has. Two words, two things:
+``window`` in this file is always the decode BUCKET's column bound,
+``reach`` a model's sliding window.
 
 Matmuls stay in the input dtype (bf16 hits the MXU's native rate),
 accumulation is f32, outputs are f32 (the engine casts back to model
@@ -78,7 +77,10 @@ dtype after the residual add, matching the XLA path's dtypes exactly).
 KV operand as a :class:`...kv_quant.QuantizedKV` pair — int8 data plus
 a per-(token, head) f32 scale streamed beside it (dense: a ``[B*H,
 1, S]`` row per block; paged: the ``[ps, H]`` sidecar of the SAME page,
-``[L, P, ps, H]``, through the same page ids). The dense kernel
+``[L, P, ps, H]``, through the same page ids — the GPT kernel's
+gathered in XLA, a slot's window of them one lane-dense ``[H, window]``
+block, since the chip's compiler slices no float32 array in HBM whose
+last axis is under 128 lanes). The dense kernel
 dequants each block in the VMEM stream (ONE multiply, before the MXU
 dot); the paged kernels feed the int8 lanes to the MXU as they are
 (exact in the compute dtype) and apply the scale where it is one number
@@ -250,35 +252,32 @@ def _pallas_decode(q, k, v, positions, scale, block_k, interpret,
 # not padded, so this is what they take
 _PAGE_BLOCK_BYTES = 4 << 20
 
+# the GPT kernel's ring of K and V blocks may take this much VMEM (its
+# own budget: the latent kernel's block follows from _PAGE_BLOCK_BYTES)
+_GPT_BLOCK_BYTES = 8 << 20
 
-def _pages_per_step(page_size, n_win, page_bytes):
-    """G, the pages one grid step folds as one block: the fewest whose
-    block has 128 columns (8 at ``page_size`` 16, 1 at 128), no more
-    than the window has, and no more than the VMEM budget holds (K and
-    V, two buffers each)."""
-    fit = _PAGE_BLOCK_BYTES // (4 * page_bytes)
-    return max(1, min(128 // page_size, n_win, fit))
+# blocks in the GPT kernel's ring: the one folded and the copies of the
+# next ones in flight, across slots, so that a short slot's copies are
+# issued a few folds before they are waited for (at the cell's shapes on
+# a TPU v5e 0.092 ms a call against 0.104 with two; three and eight
+# read as four: PERF.md, PR 40)
+_GPT_BUFFERS = 4
 
 
-def _live_page_ids(page_table, positions, group, page_size):
-    """``[B, n_blocks * G]``: the pool page each (grid step, operand)
-    of a paged kernel names. Only live pages are ever named, so
-    nothing beyond a slot's position is copied: a block that starts
-    beyond the position names the pages of the slot's last live block
-    again, and a page beyond the position inside that block names the
-    page its operand held a step before (in a slot's first block: the
-    last live page) — the pipeline copies nothing when a block index
-    repeats. Computed once from ``positions`` in XLA, so an index map
-    is one SMEM read."""
-    b, n_win = page_table.shape
-    last = jnp.clip(positions, 0, n_win * page_size - 1) // page_size
-    last = last[:, None, None]                           # [B, 1, 1]
-    blk = jnp.minimum(jnp.arange(pl.cdiv(n_win, group))[None, :, None],
-                      last // group)
-    page = blk * group + jnp.arange(group)[None, None, :]
-    page = jnp.where(page <= last, page,
-                     jnp.where(blk > 0, page - group, last))
-    return jnp.take_along_axis(page_table, page.reshape(b, -1), axis=1)
+def _gpt_block_pages(page_size, n_win, rows, row_bytes):
+    """G, the pages one block of the GPT kernel copies and folds: the
+    most (a power of two, no more than the window has) whose columns
+    fit ``_GPT_BLOCK_BYTES`` of VMEM as a ring of ``_GPT_BUFFERS`` K and
+    V blocks, a row counted at ``row_bytes`` (the width in the QUERY's
+    dtype: an int8 row is widened to it before the MXU), beside a
+    float32 score a query row. 16 pages of 16 (256 columns) at the
+    GPT-2 cell's 16 heads of 64: a block's fold streams every column
+    through the MXU, live or not, so the dead columns of a slot's last
+    block weigh against the fold's fixed chain a block; on the chip 256
+    columns beat 128, 512 and 1,024 (PERF.md, PR 40)."""
+    fit = _GPT_BLOCK_BYTES // (
+        (2 * _GPT_BUFFERS * row_bytes + 4 * rows) * page_size)
+    return min(1 << max(fit, 1).bit_length() - 1, n_win)
 
 
 def _page_spec(block_shape, layer, g, group):
@@ -312,159 +311,214 @@ def _diagonal_blocks(out, k1, heads):
     return jnp.sum(jnp.where(eye, out, 0.0), axis=3)
 
 
-def _paged_attention_kernel(pos_ref, ids_ref, q_ref, *rest, scale,
-                            page_size, group, heads, k1, quant):
-    """One (slot, block of ``group`` pages) grid cell of the PAGED
-    flash-decode, all heads and all ``k1`` query tokens at once: the
-    same online-softmax recurrence as :func:`_decode_kernel` with a
-    softmax state per query row. The query block is
-    :func:`_block_diagonal`, ``[K1 * H, H * Dh]``; each of the block's
-    pages arrives as its own ``[ps, H * Dh]`` operand — whatever PAGE
-    the table maps it to, the index map doing the indirection BEFORE
-    the DMA (:func:`_page_spec`) — and the pages are folded one under
-    the other as ONE block of ``group * ps`` columns. A block that
-    starts beyond the slot's reach folds nothing (and copied nothing);
-    the column mask keeps the pages beyond the position inside the
-    last live block out of the softmax. Query row ``(i, h)`` sits at
-    column ``pos + i`` (``k1`` 1: the single-query decode step).
-    ``quant`` (static): each page brings its ``[ps, H]`` scale sidecar
-    through the same indirection; the int8 lanes go to the MXU as they
-    are and the scale multiplies the scores (K) and the probabilities
-    (V), one number a head and column."""
-    k_refs, v_refs = rest[:group], rest[group:2 * group]
-    rest = rest[2 * group:]
+def _paged_attention_kernel(pos_ref, ids_ref, layer_ref, q_ref, k_pool,
+                            v_pool, *refs, scale, page_size, group, n_win,
+                            heads, k1, buffers, quant):
+    """One SLOT of the PAGED flash-decode, all heads and all ``k1``
+    query tokens at once: the same online-softmax recurrence as
+    :func:`_decode_kernel` with a softmax state per query row. The
+    query block is :func:`_block_diagonal`, ``[K1 * H, H * Dh]``; query
+    row ``(i, h)`` sits at column ``pos + i`` (``k1`` 1: the
+    single-query decode step).
+
+    The kernel copies its own pages (:func:`_live_page_copies`): the K
+    and V pools stay in HBM and a slot is a loop over its LIVE blocks
+    of ``group`` pages, ``(pos + k1 - 1) // (group * ps) + 1`` of them
+    (up to the LAST query row's reach). The blocks of all slots, in
+    order, go round a ring of ``buffers`` K and V blocks: while one is
+    folded the copies of the next ``buffers - 1`` are in flight, the
+    next slots' included (``next_ref``: the slot and block whose copies
+    start next, and how many have started; ``done_ref``: how many blocks
+    were folded, whose count names the buffer). The column mask keeps
+    each row's columns beyond its reach out of the softmax; what lies
+    behind the last live page is a block copied earlier or the zeros of
+    the first step, so a masked column is ``0 x`` a finite number.
+    ``quant`` (static): two more operands, the slot's K and V scales
+    ``[H, window]`` (gathered by the caller); the int8 lanes go to the
+    MXU as they are and the scale multiplies the scores (K) and the
+    probabilities (V), one number a head and column."""
     if quant:
-        ks_refs, vs_refs = rest[:group], rest[group:2 * group]
-        rest = rest[2 * group:]
-    o_ref, acc, m_scr, l_scr = rest
+        ks_ref, vs_ref, *refs = refs
+    (o_ref, k_buf, v_buf, k_sem, v_sem, acc_ref, m_ref, l_ref, done_ref,
+     next_ref) = refs
     i = pl.program_id(0)
-    kb = pl.program_id(1)
+    layer = layer_ref[0]
     block_k = group * page_size
     rows = k1 * heads
 
-    def block(refs):
-        """The step's pages one under the other: ``[G * ps, .]``."""
-        pages = [ref[0, 0] for ref in refs]
-        return pages[0] if group == 1 else jnp.concatenate(pages, axis=0)
+    def last_page(slot):
+        return jnp.clip(pos_ref[slot] + (k1 - 1), 0,
+                        n_win * page_size - 1) // page_size
 
-    if quant:  # pick[(i, h), h'] = 1 where h' is row (i, h)'s head
-        r = jax.lax.broadcasted_iota(jnp.int32, (rows, heads), 0)
-        h = jax.lax.broadcasted_iota(jnp.int32, (rows, heads), 1)
-        pick = sum((r == h + j * heads).astype(jnp.float32)
-                   for j in range(k1))
+    def copies(slot, block, ring, wait=False):
+        count = jnp.minimum(last_page(slot) + 1 - block * group, group)
+        for pool_ref, buf_ref, sem in ((k_pool, k_buf, k_sem),
+                                       (v_pool, v_buf, v_sem)):
+            _live_page_copies(
+                pool_ref, layer, ids_ref, slot * n_win + block * group,
+                count, buf_ref.at[ring], sem.at[ring], group=group,
+                page_size=page_size, wait=wait)
 
-    def row_scales(refs):
-        """``[K1 * H, G * ps]``: row ``(i, h)`` holds head ``h``'s
-        scale of every column — the ``[G * ps, H]`` sidecar block
-        turned over by a 0/1 selection contraction (exact in f32)."""
-        return jax.lax.dot_general(
-            pick, block(refs), (((1,), (1,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
+    def start_next():
+        """Start the copies of the next block in the order of slots
+        and blocks, if there is one, into the ring's next buffer."""
+        slot, block, started = next_ref[0], next_ref[1], next_ref[2]
 
-    @pl.when(kb == 0)
+        @pl.when(slot < pl.num_programs(0))
+        def _():
+            copies(slot, block, started % buffers)
+            last = block == last_page(slot) // group
+            next_ref[0] = jnp.where(last, slot + 1, slot)
+            next_ref[1] = jnp.where(last, 0, block + 1)
+            next_ref[2] = started + 1
+
+    @pl.when(i == 0)
     def _():
-        acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        k_buf[:] = jnp.zeros_like(k_buf)
+        v_buf[:] = jnp.zeros_like(v_buf)
+        done_ref[0] = 0
+        next_ref[0] = 0
+        next_ref[1] = 0
+        next_ref[2] = 0
+        for _ in range(buffers - 1):
+            start_next()
 
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
     pos = pos_ref[i]
+    n_blocks = last_page(i) // group + 1
+    first = done_ref[0]
+    # row (i, h) reaches column pos + i; i = row // heads, as a sum of
+    # comparisons (no vector division)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    reach = pos + sum((row >= j * heads).astype(jnp.int32)
+                      for j in range(1, k1))
 
-    # block entirely beyond the last query row's reach -> skip (same
-    # per-slot cost gate as the dense kernel's block gate; unallocated
-    # table entries point at the scratch page, whose values this gate
-    # and the column mask keep out of the softmax)
-    @pl.when(kb * block_k <= pos + k1 - 1)
-    def _():
+    def fold(kb, carry):
+        ring = (first + kb) % buffers
+        # into the buffer the block before this one was folded out of
+        start_next()
+
+        def row_scales(ref):
+            """``[K1 * H, G * ps]``: row ``(i, h)`` holds head ``h``'s
+            scale of each of the block's columns."""
+            blk = ref[0, :, pl.ds(pl.multiple_of(kb * block_k, block_k),
+                                  block_k)]
+            return blk if k1 == 1 else jnp.concatenate([blk] * k1, axis=0)
+
+        copies(i, kb, ring, wait=True)
         q = q_ref[0]                                 # [K1*H, H*Dh]
-        kblk, vblk = block(k_refs), block(v_refs)    # [G*ps, H*Dh]
+        kblk, vblk = k_buf[ring], v_buf[ring]        # [G*ps, H*Dh]
         if quant:
             kblk, vblk = kblk.astype(q.dtype), vblk.astype(q.dtype)
         s = jax.lax.dot_general(
             q, kblk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [K1*H, G*ps]
         if quant:
-            s = s * row_scales(ks_refs)
+            s = s * row_scales(ks_ref)
         col = kb * block_k + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
-        # row (i, h) reaches column pos + i; i = row // heads, as a sum
-        # of comparisons (no vector division)
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        reach = pos + sum((row >= j * heads).astype(jnp.int32)
-                          for j in range(1, k1))
         s = jnp.where(col <= reach, s, NEG_INF)
-        m_prev = m_scr[:]
+        m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        if quant:
-            p = p * row_scales(vs_refs)
-        acc[:] = acc[:] * corr + jnp.dot(
+        m_ref[:] = m_new
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if quant:  # a scale beyond the reach may be anything, NaN too
+            p = jnp.where(col <= reach, p * row_scales(vs_ref), 0.0)
+        acc_ref[:] = acc_ref[:] * corr + jnp.dot(
             p.astype(vblk.dtype), vblk,
             preferred_element_type=jnp.float32)      # [K1*H, H*Dh]
+        return carry
 
-    @pl.when(kb == pl.num_programs(1) - 1)
-    def _():
-        o_ref[0] = acc[:] / jnp.maximum(l_scr[:], 1e-30)
+    jax.lax.fori_loop(0, n_blocks, fold, 0)
+    done_ref[0] = first + n_blocks
+    o_ref[0] = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
 
 
+# one program a shape: the layer is an operand, so the 24 calls of a
+# GPT-2 medium decode step trace and lower ONE kernel (with the layer a
+# static argument, 24 kernels took 13-15 s of the engine's set-up a
+# decode program on the host's CPU, against 1.0-1.5 s so)
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "name"))
 def _pallas_paged_attention(q, k_pages, v_pages, page_table, positions,
                             layer, scale, interpret, name):
     """q [B, K1, H, Dh]; k/v pools [L, P, ps, H*Dh] (or the int8
     pairs, scale [L, P, ps, H]); page_table [B, n_win] int32;
-    positions [B] -> f32 [B, K1, H, Dh]. Grid is (slot, block of G
-    pages), G from :func:`_pages_per_step`: one step folds all heads
-    and query rows of ``G * ps`` columns. Positions and the page ids
-    ride in SMEM via scalar prefetch; each pool is passed G times,
-    once per page of a step's block, so a whole ``[ps, H*Dh]`` page is
-    one DMA out of layer ``layer`` of the pool in place, and the
-    pipeline keeps the next step's G pages in flight — never a page
-    beyond a slot's reach (:func:`_live_page_ids`)."""
+    positions [B] -> f32 [B, K1, H, Dh]. The grid is the slots; a slot
+    folds all heads and query rows of its live blocks of G pages, G
+    from :func:`_gpt_block_pages`. Positions and the windowed page
+    table (flat, every id clipped into the pool) ride in SMEM via
+    scalar prefetch, and so does ``layer``; the K and V pools are
+    passed ONCE and read where they lie in HBM, the layer an index of
+    each page copy, and no page beyond a slot's reach is copied.
+
+    The int8 pairs' scale sidecars are gathered here, a slot's window
+    of them as one lane-dense ``[H, n_win * ps]`` block: the chip's
+    compiler slices no float32 array in HBM whose last axis is
+    narrower than 128 lanes, as a ``[ps, H]`` sidecar page is."""
     b, k1, h, _ = q.shape
     quant = isinstance(k_pages, QuantizedKV)
     k_data, v_data = ((k_pages.data, v_pages.data) if quant
                       else (k_pages, v_pages))
-    ps, width = k_data.shape[2], k_data.shape[3]
+    n_pages, ps, width = k_data.shape[1:]
     n_win = page_table.shape[1]
     rows = k1 * h
-    group = _pages_per_step(ps, n_win, ps * width * k_data.dtype.itemsize)
+    group = _gpt_block_pages(ps, n_win, rows, width * q.dtype.itemsize)
+    table = jnp.clip(page_table.astype(jnp.int32), 0, n_pages - 1)
 
-    def page_specs(last):
-        return [_page_spec((1, 1, ps, last), layer, g, group)
-                for g in range(group)]
+    def slot_spec(shape):
+        return pl.BlockSpec((1,) + shape,
+                            lambda i, pos, ids, layer: (i, 0, 0))
 
-    q_spec = pl.BlockSpec((1, rows, width),
-                          lambda i, kb, pos, ids: (i, 0, 0))
-    in_specs = [q_spec] + page_specs(width) * 2
-    operands = [_block_diagonal(q)] + [k_data] * group + [v_data] * group
+    in_specs = [slot_spec((rows, width))] + [
+        pl.BlockSpec(memory_space=pl.ANY)] * 2
+    operands = [_block_diagonal(q), k_data, v_data]
     if quant:
-        in_specs += page_specs(h) * 2
-        operands += [k_pages.scale] * group + [v_pages.scale] * group
+        def window_scales(pool):   # [L, P, ps, H] -> [B, H, n_win * ps]
+            g = jnp.take(pool[layer], table, axis=0)
+            return jnp.swapaxes(g.reshape(b, n_win * ps, h), 1, 2)
+
+        in_specs += [slot_spec((h, n_win * ps))] * 2
+        operands += [window_scales(k_pages.scale),
+                     window_scales(v_pages.scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # positions, page ids
-        grid=(b, pl.cdiv(n_win, group)),
+        num_scalar_prefetch=3,  # positions, the windowed table, the layer
+        grid=(b,),
         in_specs=in_specs,
-        out_specs=q_spec,
+        out_specs=slot_spec((rows, width)),
         scratch_shapes=[
+            # the ring of K blocks and of V blocks, a semaphore a block
+            pltpu.VMEM((_GPT_BUFFERS, group * ps, width), k_data.dtype),
+            pltpu.VMEM((_GPT_BUFFERS, group * ps, width), v_data.dtype),
+            pltpu.SemaphoreType.DMA((_GPT_BUFFERS,)),
+            pltpu.SemaphoreType.DMA((_GPT_BUFFERS,)),
             pltpu.VMEM((rows, width), jnp.float32),  # output accumulator
             pltpu.VMEM((rows, 1), jnp.float32),      # running max
             pltpu.VMEM((rows, 1), jnp.float32),      # running denominator
+            pltpu.SMEM((1,), jnp.int32),             # blocks folded so far
+            pltpu.SMEM((3,), jnp.int32),             # the next copies
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_attention_kernel, scale=scale,
-                          page_size=ps, group=group, heads=h, k1=k1,
-                          quant=quant),
+                          page_size=ps, group=group, n_win=n_win,
+                          heads=h, k1=k1,
+                          buffers=_GPT_BUFFERS, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, width), jnp.float32),
+        # slots in order (a fold starts the copies of a block after it,
+        # the next slots' too); the bounds checks off as in the latent
+        # kernel: every id is held inside the pool below and a copy's
+        # VMEM side is static
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), disable_bounds_checks=True),
         interpret=interpret,
         name=name,
-    )(positions.astype(jnp.int32),
-      _live_page_ids(page_table.astype(jnp.int32), positions + (k1 - 1),
-                     group, ps),
-      *operands)
+    )(positions.astype(jnp.int32), table.reshape(-1),
+      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     return _diagonal_blocks(out, k1, h)              # [B, K1, H, Dh]
 
 
@@ -527,9 +581,10 @@ def paged_decode_attention(
       q: ``[B, 1, H, Dh]`` — one pending query token per slot.
       k_pages, v_pages: ``[L, P, page_size, H * Dh]`` — ALL layers'
         pages, a row a token with the heads side by side in the lanes.
-        The kernel's index map picks ``layer``, so no layer is ever
-        sliced out of (and copied from) the pool, and a page is one
-        contiguous lane-dense DMA. Or a :class:`...kv_quant.
+        The kernel reads the pools where they lie in HBM, ``layer`` an
+        index of each page copy, so no layer is ever sliced out of (and
+        copied from) the pool, and a live page is one contiguous
+        lane-dense DMA. Or a :class:`...kv_quant.
         QuantizedKV` pair (int8 data + the ``[L, P, page_size, H]``
         f32 scale sidecar).
       page_table: ``[B, n_win]`` int32 — slot ``b``'s logical column

@@ -184,10 +184,12 @@ _DECODE = [
 ] + [
     # the benchmark's serving cell (gpt2-medium.serve.closed): 32 slots,
     # 16 heads, pages of 16, window 1024 -> 64 table entries a slot
-    pytest.param(lambda kv=kv: _decode_case("paged", kv, 1, 16,
-                                            slots=32, heads=16),
-                 id=f"paged-{kv}-decode-page16-gpt2-medium-32slots")
+    pytest.param(lambda kv=kv, k1=k1: _decode_case("paged", kv, k1, 16,
+                                                   slots=32, heads=16),
+                 id=f"paged-{kv}-{'verify' if k1 > 1 else 'decode'}"
+                    "-page16-gpt2-medium-32slots")
     for kv in ("bf16", "int8")
+    for k1 in (1, K1)
 ]
 
 def _mla_case(slots=64, heads=32, rank=512, rope=128, page=16,
@@ -434,6 +436,53 @@ def test_gpt_decode_and_insert_programs_write_the_pools_in_place(topo):
         # temporaries far below one copy of the pools: written in place
         assert mem.temp_size_in_bytes < pools // 4, mem
         assert mem.alias_size_in_bytes >= pools
+
+
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_gpt_decode_program_calls_the_kernel_once_a_layer(topo, kv_dtype):
+    """The decode program of the cell ``gpt2-medium.serve.closed`` at
+    ONE layer of the published widths (32 slots, window 1,024, pages
+    of 16, bfloat16; int8 pages beside it), through ``ServingEngine``:
+    one ``paged_decode_attention`` call a layer, and the pools it reads
+    are the program's donated arguments where they lie (``pl.ANY``),
+    never a slice or a copy: the temporaries below."""
+    from perf.rehearse import as_chip
+    from pytorch_multiprocessing_distributed_tpu import models
+    from pytorch_multiprocessing_distributed_tpu.serving import ServingEngine
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    model = models.get_model("gpt_medium", dtype=BF16, num_layers=1)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"])
+    with as_chip(chip):
+        engine = ServingEngine(model, params, max_slots=32, s_max=S,
+                               kv_layout="paged", page_size=16,
+                               kv_dtype=kv_dtype)
+        assert engine.decode_attn == "pallas"
+        pool = engine.pool
+
+        def sds(x):
+            return jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), x)
+
+        compiled = engine._decode.lower(
+            sds(params), sds(pool.k_pages), sds(pool.v_pages),
+            sds(pool.device_table()), sds(pool.positions),
+            sds(pool.last_tokens), sds(pool.active), sds(pool.budgets),
+            sds(pool.eos_ids), jax.ShapeDtypeStruct((2,), jnp.uint32),
+            window=S, horizon=1).compile()
+    text = compiled.as_text()
+    assert _mosaic_names(text) == {"paged_decode_attention"}
+    assert sum("tpu_custom_call" in line
+               and "paged_decode_attention" in line
+               for line in text.splitlines()) == 1
+    pools = sum(x.nbytes for x in jax.tree.leaves((pool.k_pages,
+                                                   pool.v_pages)))
+    mem = compiled.memory_analysis()
+    # temporaries far below one copy of the pools: written in place
+    assert mem.temp_size_in_bytes < pools // 4, mem
+    assert mem.alias_size_in_bytes >= pools
 
 
 # (positions, a block's columns, page, table entries, heads, NaN in dead pages)

@@ -1,8 +1,9 @@
 """The paged decode kernel against its XLA pin, shape by shape.
 
-``_pallas_paged_attention`` folds all heads of a block of G pages in
-one grid step, reads layer ``LAYER`` of the whole ``[L, P, ps, H * Dh]``
-pool in place and names only live pages to the pipeline
+``_pallas_paged_attention`` is a grid of slots; a slot is a loop over
+its live blocks of G pages, each block's pages copied by the kernel
+itself out of layer ``LAYER`` of the whole ``[L, P, ps, H * Dh]`` pool
+where it lies, all heads folded at once
 (``ops/pallas/decode_attention.py``). Here it runs in interpret mode
 against ``xla_paged_decode_attention`` over the page sizes, head counts
 and window lengths the engine can hand it, and every batch carries the
@@ -146,38 +147,94 @@ def test_pallas_paged_verify_matches_xla(ps, heads, n_win, k1, int8):
                                atol=2e-5)
 
 
-@pytest.mark.parametrize("ps, n_win", [(8, 20), (16, 64), (16, 11),
-                                       (128, 8)])
-def test_live_page_ids_name_live_pages_only(ps, n_win):
-    """What the pipeline is asked to copy, step by step: never a page
-    beyond the slot's position, and from a slot's last live block to
-    its last grid step the SAME pages (a repeated block index is not
-    copied again) — so a dead block costs no DMA."""
-    rng = np.random.default_rng(ps + n_win)
-    window = n_win * ps
-    pos = np.array([0, ps - 1, ps, window - 1,
-                    int(rng.integers(0, window))], np.int32)
-    table = np.arange(len(pos) * n_win, dtype=np.int32).reshape(
-        len(pos), n_win) + 1          # entry -> slot and logical page
-    group = da._pages_per_step(ps, n_win, ps * 2 * D * 2)
-    assert group == min(max(1, 128 // ps), n_win)
-    named = np.asarray(da._live_page_ids(
-        jnp.asarray(table), jnp.asarray(pos), group, ps)).reshape(
-            len(pos), -(-n_win // group), group)
-    for slot, p in enumerate(pos):
-        last_page, last_block = p // ps, p // (group * ps)
-        logical = named[slot] - 1 - slot * n_win
-        assert (logical >= 0).all() and (logical <= last_page).all()
-        # a live block names each of its live pages, in order
-        for kb in range(last_block + 1):
-            live = min(group, last_page - kb * group + 1)
-            assert list(logical[kb, :live]) == list(
-                range(kb * group, kb * group + live))
-        # and a dead block names what the last live one did
-        assert (named[slot, last_block:] == named[slot, last_block]).all()
-        # a page beyond the position inside the last live block keeps
-        # the page its operand held in the block before
-        if last_block > 0:
-            beyond = slice(last_page - last_block * group + 1, group)
-            assert (named[slot, last_block, beyond]
-                    == named[slot, last_block - 1, beyond]).all()
+# (positions, a block's columns, page, table entries, heads, int8): the
+# edges the loop over a slot's live blocks creates
+_EDGES = [
+    # column 0, a page's last and first column, in a block of two pages
+    # and in one block a slot
+    pytest.param([0, 15, 16], 32, 16, 8, 4, False, id="page-edges-2-pages"),
+    pytest.param([0, 15, 16], 128, 16, 8, 4, False, id="page-edges-1-block"),
+    # a block's last and first column; a slot of one block before one of
+    # two and one of four (the ring's place carries over the slots)
+    pytest.param([31, 32, 127], 32, 16, 8, 4, False, id="block-edges"),
+    pytest.param([127, 0, 127], 32, 16, 8, 4, False, id="window-end"),
+    pytest.param([127, 64, 126], 64, 16, 8, 2, False,
+                 id="window-end-2-blocks"),
+    # a window that is no whole number of blocks: the last block is short
+    pytest.param([87, 44, 48], 32, 8, 11, 4, False, id="short-last-block"),
+    pytest.param([31, 32, 127], 32, 16, 8, 4, True, id="block-edges-int8"),
+    pytest.param([127, 5, 64], 64, 16, 8, 2, True, id="window-end-int8"),
+]
+
+
+def _loop_case(monkeypatch, positions, columns, ps, n_win, heads, int8,
+               k1, seed):
+    """(query, clean pools, table, positions) with the kernel's block
+    forced to ``columns``: three slots whose table rows are shuffled
+    pool pages (logical order never equals pool order)."""
+    monkeypatch.setattr(da, "_gpt_block_pages",
+                        lambda page, entries, *_: min(columns // page,
+                                                      entries))
+    rng = np.random.default_rng(seed)
+    slots = len(positions)
+    n_pages = slots * n_win + 1
+    q = jnp.asarray(rng.standard_normal((slots, k1, heads, D)), jnp.float32)
+    k, v = (_pages(rng.standard_normal((L, n_pages, ps, heads * D)).astype(
+        np.float32), int8) for _ in range(2))
+    table = rng.permutation(np.arange(1, n_pages)).reshape(slots, n_win)
+    return q, k, v, table.astype(np.int32), np.asarray(positions, np.int32)
+
+
+@pytest.mark.parametrize("positions, columns, ps, n_win, heads, int8",
+                         _EDGES)
+def test_loop_over_live_blocks_matches_xla_at_its_edges(
+        monkeypatch, positions, columns, ps, n_win, heads, int8):
+    """The kernel under the Pallas interpreter against the XLA form at
+    the columns where the loop over a slot's live blocks turns: a
+    page's edges, a block's edges, the window's end, one block a slot
+    and several. Every page beyond a slot's reach, and the other
+    layer, holds NaN: nothing beyond a reach is ever copied."""
+    q, k, v, table, pos = _loop_case(monkeypatch, positions, columns, ps,
+                                     n_win, heads, int8, k1=1, seed=columns)
+    args = (jnp.asarray(table), jnp.asarray(pos))
+    want = da.paged_decode_attention(q, k, v, *args, layer=LAYER,
+                                     impl="xla")
+    got = da.paged_decode_attention(
+        q, _poison(k, table, pos, ps), _poison(v, table, pos, ps),
+        *args, layer=LAYER, impl="pallas", interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+def test_verify_rows_cross_a_block_edge(monkeypatch, int8):
+    """k1 = 5: a slot's five query rows reach columns ``pos .. pos +
+    4``, and here they straddle a block's edge (the first rows end in
+    one block, the last in the next, which only they may read) or the
+    window's end; the pages up to the LAST row's reach are the live
+    ones, and everything else holds NaN."""
+    q, k, v, table, pos = _loop_case(monkeypatch, [29, 62, 123], 32, 16, 8,
+                                     2, int8, k1=5, seed=5)
+    args = (jnp.asarray(table), jnp.asarray(pos))
+    want = da.paged_verify_decode_attention(q, k, v, *args, layer=LAYER,
+                                            impl="xla")
+    reach = pos + 4
+    got = da.paged_verify_decode_attention(
+        q, _poison(k, table, reach, 16), _poison(v, table, reach, 16),
+        *args, layer=LAYER, impl="pallas", interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("page, n_win, rows, row_bytes, want", [
+    (16, 64, 16, 2048, 16),   # gpt2-medium.serve.closed: 256 columns
+    (16, 64, 80, 2048, 16),   # its verify pass, five query tokens
+    (16, 64, 12, 1536, 32),   # gpt_small's narrower rows: 512 columns
+    (16, 11, 16, 2048, 11),   # no more than the window has
+    (128, 8, 16, 2048, 2),    # pages of 128: the same 256 columns
+    (16, 64, 16, 4096, 8),    # a float32 query: rows twice as wide
+], ids=["gpt2-medium", "verify", "gpt-small", "short-window", "page128",
+        "f32"])
+def test_gpt_block_is_chosen_from_heads_rows_and_the_vmem_budget(
+        page, n_win, rows, row_bytes, want):
+    assert da._gpt_block_pages(page, n_win, rows, row_bytes) == want
