@@ -33,6 +33,7 @@ from .xing4 import Xing4, Xing4_29B_A4B, Xing4_Tiny
 from .pangu_ultra_moe import (PanguUltraMoE, PanguUltraMoE_718B,
                               PanguUltraMoE_Tiny)
 from .afmoe import Afmoe, Afmoe_Tiny, Trinity_Large_Preview
+from .mimo_v2 import MiMoV2, MiMo_V2_5, MiMo_V2_Tiny
 
 __all__ = [
     "BasicBlock",
@@ -53,5 +54,6 @@ __all__ = [
     "Xing4", "Xing4_29B_A4B", "Xing4_Tiny",
     "PanguUltraMoE", "PanguUltraMoE_718B", "PanguUltraMoE_Tiny",
     "Afmoe", "Afmoe_Tiny", "Trinity_Large_Preview",
+    "MiMoV2", "MiMo_V2_5", "MiMo_V2_Tiny",
 ]
 

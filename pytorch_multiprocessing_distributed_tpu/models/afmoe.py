@@ -210,6 +210,39 @@ def _qkvg(h, p, positions, rotate, model):
             jax.nn.sigmoid(_dot(h, p["wg"], dt)))
 
 
+def _in_reach(cache, positions, start, reach):
+    """The rows of one layer's standalone cache ``[W, row]`` that a
+    chunk at ``positions`` (from ``start``) may attend, and its mask
+    ``[T, span]``: every column under the causal mask where ``reach``
+    is None (a full layer); else the ``T + reach`` columns from ``start
+    - reach`` SLICED out (where the cache is wider) and ``i - j <
+    reach`` masked inside."""
+    t, w = positions.shape[0], cache.shape[0]
+    if reach is not None and t + reach < w:
+        span = t + reach
+        begin = jnp.clip(start - reach, 0, w - span)
+        rows = jax.lax.dynamic_slice_in_dim(cache, begin, span, axis=0)
+    else:
+        span, begin, rows = w, 0, cache
+    cols = begin + jnp.arange(span)
+    mask = cols[None, :] <= positions[:, None]              # [T, span]
+    if reach is not None:
+        mask = jnp.logical_and(
+            mask, positions[:, None] - cols[None, :] < reach)
+    return rows, mask
+
+
+def _write_row(pool, layer, table, positions, row, page_size, ring):
+    """Each slot's new ``row`` into layer ``layer`` of the WHOLE pool,
+    in place: at ``table[slot, pos // ps]`` under a page table, at the
+    slot's ring entry ``(pos // ps) % Wp`` where ``ring``."""
+    entry = positions // page_size
+    if ring:
+        entry = entry % table.shape[1]
+    page_ids = jnp.take_along_axis(table, entry[:, None], axis=1)[:, 0]
+    return pool.at[layer, page_ids, positions % page_size].set(row)
+
+
 def _attn_prefill(h, p, cache, start, sliding, model):
     """Causal grouped attention of a chunk ``h [T, C]`` at absolute
     positions ``[start, start + T)`` against one layer's standalone
@@ -221,22 +254,14 @@ def _attn_prefill(h, p, cache, start, sliding, model):
     time with its group of query heads: K and V are never repeated.
     Returns ``(out [T, C] float32, cache)``."""
     dt = model.dtype
-    t, w = h.shape[0], cache.shape[0]
+    t = h.shape[0]
     hq, hk, d = model.num_heads, model.num_kv_heads, model.head_dim
     positions = start + jnp.arange(t)
     q, row, gate = _qkvg(h, p, positions, sliding, model)
     cache = jax.lax.dynamic_update_slice(cache, row, (start, 0))
-    if sliding and t + model.sliding_window < w:
-        span = t + model.sliding_window
-        begin = jnp.clip(start - model.sliding_window, 0, w - span)
-        rows = jax.lax.dynamic_slice_in_dim(cache, begin, span, axis=0)
-    else:
-        span, begin, rows = w, 0, cache
-    cols = begin + jnp.arange(span)
-    mask = cols[None, :] <= positions[:, None]              # [T, span]
-    if sliding:
-        mask = jnp.logical_and(
-            mask, positions[:, None] - cols[None, :] < model.sliding_window)
+    rows, mask = _in_reach(cache, positions, start,
+                           model.sliding_window if sliding else None)
+    span = rows.shape[0]
     kv = rows.reshape(span, 2, hk, d)
     scale = d ** -0.5
 
@@ -269,11 +294,7 @@ def _attn_decode(h, p, pool, layer, table, read_table, sliding, positions,
     float32, pool)``."""
     ps = int(page_size)
     q, row, gate = _qkvg(h, p, positions, sliding, model)
-    entry = positions // ps
-    if sliding:
-        entry = entry % table.shape[1]
-    page_ids = jnp.take_along_axis(table, entry[:, None], axis=1)[:, 0]
-    pool = pool.at[layer, page_ids, positions % ps].set(row)
+    pool = _write_row(pool, layer, table, positions, row, ps, sliding)
     out = gqa_paged_decode_attention(
         q, pool, read_table, positions, layer=layer,
         kv_heads=model.num_kv_heads, scale=model.head_dim ** -0.5,
@@ -296,6 +317,11 @@ class AfmoeServing(PanguUltraMoEServing):
         "prefix_cache": "no page fork or gather for a ring of pages yet",
         "mesh": "no tensor-parallel grouped decode yet",
     }
+
+    # one layer's attention in the walks below: a family with another
+    # attention over the same two pools gives its own
+    attn_prefill = staticmethod(_attn_prefill)
+    attn_decode = staticmethod(_attn_decode)
 
     def embed(self, model, params, tokens):
         """``tokens [T]`` -> ``[T, C]`` float32, times ``sqrt(C)``
@@ -330,7 +356,7 @@ class AfmoeServing(PanguUltraMoEServing):
 
             def attention(h, layer=layer, sliding=sliding):
                 pref = pref_sliding if sliding else pref_full
-                out, cache = _attn_prefill(
+                out, cache = self.attn_prefill(
                     h, layer["attn"], pref[len(caches[sliding]), 0],
                     start, sliding, model)
                 caches[sliding].append(cache)
@@ -388,7 +414,7 @@ class AfmoeServing(PanguUltraMoEServing):
             sliding = kind == "sliding_attention"
 
             def attention(h, layer=layer, sliding=sliding):
-                out, pools[sliding] = _attn_decode(
+                out, pools[sliding] = self.attn_decode(
                     h, layer["attn"], pools[sliding], index[sliding],
                     ring_table if sliding else page_table,
                     ring_table if sliding else bucket_table, sliding,

@@ -195,8 +195,13 @@ def _rms(x, scale, eps):
 
 def _rotary(x, positions, model):
     """``x [T, ..., rope]`` at ``positions [T]``, pairs ``(i, i +
-    rope/2)``, in float32."""
-    inv_freq, factor = model.rope_tables()
+    rope/2)``, in float32, by the model's ``rope_tables()``."""
+    return _rotate(x, positions, *model.rope_tables())
+
+
+def _rotate(x, positions, inv_freq, factor=1.0):
+    """:func:`_rotary` by the tables themselves: ``inv_freq [rope/2]``
+    and a factor on cos and sin."""
     angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (angle.shape[-1],)
     cos = (jnp.cos(angle) * factor).reshape(shape)
@@ -342,7 +347,7 @@ def _ffn(h32, layer, model):
     assignments each held expert took, those routed to experts this
     chip does not hold and, last, the rows the grouped matmuls were
     given. ``e_bias``, where the family has one, moves the selection
-    only."""
+    only; ``shared``, where the layer has a shared expert, is added."""
     dt = model.dtype
     if "mlp" in layer:
         return _gated(h32, layer["mlp"], dt), None
@@ -354,8 +359,9 @@ def _ffn(h32, layer, model):
         h32.astype(dt), chosen, weights, moe["w_gate"], moe["w_up"],
         moe["w_down"], n_experts=model.n_experts,
         offset=model.expert_offset)
-    return (y + _gated(h32, moe["shared"], dt),
-            jnp.concatenate([counts, elsewhere[None], given[None]]))
+    if "shared" in moe:
+        y = y + _gated(h32, moe["shared"], dt)
+    return y, jnp.concatenate([counts, elsewhere[None], given[None]])
 
 
 # ---------------------------------------------------- the serving family

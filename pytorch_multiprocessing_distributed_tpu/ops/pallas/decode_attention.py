@@ -62,10 +62,12 @@ step is page ``g`` of its block, one page of 64 KiB whose copy the
 pipeline's price per operand does not bound), the page each operand
 names worked out in XLA (:func:`_reach_page_ids`), a dead step naming
 its predecessor's pages, which the pipeline does not copy twice. It
-reads a row that holds K and V side by side: a block-diagonal query
-over the KEY/VALUE heads' lanes, and a LOWER column bound ``reach``
+reads a row that holds K and V side by side (keys may be wider than
+values: ``Hkv * Dk`` lanes, then ``Hkv * Dv``): a block-diagonal query
+over the KEY/VALUE heads' key lanes, a LOWER column bound ``reach``
 that follows each slot's position (no page below it is named) beside
-the upper bound every paged kernel has. Two words, two things:
+the upper bound every paged kernel has, and an optional learned sink
+logit a head that starts the online softmax. Two words, two things:
 ``window`` in this file is always the decode BUCKET's column bound,
 ``reach`` a model's sliding window.
 
@@ -923,31 +925,38 @@ def _reach_page_ids(table, positions, group, page_size, reach):
 
 
 def _gqa_paged_decode_kernel(pos_ref, ids_ref, q_ref, *rest, scale,
-                             page_size, group, reach):
+                             page_size, group, reach, sink):
     """One (slot, block of ``group`` pages) cell of the GROUPED-head
     paged decode, all query heads at once. A cache row holds K then V
-    of the ``Hkv`` key/value heads side by side (``[ps, 2 * Hkv *
-    Dh]`` a page: one DMA for both). The query is block-diagonal over
-    the KEY/VALUE heads (``[Hq, Hkv * Dh]``: row ``t`` holds ``q_t`` in
+    of the ``Hkv`` key/value heads side by side (``[ps, Hkv * (Dk +
+    Dv)]`` a page: one DMA for both). The query is block-diagonal over
+    the KEY/VALUE heads (``[Hq, Hkv * Dk]``: row ``t`` holds ``q_t`` in
     the lanes of head ``t // (Hq / Hkv)``), so one contraction with the
-    K half is every query head's scores against its own group's keys
-    and ``P @ V half`` holds head ``t``'s output in the same lanes.
-    Block ``kb`` starts at the page of the first column in reach, not
-    at column 0: a slot attends ``[max(0, pos - reach + 1), pos]``
-    (``reach`` None: ``[0, pos]``) and the first live page is masked
-    below that bound. Same online-softmax recurrence as
-    :func:`_mla_paged_decode_kernel`."""
+    K lanes is every query head's scores against its own group's keys
+    and ``P @ V lanes`` (``[Hq, Hkv * Dv]``) holds head ``t``'s output
+    in the lanes of its group. Block ``kb`` starts at the page of the
+    first column in reach, not at column 0: a slot attends ``[max(0,
+    pos - reach + 1), pos]`` (``reach`` None: ``[0, pos]``) and the
+    first live page is masked below that bound. Same online-softmax
+    recurrence as :func:`_mla_paged_decode_kernel`; ``sink`` (static):
+    a ``[Hq, 1]`` logit a head follows the pages, a column with no
+    value that starts the recurrence (``m = sink``, ``l = 1``)."""
     row_refs = rest[:group]
-    o_ref, acc, m_scr, l_scr = rest[group:]
+    sink_ref = rest[group] if sink else None
+    o_ref, acc, m_scr, l_scr = rest[group + int(sink):]
     i = pl.program_id(0)
     kb = pl.program_id(1)
-    half = q_ref.shape[-1]
+    keys = q_ref.shape[-1]                              # Hkv * Dk
 
     @pl.when(kb == 0)
     def _():
         acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        if sink_ref is None:
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
+        else:
+            m_scr[:] = sink_ref[...]
+            l_scr[:] = jnp.ones_like(l_scr)
 
     pos = pos_ref[i]
     low = 0 if reach is None else jnp.maximum(pos - (reach - 1), 0)
@@ -958,7 +967,7 @@ def _gqa_paged_decode_kernel(pos_ref, ids_ref, q_ref, *rest, scale,
         pages = [ref[0, 0] for ref in row_refs]      # [ps, 2 * Hkv * Dh]
         rows = pages[0] if group == 1 else jnp.concatenate(pages, axis=0)
         s = jax.lax.dot_general(
-            q_ref[0], rows[:, :half], (((1,), (1,)), ((), ())),
+            q_ref[0], rows[:, :keys], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [Hq, G*ps]
         col = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(jnp.logical_and(col >= low, col <= pos), s, NEG_INF)
@@ -969,8 +978,8 @@ def _gqa_paged_decode_kernel(pos_ref, ids_ref, q_ref, *rest, scale,
         m_scr[:] = m_new
         l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc[:] = acc[:] * corr + jnp.dot(
-            p.astype(rows.dtype), rows[:, half:],
-            preferred_element_type=jnp.float32)      # [Hq, Hkv * Dh]
+            p.astype(rows.dtype), rows[:, keys:],
+            preferred_element_type=jnp.float32)      # [Hq, Hkv * Dv]
 
     @pl.when(kb == pl.num_programs(1) - 1)
     def _():
@@ -985,35 +994,45 @@ def _group_mask(heads, kv_heads):
 
 
 def _pallas_gqa_paged_decode(q, pages, table, positions, layer, kv_heads,
-                             reach, scale, interpret):
+                             reach, scale, interpret, sinks=None):
     b, heads, d = q.shape
     ps, width = pages.shape[2], pages.shape[3]
-    half = width // 2                                   # Hkv * Dh
+    keys = kv_heads * d                                 # Hkv * Dk
     entries = table.shape[1]
     group = max(1, min(_GQA_BLOCK_COLUMNS // ps, entries))
     pick = _group_mask(heads, kv_heads)
     q_bd = jnp.where(pick[None, :, :, None], q[:, :, None, :],
-                     jnp.zeros((), q.dtype)).reshape(b, heads, half)
-    slot_spec = pl.BlockSpec((1, heads, half),
-                             lambda i, kb, pos, ids: (i, 0, 0))
+                     jnp.zeros((), q.dtype)).reshape(b, heads, keys)
+
+    def slot_spec(lanes):
+        return pl.BlockSpec((1, heads, lanes),
+                            lambda i, kb, pos, ids: (i, 0, 0))
+
+    sink_specs, sink_args = [], []
+    if sinks is not None:
+        sink_specs = [pl.BlockSpec((heads, 1),
+                                   lambda i, kb, pos, ids: (0, 0))]
+        sink_args = [sinks.astype(jnp.float32).reshape(heads, 1)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # positions, page ids
         grid=(b, pl.cdiv(entries, group)),
-        in_specs=[slot_spec] + [
+        in_specs=[slot_spec(keys)] + [
             _page_spec((1, 1, ps, width), layer, g, group)
-            for g in range(group)],
-        out_specs=slot_spec,
+            for g in range(group)] + sink_specs,
+        out_specs=slot_spec(width - keys),
         scratch_shapes=[
-            pltpu.VMEM((heads, half), jnp.float32),  # output accumulator
+            pltpu.VMEM((heads, width - keys), jnp.float32),  # accumulator
             pltpu.VMEM((heads, 1), jnp.float32),     # running max
             pltpu.VMEM((heads, 1), jnp.float32),     # running denominator
         ],
     )
     out = pl.pallas_call(
         functools.partial(_gqa_paged_decode_kernel, scale=scale,
-                          page_size=ps, group=group, reach=reach),
+                          page_size=ps, group=group, reach=reach,
+                          sink=sinks is not None),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, heads, half), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, heads, width - keys),
+                                       jnp.float32),
         interpret=interpret,
         # one body, two names: the trace tells the layers that read a
         # window from those that read the context
@@ -1021,21 +1040,24 @@ def _pallas_gqa_paged_decode(q, pages, table, positions, layer, kv_heads,
               else "gqa_paged_decode_attention_window"),
     )(positions.astype(jnp.int32),
       _reach_page_ids(table.astype(jnp.int32), positions, group, ps, reach),
-      q_bd, *([pages] * group))
-    out = out.reshape(b, heads, kv_heads, d)
+      q_bd, *([pages] * group), *sink_args)
+    out = out.reshape(b, heads, kv_heads, (width - keys) // kv_heads)
     return jnp.sum(jnp.where(pick[None, :, :, None], out, 0.0), axis=2)
 
 
 def xla_gqa_paged_decode_attention(q, pages, table, positions, *, layer,
                                    kv_heads, scale,
-                                   reach: Optional[int] = None):
+                                   reach: Optional[int] = None,
+                                   sinks=None):
     """The grouped decode in plain XLA: ``take``-gather every table
     entry of layer ``layer`` into ``[B, E * ps, .]`` rows, give each
     entry the logical page it holds now (entry ``j`` holds the newest
     page ``g <= pos // ps`` with ``g % E == j``: itself under a page
     table, the wrap under a ring), mask columns outside ``[max(0, pos -
     reach + 1), pos]`` and run grouped attention with a float32
-    softmax, K and V never repeated per query head."""
+    softmax, K and V never repeated per query head; a head's ``sinks``
+    logit, where given, is one more column of the softmax with no
+    value."""
     b, entries = table.shape
     heads, d = q.shape[1], q.shape[2]
     ps = pages.shape[2]
@@ -1047,16 +1069,25 @@ def xla_gqa_paged_decode_attention(q, pages, table, positions, *, layer,
     low = (jnp.zeros_like(positions) if reach is None
            else jnp.maximum(positions - (reach - 1), 0))
     mask = jnp.logical_and(col >= low[:, None], col <= positions[:, None])
-    rows = rows.reshape(b, entries * ps, 2, kv_heads, d)
+    rows = rows.reshape(b, entries * ps, -1)
+    keys = kv_heads * d
+    k = rows[..., :keys].reshape(b, entries * ps, kv_heads, d)
+    v = rows[..., keys:].reshape(b, entries * ps, kv_heads, -1)
     s = jnp.einsum("bkgd,bwkd->bkgw",
-                   q.reshape(b, kv_heads, heads // kv_heads, d),
-                   rows[:, :, 0],
+                   q.reshape(b, kv_heads, heads // kv_heads, d), k,
                    preferred_element_type=jnp.float32) * scale
-    p = jax.nn.softmax(jnp.where(mask[:, None, None, :], s, -jnp.inf),
-                       axis=-1)
-    out = jnp.einsum("bkgw,bwkd->bkgd", p.astype(rows.dtype),
-                     rows[:, :, 1], preferred_element_type=jnp.float32)
-    return out.reshape(b, heads, d)
+    s = jnp.where(mask[:, None, None, :], s, -jnp.inf)
+    if sinks is None:
+        p = jax.nn.softmax(s, axis=-1)
+    else:
+        sink = jnp.broadcast_to(
+            sinks.astype(jnp.float32).reshape(1, kv_heads, -1, 1),
+            s.shape[:-1] + (1,))
+        p = jax.nn.softmax(jnp.concatenate([s, sink], axis=-1),
+                           axis=-1)[..., :-1]
+    out = jnp.einsum("bkgw,bwkd->bkgd", p.astype(rows.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, heads, -1)
 
 
 def gqa_paged_decode_attention(
@@ -1069,20 +1100,22 @@ def gqa_paged_decode_attention(
     kv_heads: int,
     scale: float,
     reach: Optional[int] = None,
+    sinks: Optional[jax.Array] = None,
     impl: str = "auto",
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Single-step attention of GROUPED query heads through a table of
-    pages, with an optional LOWER column bound (a sliding window).
+    pages, with an optional LOWER column bound (a sliding window) and
+    an optional learned sink logit a head.
 
     Args:
-      q: ``[B, Hq, Dh]`` - one pending query token a slot; heads ``t``
+      q: ``[B, Hq, Dk]`` - one pending query token a slot; heads ``t``
         with equal ``t // (Hq / Hkv)`` share a key/value head.
-      pages: ``[L, P, page_size, 2 * Hkv * Dh]`` - ALL the pool's
-        layers; a row is a token's K of every key/value head, then its
-        V (``Hkv * Dh`` lanes each), so a page is one contiguous DMA.
-        The kernel's index map picks ``layer``: the donated pool is
-        read in place.
+      pages: ``[L, P, page_size, Hkv * (Dk + Dv)]`` - ALL the pool's
+        layers; a row is a token's K of every key/value head (``Hkv *
+        Dk`` lanes), then its V (``Hkv * Dv``: values may be narrower
+        than keys), so a page is one contiguous DMA. The kernel's
+        index map picks ``layer``: the donated pool is read in place.
       table: ``[B, E]`` int32 - logical page ``g`` of slot ``b`` lives
         at ``table[b, g % E]``. A PAGE TABLE (``g < E``: callers pass
         the slice up to the decode BUCKET's column bound, as for
@@ -1099,9 +1132,12 @@ def gqa_paged_decode_attention(
         the whole context. Not the decode bucket's ``window``, which
         bounds a step's columns from above and is spent in the slice of
         ``table`` the caller passes.
+      sinks: ``[Hq]`` float32 or None - head ``t``'s learned sink logit
+        adds ``exp(sinks[t])`` to its softmax's denominator and no
+        value (the online softmax starts from it).
 
     Only pages that hold a column in reach are copied; the first of
-    them is masked below the bound. Returns ``[B, Hq, Dh]`` f32.
+    them is masked below the bound. Returns ``[B, Hq, Dv]`` f32.
     """
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
@@ -1113,13 +1149,13 @@ def gqa_paged_decode_attention(
             interpret = default_interpret()
         return _pallas_gqa_paged_decode(
             q, pages, table, positions, int(layer), int(kv_heads), reach,
-            float(scale), bool(interpret))
+            float(scale), bool(interpret), sinks)
     if impl != "xla":
         raise ValueError(
             f"impl must be 'pallas', 'xla' or 'auto', got {impl!r}")
     return xla_gqa_paged_decode_attention(
         q, pages, table, positions, layer=layer, kv_heads=kv_heads,
-        scale=scale, reach=reach)
+        scale=scale, reach=reach, sinks=sinks)
 
 
 def xla_decode_attention(q, k, v, mask):

@@ -2302,12 +2302,16 @@ class ServingEngine:
             # 0-transfer pin holds
             caches = (pool.k_pages, pool.v_pages, pool.device_table())
             # the share of the window's pages the decode kernel has
-            # to read (host mirror: no device read)
+            # to read (host mirror: no device read); two kinds of
+            # layer: what one layer of each kind reads, in pages and
+            # in bytes
+            by_kind = pool.live_pages_by_kind() if pool.ring_pages else {}
             dispatch_span.note(
                 kv_pages_live=pool.live_pages,
                 kv_pages_window=pool.max_slots
                 * -(-window // pool.page_size),
-                **(pool.live_pages_by_kind() if pool.ring_pages else {}))
+                **by_kind,
+                **(pool.live_bytes_by_kind(by_kind) if by_kind else {}))
 
             if k:
                 if self._drafter is not None:

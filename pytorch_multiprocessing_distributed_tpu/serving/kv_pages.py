@@ -51,7 +51,9 @@ family one row all heads share, and a zero-width placeholder.
 
 **Two kinds of layer** (a family whose ``cache_rows`` say so,
 ``inference.generate.cache_pools``: sliding-window layers beside full
-ones). Each pool then holds ITS layers only. The pool of the layers
+ones). Each pool then holds ITS layers only, at its own row (the two
+kinds may keep rows of different widths: MiMo-V2's full layers 1,280
+values a token, its window layers 2,560). The pool of the layers
 that attend the whole context is the paged pool above, under the page
 table, the free list and the refcounts. The pool of the layers that
 attend a window is a **ring**: ``ring_pages = ceil(window / page_size)
@@ -220,6 +222,12 @@ class PagePool:
             else min(self.pages_per_slot,
                      -(-self.ring_window // page_size) + 1))
         self.ring_pages_overwritten = 0
+        # bytes of one page of ONE layer, by kind (held as a ring or
+        # not): what a layer's kernel copies a page
+        self._layer_page_bytes = {
+            columns is not None: page_size * int(np.prod(row, dtype=int))
+            * jnp.dtype(dtype).itemsize
+            for _, row, dtype, _, columns in cache_pools(model)}
         self.k_pages, self.v_pages = (
             self._cache_sharded(self._empty_pages(
                 row, layers, self.num_pages if columns is None
@@ -666,6 +674,15 @@ class PagePool:
                 "kv_pages_live_window": sum(
                     p // ps - max(p - window + 1, 0) // ps + 1
                     for p in live)}
+
+    def live_bytes_by_kind(self, pages: Dict[str, int]) -> Dict[str, int]:
+        """:meth:`live_pages_by_kind`'s ``pages`` in bytes, each kind's
+        pages at its own row: the rows of the two kinds may differ, so
+        pages alone no longer say what a layer's kernel reads."""
+        return {"kv_bytes_live_full": pages["kv_pages_live_full"]
+                * self._layer_page_bytes[False],
+                "kv_bytes_live_window": pages["kv_pages_live_window"]
+                * self._layer_page_bytes[True]}
 
 
 class PrefixEntry:

@@ -231,6 +231,32 @@ def _gqa_case(reach, slots=48, heads=48, kv_heads=8, dim=128, page=16,
         reach=reach, impl="pallas", interpret=False), args)
 
 
+def _gqa_mimo_case(window):
+    """The grouped decode kernel at the shapes of the cell
+    ``mimo-v2.5.serve.closed-1k8k``: 128 slots, 64 query heads, keys of
+    192 and values of 128, pages of 16. ``window`` False: layer 1 of
+    the two full layers' pool (4 key/value heads, rows of 1,280) under
+    the page table of a 9,216-column bucket; True: layer 3 of the five
+    window layers' rings (8 key/value heads, rows of 2,560, 9 pages a
+    slot) with a sink a head."""
+    slots, heads, dk, dv, page = 128, 64, 192, 128, 16
+    kv_heads = 8 if window else 4
+    row = kv_heads * (dk + dv)
+    if window:
+        entries, layers, layer, pages = 9, 5, 3, slots * 9
+    else:
+        entries, layers, layer = 9216 // page, 2, 1
+        pages = slots * entries + 1
+    args = (_sds((slots, heads, dk), BF16),
+            _sds((layers, pages, page, row), BF16),
+            _sds((slots, entries), jnp.int32), _sds((slots,), jnp.int32),
+            _sds((heads,), jnp.float32))
+    return (lambda q, c, t, p, s: da.gqa_paged_decode_attention(
+        q, c, t, p, layer=layer, kv_heads=kv_heads, scale=dk ** -0.5,
+        reach=128 if window else None, sinks=s if window else None,
+        impl="pallas", interpret=False), args)
+
+
 _CASES = _DECODE + [
     # the cell trinity-large-preview.serve.closed-8k1k: one body, two
     # names (the full layer's page table; a sliding layer's ring)
@@ -238,6 +264,12 @@ _CASES = _DECODE + [
                  id="gqa-paged-decode-full-48slots-w9216"),
     pytest.param(lambda: _gqa_case(4096),
                  id="gqa-paged-decode-window-48slots-ring257"),
+    # the cell mimo-v2.5.serve.closed-1k8k: keys wider than values, a
+    # different head count a kind, the window's sink
+    pytest.param(lambda: _gqa_mimo_case(False),
+                 id="gqa-paged-decode-full-dk192-dv128-128slots-w9216"),
+    pytest.param(lambda: _gqa_mimo_case(True),
+                 id="gqa-paged-decode-window-sink-128slots-ring9"),
     pytest.param(_mla_case, id="mla-paged-decode-xing4-64slots-w8192"),
     # the cell openpangu-ultra-moe-718b.serve.closed-2k1k: 128 slots,
     # 128 heads (242 operations a byte of cache), window 4,096
