@@ -449,9 +449,10 @@ class GPTServing:
                         cs_cache=cs_cache)
 
     def chunk(self, model, params, k_pref, v_pref, tokens, start,
-              cs=_no_cs, cs_cache=None):
+              cs=_no_cs, cs_cache=None, attn_impl="xla"):
         """One ``[1, chunk]`` slice of an incremental prefill at
-        ``[start, start + chunk)`` against the standalone caches."""
+        ``[start, start + chunk)`` against the standalone caches (the
+        attention plain XLA whatever the engine's ``attn_impl``)."""
         dtype = model.dtype
         eps = getattr(model, "ln_eps", _LN_EPS)
         moe_k = getattr(model, "moe_top_k", 1)

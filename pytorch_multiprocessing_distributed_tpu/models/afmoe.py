@@ -56,8 +56,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas.chunk_attention import gqa_chunk_attention
 from ..ops.pallas.decode_attention import gqa_paged_decode_attention
-from .latent import _dot, _ffn, _rms, _rotary, _softmax_rows
+from .latent import _dot, _ffn, _rms, _rotary
 from .pangu_ultra_moe import PanguUltraMoEServing
 from .registry import register
 
@@ -91,7 +92,8 @@ class Afmoe:
     experts_held: Optional[int] = None
     expert_offset: int = 0
     dtype: Any = jnp.float32
-    # prefill attention is plain XLA; the CLIs pass and print the field
+    # the engine's decode_attn picks the chunk's and decode's attention;
+    # the CLIs pass and print the field
     attn_impl: str = "xla"
 
     # ---- derived sizes ------------------------------------------------
@@ -210,28 +212,6 @@ def _qkvg(h, p, positions, rotate, model):
             jax.nn.sigmoid(_dot(h, p["wg"], dt)))
 
 
-def _in_reach(cache, positions, start, reach):
-    """The rows of one layer's standalone cache ``[W, row]`` that a
-    chunk at ``positions`` (from ``start``) may attend, and its mask
-    ``[T, span]``: every column under the causal mask where ``reach``
-    is None (a full layer); else the ``T + reach`` columns from ``start
-    - reach`` SLICED out (where the cache is wider) and ``i - j <
-    reach`` masked inside."""
-    t, w = positions.shape[0], cache.shape[0]
-    if reach is not None and t + reach < w:
-        span = t + reach
-        begin = jnp.clip(start - reach, 0, w - span)
-        rows = jax.lax.dynamic_slice_in_dim(cache, begin, span, axis=0)
-    else:
-        span, begin, rows = w, 0, cache
-    cols = begin + jnp.arange(span)
-    mask = cols[None, :] <= positions[:, None]              # [T, span]
-    if reach is not None:
-        mask = jnp.logical_and(
-            mask, positions[:, None] - cols[None, :] < reach)
-    return rows, mask
-
-
 def _write_row(pool, layer, table, positions, row, page_size, ring):
     """Each slot's new ``row`` into layer ``layer`` of the WHOLE pool,
     in place: at ``table[slot, pos // ps]`` under a page table, at the
@@ -243,43 +223,24 @@ def _write_row(pool, layer, table, positions, row, page_size, ring):
     return pool.at[layer, page_ids, positions % page_size].set(row)
 
 
-def _attn_prefill(h, p, cache, start, sliding, model):
+def _attn_prefill(h, p, cache, start, sliding, model, attn_impl="xla"):
     """Causal grouped attention of a chunk ``h [T, C]`` at absolute
     positions ``[start, start + T)`` against one layer's standalone
-    cache ``[W, 2 Hkv Dh]``, which already holds ``[0, start)``. A
-    full layer attends the whole cache under the causal mask; a
-    sliding layer SLICES the columns in its reach out of it (``T +
-    sliding_window`` of them, from ``start - sliding_window``) and
-    masks ``i - j < sliding_window`` inside. One key/value head at a
-    time with its group of query heads: K and V are never repeated.
-    Returns ``(out [T, C] float32, cache)``."""
-    dt = model.dtype
+    cache ``[W, 2 Hkv Dh]``, which already holds ``[0, start)``: writes
+    the chunk's rows, then attends every column under the causal mask
+    (a full layer) or the ``sliding_window`` columns up to each query
+    (a sliding layer) through :func:`...ops.pallas.chunk_attention.
+    gqa_chunk_attention` in the engine's ``attn_impl``. Returns ``(out
+    [T, C] float32, cache)``."""
     t = h.shape[0]
-    hq, hk, d = model.num_heads, model.num_kv_heads, model.head_dim
     positions = start + jnp.arange(t)
     q, row, gate = _qkvg(h, p, positions, sliding, model)
     cache = jax.lax.dynamic_update_slice(cache, row, (start, 0))
-    rows, mask = _in_reach(cache, positions, start,
-                           model.sliding_window if sliding else None)
-    span = rows.shape[0]
-    kv = rows.reshape(span, 2, hk, d)
-    scale = d ** -0.5
-
-    def one_group(args):
-        qg, kg, vg = args           # [T, g, Dh], [span, Dh], [span, Dh]
-        s = jnp.einsum("tgd,wd->gtw", qg, kg,
-                       preferred_element_type=jnp.float32) * scale
-        pr, total = _softmax_rows(jnp.where(mask[None], s, -jnp.inf))
-        out = jnp.einsum("gtw,wd->gtd", pr.astype(dt), vg,
-                         preferred_element_type=jnp.float32)
-        return out / total                                  # [g, T, Dh]
-
-    out = jax.lax.map(
-        one_group,
-        (jnp.moveaxis(q.reshape(t, hk, hq // hk, d), 1, 0),
-         jnp.moveaxis(kv[:, 0], 1, 0), jnp.moveaxis(kv[:, 1], 1, 0)))
-    out = jnp.moveaxis(out.reshape(hq, t, d), 0, 1).reshape(t, hq * d)
-    return _dot(out * gate, p["wo"], dt), cache
+    out = gqa_chunk_attention(
+        q, cache, start, kv_heads=model.num_kv_heads,
+        scale=model.head_dim ** -0.5,
+        reach=model.sliding_window if sliding else None, impl=attn_impl)
+    return _dot(out.reshape(t, -1) * gate, p["wo"], model.dtype), cache
 
 
 def _attn_decode(h, p, pool, layer, table, read_table, sliding, positions,
@@ -342,11 +303,12 @@ class AfmoeServing(PanguUltraMoEServing):
                  model.sliding_window))
 
     def chunk(self, model, params, pref_full, pref_sliding, tokens, start,
-              cs=None, cs_cache=None):
+              cs=None, cs_cache=None, attn_impl="xla"):
         """One chunk ``tokens [1, T]`` at positions ``[start, start +
         T)`` against the two standalone caches ``[n_full, 1, W, row]``
         and ``[n_sliding, 1, W, row]`` (both hold every column: the
-        window is cut when the prompt is spliced into the ring);
+        window is cut when the prompt is spliced into the ring), its
+        attention in ``attn_impl`` (the engine's, as in decode);
         returns ``(x [1, T, C], pref_full, pref_sliding)``."""
         x = self.embed(model, params, tokens[0])
         caches = {False: [], True: []}
@@ -358,7 +320,7 @@ class AfmoeServing(PanguUltraMoEServing):
                 pref = pref_sliding if sliding else pref_full
                 out, cache = self.attn_prefill(
                     h, layer["attn"], pref[len(caches[sliding]), 0],
-                    start, sliding, model)
+                    start, sliding, model, attn_impl)
                 caches[sliding].append(cache)
                 return out, None
 

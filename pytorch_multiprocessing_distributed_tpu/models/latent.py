@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.moe import dropless_experts, route_sigmoid_topk
+from ..ops.pallas.chunk_attention import softmax_rows
 from ..ops.pallas.decode_attention import mla_paged_decode_attention
 
 _LANES = 128
@@ -238,17 +239,6 @@ def _qkv(h, p, positions, model):
             row.astype(dt))
 
 
-def _softmax_rows(s):
-    """``exp(s - max)`` and its row sums, float32. The barrier keeps
-    the row maximum out of the fusion that exponentiates: fused into
-    it, the chip's compiler recomputes the maximum of a whole
-    8,192-wide row for every tile of the output (23 ms for a [8, 1024,
-    8192] block in place of 1: PERF.md section 6, PR 29)."""
-    m = jax.lax.optimization_barrier(jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m)
-    return p, jnp.sum(p, axis=-1, keepdims=True)
-
-
 def _attn_prefill(h, p, cache, start, model):
     """Decompressed causal attention of a chunk ``h [T, C]`` at
     absolute positions ``[start, start + T)`` against one layer's
@@ -282,7 +272,7 @@ def _attn_prefill(h, p, cache, start, model):
         qg, kg, vg = args           # [T, g, .], [W, g, .], [W, g, .]
         s = jnp.einsum("tgd,wgd->gtw", qg, kg,
                        preferred_element_type=jnp.float32) * scale
-        pr, total = _softmax_rows(jnp.where(mask[None], s, -jnp.inf))
+        pr, total = softmax_rows(jnp.where(mask[None], s, -jnp.inf))
         out = jnp.einsum("gtw,wgd->gtd", pr.astype(dt), vg,
                          preferred_element_type=jnp.float32)
         return (out / total).astype(dt)                     # [g, T, v]
@@ -408,10 +398,11 @@ class LatentServing:
         return (model.n_moe_layers, model.n_held + 2)
 
     def chunk(self, model, params, pref, unused, tokens, start,
-              cs=None, cs_cache=None):
+              cs=None, cs_cache=None, attn_impl="xla"):
         """One chunk ``tokens [1, T]`` at positions ``[start, start +
         T)`` against the standalone cache ``[L, 1, W, R + Rw]``;
-        returns ``(x [1, T, ...], pref, unused)``."""
+        returns ``(x [1, T, ...], pref, unused)``. The decompressed
+        attention is plain XLA whatever the engine's ``attn_impl``."""
         x = self.embed(model, params, tokens[0])
         caches = []
         for i in range(model.num_layers):

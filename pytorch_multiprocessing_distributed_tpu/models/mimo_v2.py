@@ -52,9 +52,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas.chunk_attention import gqa_chunk_attention
 from ..ops.pallas.decode_attention import gqa_paged_decode_attention
-from .afmoe import AfmoeServing, _in_reach, _write_row
-from .latent import _dot, _rms, _rotate, _softmax_rows
+from .afmoe import AfmoeServing, _write_row
+from .latent import _dot, _rms, _rotate
 from .pangu_ultra_moe import PanguUltraMoEServing
 from .registry import register
 
@@ -99,7 +100,8 @@ class MiMoV2:
     experts_held: Optional[int] = None
     expert_offset: int = 0
     dtype: Any = jnp.float32
-    # prefill attention is plain XLA; the CLIs pass and print the field
+    # the engine's decode_attn picks the chunk's and decode's attention;
+    # the CLIs pass and print the field
     attn_impl: str = "xla"
 
     def __post_init__(self):
@@ -247,53 +249,24 @@ def _qkv(h, p, positions, sliding, model):
     return q.astype(dt), row.astype(dt)
 
 
-def _attn_prefill(h, p, cache, start, sliding, model):
+def _attn_prefill(h, p, cache, start, sliding, model, attn_impl="xla"):
     """Causal grouped attention of a chunk ``h [T, C]`` at absolute
     positions ``[start, start + T)`` against one layer's standalone
-    cache ``[W, Hkv (Dk + Dv)]``, which already holds ``[0, start)``: a
-    window layer slices the ``T + window`` columns in its reach
-    (``models/afmoe.py::_in_reach``) and adds its heads' sink column to
-    the softmax. One key/value head at a time with its group of query
-    heads. Returns ``(out [T, C] float32, cache)``."""
-    dt = model.dtype
+    cache ``[W, Hkv (Dk + Dv)]``, which already holds ``[0, start)``:
+    :func:`...ops.pallas.chunk_attention.gqa_chunk_attention` at ``Dk
+    != Dv`` in the engine's ``attn_impl``, a window layer's ``window``
+    columns up to each query and its heads' sink logits. Returns
+    ``(out [T, C] float32, cache)``."""
     t = h.shape[0]
-    hq, hk = model.num_heads, model.kv_heads(sliding)
-    dk, dv, group = model.head_dim, model.v_head_dim, hq // hk
     positions = start + jnp.arange(t)
     q, row = _qkv(h, p, positions, sliding, model)
     cache = jax.lax.dynamic_update_slice(cache, row, (start, 0))
-    rows, mask = _in_reach(cache, positions, start,
-                           model.sliding_window if sliding else None)
-    span = rows.shape[0]
-    keys = rows[:, :hk * dk].reshape(span, hk, dk)
-    values = rows[:, hk * dk:].reshape(span, hk, dv)
-    sinks = p.get("sinks")
-    scale = dk ** -0.5
-
-    def one_group(args):
-        qg, kg, vg, sg = args   # [T, g, Dk], [span, Dk], [span, Dv], [g]
-        s = jnp.einsum("tgd,wd->gtw", qg, kg,
-                       preferred_element_type=jnp.float32) * scale
-        s = jnp.where(mask[None], s, -jnp.inf)
-        if sinks is not None:
-            s = jnp.concatenate(
-                [s, jnp.broadcast_to(sg[:, None, None], (group, t, 1))],
-                axis=-1)
-        pr, total = _softmax_rows(s)
-        if sinks is not None:
-            pr = pr[..., :-1]
-        out = jnp.einsum("gtw,wd->gtd", pr.astype(dt), vg,
-                         preferred_element_type=jnp.float32)
-        return out / total                                  # [g, T, Dv]
-
-    out = jax.lax.map(
-        one_group,
-        (jnp.moveaxis(q.reshape(t, hk, group, dk), 1, 0),
-         jnp.moveaxis(keys, 1, 0), jnp.moveaxis(values, 1, 0),
-         (jnp.zeros((hk, group), jnp.float32) if sinks is None
-          else sinks.astype(jnp.float32).reshape(hk, group))))
-    out = jnp.moveaxis(out.reshape(hq, t, dv), 0, 1).reshape(t, hq * dv)
-    return _dot(out, p["wo"], dt), cache
+    out = gqa_chunk_attention(
+        q, cache, start, kv_heads=model.kv_heads(sliding),
+        scale=model.head_dim ** -0.5,
+        reach=model.sliding_window if sliding else None,
+        sinks=p.get("sinks"), impl=attn_impl)
+    return _dot(out.reshape(t, -1), p["wo"], model.dtype), cache
 
 
 def _attn_decode(h, p, pool, layer, table, read_table, sliding, positions,

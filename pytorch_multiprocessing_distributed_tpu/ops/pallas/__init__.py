@@ -11,6 +11,9 @@ kernels cover the spots where hand scheduling buys something XLA can't:
   one-query-per-slot cached attention step, K/V streamed once through
   VMEM with an online softmax and a per-slot position gate (cost tracks
   each slot's true length, not the window).
+- :mod:`.chunk_attention` — a prompt chunk's grouped-query attention
+  against one layer's cache (causal, an optional window and sink), K/V
+  streamed once through VMEM over only the column blocks in reach.
 - :mod:`.fused_update` — single-pass SGD(momentum, nesterov, wd) update:
   one read of (param, grad, buf), one write of (param, buf), aliased
   in-place in HBM.

@@ -1008,16 +1008,19 @@ class ServingEngine:
         prefill cache and attends each token to its causal prefix
         (``inference.generate._block_chunk_prefill``). ONE static shape
         per (chunk, cache-width) pair regardless of prompt length or
-        chunk index — ``start`` is traced."""
+        chunk index — ``start`` is traced. A family with a chunk
+        attention kernel runs it in the decode step's implementation."""
         model, family = self.model, self._family
         cs = _make_cs(self.mesh)
+        attn_impl = self._attn_impl
 
         def cs_cache(c):
             return cs(c, None, None, None, "model", None)
 
         def chunk(params, k_pref, v_pref, tokens, start):
             return family.chunk(model, params, k_pref, v_pref, tokens,
-                                start, cs=cs, cs_cache=cs_cache)
+                                start, cs=cs, cs_cache=cs_cache,
+                                attn_impl=attn_impl)
 
         return chunk
 

@@ -39,6 +39,8 @@ from pytorch_multiprocessing_distributed_tpu.inference.generate import (
 from pytorch_multiprocessing_distributed_tpu.models import afmoe, latent
 from pytorch_multiprocessing_distributed_tpu.ops.moe import (
     dropless_experts, route_sigmoid_topk)
+from pytorch_multiprocessing_distributed_tpu.ops.pallas import (
+    chunk_attention)
 from pytorch_multiprocessing_distributed_tpu.runtime.scope import scoped
 from pytorch_multiprocessing_distributed_tpu.serving import (
     PagePool, PagePoolExhausted, ServingEngine, init_params)
@@ -111,9 +113,9 @@ def _prefill_logits(model, params, tokens):
     return np.asarray(family.logits(model, params, x)[0])
 
 
-def _chunked_logits(model, params, tokens, chunk=16):
+def _chunked_logits(model, params, tokens, chunk=16, impl="xla"):
     """The chunk program over a standalone cache as wide as the
-    prompt, one chunk after the other."""
+    prompt, one chunk after the other, its attention in ``impl``."""
     family = model.serving_family
     full, sliding = (jnp.zeros(shape, jnp.float32)
                      for shape in pref_cache_shapes(model, len(tokens)))
@@ -122,7 +124,7 @@ def _chunked_logits(model, params, tokens, chunk=16):
         x, full, sliding = family.chunk(
             model, params, full, sliding,
             jnp.asarray(tokens[start:start + chunk])[None],
-            jnp.int32(start))
+            jnp.int32(start), attn_impl=impl)
         out.append(family.logits(model, params, x)[0])
     return np.concatenate(out)
 
@@ -223,9 +225,10 @@ def test_registry_and_published_sizes():
     assert [p[3:] for p in cache_pools(gpt)] == [(gpt.num_layers, None)] * 2
 
 
-@pytest.mark.parametrize("form", ["whole-prompt", "chunked", "decode",
+@pytest.mark.parametrize("form", ["whole-prompt", "chunked",
+                                  "chunked-kernel", "decode",
                                   "decode-kernel"])
-def test_program_equals_the_reference(tiny, ref_logits, form):
+def test_program_equals_the_reference(tiny, ref_logits, monkeypatch, form):
     """Attention in its three forms against the reference's full score
     matrix under a band mask: 96 tokens are twelve windows; the decode
     runs from position 8 to 47 through the page table AND the ring,
@@ -234,9 +237,18 @@ def test_program_equals_the_reference(tiny, ref_logits, form):
     if form == "whole-prompt":
         tokens = _tokens(96)
         got, want = _prefill_logits(model, params, tokens), ref_logits(tokens)
-    elif form == "chunked":
+    elif form.startswith("chunked"):
         tokens = _tokens(96, seed=1)
-        got, want = _chunked_logits(model, params, tokens), ref_logits(tokens)
+        impl = "xla"
+        if form == "chunked-kernel":
+            # blocks of 8 queries and 16 columns: the kernel skips and
+            # masks column blocks in every layer of each chunk, a
+            # matmul a query head as at trinity's heads of 128
+            monkeypatch.setattr(chunk_attention, "_chunk_blocks",
+                                lambda *_: (8, 16, 1))
+            impl = "pallas"
+        got = _chunked_logits(model, params, tokens, impl=impl)
+        want = ref_logits(tokens)
     else:
         tokens = _tokens(48, seed=2)
         got = _decode_logits(model, params, tokens, impl=(

@@ -36,6 +36,8 @@ from perf.trace_reduce import MOSAIC, parse_instruction
 # the module, not the same-named function ops.pallas re-exports
 da = importlib.import_module(
     "pytorch_multiprocessing_distributed_tpu.ops.pallas.decode_attention")
+ca = importlib.import_module(
+    "pytorch_multiprocessing_distributed_tpu.ops.pallas.chunk_attention")
 
 B, S, H, D = 8, 1024, 12, 64      # gpt_small serving: 8 slots, window 1024
 K1 = 5                             # --draft_k 4 verify block
@@ -83,6 +85,7 @@ KERNEL_NAMES = {
     "paged_verify_decode_attention", "fused_sgd_update", "ring_all_reduce",
     "mla_paged_decode_attention",
     "gqa_paged_decode_attention_full", "gqa_paged_decode_attention_window",
+    "gqa_chunk_attention",
 }
 
 
@@ -257,7 +260,34 @@ def _gqa_mimo_case(window):
         impl="pallas", interpret=False), args)
 
 
+def _chunk_case(heads, kv_heads, dk, dv, width, reach, sink, t=1024):
+    """The grouped chunk-attention kernel at a cell's chunk: ``t``
+    queries of ``heads`` heads against one layer's standalone cache of
+    ``width`` columns (rows of ``kv_heads * (dk + dv)`` values), the
+    chunk's start traced."""
+    args = [_sds((t, heads, dk), BF16),
+            _sds((width, kv_heads * (dk + dv)), BF16),
+            _sds((), jnp.int32)] + ([_sds((heads,), jnp.float32)] if sink
+                                    else [])
+    return (lambda q, c, s, *sinks: ca.gqa_chunk_attention(
+        q, c, s, kv_heads=kv_heads, scale=dk ** -0.5, reach=reach,
+        sinks=sinks[0] if sinks else None, impl="pallas",
+        interpret=False), args)
+
+
 _CASES = _DECODE + [
+    # the chunk of trinity-large-preview.serve.closed-8k1k: 1,024
+    # queries, a bucket of 8,192 columns, 48 heads on 8 of 128; and of
+    # mimo-v2.5.serve.closed-1k8k: a bucket of 1,024, 64 heads on 4
+    # (full) and on 8 (window of 128, a sink), keys 192, values 128
+    pytest.param(lambda: _chunk_case(48, 8, 128, 128, 8192, None, False),
+                 id="gqa-chunk-full-t1024-w8192-48on8-d128"),
+    pytest.param(lambda: _chunk_case(48, 8, 128, 128, 8192, 4096, False),
+                 id="gqa-chunk-window4096-t1024-w8192-48on8-d128"),
+    pytest.param(lambda: _chunk_case(64, 4, 192, 128, 1024, None, False),
+                 id="gqa-chunk-full-t1024-w1024-64on4-dk192-dv128"),
+    pytest.param(lambda: _chunk_case(64, 8, 192, 128, 1024, 128, True),
+                 id="gqa-chunk-window128-sink-t1024-w1024-64on8-dk192-dv128"),
     # the cell trinity-large-preview.serve.closed-8k1k: one body, two
     # names (the full layer's page table; a sliding layer's ring)
     pytest.param(lambda: _gqa_case(None),

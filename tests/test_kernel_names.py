@@ -7,7 +7,7 @@ reads it out of the compiled text). The benchmark's kernel metrics
 (``perf/layer_metrics/flash_*_ms.train.json``,
 ``paged_decode_attn_ms.serve.json``) match on these names, so the
 ledger can compare a kernel's time across PRs that rewrite what is
-around it. Here each of the ten call sites is traced (nothing runs)
+around it. Here each of the eleven call sites is traced (nothing runs)
 and the name is read out of the jaxpr.
 """
 
@@ -29,6 +29,8 @@ from pytorch_multiprocessing_distributed_tpu.ops.pallas import (
 # the module, not the same-named function ops.pallas re-exports
 da = importlib.import_module(
     "pytorch_multiprocessing_distributed_tpu.ops.pallas.decode_attention")
+ca = importlib.import_module(
+    "pytorch_multiprocessing_distributed_tpu.ops.pallas.chunk_attention")
 
 B, S, H, D, K1, PAGE = 2, 64, 2, 32, 3, 16
 
@@ -80,6 +82,16 @@ def _mla():
         interpret=True), args)
 
 
+def _chunk():
+    """The grouped chunk kernel: a chunk of S queries of 2H heads on H
+    against one layer's cache [2S, H * 2D], a window and a sink."""
+    args = (_sds((S, 2 * H, D)), _sds((2 * S, H * 2 * D)),
+            _sds((), jnp.int32), _sds((2 * H,)))
+    return (lambda q, c, s, sinks: ca.gqa_chunk_attention(
+        q, c, s, kv_heads=H, scale=0.1, reach=PAGE, sinks=sinks,
+        impl="pallas", interpret=True), args)
+
+
 def _sgd():
     leaves = {"w": _sds((24, 40)), "b": _sds((40,))}
     return (lambda p, g, m: fused_sgd_apply(p, g, m, 0.1, interpret=True),
@@ -127,6 +139,7 @@ _SITES = [
      "paged_verify_decode_attention"),
     ("decode_attention.py latent paged", _mla,
      "mla_paged_decode_attention"),
+    ("chunk_attention.py grouped chunk", _chunk, "gqa_chunk_attention"),
     ("fused_update.py", _sgd, "fused_sgd_update"),
     ("ring_allreduce.py", _ring, "ring_all_reduce"),
     # the int8 variants go through the same call sites
@@ -156,14 +169,15 @@ def test_kernel_metrics_match_the_names_the_kernels_carry():
     """The metric files that select by regex over ``mosaic:<name>``
     (PR 25's three, PR 27's and PR 29's rooflines, PR 29's latent
     kernel): each must pick out exactly its kernels."""
-    assert len(KERNEL_NAMES) == 9
+    assert len(KERNEL_NAMES) == 10
     labels = ["mosaic:" + name for name in KERNEL_NAMES]
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     picked = {}
     for metric in ("flash_fwd_ms.train", "flash_bwd_ms.train",
                    "paged_decode_attn_ms.serve", "mla_decode_attn_ms.serve",
                    "mla_decode_attn_roofline.serve",
-                   "paged_decode_attn_roofline.serve"):
+                   "paged_decode_attn_roofline.serve",
+                   "chunk_attn_ms.serve"):
         with open(os.path.join(root, "perf", "layer_metrics",
                                metric + ".json")) as fh:
             rx = re.compile(json.load(fh)["args"]["match"])
@@ -178,4 +192,5 @@ def test_kernel_metrics_match_the_names_the_kernels_carry():
         "mla_decode_attn_ms.serve": {"mosaic:mla_paged_decode_attention"},
         "mla_decode_attn_roofline.serve": {
             "mosaic:mla_paged_decode_attention"},
+        "chunk_attn_ms.serve": {"mosaic:gqa_chunk_attention"},
     }

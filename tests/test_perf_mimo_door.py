@@ -50,8 +50,9 @@ def test_the_mimo_cell_holds_its_parameters():
     reported = _reported(serve)
     trinity = harness.load_cell("trinity-large-preview.serve.closed-8k1k")
     # every metric of the cell it shares the grouped kernel, the two
-    # pools and the share's experts with, and the window's roofline
-    assert reported == _reported(trinity) | {
+    # pools and the share's experts with, and the window's roofline;
+    # not the chunk kernel's time, which its tail draws about once
+    assert reported == (_reported(trinity) - {"chunk_attn_ms.serve"}) | {
         "window_decode_attn_roofline.serve"}
     assert "paged_decode_attn_ms.serve" not in reported
 
