@@ -34,6 +34,7 @@ from .pangu_ultra_moe import (PanguUltraMoE, PanguUltraMoE_718B,
                               PanguUltraMoE_Tiny)
 from .afmoe import Afmoe, Afmoe_Tiny, Trinity_Large_Preview
 from .mimo_v2 import MiMoV2, MiMo_V2_5, MiMo_V2_Tiny
+from .lfm2_moe import Lfm2Moe, LFM2_8B_A1B, Lfm2Moe_Tiny
 
 __all__ = [
     "BasicBlock",
@@ -55,5 +56,6 @@ __all__ = [
     "PanguUltraMoE", "PanguUltraMoE_718B", "PanguUltraMoE_Tiny",
     "Afmoe", "Afmoe_Tiny", "Trinity_Large_Preview",
     "MiMoV2", "MiMo_V2_5", "MiMo_V2_Tiny",
+    "Lfm2Moe", "LFM2_8B_A1B", "Lfm2Moe_Tiny",
 ]
 

@@ -283,6 +283,16 @@ class AfmoeServing(PanguUltraMoEServing):
     # attention over the same two pools gives its own
     attn_prefill = staticmethod(_attn_prefill)
     attn_decode = staticmethod(_attn_decode)
+    # the kind of layer whose rows the second pool holds (a ring a
+    # slot), and the key of its mixer's weights in the layer: a family
+    # with another mixer over the ring names its own
+    ring_kind = "sliding_attention"
+    ring_mixer = "attn"
+
+    def mixer(self, layer, ring):
+        """The weights of a layer's first sublayer: its attention, or
+        on a layer of ``ring_kind`` the family's ring mixer."""
+        return layer[self.ring_mixer if ring else "attn"]
 
     def embed(self, model, params, tokens):
         """``tokens [T]`` -> ``[T, C]`` float32, times ``sqrt(C)``
@@ -314,13 +324,14 @@ class AfmoeServing(PanguUltraMoEServing):
         caches = {False: [], True: []}
         for i, kind in enumerate(model.layer_types):
             layer = params[f"layer_{i}"]
-            sliding = kind == "sliding_attention"
+            sliding = kind == self.ring_kind
 
             def attention(h, layer=layer, sliding=sliding):
                 pref = pref_sliding if sliding else pref_full
                 out, cache = self.attn_prefill(
-                    h, layer["attn"], pref[len(caches[sliding]), 0],
-                    start, sliding, model, attn_impl)
+                    h, self.mixer(layer, sliding),
+                    pref[len(caches[sliding]), 0], start, sliding, model,
+                    attn_impl)
                 caches[sliding].append(cache)
                 return out, None
 
@@ -373,11 +384,12 @@ class AfmoeServing(PanguUltraMoEServing):
         loads = []
         for i, kind in enumerate(model.layer_types):
             layer = params[f"layer_{i}"]
-            sliding = kind == "sliding_attention"
+            sliding = kind == self.ring_kind
 
             def attention(h, layer=layer, sliding=sliding):
                 out, pools[sliding] = self.attn_decode(
-                    h, layer["attn"], pools[sliding], index[sliding],
+                    h, self.mixer(layer, sliding), pools[sliding],
+                    index[sliding],
                     ring_table if sliding else page_table,
                     ring_table if sliding else bucket_table, sliding,
                     positions, ps, attn_impl, model)

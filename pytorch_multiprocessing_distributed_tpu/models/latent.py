@@ -344,7 +344,7 @@ def _ffn(h32, layer, model):
     moe = layer["moe"]
     chosen, weights = route_sigmoid_topk(
         h32, moe["router"], moe.get("e_bias"), model.moe_top_k,
-        model.routed_scale)
+        model.routed_scale, getattr(model, "route_eps", 1e-20))
     y, counts, elsewhere, given = dropless_experts(
         h32.astype(dt), chosen, weights, moe["w_gate"], moe["w_up"],
         moe["w_down"], n_experts=model.n_experts,

@@ -255,16 +255,17 @@ def audit_programs():
 # ------------------------------------------------------------ dropless
 
 def route_sigmoid_topk(x32, router, e_bias, top_k: int,
-                       routed_scale: float = 1.0):
+                       routed_scale: float = 1.0, eps: float = 1e-20):
     """Sigmoid-scored top-k routing with a selection bias (the
     ``noaux_tc`` method with one group): ``s = sigmoid(x Wg)`` in
     float32 at the highest matmul precision (the top-k of near-equal
     scores must not turn on a bf16 pass of the MXU); the ``top_k`` of
     ``s + e_bias`` (of ``s`` alone where ``e_bias`` is None) are
     CHOSEN, the weights come from ``s`` alone, normalised over the
-    chosen and scaled. The router scores ALL experts of the model,
-    whichever of them this chip holds. ``x32 [T, D]`` -> ``(experts
-    [T, k] int32, weights [T, k] f32)``."""
+    chosen (their sum plus ``eps``, the family's own) and scaled. The
+    router scores ALL experts of the model, whichever of them this chip
+    holds. ``x32 [T, D]`` -> ``(experts [T, k] int32, weights [T, k]
+    f32)``."""
     scores = jax.nn.sigmoid(jnp.dot(
         x32.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
@@ -272,7 +273,7 @@ def route_sigmoid_topk(x32, router, e_bias, top_k: int,
               else scores + e_bias.astype(jnp.float32))
     _, chosen = jax.lax.top_k(biased, top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    weights = (picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    weights = (picked / (jnp.sum(picked, axis=-1, keepdims=True) + eps)
                * routed_scale)
     return chosen.astype(jnp.int32), weights
 

@@ -132,6 +132,20 @@ from ..runtime import scope as graftscope
 PAGE_SPEC = P(None, None, None, "model")
 
 
+# the counters of the attention families' pools, which metrics and
+# traces read by these names; any other pool a family declares holds
+# state that is not a key or a value, and is counted as such
+_KV_LIVE_COUNTERS = {"full": "kv_{}_live_full", "sliding": "kv_{}_live_window"}
+
+
+def live_counter(pool_name: str) -> str:
+    """The name pattern (``{}``: ``pages`` or ``bytes``) of a declared
+    pool's live counters: ``kv_{}_live_full`` / ``kv_{}_live_window``
+    for the attention pools, ``state_{}_live_<name>`` for any other
+    (the LFM2 family's ``conv`` ring: ``state_bytes_live_conv``)."""
+    return _KV_LIVE_COUNTERS.get(pool_name, f"state_{{}}_live_{pool_name}")
+
+
 def _ring_window(model) -> Optional[int]:
     """The columns a slot's ring has to hold: the widest window among
     the family's cache rows that hold a window only; None where every
@@ -228,6 +242,9 @@ class PagePool:
             columns is not None: page_size * int(np.prod(row, dtype=int))
             * jnp.dtype(dtype).itemsize
             for _, row, dtype, _, columns in cache_pools(model)}
+        # the counters each pool's live pages and bytes go under
+        self._live_names = {columns is not None: live_counter(name)
+                            for name, _, _, _, columns in cache_pools(model)}
         self.k_pages, self.v_pages = (
             self._cache_sharded(self._empty_pages(
                 row, layers, self.num_pages if columns is None
@@ -663,15 +680,17 @@ class PagePool:
                                       self._active_host) if live)
 
     def live_pages_by_kind(self) -> Dict[str, int]:
-        """``live_pages`` for two kinds of layer: what ONE full
-        layer's kernel reads (every page up to the position) and what
-        one sliding layer's reads (the pages that hold a column in the
-        window's reach), off the host mirror."""
+        """``live_pages`` for two kinds of layer, under each pool's
+        counter name (:func:`live_counter`): what ONE layer of the paged
+        pool reads (every page up to the position) and what one layer of
+        the ring reads (the pages that hold a column in the window's
+        reach), off the host mirror."""
         ps, window = self.page_size, self.ring_window
         live = [p for p, on in zip(self._positions_host,
                                    self._active_host) if on]
-        return {"kv_pages_live_full": sum(p // ps + 1 for p in live),
-                "kv_pages_live_window": sum(
+        names = self._live_names
+        return {names[False].format("pages"): sum(p // ps + 1 for p in live),
+                names[True].format("pages"): sum(
                     p // ps - max(p - window + 1, 0) // ps + 1
                     for p in live)}
 
@@ -679,10 +698,9 @@ class PagePool:
         """:meth:`live_pages_by_kind`'s ``pages`` in bytes, each kind's
         pages at its own row: the rows of the two kinds may differ, so
         pages alone no longer say what a layer's kernel reads."""
-        return {"kv_bytes_live_full": pages["kv_pages_live_full"]
-                * self._layer_page_bytes[False],
-                "kv_bytes_live_window": pages["kv_pages_live_window"]
-                * self._layer_page_bytes[True]}
+        return {name.format("bytes"): pages[name.format("pages")]
+                * self._layer_page_bytes[ring]
+                for ring, name in self._live_names.items()}
 
 
 class PrefixEntry:

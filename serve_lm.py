@@ -71,7 +71,8 @@ parser.add_argument('--model', default='gpt_tiny', type=str,
                          'xing4_tiny | xing4_29b_a4b | '
                          'pangu_ultra_moe_tiny | pangu_ultra_moe_718b | '
                          'afmoe_tiny | trinity_large_preview | '
-                         'mimo_v2_tiny | mimo_v2_5')
+                         'mimo_v2_tiny | mimo_v2_5 | '
+                         'lfm2_moe_tiny | lfm2_8b_a1b')
 parser.add_argument('--model_kwargs', default='', type=str,
                     help='JSON object of keywords for the registry '
                          'constructor, e.g. the depth one serving stage '

@@ -38,6 +38,8 @@ da = importlib.import_module(
     "pytorch_multiprocessing_distributed_tpu.ops.pallas.decode_attention")
 ca = importlib.import_module(
     "pytorch_multiprocessing_distributed_tpu.ops.pallas.chunk_attention")
+sc = importlib.import_module(
+    "pytorch_multiprocessing_distributed_tpu.ops.pallas.short_conv")
 
 B, S, H, D = 8, 1024, 12, 64      # gpt_small serving: 8 slots, window 1024
 K1 = 5                             # --draft_k 4 verify block
@@ -85,7 +87,7 @@ KERNEL_NAMES = {
     "paged_verify_decode_attention", "fused_sgd_update", "ring_all_reduce",
     "mla_paged_decode_attention",
     "gqa_paged_decode_attention_full", "gqa_paged_decode_attention_window",
-    "gqa_chunk_attention",
+    "gqa_chunk_attention", "short_conv",
 }
 
 
@@ -275,6 +277,20 @@ def _chunk_case(heads, kv_heads, dk, dv, width, reach, sink, t=1024):
         interpret=False), args)
 
 
+def _short_conv_case(slots=128, width=2048, page=16, layers=9):
+    """The conv's decode kernel at the shapes of the cell
+    ``lfm2-8b-a1b.serve.closed-4k1k``: 128 slots, projections of 3 x
+    2,048, layer 4 of the nine conv layers' rings of 2 pages of 16 a
+    slot (the layer an operand)."""
+    args = (_sds((slots, 3 * width), BF16), _sds((3, width), BF16),
+            _sds((layers, slots * 2, page, width), BF16),
+            _sds((slots, 2), jnp.int32), _sds((slots,), jnp.int32),
+            _sds((), jnp.int32))
+    return (lambda p, t, pool, table, pos, layer: sc.short_conv(
+        p, t, pool, table, pos, layer=layer, impl="pallas",
+        interpret=False), args)
+
+
 _CASES = _DECODE + [
     # the chunk of trinity-large-preview.serve.closed-8k1k: 1,024
     # queries, a bucket of 8,192 columns, 48 heads on 8 of 128; and of
@@ -300,6 +316,15 @@ _CASES = _DECODE + [
                  id="gqa-paged-decode-full-dk192-dv128-128slots-w9216"),
     pytest.param(lambda: _gqa_mimo_case(True),
                  id="gqa-paged-decode-window-sink-128slots-ring9"),
+    # the cell lfm2-8b-a1b.serve.closed-4k1k: heads of 64 (32 on 8),
+    # the full layers' pages under a 5,120-column table; the chunk of a
+    # 4,096-token prompt; the conv's ring, written in place
+    pytest.param(lambda: _gqa_case(None, slots=128, heads=32, dim=64,
+                                   s_max=5120),
+                 id="gqa-paged-decode-full-128slots-32on8-d64-w5120"),
+    pytest.param(lambda: _chunk_case(32, 8, 64, 64, 4096, None, False),
+                 id="gqa-chunk-full-t1024-w4096-32on8-d64"),
+    pytest.param(_short_conv_case, id="short-conv-decode-128slots-c2048"),
     pytest.param(_mla_case, id="mla-paged-decode-xing4-64slots-w8192"),
     # the cell openpangu-ultra-moe-718b.serve.closed-2k1k: 128 slots,
     # 128 heads (242 operations a byte of cache), window 4,096
@@ -497,6 +522,68 @@ def test_gpt_decode_and_insert_programs_write_the_pools_in_place(topo):
         mem = program.memory_analysis()
         # temporaries far below one copy of the pools: written in place
         assert mem.temp_size_in_bytes < pools // 4, mem
+        assert mem.alias_size_in_bytes >= pools
+
+
+def test_lfm2_decode_and_insert_programs_write_both_pools_in_place(topo):
+    """The decode and insert programs of the cell ``lfm2-8b-a1b.serve.
+    closed-4k1k`` (published widths, 128 slots, window 5,120, pages of
+    16, bfloat16; depth cut to the first three layers, two conv and one
+    full, to stay in tier-1), through ``ServingEngine``: the conv kernel
+    runs once a conv layer and the grouped kernel once a full layer
+    inside the decode program, and both programs write the donated full
+    pool and conv ring in place."""
+    from perf.rehearse import as_chip
+    from pytorch_multiprocessing_distributed_tpu import models
+    from pytorch_multiprocessing_distributed_tpu.inference.generate import (
+        pref_cache_shapes)
+    from pytorch_multiprocessing_distributed_tpu.serving import ServingEngine
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    model = models.get_model("lfm2_8b_a1b", dtype=BF16, num_layers=3)
+    params = jax.eval_shape(lambda: model._init(jax.random.PRNGKey(0)))
+    slots, s_max = 128, 5120
+    with as_chip(chip):
+        engine = ServingEngine(model, params, max_slots=slots, s_max=s_max,
+                               kv_layout="paged", page_size=16,
+                               prefill_chunk=1024)
+        assert engine.decode_attn == "pallas"
+        pool = engine.pool
+
+        def sds(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+
+        state = (sds(pool.positions), sds(pool.last_tokens),
+                 sds(pool.active), sds(pool.budgets), sds(pool.eos_ids))
+        decode = engine._decode.lower(
+            jax.tree.map(sds, params), sds(pool.k_pages),
+            sds(pool.v_pages), sds(pool.device_table()), *state,
+            jax.ShapeDtypeStruct((2,), jnp.uint32), window=s_max,
+            horizon=1).compile()
+        prefs = [jax.ShapeDtypeStruct(shape, BF16)
+                 for shape in pref_cache_shapes(model, 1024)]
+        scalar = jax.ShapeDtypeStruct((), jnp.int32)
+        insert = engine._insert_jit.lower(
+            sds(pool.k_pages), sds(pool.v_pages), *state, *prefs,
+            jax.ShapeDtypeStruct((1024 // 16,), jnp.int32),
+            *(scalar,) * 5).compile()
+    assert pool.k_pages.shape == (1, slots * 320 + 1, 16, 1024)
+    assert pool.v_pages.shape == (2, slots * 2, 16, 2048)
+    text = decode.as_text()
+    assert _mosaic_names(text) >= {"short_conv",
+                                   "gqa_paged_decode_attention_full"}
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert sum("short_conv" in line for line in calls) == 2
+    assert sum("gqa_paged_decode_attention_full" in line
+               for line in calls) == 1
+    pools = pool.k_pages.nbytes + pool.v_pages.nbytes
+    ring = pool.v_pages.nbytes
+    for program in (decode, insert):
+        mem = program.memory_analysis()
+        # temporaries far below one copy of the pools, and below one
+        # copy of the ring: both written in place
+        assert mem.temp_size_in_bytes < min(pools // 4, ring), mem
         assert mem.alias_size_in_bytes >= pools
 
 

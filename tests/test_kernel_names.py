@@ -7,7 +7,7 @@ reads it out of the compiled text). The benchmark's kernel metrics
 (``perf/layer_metrics/flash_*_ms.train.json``,
 ``paged_decode_attn_ms.serve.json``) match on these names, so the
 ledger can compare a kernel's time across PRs that rewrite what is
-around it. Here each of the eleven call sites is traced (nothing runs)
+around it. Here each of the twelve call sites is traced (nothing runs)
 and the name is read out of the jaxpr.
 """
 
@@ -31,6 +31,8 @@ da = importlib.import_module(
     "pytorch_multiprocessing_distributed_tpu.ops.pallas.decode_attention")
 ca = importlib.import_module(
     "pytorch_multiprocessing_distributed_tpu.ops.pallas.chunk_attention")
+sc = importlib.import_module(
+    "pytorch_multiprocessing_distributed_tpu.ops.pallas.short_conv")
 
 B, S, H, D, K1, PAGE = 2, 64, 2, 32, 3, 16
 
@@ -92,6 +94,17 @@ def _chunk():
         impl="pallas", interpret=True), args)
 
 
+def _short_conv():
+    """The conv's decode kernel over a ``[L, N * 2, ps, C]`` ring pool,
+    the layer an operand."""
+    args = (_sds((B, 3 * 128)), _sds((3, 128)),
+            _sds((2, B * 2, PAGE, 128)), _sds((B, 2), jnp.int32),
+            _sds((B,), jnp.int32))
+    return (lambda p, t, pool, table, pos: sc.short_conv(
+        p, t, pool, table, pos, layer=1, impl="pallas", interpret=True),
+        args)
+
+
 def _sgd():
     leaves = {"w": _sds((24, 40)), "b": _sds((40,))}
     return (lambda p, g, m: fused_sgd_apply(p, g, m, 0.1, interpret=True),
@@ -140,6 +153,7 @@ _SITES = [
     ("decode_attention.py latent paged", _mla,
      "mla_paged_decode_attention"),
     ("chunk_attention.py grouped chunk", _chunk, "gqa_chunk_attention"),
+    ("short_conv.py decode", _short_conv, "short_conv"),
     ("fused_update.py", _sgd, "fused_sgd_update"),
     ("ring_allreduce.py", _ring, "ring_all_reduce"),
     # the int8 variants go through the same call sites
@@ -167,9 +181,9 @@ def test_call_site_passes_its_stable_name(make, want):
 
 def test_kernel_metrics_match_the_names_the_kernels_carry():
     """The metric files that select by regex over ``mosaic:<name>``
-    (PR 25's three, PR 27's and PR 29's rooflines, PR 29's latent
-    kernel): each must pick out exactly its kernels."""
-    assert len(KERNEL_NAMES) == 10
+    (the kernels' times and rooflines): each must pick out exactly its
+    kernels."""
+    assert len(KERNEL_NAMES) == 11
     labels = ["mosaic:" + name for name in KERNEL_NAMES]
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     picked = {}
@@ -177,7 +191,8 @@ def test_kernel_metrics_match_the_names_the_kernels_carry():
                    "paged_decode_attn_ms.serve", "mla_decode_attn_ms.serve",
                    "mla_decode_attn_roofline.serve",
                    "paged_decode_attn_roofline.serve",
-                   "chunk_attn_ms.serve"):
+                   "chunk_attn_ms.serve", "short_conv_decode_ms.serve",
+                   "short_conv_roofline.serve"):
         with open(os.path.join(root, "perf", "layer_metrics",
                                metric + ".json")) as fh:
             rx = re.compile(json.load(fh)["args"]["match"])
@@ -193,4 +208,6 @@ def test_kernel_metrics_match_the_names_the_kernels_carry():
         "mla_decode_attn_roofline.serve": {
             "mosaic:mla_paged_decode_attention"},
         "chunk_attn_ms.serve": {"mosaic:gqa_chunk_attention"},
+        "short_conv_decode_ms.serve": {"mosaic:short_conv"},
+        "short_conv_roofline.serve": {"mosaic:short_conv"},
     }

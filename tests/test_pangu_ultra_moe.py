@@ -665,10 +665,10 @@ def _top_k_over_the_held_only(model, monkeypatch):
     and routes over its own experts, not over all."""
     inner = latent.route_sigmoid_topk
 
-    def route(x, router, e_bias, top_k, scale):
+    def route(x, router, e_bias, top_k, scale, eps=1e-20):
         lo = model.expert_offset
         chosen, weights = inner(x, router[:, lo:lo + model.n_held], e_bias,
-                                top_k, scale)
+                                top_k, scale, eps)
         return chosen + lo, weights
 
     monkeypatch.setattr(latent, "route_sigmoid_topk", route)
@@ -678,8 +678,8 @@ def _top_k_over_the_held_only(model, monkeypatch):
 def _weights_normalised_over_the_held_only(model, monkeypatch):
     inner = latent.route_sigmoid_topk
 
-    def route(x, router, e_bias, top_k, scale):
-        chosen, weights = inner(x, router, e_bias, top_k, scale)
+    def route(x, router, e_bias, top_k, scale, eps=1e-20):
+        chosen, weights = inner(x, router, e_bias, top_k, scale, eps)
         lo = model.expert_offset
         here = (chosen >= lo) & (chosen < lo + model.n_held)
         mine = jnp.where(here, weights, 0.0)

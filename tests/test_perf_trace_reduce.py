@@ -35,8 +35,10 @@ def test_a_collector_pause_takes_the_gap_it_covers_and_no_other():
 # The two cases below replace the same-named cases of
 # ``perf/tests/test_trace_reduce.py`` in tier-1: those count the
 # manifest's program metrics, and ``chunk_attn_ms.serve`` (the grouped
-# chunk kernel's seconds in a prompt program) is one more. The
-# benchmark's own file takes the count in a ``benchmark`` PR.
+# chunk kernel's seconds in a prompt program) and
+# ``short_conv_decode_ms.serve`` (the conv kernel's in the decode
+# program) are two more. The benchmark's own file takes the count in a
+# ``benchmark`` PR.
 from perf import harness, readers  # noqa: E402
 from perf.spans import Recording  # noqa: E402
 from perf.tests.test_trace_reduce import _program_events  # noqa: E402
@@ -77,7 +79,7 @@ def test_the_program_metrics_match_the_names_the_engine_jits():
     specs = [harness.load_layer_metric(m["name"])
              for m in harness.load_manifest()["per_layer"]]
     specs = [m for m in specs if m["reducer"].startswith("program_")]
-    assert len(specs) == 6
+    assert len(specs) == 7
     for spec in specs:
         args = spec["args"]
         if "beside" in args:            # "every program but" the decode
@@ -110,4 +112,4 @@ def test_a_renamed_decode_program_drops_the_share_and_reads_no_100():
     read = {m["name"]: readers.read(m, rec) for m in specs
             if m["reducer"].startswith("program_")}
     assert read.pop("prefill_program_ms.serve") == pytest.approx(210e-6)
-    assert len(read) == 5 and set(read.values()) == {None}
+    assert len(read) == 6 and set(read.values()) == {None}
