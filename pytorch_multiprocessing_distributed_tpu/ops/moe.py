@@ -36,6 +36,10 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
+# a rung of row_ladder is a whole number of the kernel's row tiles
+from .pallas.grouped_matmul import _MAX_ROW_TILE as _ROW_TILE
+from .pallas.grouped_matmul import gated_grouped_matmul, grouped_matmul
+
 
 class MoEMlp(nn.Module):
     """Switch-style top-1 MoE feed-forward block.
@@ -278,11 +282,6 @@ def route_sigmoid_topk(x32, router, e_bias, top_k: int,
     return chosen.astype(jnp.int32), weights
 
 
-# a rung of the ladder below is a whole number of the row tiles the
-# TPU's grouped-matmul kernel works in
-_ROW_TILE = 128
-
-
 def row_ladder(t: int, k: int, held: int, n_experts: int) -> tuple:
     """The static row bounds :func:`dropless_experts` chooses among for
     ``t`` tokens at top-``k``, ``held`` of ``n_experts`` experts held:
@@ -315,16 +314,16 @@ def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, *,
 
     The ``T * k`` assignments are sorted by expert, this chip's first
     (stable, so a token's choices keep their order); each held expert's
-    rows then form one contiguous group from row 0 and the three
-    gated-SiLU matmuls are grouped matmuls over those groups
-    (``jax.lax.ragged_dot``: on the TPU a native kernel that reads only
-    the experts that have rows). The rows routed elsewhere lie behind
-    the last group, in no matmul — but the kernel's time follows the
-    rows it is GIVEN, in a group or not (1.76 ms a call at 1,024 rows
-    against 0.99 at 128, 64 of them held: PERF.md), and so do the
-    gather before it and the unsort after it. So the layer works on the
-    first ``B`` sorted rows only, ``B`` the smallest rung of
-    :func:`row_ladder` that is ``>= sum(counts)``, chosen ON THE DEVICE
+    rows then form one contiguous group from row 0 and the gated-SiLU
+    feed-forward is two grouped matmuls over those groups
+    (``ops/pallas/grouped_matmul.py``: on the TPU a kernel that streams
+    each expert that has rows once a call, gate and up in one call and
+    down in the other; elsewhere ``jax.lax.ragged_dot``). The rows
+    routed elsewhere lie behind the last group, in no matmul — but the
+    gather before the matmuls and the unsort after them follow the rows
+    they are GIVEN. So the layer works on the first ``B`` sorted rows
+    only, ``B`` the smallest rung of :func:`row_ladder` that is ``>=
+    sum(counts)``, chosen ON THE DEVICE
     from the counts it has (``jax.lax.switch``: no host read, every
     rung a static shape). In a 16-chip deployment the exchange hands a
     chip the rows of its own experts and no others; this is that chip.
@@ -367,13 +366,8 @@ def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, *,
         is among them) -> ``y [T, D]`` float32."""
         first = order[:b]
         rows = jnp.take(x, first // k, axis=0)           # [b, D]
-        gate = jax.lax.ragged_dot(rows, w_gate, counts,
-                                  preferred_element_type=jnp.float32)
-        up = jax.lax.ragged_dot(rows, w_up, counts,
-                                preferred_element_type=jnp.float32)
-        hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
-        out = jax.lax.ragged_dot(hidden, w_down, counts,
-                                 preferred_element_type=jnp.float32)
+        hidden = gated_grouped_matmul(rows, w_gate, w_up, counts)
+        out = grouped_matmul(hidden, w_down, counts)     # [b, D] f32
         out = out * jnp.take(weights.reshape(t * k), first)[:, None]
         if held < n_experts:
             # a row behind the last group is in no matmul, and what the

@@ -14,6 +14,9 @@ kernels cover the spots where hand scheduling buys something XLA can't:
 - :mod:`.chunk_attention` — a prompt chunk's grouped-query attention
   against one layer's cache (causal, an optional window and sink), K/V
   streamed once through VMEM over only the column blocks in reach.
+- :mod:`.grouped_matmul` — the routed experts' grouped matmul over
+  rows sorted by expert: each expert that has rows streams its matrix
+  once a call, gate and up fused into one call.
 - :mod:`.fused_update` — single-pass SGD(momentum, nesterov, wd) update:
   one read of (param, grad, buf), one write of (param, buf), aliased
   in-place in HBM.
@@ -29,8 +32,10 @@ compiler names the kernel's HLO instruction after it, so a profiler
 trace shows ``flash_attention_fwd.7`` rather than the flax module or
 jax wrapper the call happens to sit in, and ``perf/trace_reduce.py``
 labels it ``mosaic:flash_attention_fwd``. The benchmark's kernel
-metrics match on these names: letters and underscores, no trailing
-digit, and a rename is a change to what the ledger compares.
+metrics match on these names: letters and underscores (hyphens in
+``ragged-dot-experts``, which keeps the label of the compiler's
+grouped-matmul kernel it replaced under ``^mosaic:ragged-dot``), no
+trailing digit, and a rename is a change to what the ledger compares.
 """
 
 from .decode_attention import (  # noqa: F401

@@ -13,7 +13,9 @@ case calls the kernel with ``interpret=False`` itself.
 """
 
 import importlib
+import json
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -40,6 +42,8 @@ ca = importlib.import_module(
     "pytorch_multiprocessing_distributed_tpu.ops.pallas.chunk_attention")
 sc = importlib.import_module(
     "pytorch_multiprocessing_distributed_tpu.ops.pallas.short_conv")
+gm = importlib.import_module(
+    "pytorch_multiprocessing_distributed_tpu.ops.pallas.grouped_matmul")
 
 B, S, H, D = 8, 1024, 12, 64      # gpt_small serving: 8 slots, window 1024
 K1 = 5                             # --draft_k 4 verify block
@@ -87,7 +91,7 @@ KERNEL_NAMES = {
     "paged_verify_decode_attention", "fused_sgd_update", "ring_all_reduce",
     "mla_paged_decode_attention",
     "gqa_paged_decode_attention_full", "gqa_paged_decode_attention_window",
-    "gqa_chunk_attention", "short_conv",
+    "gqa_chunk_attention", "short_conv", "ragged-dot-experts",
 }
 
 
@@ -291,7 +295,44 @@ def _short_conv_case(slots=128, width=2048, page=16, layers=9):
         interpret=False), args)
 
 
-_CASES = _DECODE + [
+def _grouped_case(rows, d, f, held, gated):
+    """The routed experts' grouped matmul at a cell's call: ``rows``
+    sorted rows over ``held`` experts of ``D`` -> ``F`` (the gated form:
+    gate and up in one call) or ``F`` -> ``D`` (down), counts traced."""
+    if gated:
+        args = (_sds((rows, d), BF16), _sds((held, d, f), BF16),
+                _sds((held, d, f), BF16), _sds((held,), jnp.int32))
+        return (lambda x, a, b, c: gm.gated_grouped_matmul(
+            x, a, b, c, impl="pallas", interpret=False), args)
+    args = (_sds((rows, f), BF16), _sds((held, f, d), BF16),
+            _sds((held,), jnp.int32))
+    return (lambda x, a, c: gm.grouped_matmul(
+        x, a, c, impl="pallas", interpret=False), args)
+
+
+# the routed experts of the five expert cells at a decode step's and a
+# chunk's first rung of rows (held experts of D -> F): gate and up in
+# one call, down in another
+_GROUPED = [
+    pytest.param(lambda rows=rows, d=d, f=f, held=held, gated=gated:
+                 _grouped_case(rows, d, f, held, gated),
+                 id=f"grouped-{'gate-up' if gated else 'down'}-{cell}")
+    for cell, rows, d, f, held in (
+        ("lfm2-decode", 512, 2048, 1792, 32),
+        ("lfm2-chunk", 4096, 2048, 1792, 32),
+        ("xing4-decode", 256, 3584, 1024, 64),
+        ("xing4-chunk", 4096, 3584, 1024, 64),
+        ("trinity-decode", 128, 3072, 3072, 32),
+        ("trinity-chunk", 1024, 3072, 3072, 32),
+        ("openpangu-decode", 128, 7680, 2048, 16),
+        ("openpangu-chunk", 1024, 7680, 2048, 16),
+        ("mimo-decode", 128, 4096, 2048, 16),
+        ("mimo-chunk", 1024, 4096, 2048, 16))
+    for gated in (True, False)
+]
+
+
+_CASES = _DECODE + _GROUPED + [
     # the chunk of trinity-large-preview.serve.closed-8k1k: 1,024
     # queries, a bucket of 8,192 columns, 48 heads on 8 of 128; and of
     # mimo-v2.5.serve.closed-1k8k: a bucket of 1,024, 64 heads on 4
@@ -350,6 +391,21 @@ def test_kernel_compiles_for_v5e(topo, make):
     # after whatever wrapper the call sits in
     names = _mosaic_names(text)
     assert names and names <= KERNEL_NAMES, names
+
+
+def test_the_grouped_matmul_is_read_by_the_expert_metrics(topo):
+    """The kernel's label in a trace of the chip is what the two
+    routed-expert metrics match (``^mosaic:ragged-dot``): they read the
+    grouped expert matmuls whichever kernel implements them."""
+    fn, args = _grouped_case(512, 2048, 1792, 32, True)
+    text = _compile(fn, args, SingleDeviceSharding(topo.devices[0]))
+    assert _mosaic_names(text) == {"ragged-dot-experts"}
+    for name in ("routed_expert_matmul_ms.serve",
+                 "routed_expert_matmul_decode_ms.serve"):
+        with open(os.path.join(os.path.dirname(__file__), "..", "perf",
+                               "layer_metrics", name + ".json")) as f:
+            match = json.load(f)["args"]["match"]
+        assert re.match(match, MOSAIC + "ragged-dot-experts"), name
 
 
 def test_ring_all_reduce_compiles_on_four_chips(topo):
@@ -530,8 +586,9 @@ def test_lfm2_decode_and_insert_programs_write_both_pools_in_place(topo):
     closed-4k1k`` (published widths, 128 slots, window 5,120, pages of
     16, bfloat16; depth cut to the first three layers, two conv and one
     full, to stay in tier-1), through ``ServingEngine``: the conv kernel
-    runs once a conv layer and the grouped kernel once a full layer
-    inside the decode program, and both programs write the donated full
+    runs once a conv layer, the grouped kernel once a full layer and
+    the grouped expert matmul twice an expert layer inside the decode
+    program, and both programs write the donated full
     pool and conv ring in place."""
     from perf.rehearse import as_chip
     from pytorch_multiprocessing_distributed_tpu import models
@@ -577,6 +634,8 @@ def test_lfm2_decode_and_insert_programs_write_both_pools_in_place(topo):
     assert sum("short_conv" in line for line in calls) == 2
     assert sum("gqa_paged_decode_attention_full" in line
                for line in calls) == 1
+    # the one expert layer: gate and up in one grouped call, down in one
+    assert sum("ragged-dot-experts" in line for line in calls) == 2
     pools = pool.k_pages.nbytes + pool.v_pages.nbytes
     ring = pool.v_pages.nbytes
     for program in (decode, insert):
